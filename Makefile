@@ -31,8 +31,8 @@ fast: ## Sub-2-minute smoke tier (curated module list: tests/conftest.py FAST_MO
 	$(PYTHON) -m pytest tests/ -q -m fast
 
 .PHONY: test-tpu
-test-tpu: ## Hardware kernel tests on a real TPU (interpret=False, bench shapes).
-	FUSIONINFER_TEST_TPU=1 $(PYTHON) -m pytest tests/test_kernels_tpu.py -x -q
+test-tpu: ## Hardware kernel tests (interpret=False, serving shapes): a chip-tool command — fails, not skips, without a TPU.
+	FUSIONINFER_TEST_TPU=1 $(PYTHON) -m pytest tests/test_kernels_tpu.py -q
 
 KIND_CLUSTER ?= fusioninfer-tpu-e2e
 
@@ -60,7 +60,7 @@ autoscale: ## Autoscaling suite (fake-clock control-loop + drain + chaos; docs/d
 lint: ## Gating lint: fusionlint (all thirteen passes incl. trace-boundary + thread-safety, JSON archived to dist/lint.json) + fault-site coverage + byte-compile (CI adds ruff).
 	$(PYTHON) -m tools.fusionlint --json-out dist/lint.json
 	$(PYTHON) tools/check_fault_sites.py
-	$(PYTHON) -m compileall -q fusioninfer_tpu tests tools bench.py __graft_entry__.py
+	$(PYTHON) -m compileall -q fusioninfer_tpu tests tools bench.py chip_smoke.py __graft_entry__.py
 
 .PHONY: lint-changed
 lint-changed: ## Fast pre-commit lint: fusionlint over files differing from HEAD only.
@@ -83,12 +83,12 @@ verify-manifests: ## Regenerate CRDs/config from the Python sources in memory, f
 	$(PYTHON) tools/verify_manifests.py
 
 .PHONY: bench
-bench: ## One-line JSON decode-throughput benchmark (real chip if present).
+bench: ## One-line JSON decode-throughput benchmark on the accelerator (fails without one; BENCH_PLATFORM=cpu = bench-smoke).
 	$(PYTHON) bench.py
 	$(PYTHON) tools/check_bench_record.py BENCH_OUT.json
 
 .PHONY: bench-smoke
-bench-smoke: ## CPU bench smoke + record gates: ceiling_fraction/scheduler fields, tp=2 sharedprefix leg, AOT warm start (warm >= 3x cold, cache hits).
+bench-smoke: ## CPU bench smoke + record gates: ceiling_fraction/scheduler fields, tp=2 sharedprefix leg.
 	BENCH_PLATFORM=cpu $(PYTHON) bench.py
 	$(PYTHON) tools/check_bench_record.py BENCH_OUT.json
 
@@ -100,6 +100,10 @@ fleet-smoke: ## Closed-loop fleet smoke (CPU, 3 engines + PD pair): real manager
 .PHONY: dryrun
 dryrun: ## Multichip sharding dry-run on 8 virtual CPU devices.
 	$(PYTHON) __graft_entry__.py 8
+
+.PHONY: chip-smoke
+chip-smoke: ## engine serve qwen3-1.7b on the chip, end to end: a chip-tool command (python chip_smoke.py --cpu-dry-run = CPU control-flow check).
+	$(PYTHON) chip_smoke.py
 
 ##@ Render
 

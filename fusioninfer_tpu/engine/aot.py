@@ -6,13 +6,14 @@ JIT compilation before its first token — scale-up latency was compile
 latency.  This module finishes what the PR 7 test-tier XLA cache
 started, in three pieces:
 
-* **One persistent cache, one env knob.** :func:`configure_cache`
-  points jax's persistent compilation cache at the directory named by
-  ``FUSIONINFER_AOT_CACHE`` (default ``/tmp/fusioninfer-xla-cache`` —
-  the same directory, resolution order and code path the test tier uses
-  via ``tests/conftest.py``, so warm test runs and warm pods exercise
-  the same machinery).  An explicit ``JAX_COMPILATION_CACHE_DIR`` wins,
-  matching jax's own convention.
+* **One persistent cache, placed from outside.** :func:`configure_cache`
+  points jax's persistent compilation cache (and the AOT manifests
+  below) at ``JAX_COMPILATION_CACHE_DIR`` where that is set — jax's own
+  variable, so every process of the program and every child it starts
+  agree without a second knob — and otherwise at one fixed, git-ignored
+  directory inside the checkout (:data:`DEFAULT_CACHE_DIR`).  The path
+  never comes from a temporary name, a pid or the time: a cache that
+  moves never hits.
 
 * **AOT build of every serving entry point.** :func:`warmup` walks the
   engine's :meth:`~fusioninfer_tpu.engine.engine.NativeEngine.
@@ -36,9 +37,10 @@ started, in three pieces:
   gate the result.
 
 Wire-up: ``fusioninfer-tpu engine serve --aot-warmup`` (and the
-``engine warmup`` subcommand that builds the cache and exits), the
-bench's cold/warm subprocess measurement, and fleetsim's scale-up /
-revocation replacement pods (``docs/design/parallelism.md``).
+``engine warmup`` subcommand that builds the cache and exits),
+``chip_smoke.py`` (run twice against one directory: misses, then hits)
+and fleetsim's scale-up / revocation replacement pods
+(``docs/design/parallelism.md``).
 """
 
 from __future__ import annotations
@@ -52,40 +54,32 @@ from typing import Callable, Iterable, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
-# THE env knob (shared with tests/conftest.py): directory of the
-# persistent compile cache + AOT manifests.  Empty/unset falls back to
-# jax's own JAX_COMPILATION_CACHE_DIR, then the shared default below.
-ENV_CACHE_DIR = "FUSIONINFER_AOT_CACHE"
-DEFAULT_CACHE_DIR = "/tmp/fusioninfer-xla-cache"
+# jax's own variable names the directory from outside; unset, the cache
+# lives at this fixed path in the checkout (listed in .gitignore)
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 # one warmup entry: (name, thunk) — the thunk lowers AND compiles the
 # entry point at a concrete serving signature
 Signature = Tuple[str, Callable[[], object]]
 
 
-def resolve_cache_dir(explicit: Optional[str] = None) -> Optional[str]:
-    """Cache-dir resolution order (ONE scheme for tests and pods):
-    explicit argument > ``FUSIONINFER_AOT_CACHE`` > jax's own
-    ``JAX_COMPILATION_CACHE_DIR`` > the shared default.  Returns None
-    when the knob is explicitly disabled (``FUSIONINFER_AOT_CACHE=0``).
-    """
-    for cand in (explicit, os.environ.get(ENV_CACHE_DIR),
-                 os.environ.get("JAX_COMPILATION_CACHE_DIR"),
-                 DEFAULT_CACHE_DIR):
-        if cand == "0":
-            return None
-        if cand:
-            return cand
-    return None
+def resolve_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR`` where it is set, else the fixed
+    in-checkout default — ONE rule for serve, warmup, bench, smoke,
+    their children and the test tier."""
+    return os.environ.get(ENV_CACHE_DIR) or DEFAULT_CACHE_DIR
 
 
-def configure_cache(cache_dir: Optional[str] = None,
-                    min_compile_seconds: Optional[float] = None
-                    ) -> Optional[str]:
+def configure_cache(min_compile_seconds: Optional[float] = None) -> Optional[str]:
     """Point jax's persistent compilation cache at the resolved
-    directory; returns the directory actually configured (None when
-    disabled or unusable — a read-only /tmp must degrade to uncached,
-    never crash the server).
+    directory and return it.  A directory named from outside
+    (``JAX_COMPILATION_CACHE_DIR``) that cannot be used is an error —
+    whoever placed the cache must hear that nothing is being kept;
+    only the in-checkout default degrades to uncached (None) with a
+    warning, since a read-only install has nowhere else to go.
 
     ``min_compile_seconds`` sets the persistence threshold; ``None``
     leaves the process's active threshold untouched.  Only
@@ -96,19 +90,22 @@ def configure_cache(cache_dir: Optional[str] = None,
     owner's threshold."""
     import jax
 
-    path = resolve_cache_dir(cache_dir)
-    if not path:
-        return None
+    path = resolve_cache_dir()
     try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        if min_compile_seconds is not None:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              min_compile_seconds)
-    except Exception as e:  # noqa: BLE001 - cache is an optimization
+        if not os.access(path, os.W_OK | os.X_OK):
+            raise PermissionError(f"{path} is not writable")
+    except OSError as e:
+        if os.environ.get(ENV_CACHE_DIR):
+            raise RuntimeError(
+                f"{ENV_CACHE_DIR}={path} is unusable: {e}") from e
         logger.warning("persistent compile cache unavailable at %s: %s",
                        path, e)
         return None
+    jax.config.update("jax_compilation_cache_dir", path)
+    if min_compile_seconds is not None:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          min_compile_seconds)
     return path
 
 
@@ -212,8 +209,7 @@ def _write_manifest(cache_dir: Optional[str], fp: str,
         logger.warning("AOT manifest write failed: %s", e)
 
 
-def warmup(engine, cache_dir: Optional[str] = None,
-           signatures: Optional[Iterable[Signature]] = None,
+def warmup(engine, signatures: Optional[Iterable[Signature]] = None,
            force: bool = False) -> dict:
     """Build (or load) the compiled-executable cache for ``engine``
     BEFORE admission opens; returns the warmup report and stamps it on
@@ -228,7 +224,7 @@ def warmup(engine, cache_dir: Optional[str] = None,
     honest wall time — a warm pod's evidence is hits > 0 AND a small
     build_seconds; ``force=True`` rebuilds hits too (cache repair)."""
     t0 = time.perf_counter()
-    path = configure_cache(cache_dir)
+    path = configure_cache()
     fp = fingerprint(engine)
     prior = _load_manifest(path, fp)
     sigs = list(signatures if signatures is not None
@@ -247,10 +243,11 @@ def warmup(engine, cache_dir: Optional[str] = None,
             compiled = getattr(lowered, "compile", None)
             if compiled is not None:
                 compiled()
-        except Exception as e:  # noqa: BLE001 - one bad signature must
-            # not abort the warmup: the entry just stays cold and the
-            # first real request compiles it (the pre-AOT behavior)
-            errors.append(f"{name}: {type(e).__name__}: {str(e)[:200]}")
+        except Exception as e:  # noqa: BLE001 - collect every failing
+            # entry before the caller decides: the serve path treats
+            # any error as fatal, and one report naming all the refused
+            # signatures beats dying on the first
+            errors.append(f"{name}: {type(e).__name__}: {str(e)[:600]}")
             continue
         entries[name] = round(time.perf_counter() - t1, 4)
         misses += 1

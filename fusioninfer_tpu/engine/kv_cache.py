@@ -147,22 +147,24 @@ def auto_cache_config(
     Without prefix caching the pool stays demand-sized: extra pages could
     never be allocated.
 
-    Falls back to request-shaped sizing when HBM stats are unavailable
-    (CPU tests).  With tensor parallelism both weights and KV heads are
-    sharded, so per-device cost divides by ``tp`` on both sides of the
-    subtraction.
+    The CPU reports no memory limit and gets request-shaped sizing; an
+    accelerator that reports none is an error, not a fallback — a pool
+    sized blind either wastes the chip or OOMs it mid-serving.  With
+    tensor parallelism both weights and KV heads are sharded, so
+    per-device cost divides by ``tp`` on both sides of the subtraction.
     """
     pages_per_seq = max(1, -(-max_model_len // page_size))
     min_pages = pages_per_seq * max_batch_size + 1
     if hbm_bytes is None:
-        try:
-            # local_devices: under multi-process serving, devices()[0] is
-            # the leader's device and MemoryStats on a non-addressable
-            # device raises on every follower
-            stats = jax.local_devices()[0].memory_stats() or {}
-        except jax.errors.JaxRuntimeError:
-            stats = {}
-        hbm_bytes = stats.get("bytes_limit")
+        # local_devices: under multi-process serving, devices()[0] is
+        # the leader's device and MemoryStats on a non-addressable
+        # device raises on every follower
+        device = jax.local_devices()[0]
+        hbm_bytes = (device.memory_stats() or {}).get("bytes_limit")
+        if not hbm_bytes and device.platform != "cpu":
+            raise RuntimeError(
+                f"{device.device_kind} reports no bytes_limit: cannot size "
+                "the KV page pool from device memory")
     n_pages = min_pages
     if hbm_bytes:
         budget = int(hbm_bytes * hbm_utilization) - model_param_bytes(cfg) // tp

@@ -1967,7 +1967,12 @@ class EngineServer:
                         # readiness gate: the LB must stop routing here
                         self._send_json({"status": "draining"}, 503)
                     else:
-                        self._send_json({"status": "ok"})
+                        # what the engine resolved for its device rides
+                        # the readiness answer (NativeEngine.runtime_info)
+                        info = getattr(server.engine, "runtime_info", None)
+                        self._send_json(
+                            {"status": "ok",
+                             **({"engine": info()} if info else {})})
                 elif self.path == "/metrics":
                     data = server.metrics.render(server.engine).encode()
                     self.send_response(200)
@@ -2521,13 +2526,15 @@ def serve_from_args(args) -> int:
     from fusioninfer_tpu.engine import aot
 
     aot_warm = getattr(args, "aot_warmup", True)
-    aot_cache = getattr(args, "aot_cache", "") or None
     if aot_warm:
         # 0.0: every warmup build persists (this process owns the knob)
-        aot.configure_cache(aot_cache, min_compile_seconds=0.0)
+        aot.configure_cache(min_compile_seconds=0.0)
     maybe_init_distributed()
     import jax
 
+    from fusioninfer_tpu.ops.dispatch import require_requested_backend
+
+    require_requested_backend()
     engine, model_name = _engine_from_args(args)
     slo_tiers = None
     slo_tiers_raw = getattr(args, "slo_tiers", "") or ""
@@ -2548,8 +2555,17 @@ def serve_from_args(args) -> int:
         else:
             # build (or load) the compiled-executable cache BEFORE
             # admission opens: a warm pod's first request never waits
-            # on XLA (docs/design/parallelism.md)
-            aot.warmup(engine, cache_dir=aot_cache)
+            # on XLA (docs/design/parallelism.md).  A signature the
+            # compiler refuses is fatal here: the engine would turn it
+            # into a failed request and keep serving, so a user would
+            # be the first to see it
+            report = aot.warmup(engine)
+            if report["errors"]:
+                raise SystemExit(
+                    "AOT warmup failed for %d of %d entry points:\n  %s" % (
+                        len(report["errors"]),
+                        len(report["errors"]) + report["entries"],
+                        "\n  ".join(report["errors"])))
     server = EngineServer(
         model=model_name,
         host=args.host,
@@ -2745,15 +2761,17 @@ def warmup_from_args(args) -> int:
                         format="%(asctime)s %(levelname)s %(name)s %(message)s")
     from fusioninfer_tpu.engine import aot
 
-    aot_cache = getattr(args, "aot_cache", "") or None
-    aot.configure_cache(aot_cache, min_compile_seconds=0.0)
+    aot.configure_cache(min_compile_seconds=0.0)
     maybe_init_distributed()
     import jax
 
+    from fusioninfer_tpu.ops.dispatch import require_requested_backend
+
+    require_requested_backend()
     if jax.process_count() > 1:
         raise SystemExit("engine warmup is single-process (run it on "
                          "the leader's image before scaling)")
     engine, _ = _engine_from_args(args)
-    report = aot.warmup(engine, cache_dir=aot_cache)
+    report = aot.warmup(engine)
     print(json.dumps(report, sort_keys=True))
     return 0 if not report["errors"] else 1

@@ -1,7 +1,7 @@
 """FLEET evidence record: schema + builder (docs/design/fleet-sim.md).
 
-``FLEET_r0N.json`` is the fleet-level sibling of ``BENCH_r0N.json``:
-per-phase TTFT/TPOT percentiles and per-stratum percentiles, the scale
+``FLEET_r0N.json`` is the fleet-level evidence record: per-phase
+TTFT/TPOT percentiles and per-stratum percentiles, the scale
 events the autoscaler actually applied, the fault ledger (every armed
 site with its fired counts), the prefix-hit-rate window per phase, and
 an ``slo`` block whose fields are the acceptance criteria themselves —

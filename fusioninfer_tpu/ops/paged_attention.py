@@ -27,7 +27,7 @@ coalesced):
 * **coalesced** (default): grid ``(B,)`` — one program per sequence
   DMAs each page once for ALL KV heads (``k_pages.at[:, page]`` →
   ``[KV, ps, Hd]``, slot scratch ``[2, KV, ps, Hd]``).  KV× fewer DMA
-  issues; measured +10%/+28% full-model decode at short/ragged contexts.
+  issues.
 * **per-head**: grid ``(B, KV)`` — the ``G = H // KV`` query heads of a
   group attend together, one ``[ps, Hd]`` copy per (sequence, head).
 
@@ -114,13 +114,16 @@ def _split_rest(rest, quantized):
     return None, o_ref, k_buf, v_buf, None, sem
 
 
-# conservative VMEM ceiling for the coalesced grid's double-buffered
-# page scratch: VMEM is ~16 MiB/core on current TPU generations (pallas
-# guide), and the kernel also needs its q/out blocks plus compiler
-# temporaries — so the scratch may take at most half.  Oversized
-# configurations (huge page_size × Hd × KV products) fall back to the
-# per-head grid, whose per-slot scratch is KV× smaller, instead of
-# failing Mosaic allocation at trace time.
+# VMEM ceiling for the coalesced grids' double-buffered page scratch
+# plus their q/out tiles and partial blocks, as the *_fits_vmem guards
+# count them.  No pallas_call here sets ``vmem_limit_bytes``, so the
+# bound that matters is Mosaic's default scoped-VMEM limit: compiled
+# for a v5e target (libtpu 0.0.34), footprints of 8.5 MiB by this
+# arithmetic are accepted and 16 MiB is refused ("Ran out of memory in
+# memory space vmem") — 8 MiB leaves the compiler's own temporaries
+# their room.  Oversized configurations (huge page_size × Hd × KV
+# products) fall back to the per-head grid, whose per-slot scratch is
+# KV× smaller, instead of failing Mosaic allocation at trace time.
 _COALESCE_VMEM_SCRATCH_BUDGET = 8 * 1024 * 1024
 
 
@@ -1182,10 +1185,9 @@ def ragged_paged_attention(
         from fusioninfer_tpu.ops import dispatch
 
         coalesce = dispatch.decode_coalesce()
-    if coalesce and not ragged_fits_vmem(
-            block_q, page_size, Hd, KV, G, q.dtype, k_pages.dtype,
-            v_pages.dtype, quantized):
-        coalesce = False
+    coalesce = resolve_ragged_grid(
+        page_size, Hd, KV, G, q.dtype, k_pages.dtype, v_pages.dtype,
+        quantized, coalesce=coalesce, block_q=block_q)[0] == "coalesced"
     # pad the flat axis to a tile multiple; padding tokens belong to no
     # row (their output is sliced off below)
     Tp = -(-T // block_q) * block_q
@@ -1328,6 +1330,33 @@ def kvsplit_fits_vmem(block_q: int, page_size: int, Hd: int, kv_heads: int,
     return pages + q_tile + partials <= budget
 
 
+def resolve_ragged_grid(page_size: int, Hd: int, kv_heads: int, group: int,
+                        q_dtype, k_dtype, v_dtype, quantized: bool, *,
+                        coalesce: bool, kv_splits: int = 0,
+                        block_q: int = RAGGED_BLOCK_Q) -> tuple[str, int]:
+    """The grid a ragged dispatch takes after the VMEM guards:
+    ``(layout, splits)`` with layout ``"coalesced"`` or ``"per-head"``
+    and ``splits`` the KV-split program count (0 = single walk).  The
+    ONE place the demotions are decided — the kernel wrappers dispatch
+    on it and the engine reports it, so what a server says it runs is
+    what it traced."""
+    shape = (block_q, page_size, Hd, kv_heads, group, q_dtype, k_dtype,
+             v_dtype, quantized)
+    if kv_splits > 0:
+        # the KV-split grid is coalesced-only; configurations its
+        # scratch + partials would blow demote to the single-walk grid
+        # (whose own guard may further demote to per-head)
+        S = max(1, min(int(kv_splits), KV_SPLIT_CHUNKS))
+        while KV_SPLIT_CHUNKS % S:
+            S -= 1
+        if kvsplit_fits_vmem(*shape, S):
+            return "coalesced", S
+        coalesce = True
+    if coalesce and ragged_fits_vmem(*shape):
+        return "coalesced", 0
+    return "per-head", 0
+
+
 def _ragged_kernel_kvsplit(
     # scalar prefetch (the single-walk ragged layout)
     page_tables_ref,  # [R, mp] int32 (SMEM)
@@ -1426,14 +1455,11 @@ def ragged_paged_attention_kvsplit(
     G = H // KV
     sm_scale = sm_scale if sm_scale is not None else Hd ** -0.5
     quantized = k_scales is not None
-    S = max(1, min(int(kv_splits), KV_SPLIT_CHUNKS))
-    while KV_SPLIT_CHUNKS % S:
-        S -= 1
-    if not kvsplit_fits_vmem(block_q, page_size, Hd, KV, G, q.dtype,
-                             k_pages.dtype, v_pages.dtype, quantized, S):
-        # the KV-split grid is coalesced-only; configurations its
-        # scratch + partials would blow demote to the single-walk grid
-        # (whose own guard may further demote to per-head)
+    _, S = resolve_ragged_grid(
+        page_size, Hd, KV, G, q.dtype, k_pages.dtype, v_pages.dtype,
+        quantized, coalesce=True, kv_splits=max(1, int(kv_splits)),
+        block_q=block_q)
+    if not S:
         return ragged_paged_attention(
             q, k_pages, v_pages, page_tables, row_starts, q_begins,
             q_lens, k_scales, v_scales, sm_scale=sm_scale,
@@ -1495,13 +1521,8 @@ def ragged_paged_attention_kvsplit(
     # the split axis carries no cross-program dependency (each program
     # owns distinct chunk blocks): declare it parallel so Mosaic may
     # partition it across cores where the part exposes more than one
-    # (megacore generations); ignored in interpret mode, harmless on a
-    # single-TensorCore v5e, where the win is the per-program page
-    # chains pipelining instead of one serial chain
-    extra = {}
-    if hasattr(pltpu, "TPUCompilerParams"):
-        extra["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+    # (megacore generations); on a single-TensorCore v5e the point is
+    # the per-program page chains pipelining instead of one serial chain
     acc_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1511,7 +1532,8 @@ def ragged_paged_attention_kvsplit(
             jax.ShapeDtypeStruct((chunks, Tp, KV, G), jnp.float32),
         ),
         interpret=interpret,
-        **extra,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(*operands)
     # the cross-chunk combine: a strict left-to-right fold at the fixed
     # chunk granularity (bit-identical whatever kv_splits computed the

@@ -27,7 +27,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from fusioninfer_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 
 from fusioninfer_tpu.ops.flash_attention import flash_attention

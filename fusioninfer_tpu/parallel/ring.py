@@ -19,11 +19,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh
+
 from fusioninfer_tpu.parallel.axes import default_rules
-from fusioninfer_tpu.utils import jax_compat
-from fusioninfer_tpu.utils.jax_compat import shard_map
 
 NEG_INF = -1e30
 
@@ -78,7 +77,7 @@ def ring_attention_local(
     B, S, H, Hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    n = jax_compat.axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
 
     q_pos = me * S + jnp.arange(S)

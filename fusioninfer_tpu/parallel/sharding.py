@@ -198,6 +198,25 @@ def kv_scale_spec(rules: AxisRules | None = None):
         "layers", "kv", "pages", None, "page")
 
 
+def sharded_kv_cache(cfg: ModelConfig, cache_cfg, mesh: Mesh,
+                     rules: AxisRules | None = None) -> dict:
+    """Allocate the paged KV cache directly in its sharded layout.  The
+    pool is sized per DEVICE (``auto_cache_config(tp=...)``), so the
+    whole of it does not fit one device: building it unsharded and
+    ``device_put``-ing it afterwards exhausts device 0 on a real slice."""
+    from fusioninfer_tpu.engine.kv_cache import init_kv_cache
+
+    def build():
+        return init_kv_cache(cfg, cache_cfg)
+
+    shardings = {
+        name: jax.sharding.NamedSharding(
+            mesh, kv_scale_spec(rules) if name.endswith("_scale")
+            else kv_cache_spec(rules))
+        for name in jax.eval_shape(build)}
+    return jax.jit(build, out_shardings=shardings)()
+
+
 def shard_params(cfg: ModelConfig, mesh: Mesh, params: Params,
                  rules: AxisRules | None = None) -> Params:
     """Place an existing (host/replicated) param pytree onto the mesh —

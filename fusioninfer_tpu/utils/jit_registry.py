@@ -326,6 +326,11 @@ ENTRY_POINTS: dict[str, dict] = {
         "family": "model",
         "runtime": None,
     },
+    "fusioninfer_tpu/parallel/sharding.py::sharded_kv_cache": {
+        "kind": "factory-jit",
+        "family": "model",
+        "runtime": None,
+    },
     "fusioninfer_tpu/parallel/ring.py::make_ring_attention": {
         "kind": "factory-jit",
         "family": "model",

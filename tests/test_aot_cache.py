@@ -1,17 +1,18 @@
-"""AOT warm start: cache keying, warmup accounting, metrics surface.
+"""AOT warm start: cache placement, keying, warmup accounting, metrics.
 
-The warm-start contract (docs/design/parallelism.md): one env knob and
-one resolution order shared with the test tier's persistent cache, a
-fingerprint covering everything that changes the compiled executables,
-a warmup whose manifest turns a twin pod's build into a load (hits,
-~zero build seconds), and the ``fusioninfer:aot_cache_*`` /
-``cold_start_to_first_token_s`` metrics the bench and fleetsim gates
-read.  The cold-vs-warm WALL-CLOCK proof lives in the bench
-(``run_warm_start``: two subprocesses against one fresh cache dir,
-gated >= 3x by check_bench_record) — subprocess spawns are too heavy
-for tier-1."""
+The warm-start contract (docs/design/parallelism.md): the cache lives
+where ``JAX_COMPILATION_CACHE_DIR`` says, else at one fixed git-ignored
+path in the checkout; a fingerprint covers everything that changes the
+compiled executables; a warmup's manifest turns a twin pod's build into
+a load (hits, ~zero build seconds); a warm-up error is fatal on the
+serve path; and the ``fusioninfer:aot_cache_*`` /
+``cold_start_to_first_token_s`` metrics are what ``chip_smoke.py`` and
+the fleetsim gate read.  Cold-versus-warm on a device is
+``chip_smoke.py`` twice against one directory."""
 
 import json
+import os
+import subprocess
 
 import pytest
 
@@ -20,6 +21,11 @@ from fusioninfer_tpu.engine.engine import NativeEngine
 from fusioninfer_tpu.engine.kv_cache import CacheConfig
 from fusioninfer_tpu.engine.metrics import EngineMetrics
 from fusioninfer_tpu.models.config import get_preset
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# spelled in pieces so this file is not itself a hit for the grep below
+RETIRED_KNOB = "FUSIONINFER_" + "AOT_CACHE"
+RETIRED_DEFAULT = "fusioninfer-" + "xla-cache"
 
 
 def tiny_engine(**kw):
@@ -32,33 +38,73 @@ def tiny_engine(**kw):
     return NativeEngine(get_preset("qwen3-tiny"), **kw)
 
 
+@pytest.fixture
+def cache_dir(tmp_path, monkeypatch):
+    """A cache directory placed the only way there is: from outside,
+    through jax's own variable.  The process's jax config is restored
+    so the rest of the session keeps the tier's cache."""
+    import jax
+
+    prior = jax.config.jax_compilation_cache_dir
+    path = str(tmp_path / "aot")
+    monkeypatch.setenv(aot.ENV_CACHE_DIR, path)
+    yield path
+    jax.config.update("jax_compilation_cache_dir", prior)
+
+
 class TestCacheResolution:
-    def test_resolution_order(self, monkeypatch):
-        monkeypatch.setenv(aot.ENV_CACHE_DIR, "/tmp/from-env")
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/from-jax")
-        assert aot.resolve_cache_dir("/tmp/explicit") == "/tmp/explicit"
-        assert aot.resolve_cache_dir() == "/tmp/from-env"
-        monkeypatch.delenv(aot.ENV_CACHE_DIR)
-        assert aot.resolve_cache_dir() == "/tmp/from-jax"
+    def test_env_wins_and_default_is_fixed_in_checkout(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        # the retired knob is not consulted any more
+        monkeypatch.setenv(RETIRED_KNOB, "/tmp/retired-knob")
+        assert aot.resolve_cache_dir() == "/some/dir"
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert aot.resolve_cache_dir() == aot.DEFAULT_CACHE_DIR
+        # fixed: a function of where the checkout is, nothing else
+        assert aot.DEFAULT_CACHE_DIR == os.path.join(REPO, ".xla_cache")
 
-    def test_zero_disables(self, monkeypatch):
-        monkeypatch.setenv(aot.ENV_CACHE_DIR, "0")
-        assert aot.resolve_cache_dir() is None
+    def test_default_dir_is_git_ignored(self):
+        probe = os.path.join(aot.DEFAULT_CACHE_DIR, "jit_x-cache")
+        rc = subprocess.run(["git", "check-ignore", "-q", probe],
+                            cwd=REPO).returncode
+        assert rc == 0, ".xla_cache/ must be listed in .gitignore"
+
+    def test_configured_dir_holds_cache_and_manifest(self, cache_dir):
+        assert aot.configure_cache() == cache_dir
+        report = aot.warmup(tiny_engine(),
+                            signatures=[("ok/one", lambda: None)])
+        assert report["cache_dir"] == cache_dir
+        assert any(n.startswith("aot-manifest-")
+                   for n in os.listdir(cache_dir))
+
+    def test_unusable_named_dir_is_an_error(self, tmp_path, monkeypatch):
+        blocker = tmp_path / "a-file"
+        blocker.write_text("not a directory")
+        monkeypatch.setenv(aot.ENV_CACHE_DIR, str(blocker / "cache"))
+        with pytest.raises(RuntimeError, match="JAX_COMPILATION_CACHE_DIR"):
+            aot.configure_cache()
+
+    def test_unusable_default_degrades_to_uncached(self, tmp_path,
+                                                   monkeypatch):
+        blocker = tmp_path / "a-file"
+        blocker.write_text("not a directory")
+        monkeypatch.delenv(aot.ENV_CACHE_DIR, raising=False)
+        monkeypatch.setattr(aot, "DEFAULT_CACHE_DIR",
+                            str(blocker / "cache"))
         assert aot.configure_cache() is None
 
-    def test_conftest_and_warmup_share_the_knob(self):
-        """ONE keying scheme, ONE env knob: the test tier's persistent
-        cache (tests/conftest.py) and the production warmup resolve
-        through the same function and land on the same default dir."""
-        import inspect
-
-        import tests.conftest as c
-
-        src = inspect.getsource(c)
-        assert "configure_cache" in src
-        assert aot.DEFAULT_CACHE_DIR == "/tmp/fusioninfer-xla-cache"
+    def test_no_temporary_cache_names_in_tracked_code(self):
+        """The path never comes from a temporary name, a pid or the
+        time, and the retired knob and default are gone from everything
+        git tracks (the operator's metrics TLS dir is not a compile
+        cache)."""
+        out = subprocess.run(
+            ["git", "grep", "-n", "-e", "mkd" + "temp", "-e", RETIRED_KNOB,
+             "-e", RETIRED_DEFAULT, "--", "*.py", "Makefile"],
+            cwd=REPO, capture_output=True, text=True).stdout
+        hits = [ln for ln in out.splitlines()
+                if "fusioninfer-metrics-tls-" not in ln]
+        assert hits == []
 
 
 class TestFingerprint:
@@ -127,10 +173,9 @@ class TestSignatures:
 
 
 class TestWarmup:
-    def test_cold_build_then_twin_hits(self, tmp_path):
-        cache = str(tmp_path / "aot")
+    def test_cold_build_then_twin_hits(self, cache_dir, tmp_path):
         e = tiny_engine()
-        cold = aot.warmup(e, cache_dir=cache)
+        cold = aot.warmup(e)
         assert cold["misses"] == cold["entries"] > 0
         assert cold["hits"] == 0 and cold["errors"] == []
         assert e.aot_stats is cold
@@ -141,37 +186,56 @@ class TestWarmup:
         assert len(manifest["entries"]) == cold["entries"]
         # a twin engine (same fingerprint) loads instead of building
         twin = tiny_engine()
-        warm = aot.warmup(twin, cache_dir=cache)
+        warm = aot.warmup(twin)
         assert warm["hits"] == cold["entries"] and warm["misses"] == 0
         # the load is not a rebuild: orders of magnitude cheaper
         assert warm["build_seconds"] < max(1.0, cold["build_seconds"] / 3)
 
-    def test_fingerprint_drift_misses(self, tmp_path):
-        cache = str(tmp_path / "aot")
-        aot.warmup(tiny_engine(), cache_dir=cache)
-        drifted = aot.warmup(tiny_engine(max_batch_size=4),
-                             cache_dir=cache)
+    def test_fingerprint_drift_misses(self, cache_dir):
+        aot.warmup(tiny_engine())
+        drifted = aot.warmup(tiny_engine(max_batch_size=4))
         assert drifted["hits"] == 0 and drifted["misses"] > 0
 
-    def test_force_rebuilds_hits(self, tmp_path):
-        cache = str(tmp_path / "aot")
-        aot.warmup(tiny_engine(), cache_dir=cache)
-        forced = aot.warmup(tiny_engine(), cache_dir=cache, force=True)
+    def test_force_rebuilds_hits(self, cache_dir):
+        aot.warmup(tiny_engine())
+        forced = aot.warmup(tiny_engine(), force=True)
         assert forced["hits"] == 0 and forced["misses"] == forced["entries"]
 
-    def test_one_bad_signature_does_not_abort(self, tmp_path):
+    def test_report_names_every_bad_signature(self, cache_dir):
         def boom():
             raise RuntimeError("lowering exploded")
 
         e = tiny_engine()
         report = aot.warmup(
-            e, cache_dir=str(tmp_path / "aot"),
-            signatures=[("ok/trivial", lambda: None), ("bad/boom", boom)])
+            e, signatures=[("ok/trivial", lambda: None), ("bad/boom", boom),
+                           ("bad/boom2", boom)])
         assert report["entries"] == 1
-        assert len(report["errors"]) == 1
+        assert len(report["errors"]) == 2
         assert "bad/boom" in report["errors"][0]
 
-    def test_warmed_engine_streams_identically(self, tmp_path):
+    def test_serve_path_warmup_error_is_fatal(self, cache_dir, monkeypatch):
+        """``engine serve`` must not open admission over a signature
+        the compiler refused: the engine would turn it into a failed
+        request and keep serving."""
+        import argparse
+
+        from fusioninfer_tpu.engine import server
+
+        def refused(engine):
+            return {"entries": 3, "hits": 0, "misses": 3,
+                    "errors": ["fused/chunk-t64: MosaicError: no"]}
+
+        monkeypatch.setattr(server, "_engine_from_args",
+                            lambda args: (tiny_engine(), "qwen3-tiny"))
+        monkeypatch.setattr(aot, "warmup", refused)
+        monkeypatch.setattr(
+            server.EngineServer, "serve_forever",
+            lambda self: pytest.fail("served over a refused signature"))
+        with pytest.raises(SystemExit, match="fused/chunk-t64"):
+            server.serve_from_args(argparse.Namespace(
+                host="127.0.0.1", port=0, aot_warmup=True))
+
+    def test_warmed_engine_streams_identically(self, cache_dir):
         """Warmup must be invisible to outputs: greedy tokens from a
         warmed engine match an unwarmed twin bit-for-bit (AOT lowering
         executes nothing and donates nothing)."""
@@ -188,15 +252,14 @@ class TestWarmup:
             return toks
 
         warmed = tiny_engine()
-        aot.warmup(warmed, cache_dir=str(tmp_path / "aot"))
+        aot.warmup(warmed)
         assert drain(warmed) == drain(tiny_engine())
 
 
 class TestMetricsSurface:
-    def test_aot_families_render_after_warmup(self, tmp_path):
+    def test_aot_families_render_after_warmup(self, cache_dir):
         e = tiny_engine()
-        aot.warmup(e, cache_dir=str(tmp_path / "aot"),
-                   signatures=[("ok/one", lambda: None)])
+        aot.warmup(e, signatures=[("ok/one", lambda: None)])
         m = EngineMetrics("tiny")
         text = m.render(e)
         assert "fusioninfer:aot_cache_hits{" in text
@@ -251,47 +314,7 @@ class TestServerColdStartGauge:
         assert srv.boot_t0 is None
 
 
-class TestBenchChecker:
-    """check_bench_record's warm-start gate (tools side, no jax)."""
-
-    def _ws(self, **kw):
-        ws = {
-            "cold": {"cold_start_to_first_token_s": 15.0},
-            "warm": {"cold_start_to_first_token_s": 3.0,
-                     "aot": {"hits": 12, "misses": 0}},
-            "warm_speedup": 5.0,
-            "ceiling_fraction": 0.4,
-        }
-        ws.update(kw)
-        return ws
-
-    def test_good_record_passes(self):
-        from tools.check_bench_record import check_warm_start
-
-        assert check_warm_start({"warm_start": self._ws()}) == []
-
-    def test_missing_leg_flags(self):
-        from tools.check_bench_record import check_warm_start
-
-        assert check_warm_start({}) == ["warm_start leg missing"]
-
-    @pytest.mark.parametrize("mut,needle", [
-        ({"warm_speedup": 2.4}, ">= 3x"),
-        ({"warm": {"cold_start_to_first_token_s": 3.0,
-                   "aot": {"hits": 0, "misses": 0}}}, "hits"),
-        ({"warm": {"cold_start_to_first_token_s": 3.0,
-                   "aot": {"hits": 5, "misses": 2}}}, "misses"),
-        ({"ceiling_fraction": None}, "ceiling_fraction"),
-    ])
-    def test_degraded_records_flag(self, mut, needle):
-        from tools.check_bench_record import check_warm_start
-
-        ws = self._ws(**mut)
-        if mut.get("ceiling_fraction", 0) is None:
-            ws.pop("ceiling_fraction")
-        problems = check_warm_start({"warm_start": ws})
-        assert any(needle in p for p in problems), problems
-
+class TestFleetChecker:
     def test_fleet_checker_gates_warm_start(self):
         from tools.check_fleet_record import check_record
 

@@ -10,7 +10,6 @@ import pytest
 from fusioninfer_tpu.engine.engine import NativeEngine
 from fusioninfer_tpu.engine.kv_cache import CacheConfig
 from fusioninfer_tpu.models.config import get_preset
-from fusioninfer_tpu.utils.jax_compat import LEGACY_JAX
 
 CFG = get_preset("qwen3-tiny")
 CACHE = CacheConfig(n_pages=33, page_size=16, max_pages_per_seq=4)
@@ -104,9 +103,6 @@ class TestEmbeddings:
         assert len(results["e"]["data"]) == 1
 
 
-@pytest.mark.skipif(LEGACY_JAX, reason=(
-    "known jax-0.4 SPMD semantic gap (pjit donation sharding / EP "
-    "all-to-all numerics); passes on current jax, the CI pip image"))
 def test_embeddings_on_sharded_mesh():
     """A dp×tp mesh serves /v1/embeddings through the same SPMD forward
     as generation — results match the single-device engine (the r4-era

@@ -1105,7 +1105,7 @@ class TestJitRegistryPass:
         reg = tmp_path / "registry.py"
         reg.write_text(textwrap.dedent(registry_src))
         return JitRegistryPass(registry_path=str(reg),
-                               scan_modules=["*"], exempt=[])
+                               scan_modules=["*"])
 
     def test_unregistered_entry_point_flags(self, tmp_path):
         p = self._pass(tmp_path, "ENTRY_POINTS = {}\n")
@@ -1175,7 +1175,7 @@ class TestJitRegistryPass:
     def test_shard_map_site_detected(self, tmp_path):
         p = self._pass(tmp_path, "ENTRY_POINTS = {}\n")
         result = lint(tmp_path, """\
-            from fusioninfer_tpu.utils.jax_compat import shard_map
+            from jax import shard_map
 
             def wrapper_tp(mesh, q):
                 fn = shard_map(lambda x: x, mesh=mesh)

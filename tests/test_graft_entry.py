@@ -6,7 +6,6 @@ import jax
 import pytest
 
 from __graft_entry__ import _layout, dryrun_multichip, entry
-from fusioninfer_tpu.utils.jax_compat import LEGACY_JAX
 
 
 def test_entry_compiles_and_runs():
@@ -23,8 +22,5 @@ def test_layout_factors_device_count(n):
     assert layout.dp * layout.sp * layout.ep * layout.tp == n
 
 
-@pytest.mark.skipif(LEGACY_JAX, reason=(
-    "known jax-0.4 SPMD semantic gap (pjit donation sharding / EP "
-    "all-to-all numerics); passes on current jax, the CI pip image"))
 def test_dryrun_multichip_8():
     dryrun_multichip(8)

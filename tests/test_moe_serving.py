@@ -27,7 +27,6 @@ from fusioninfer_tpu.engine.kv_cache import CacheConfig
 from fusioninfer_tpu.engine.sampler import SamplingParams
 from fusioninfer_tpu.models.config import get_preset
 from fusioninfer_tpu.parallel import MeshConfig, build_mesh
-from fusioninfer_tpu.utils.jax_compat import LEGACY_JAX
 
 MOE = dataclasses.replace(get_preset("moe-tiny"), dtype="float32",
                           attn_impl="reference")
@@ -62,9 +61,6 @@ def ref_tokens():
     return _greedy(None)
 
 
-@pytest.mark.skipif(LEGACY_JAX, reason=(
-    "known jax-0.4 SPMD semantic gap (pjit donation sharding / EP "
-    "all-to-all numerics); passes on current jax, the CI pip image"))
 class TestEpShardedDecode:
     def test_ep2_tp2_token_identity(self, ref_tokens):
         mesh = build_mesh(MeshConfig(ep=2, tp=2).validate(4),

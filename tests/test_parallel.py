@@ -12,7 +12,6 @@ import pytest
 
 from fusioninfer_tpu.models.config import get_preset
 from fusioninfer_tpu.models.transformer import forward, init_params
-from fusioninfer_tpu.utils.jax_compat import LEGACY_JAX
 from fusioninfer_tpu.parallel import (
     MeshConfig,
     build_mesh,
@@ -108,9 +107,33 @@ def test_sharded_init_lands_sharded():
     assert shard_shapes == {(CFG.n_layers, CFG.d_model, CFG.n_heads * CFG.head_dim // 8)}
 
 
-@pytest.mark.skipif(LEGACY_JAX, reason=(
-    "known jax-0.4 SPMD semantic gap (pjit donation sharding / EP "
-    "all-to-all numerics); passes on current jax, the CI pip image"))
+@pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+def test_sharded_kv_cache_is_born_sharded(kv_dtype):
+    """The pool is sized per device, so it must never exist whole on
+    one: every leaf comes back with its KV-head axis split over tp and
+    each device holding only its heads (on the chip the unsharded
+    build-then-device_put exhausted device 0 at tp=4)."""
+    from fusioninfer_tpu.engine.kv_cache import CacheConfig, init_kv_cache
+    from fusioninfer_tpu.parallel.sharding import sharded_kv_cache
+
+    mesh = build_mesh(MeshConfig(tp=2), jax.devices()[:2])
+    cc = CacheConfig(n_pages=9, page_size=8, max_pages_per_seq=4,
+                     kv_dtype=kv_dtype)
+    cache = sharded_kv_cache(CFG, cc, mesh)
+    whole = jax.eval_shape(lambda: init_kv_cache(CFG, cc))
+    assert set(cache) == set(whole)
+    for name, leaf in cache.items():
+        assert leaf.shape == whole[name].shape
+        assert leaf.dtype == whole[name].dtype
+        shards = leaf.addressable_shards
+        assert len(shards) == 2
+        for shard in shards:
+            want = list(leaf.shape)
+            want[1] //= 2  # [L, KV/tp, ...]
+            assert list(shard.data.shape) == want
+        assert not np.asarray(leaf).any()
+
+
 def test_train_step_runs_and_descends():
     mesh = build_mesh(MeshConfig(dp=2, sp=2, tp=2))
     params = sharded_init(CFG, mesh, jax.random.PRNGKey(0))
@@ -134,9 +157,6 @@ def test_single_device_mesh_works():
     assert out.shape == (1, 8, CFG.vocab_size)
 
 
-@pytest.mark.skipif(LEGACY_JAX, reason=(
-    "known jax-0.4 SPMD semantic gap (pjit donation sharding / EP "
-    "all-to-all numerics); passes on current jax, the CI pip image"))
 def test_moe_sharded_forward_over_ep():
     cfg = get_preset("moe-tiny")
     mesh = build_mesh(MeshConfig(dp=1, sp=1, ep=2, tp=4))

@@ -44,12 +44,16 @@ def _launch_group(http_ports: tuple[int, int], coord_port: int,
     for idx, container in enumerate(containers):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)  # one CPU device per process
+        # no persistent compile cache in the group: on jax 0.9.0 a
+        # multi-process CPU run that LOADS its executables from the
+        # cache (a second run against a warm directory) deadlocks in
+        # its first collective — cold it passes in ~20 s
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
         env.update(_resolve_env(container, worker_index=idx))
         env.update({
             "LWS_LEADER_ADDRESS": "127.0.0.1",
             "FUSIONINFER_COORDINATOR_PORT": str(coord_port),
             "JAX_PLATFORMS": "cpu",
-            "FUSIONINFER_PLATFORM": "cpu",
             "PYTHONPATH": repo_root,
         })
         procs.append(subprocess.Popen(
@@ -58,7 +62,8 @@ def _launch_group(http_ports: tuple[int, int], coord_port: int,
              "--host", "127.0.0.1", "--port", str(http_ports[idx]),
              "--tensor-parallel-size", "2",
              "--max-batch-size", "4", "--max-model-len", "256",
-             "--page-size", "16", "--seed", "0"] + extra_args,
+             "--page-size", "16", "--seed", "0", "--no-aot-warmup"]
+            + extra_args,
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True, cwd=repo_root,
         ))

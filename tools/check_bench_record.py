@@ -147,7 +147,6 @@ def check_record(record: dict) -> list[str]:
         problems.append(
             "workload_sharedprefix_tp.tensor_parallel must be 2, got "
             f"{tp_leg.get('tensor_parallel')!r}")
-    problems += check_warm_start(record)
     return problems
 
 
@@ -185,48 +184,6 @@ def check_sharedprefix_leg(record: dict, leg: str) -> list[str]:
     return problems
 
 
-def check_warm_start(record: dict) -> list[str]:
-    """AOT warm-start gate (r12): cold vs warm start-to-first-token
-    through the real warmup path — the warm pod must be >= 3x faster
-    to its first token on the smoke box, with its executables
-    demonstrably loaded from the persisted cache (aot hits > 0,
-    misses == 0) and the warm-path ceiling_fraction re-measured."""
-    problems: list[str] = []
-    ws = record.get("warm_start")
-    if not isinstance(ws, dict):
-        return ["warm_start leg missing"]
-    if ws.get("error"):
-        return [f"warm_start errored: {ws['error']}"]
-    for pass_name in ("cold", "warm"):
-        val = (ws.get(pass_name) or {}).get("cold_start_to_first_token_s")
-        if not isinstance(val, (int, float)) or val <= 0:
-            problems.append(
-                f"warm_start.{pass_name}.cold_start_to_first_token_s "
-                f"missing or non-positive ({val!r})")
-    speedup = ws.get("warm_speedup")
-    if not isinstance(speedup, (int, float)) or speedup < 3.0:
-        problems.append(
-            "warm_start: warm start-to-first-token must be >= 3x faster "
-            f"than cold on the smoke box (warm_speedup={speedup!r}, "
-            f"cold={(ws.get('cold') or {}).get('cold_start_to_first_token_s')!r}s, "
-            f"warm={(ws.get('warm') or {}).get('cold_start_to_first_token_s')!r}s)")
-    aot = (ws.get("warm") or {}).get("aot") or {}
-    if not aot.get("hits"):
-        problems.append(
-            f"warm_start.warm.aot.hits must be nonzero, got "
-            f"{aot.get('hits')!r} — the warm pod never loaded the "
-            "persisted executables")
-    if aot.get("misses"):
-        problems.append(
-            f"warm_start.warm.aot.misses must be 0, got "
-            f"{aot.get('misses')!r} — the fingerprint drifted between "
-            "the cold build and the warm boot")
-    if "ceiling_fraction" not in ws:
-        problems.append("warm_start.ceiling_fraction (warm-path "
-                        "serving-gap re-measure) missing")
-    return problems
-
-
 def main(argv: list[str]) -> int:
     path = pathlib.Path(argv[1]) if len(argv) > 1 else (
         pathlib.Path(__file__).resolve().parent.parent / "BENCH_OUT.json")
@@ -242,8 +199,7 @@ def main(argv: list[str]) -> int:
             print(f"check_bench_record: {p}", file=sys.stderr)
         return 1
     print(f"check_bench_record: {path.name} carries ceiling_fraction + "
-          "scheduler budget fields, the tp sharedprefix leg, and the "
-          "AOT warm-start evidence (warm >= 3x cold, hits > 0)")
+          "scheduler budget fields and the tp sharedprefix leg")
     return 0
 
 

@@ -12,7 +12,8 @@ from __future__ import annotations
 
 # what `python -m tools.fusionlint` lints when no paths are given
 DEFAULT_TARGETS = [
-    "fusioninfer_tpu", "tests", "tools", "bench.py", "__graft_entry__.py",
+    "fusioninfer_tpu", "tests", "tools", "bench.py", "chip_smoke.py",
+    "__graft_entry__.py",
 ]
 
 # -- resilience pass ---------------------------------------------------
@@ -173,8 +174,6 @@ AOT_SIGNATURES_MODULE = "fusioninfer_tpu/engine/engine.py"
 # ad-hoc jits deliberately — only the package's entry points are the
 # compile-discipline surface)
 JIT_SCAN_MODULES = ["fusioninfer_tpu/*.py", "fusioninfer_tpu/*/*.py"]
-# the shard_map version shim re-exports shard_map by design
-JIT_SCAN_EXEMPT = ["fusioninfer_tpu/utils/jax_compat.py"]
 
 # sanctioned dynamic-dim helpers: a host int that passed through one of
 # these is SHAPE-DISCIPLINED (bounded compile-signature family); a raw

@@ -57,8 +57,7 @@ class JitRegistryPass(LintPass):
 
     def __init__(self,
                  registry_path: str | None = None,
-                 scan_modules: list[str] | None = None,
-                 exempt: list[str] | None = None):
+                 scan_modules: list[str] | None = None):
         self.registry_rel = (config.JIT_REGISTRY_MODULE
                              if registry_path is None else registry_path)
         path = pathlib.Path(self.registry_rel)
@@ -71,7 +70,6 @@ class JitRegistryPass(LintPass):
             self.registry = None  # reported in finalize
         self.scan_modules = (config.JIT_SCAN_MODULES
                              if scan_modules is None else scan_modules)
-        self.exempt = config.JIT_SCAN_EXEMPT if exempt is None else exempt
 
     def finalize(self, modules: list[Module]) -> list[Finding]:
         if self.registry is None:
@@ -81,9 +79,7 @@ class JitRegistryPass(LintPass):
                 "entry-point contract cannot be checked")]
         findings: list[Finding] = []
         seen: dict[str, tuple[Module, int]] = {}
-        scan = [m for m in modules
-                if m.matches(self.scan_modules)
-                and not m.matches(self.exempt)]
+        scan = [m for m in modules if m.matches(self.scan_modules)]
         # --changed safety: editing the registry FILE can invalidate
         # entries whose sites live in files outside the changed set (a
         # deleted entry's site, a retyped split).  When the registry
@@ -99,8 +95,7 @@ class JitRegistryPass(LintPass):
             for f in collect_files(roots):
                 extra = Module(f)
                 if (extra.rel in have or extra.tree is None
-                        or not extra.matches(self.scan_modules)
-                        or extra.matches(self.exempt)):
+                        or not extra.matches(self.scan_modules)):
                     continue
                 scan.append(extra)
         for mod in scan:

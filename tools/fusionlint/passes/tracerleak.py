@@ -61,7 +61,6 @@ class TracerLeakPass(LintPass):
                  hot_modules: dict[str, tuple[str, ...]] | None = None):
         self.scan_modules = (config.JIT_SCAN_MODULES
                              if scan_modules is None else scan_modules)
-        self.exempt = config.JIT_SCAN_EXEMPT
         self.hot_modules = (config.HOST_SYNC_MODULES
                             if hot_modules is None else hot_modules)
         self.analysis = ProvenanceAnalysis()
@@ -69,7 +68,7 @@ class TracerLeakPass(LintPass):
     def check_module(self, mod: Module) -> list[Finding]:
         findings: list[Finding] = []
         jitted: list[ast.AST] = []
-        if mod.matches(self.scan_modules) and not mod.matches(self.exempt):
+        if mod.matches(self.scan_modules):
             jitted = scan_module(mod).jitted_bodies
             for body in jitted:
                 findings.extend(self._check_jit_body(mod, body))
