@@ -54,6 +54,7 @@ from fusioninfer_tpu.engine.kv_cache import (
     init_kv_cache,
 )
 from fusioninfer_tpu.engine.fused import pack_ragged_batch, pow2_rows
+from fusioninfer_tpu.engine.metrics import TTFT_BUCKETS, Histogram
 from fusioninfer_tpu.engine.model_runner import (
     CTL_F_COLS,
     CTL_I_COLS,
@@ -82,6 +83,7 @@ from fusioninfer_tpu.engine.sampler import (
 )
 from fusioninfer_tpu.models.config import ModelConfig
 from fusioninfer_tpu.models.transformer import init_params, lm_head_operands
+from fusioninfer_tpu.utils import spans
 
 logger = logging.getLogger("fusioninfer.engine")
 
@@ -582,6 +584,13 @@ class NativeEngine:
         self.admission_timings: collections.deque = collections.deque(
             maxlen=4096)
         self._admit_t: dict[str, tuple[float, float]] = {}
+        # the same two times as /metrics histograms, under vLLM's names
+        self.queue_time = Histogram(TTFT_BUCKETS)
+        self.prefill_time = Histogram(TTFT_BUCKETS)
+        # host spans of the engine thread (utils/spans.py); the server's
+        # loop adds loop.idle / loop.publish to the same clock
+        self.spans = spans.SpanClock()
+        spans.watch_jit()
         # request_id -> precomputed usable block-hash chain, set at
         # admission pop and consumed at match_prefix (engine thread only)
         self._admission_chains: dict[str, list] = {}
@@ -2308,49 +2317,68 @@ class NativeEngine:
 
     def step(self) -> list[StepOutput]:
         """Admit + prefill new work, then one batched decode pass."""
-        if self._mh is not None:
-            self._exchange_multihost_events()
-        self._in_step_body = True
-        try:
-            if self._evacuating:
-                return self._evacuate_step()
-            self._process_cancellations()
-            self._serve_slab_requests()
-            self._serve_embedding_requests()
-            outputs: list[StepOutput] = []
-            outputs += self._admit_streamed()
-            outputs += self._admit_prefilled()
-            # open the step's token ledger AFTER prefilled admissions
-            # (they decode this step too): the budget is charged with
-            # the running batch's decode tokens first, and _admit /
-            # _advance_prefilling spend the remainder on prefill work.
-            # Reads only replicated scheduler state (SPMD-safe).
-            # speculative rows verify up to spec_k drafts + 1 token per
-            # step: charge the worst case so the prefill remainder can
-            # never let a step blow the budget (conservative — shrunken
-            # drafts just leave some budget unspent).  Tier enforcement
-            # runs FIRST: a batch-saturated batch yields rows (KV
-            # parked) before the decode charge is struck, so the freed
-            # budget is visible to this very step's admission.
-            self._tier_budget_evict()
-            per_row = 1 + (self.spec_k or 0)
-            self._step_prefill_left = self.sched.begin_step(
-                per_row * sum(1 for st in self.running.values()
-                              if st.n_generated
-                              < st.request.params.max_tokens))
-            self._begin_tier_step()
-            outputs += self._admit()
-            if self._use_fused_step():
-                # both row kinds exist: ONE weight pass covers this
-                # step's decode rows and its budgeted prefill chunks
-                outputs += self._fused_step()
-            else:
-                outputs += self._advance_prefilling()
-                outputs += self._decode()
-        finally:
-            self._in_step_body = False
-            self._last_step_end = self._clock()
-        return [o for o in outputs if o is not None]
+        span = self.spans.span
+        with span("step", step=self.sched.steps_total,
+                  running=len(self.running), waiting=len(self.waiting),
+                  prefilling=len(self.prefilling)):
+            if self._mh is not None:
+                self._exchange_multihost_events()
+            self._in_step_body = True
+            try:
+                if self._evacuating:
+                    return self._evacuate_step()
+                with span("step.admit") as sp:
+                    outputs = self._admit_half()
+                    if sp.ann is not None:
+                        sp.note(admitted=",".join(
+                            o.request_id for o in outputs
+                            if o is not None and o.is_first_token))
+                if self._use_fused_step():
+                    # both row kinds exist: ONE weight pass covers this
+                    # step's decode rows and its budgeted prefill chunks
+                    with span("step.pack", rows=len(self.running)):
+                        outputs += self._fused_step()
+                else:
+                    if self.prefilling:
+                        with span("step.prefill", rows=len(self.prefilling)):
+                            outputs += self._advance_prefilling()
+                    with span("step.pack", rows=len(self.running)):
+                        outputs += self._decode()
+            finally:
+                self._in_step_body = False
+                self._last_step_end = self._clock()
+            return [o for o in outputs if o is not None]
+
+    def _admit_half(self) -> list[StepOutput]:
+        """A step before its decode half: cancellations, slab, embedding
+        and PD service, the step's token ledger, admission."""
+        self._process_cancellations()
+        self._serve_slab_requests()
+        self._serve_embedding_requests()
+        outputs: list[StepOutput] = []
+        outputs += self._admit_streamed()
+        outputs += self._admit_prefilled()
+        # open the step's token ledger AFTER prefilled admissions
+        # (they decode this step too): the budget is charged with
+        # the running batch's decode tokens first, and _admit /
+        # _advance_prefilling spend the remainder on prefill work.
+        # Reads only replicated scheduler state (SPMD-safe).
+        # speculative rows verify up to spec_k drafts + 1 token per
+        # step: charge the worst case so the prefill remainder can
+        # never let a step blow the budget (conservative — shrunken
+        # drafts just leave some budget unspent).  Tier enforcement
+        # runs FIRST: a batch-saturated batch yields rows (KV
+        # parked) before the decode charge is struck, so the freed
+        # budget is visible to this very step's admission.
+        self._tier_budget_evict()
+        per_row = 1 + (self.spec_k or 0)
+        self._step_prefill_left = self.sched.begin_step(
+            per_row * sum(1 for st in self.running.values()
+                          if st.n_generated
+                          < st.request.params.max_tokens))
+        self._begin_tier_step()
+        outputs += self._admit()
+        return outputs
 
     def _process_cancellations(self) -> None:
         with self._lock:
@@ -2664,8 +2692,9 @@ class NativeEngine:
                         short_hits.append((request, prefix, resumed, reused))
                         continue
                     try:
-                        outputs.append(self._prefill_suffix_one(
-                            request, prefix, resumed, reused))
+                        with self.spans.span("step.prefill", rows=1):
+                            outputs.append(self._prefill_suffix_one(
+                                request, prefix, resumed, reused))
                     except Exception as e:
                         logger.exception("prefill of %s failed", rid)
                         self.alloc.release(rid)
@@ -2694,9 +2723,13 @@ class NativeEngine:
                     # bounded at (buckets × log2(max_batch)) signatures
                     n = 1 << (len(items).bit_length() - 1)
                     group, items = items[:n], items[n:]
-                    outputs.extend(self._prefill_fresh_group(bucket, group))
+                    with self.spans.span("step.prefill", rows=n,
+                                         chunk_tokens=bucket):
+                        outputs.extend(
+                            self._prefill_fresh_group(bucket, group))
             if short_hits:
-                outputs.extend(self._prefill_suffix_batch(short_hits))
+                with self.spans.span("step.prefill", rows=len(short_hits)):
+                    outputs.extend(self._prefill_suffix_batch(short_hits))
             pending = [pending[i] for i in deferred_idx]
         return outputs
 
@@ -2933,13 +2966,18 @@ class NativeEngine:
             ctl_f = np.asarray(
                 [p.temperature, p.top_p, p.min_p, p.presence_penalty,
                  p.frequency_penalty, p.repetition_penalty], np.float32)
-            tok_d, counts_row, out_row, sup_row = sample_first(
-                logits, jnp.asarray(padded), jnp.asarray(ctl_i),
-                jnp.asarray(ctl_f), jnp.asarray(sids),
-                mode=self._sample_mode((p,)))
+            with self.spans.span("step.dispatch", program="sample_first"):
+                tok_d, counts_row, out_row, sup_row = sample_first(
+                    logits, jnp.asarray(padded), jnp.asarray(ctl_i),
+                    jnp.asarray(ctl_f), jnp.asarray(sids),
+                    mode=self._sample_mode((p,)))
             # defer_fetch: hand back the DEVICE scalar so a group
             # admission path can fetch the whole group in one transfer
-            token = tok_d if defer_fetch else int(tok_d)
+            if defer_fetch:
+                token = tok_d
+            else:
+                with self.spans.span("step.fetch", program="sample_first"):
+                    token = int(tok_d)
             if return_state:
                 return token, (counts_row, out_row, sup_row)
             return token
@@ -3002,12 +3040,13 @@ class NativeEngine:
             out_row = self._prompt_counts(tokens[n_prompt:])
             sup_row = self._stop_suppress_row(params)
             bump_token, bump = 0, 0  # rows already cover every token
-        self._token_counts, self._output_counts, self._suppress = (
-            _install_slot_rows(
-                self._token_counts, self._output_counts, self._suppress,
-                jnp.int32(slot), counts_row, out_row, sup_row,
-                jnp.int32(bump_token), jnp.int32(bump),
-            ))
+        with self.spans.span("step.dispatch", program="install_slot_rows"):
+            self._token_counts, self._output_counts, self._suppress = (
+                _install_slot_rows(
+                    self._token_counts, self._output_counts, self._suppress,
+                    jnp.int32(slot), counts_row, out_row, sup_row,
+                    jnp.int32(bump_token), jnp.int32(bump),
+                ))
         if params.logit_bias:
             self._slot_bias[slot] = (
                 jnp.asarray([t for t, _ in params.logit_bias], jnp.int32),
@@ -3054,22 +3093,23 @@ class NativeEngine:
         windows, chunk advances, batched cache-hit suffixes, mixed
         fused steps — assembles a :class:`RaggedBatch` and lands here,
         so no path can reacquire a private scorer."""
-        self.cache, logits, chunk_logits = fused_step(
-            self.cfg, self.cache_cfg, self.params, self.cache,
-            jnp.asarray(packed.tokens), jnp.asarray(packed.row_starts),
-            jnp.asarray(packed.q_begins), jnp.asarray(packed.q_lens),
-            jnp.asarray(packed.page_tables), jnp.asarray(packed.sel),
-            jnp.asarray(packed.chunk_sel),
-            mesh=self._kernel_mesh, lora=lora,
-            adapter_ids=(jnp.asarray(packed.adapter_ids)
-                         if lora is not None else None),
-            # eager env-var resolution: a mid-process flip of
-            # FUSIONINFER_DECODE_COALESCE must retrace, not silently
-            # reuse the latched variant (ops/dispatch.py)
-            coalesce=ops_dispatch.decode_coalesce(),
-            kv_splits=self._kv_splits,
-            decode_hidden=decode_hidden,
-        )
+        with self.spans.span("step.dispatch", program="fused_step"):
+            self.cache, logits, chunk_logits = fused_step(
+                self.cfg, self.cache_cfg, self.params, self.cache,
+                jnp.asarray(packed.tokens), jnp.asarray(packed.row_starts),
+                jnp.asarray(packed.q_begins), jnp.asarray(packed.q_lens),
+                jnp.asarray(packed.page_tables), jnp.asarray(packed.sel),
+                jnp.asarray(packed.chunk_sel),
+                mesh=self._kernel_mesh, lora=lora,
+                adapter_ids=(jnp.asarray(packed.adapter_ids)
+                             if lora is not None else None),
+                # eager env-var resolution: a mid-process flip of
+                # FUSIONINFER_DECODE_COALESCE must retrace, not silently
+                # reuse the latched variant (ops/dispatch.py)
+                coalesce=ops_dispatch.decode_coalesce(),
+                kv_splits=self._kv_splits,
+                decode_hidden=decode_hidden,
+            )
         self.sched.charge_weight_pass()
         return logits, chunk_logits
 
@@ -3284,13 +3324,14 @@ class NativeEngine:
             ids[i] = self._adapter_id(request)
         lora = self.lora_set.stacked if self.lora_set is not None else None
         try:
-            self.cache, logits = prefill(
-                self.cfg, self.cache_cfg, self.params, self.cache,
-                jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(rows),
-                mesh=self._kernel_mesh,
-                lora=lora,
-                adapter_ids=jnp.asarray(ids) if lora is not None else None,
-            )
+            with self.spans.span("step.dispatch", program="prefill"):
+                self.cache, logits = prefill(
+                    self.cfg, self.cache_cfg, self.params, self.cache,
+                    jnp.asarray(padded), jnp.asarray(lens), jnp.asarray(rows),
+                    mesh=self._kernel_mesh,
+                    lora=lora,
+                    adapter_ids=jnp.asarray(ids) if lora is not None else None,
+                )
         except Exception as e:
             logger.exception("batched prefill of %d requests failed", B)
             outputs = []
@@ -3334,7 +3375,9 @@ class NativeEngine:
         pend = [c for c in ctxs if c["token"] is None]
         if pend:
             try:
-                toks = np.asarray(jnp.stack([c["tok_dev"] for c in pend]))
+                with self.spans.span("step.fetch", program="sample_first"):
+                    toks = np.asarray(
+                        jnp.stack([c["tok_dev"] for c in pend]))
                 for c, t in zip(pend, toks):
                     c["token"] = int(t)
             except Exception as e:
@@ -3394,7 +3437,8 @@ class NativeEngine:
         """Fetch half of activation: claim the slot, install device
         sampling state, emit the first token."""
         if ctx["token"] is None:
-            ctx["token"] = int(np.asarray(ctx["tok_dev"]))
+            with self.spans.span("step.fetch", program="sample_first"):
+                ctx["token"] = int(np.asarray(ctx["tok_dev"]))
         request = ctx["request"]
         prefix = ctx["prefix"]
         resumed = ctx["resumed"]
@@ -3528,44 +3572,49 @@ class NativeEngine:
         matches `_decode_finish`'s plain branch exactly; eligibility
         (`_fused_sampling_mode`) already excluded every row kind that
         branch special-cases."""
-        head, tied = lm_head_operands(self.cfg, self.params)
-        early = jnp.asarray(ctl["gen_counts"] < ctl["min_toks"])
-        if self._kernel_mesh is not None:
-            from fusioninfer_tpu.ops.sharded import lm_head_topk_tp
+        span = self.spans.span
+        with span("step.dispatch", program="lm_head_topk"):
+            head, tied = lm_head_operands(self.cfg, self.params)
+            early = jnp.asarray(ctl["gen_counts"] < ctl["min_toks"])
+            if self._kernel_mesh is not None:
+                from fusioninfer_tpu.ops.sharded import lm_head_topk_tp
 
-            vals, idx = lm_head_topk_tp(
-                self._kernel_mesh, hidden, head, self._token_counts,
-                self._output_counts, jnp.asarray(ctl["presence"]),
-                jnp.asarray(ctl["frequency"]),
-                jnp.asarray(ctl["repetition"]), early, self._suppress,
-                tied=tied)
-        else:
-            vals, idx = lm_head_topk(
-                hidden, head, self._token_counts, self._output_counts,
-                jnp.asarray(ctl["presence"]), jnp.asarray(ctl["frequency"]),
-                jnp.asarray(ctl["repetition"]), early, self._suppress,
-                tied=tied)
-        keys = make_row_keys(jnp.asarray(ctl["seeds"]),
-                             jnp.asarray(ctl["gen_counts"]))
-        sampled_dev = sample_topk(vals, idx, keys,
-                                  jnp.asarray(ctl["temps"]),
-                                  jnp.asarray(ctl["top_ks"]),
-                                  jnp.asarray(ctl["top_ps"]), mode=mode)
-        B = self.max_batch_size
-        live_mask = np.zeros(B, bool)
-        live_mask[list(live)] = True
-        self._token_counts, self._output_counts = _bump_count_rows(
-            self._token_counts, self._output_counts, sampled_dev,
-            jnp.asarray(live_mask))
-        sampled = np.asarray(sampled_dev)
+                vals, idx = lm_head_topk_tp(
+                    self._kernel_mesh, hidden, head, self._token_counts,
+                    self._output_counts, jnp.asarray(ctl["presence"]),
+                    jnp.asarray(ctl["frequency"]),
+                    jnp.asarray(ctl["repetition"]), early, self._suppress,
+                    tied=tied)
+            else:
+                vals, idx = lm_head_topk(
+                    hidden, head, self._token_counts, self._output_counts,
+                    jnp.asarray(ctl["presence"]),
+                    jnp.asarray(ctl["frequency"]),
+                    jnp.asarray(ctl["repetition"]), early, self._suppress,
+                    tied=tied)
+            keys = make_row_keys(jnp.asarray(ctl["seeds"]),
+                                 jnp.asarray(ctl["gen_counts"]))
+            sampled_dev = sample_topk(vals, idx, keys,
+                                      jnp.asarray(ctl["temps"]),
+                                      jnp.asarray(ctl["top_ks"]),
+                                      jnp.asarray(ctl["top_ps"]), mode=mode)
+            B = self.max_batch_size
+            live_mask = np.zeros(B, bool)
+            live_mask[list(live)] = True
+            self._token_counts, self._output_counts = _bump_count_rows(
+                self._token_counts, self._output_counts, sampled_dev,
+                jnp.asarray(live_mask))
+        with span("step.fetch", program="lm_head_topk"):
+            sampled = np.asarray(sampled_dev)
         self.sched.charge_decode(len(live))
         self.fused_sampling_steps_total += 1
         outputs = list(failures)
-        for slot, st in live.items():
-            token = int(sampled[slot])
-            st.tokens.append(token)
-            self.generation_tokens_total += 1
-            outputs.append(self._emit(st, token))
+        with span("step.emit", tokens=len(live)):
+            for slot, st in live.items():
+                token = int(sampled[slot])
+                st.tokens.append(token)
+                self.generation_tokens_total += 1
+                outputs.append(self._emit(st, token))
         return outputs
 
     def _decode_need(self, st: "_SeqState", span: int) -> int:
@@ -3745,25 +3794,30 @@ class NativeEngine:
             tables = np.full((B, mp), self.cache_cfg.trash_page, np.int32)
             for s, st in snapshot.items():
                 tables[s] = self.alloc.page_table_row(st.request.request_id)
-            s_dev, s_next = self._dispatch_burst(
-                next_ctl, ctl_f_dev, jnp.asarray(tables), span, mode, lora)
+            with self.spans.span("step.dispatch", program="decode_burst"):
+                s_dev, s_next = self._dispatch_burst(
+                    next_ctl, ctl_f_dev, jnp.asarray(tables), span, mode,
+                    lora)
             successor = (s_dev, s_next, ctl_f_dev, dict(snapshot), span,
                          mode, lora)
             self.sched.dispatch_ahead_total += 1
         self.sched.charge_decode(span * len(snapshot))
-        sampled_all = np.asarray(sampled_dev)  # [span, B] — blocks here
+        with self.spans.span("step.fetch", program="decode_burst"):
+            sampled_all = np.asarray(sampled_dev)  # [span, B] — blocks here
         outputs: list[StepOutput] = []
-        for slot, st in snapshot.items():
-            if self.running.get(slot) is not st:
-                continue  # cancelled/preempted since dispatch — discard
-            for k in range(span):
-                token = int(sampled_all[k, slot])
-                st.tokens.append(token)
-                self.generation_tokens_total += 1
-                out = self._emit(st, token)
-                outputs.append(out)
-                if out.finished:
-                    break  # trailing burst tokens are discarded
+        with self.spans.span("step.emit") as sp:
+            for slot, st in snapshot.items():
+                if self.running.get(slot) is not st:
+                    continue  # cancelled/preempted since dispatch — discard
+                for k in range(span):
+                    token = int(sampled_all[k, slot])
+                    st.tokens.append(token)
+                    self.generation_tokens_total += 1
+                    out = self._emit(st, token)
+                    outputs.append(out)
+                    if out.finished:
+                        break  # trailing burst tokens are discarded
+            sp.note(tokens=len(outputs))
         if successor is not None and any(
                 self.running.get(s) is st for s, st in snapshot.items()):
             self._inflight = successor
@@ -3850,19 +3904,21 @@ class NativeEngine:
         # chunk bookkeeping mirrors _advance_prefilling_batch: charged
         # after the forward, completed prefills activate into their
         # reserved slots off their chunk row's last-token logits
-        self._spend_prefill(sum(chunks), chunks=len(take))
-        for i, st in enumerate(take):
-            self._note_tier_spend(st.request.priority, chunks[i])
-        done = []
-        for i, st in enumerate(take):
-            st.pos += chunks[i]
-            if st.pos == len(st.prefix):
-                self.prefilling.remove(st)
-                done.append((st.request, st.prefix, st.resumed,
-                             chunk_logits[i][None]))
-        outputs = list(failures)
-        if done:
-            outputs += self._activate_group(done)
+        with self.spans.span("step.prefill", rows=len(take),
+                             chunk_tokens=sum(chunks)):
+            self._spend_prefill(sum(chunks), chunks=len(take))
+            for i, st in enumerate(take):
+                self._note_tier_spend(st.request.priority, chunks[i])
+            done = []
+            for i, st in enumerate(take):
+                st.pos += chunks[i]
+                if st.pos == len(st.prefix):
+                    self.prefilling.remove(st)
+                    done.append((st.request, st.prefix, st.resumed,
+                                 chunk_logits[i][None]))
+            outputs = list(failures)
+            if done:
+                outputs += self._activate_group(done)
         # decode sampling/spec-verify off the slot-aligned decode rows;
         # on the fused-sampling path logits_f carries HIDDEN states and
         # the candidate tail samples without [B, V] logits
@@ -3965,10 +4021,12 @@ class NativeEngine:
                 axis=1)
             mode = self._sample_mode(
                 st.request.params for st in burst_rows.values())
-            ctl_f_dev = jnp.asarray(ctl_f)
-            sampled_dev, next_ctl = self._dispatch_burst(
-                jnp.asarray(ctl_i), ctl_f_dev, jnp.asarray(ctl["page_tables"]),
-                span, mode, lora)
+            with self.spans.span("step.dispatch", program="decode_burst",
+                                 rows=len(burst_rows), span=span):
+                ctl_f_dev = jnp.asarray(ctl_f)
+                sampled_dev, next_ctl = self._dispatch_burst(
+                    jnp.asarray(ctl_i), ctl_f_dev,
+                    jnp.asarray(ctl["page_tables"]), span, mode, lora)
             # hand the fresh burst to the consume path, which may
             # dispatch its successor before the blocking fetch
             self._inflight = (sampled_dev, next_ctl, ctl_f_dev,
@@ -4077,7 +4135,8 @@ class NativeEngine:
         sequential-equivalent full draws for every window position."""
         B = self.max_batch_size
         C = self.spec_k + 1
-        spec = {"argmax_w": np.asarray(jnp.argmax(logits_w, axis=-1))}
+        with self.spans.span("step.fetch", program="spec_window"):
+            spec = {"argmax_w": np.asarray(jnp.argmax(logits_w, axis=-1))}
         if any(ctl["temps"][s] > 0.0 for s in spec_drafts):
             counters = (ctl["gen_counts"][:, None]
                         + np.arange(C)[None, :]).reshape(-1)
@@ -4090,10 +4149,11 @@ class NativeEngine:
                 logits_w.astype(jnp.float32), jnp.asarray(draft_next),
                 keys_w, jnp.asarray(ctl["temps"]), jnp.asarray(ctl["top_ks"]),
                 jnp.asarray(ctl["top_ps"]), jnp.asarray(ctl["min_ps"]))
-            spec["full_w"] = np.asarray(full_d)
-            spec["p_draft_w"] = np.asarray(p_draft_d)
-            spec["u_w"] = np.asarray(u_d)
-            spec["repl_w"] = np.asarray(repl_d)
+            with self.spans.span("step.fetch", program="spec_window"):
+                spec["full_w"] = np.asarray(full_d)
+                spec["p_draft_w"] = np.asarray(p_draft_d)
+                spec["u_w"] = np.asarray(u_d)
+                spec["repl_w"] = np.asarray(repl_d)
         return spec
 
     def _decode_finish(self, live: dict, logits, ctl: dict,
@@ -4105,130 +4165,136 @@ class NativeEngine:
         speculation is on).  ``logits`` are the batch's slot-aligned
         next-token logits [B, V] from whichever forward ran."""
         B = self.max_batch_size
-        # raw-distribution logprobs, computed only when someone asked
-        lp_n = max((st.request.params.logprobs or 0 for st in live.values()),
-                   default=0)
-        raw_logp = top_lp = None
-        if lp_n or any(st.request.params.logprobs is not None
-                       for st in live.values()):
-            raw_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            if lp_n:
-                top_lp = jax.lax.top_k(raw_logp, lp_n)
-        logits = apply_penalties(
-            logits, self._token_counts, self._output_counts,
-            jnp.asarray(ctl["presence"]), jnp.asarray(ctl["frequency"]),
-            jnp.asarray(ctl["repetition"]),
-        )
-        # min_tokens: stop ids stay unsampleable until enough generated
-        # (fused jit: the eager where/& chain was a per-step host cost)
-        logits = _suppress_early_rows(
-            logits, jnp.asarray(ctl["gen_counts"] < ctl["min_toks"]),
-            self._suppress)
-        # guided rows: only grammatically legal bytes are sampleable
-        guided_live = {s: st.guided for s, st in live.items()
-                       if st.guided is not None}
-        if guided_live:
-            key = tuple(sorted((s, m.signature())
-                               for s, m in guided_live.items()))
-            legal_dev = self._guided_legal_dev.get(key)
-            if legal_dev is None:
-                legal = np.zeros((B, self.cfg.vocab_size), bool)
-                for slot, m in guided_live.items():
-                    legal[slot] = self._masker.token_mask(m)
-                legal_dev = jnp.asarray(legal)
-                if len(self._guided_legal_dev) >= 8:  # bound HBM held
-                    self._guided_legal_dev.popitem(last=False)
-                self._guided_legal_dev[key] = legal_dev
-            else:
-                self._guided_legal_dev.move_to_end(key)
-            grow = np.zeros((B,), bool)
-            grow[list(guided_live)] = True
-            logits = _mask_guided_rows(logits, legal_dev,
-                                       jnp.asarray(grow))
-        # per-request logit_bias rows (arrays cached at slot registration)
-        for slot in live:
-            bias = self._slot_bias.get(slot)
-            if bias is not None:
-                logits = logits.at[slot, bias[0]].add(bias[1])
-        keys = make_row_keys(jnp.asarray(ctl["seeds"]),
-                             jnp.asarray(ctl["gen_counts"]))
-        sampled_dev = sample(logits, keys, jnp.asarray(ctl["temps"]),
-                             jnp.asarray(ctl["top_ks"]),
-                             jnp.asarray(ctl["top_ps"]),
-                             jnp.asarray(ctl["min_ps"]),
-                             mode=self._sample_mode(
-                                 st.request.params for st in live.values()))
-        live_mask = np.zeros(B, bool)
-        live_mask[list(live)] = True
-        self._token_counts, self._output_counts = _bump_count_rows(
-            self._token_counts, self._output_counts, sampled_dev,
-            jnp.asarray(live_mask))
-        sampled = np.asarray(sampled_dev)
-        if raw_logp is not None:
-            chosen_lp = np.asarray(raw_logp[jnp.arange(B), sampled_dev])
-            top_vals = np.asarray(top_lp[0]) if top_lp is not None else None
-            top_ids = np.asarray(top_lp[1]) if top_lp is not None else None
+        with self.spans.span("step.dispatch", program="sample"):
+            # raw-distribution logprobs, computed only when someone asked
+            lp_n = max((st.request.params.logprobs or 0 for st in live.values()),
+                       default=0)
+            raw_logp = top_lp = None
+            if lp_n or any(st.request.params.logprobs is not None
+                           for st in live.values()):
+                raw_logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+                if lp_n:
+                    top_lp = jax.lax.top_k(raw_logp, lp_n)
+            logits = apply_penalties(
+                logits, self._token_counts, self._output_counts,
+                jnp.asarray(ctl["presence"]), jnp.asarray(ctl["frequency"]),
+                jnp.asarray(ctl["repetition"]),
+            )
+            # min_tokens: stop ids stay unsampleable until enough generated
+            # (fused jit: the eager where/& chain was a per-step host cost)
+            logits = _suppress_early_rows(
+                logits, jnp.asarray(ctl["gen_counts"] < ctl["min_toks"]),
+                self._suppress)
+            # guided rows: only grammatically legal bytes are sampleable
+            guided_live = {s: st.guided for s, st in live.items()
+                           if st.guided is not None}
+            if guided_live:
+                key = tuple(sorted((s, m.signature())
+                                   for s, m in guided_live.items()))
+                legal_dev = self._guided_legal_dev.get(key)
+                if legal_dev is None:
+                    legal = np.zeros((B, self.cfg.vocab_size), bool)
+                    for slot, m in guided_live.items():
+                        legal[slot] = self._masker.token_mask(m)
+                    legal_dev = jnp.asarray(legal)
+                    if len(self._guided_legal_dev) >= 8:  # bound HBM held
+                        self._guided_legal_dev.popitem(last=False)
+                    self._guided_legal_dev[key] = legal_dev
+                else:
+                    self._guided_legal_dev.move_to_end(key)
+                grow = np.zeros((B,), bool)
+                grow[list(guided_live)] = True
+                logits = _mask_guided_rows(logits, legal_dev,
+                                           jnp.asarray(grow))
+            # per-request logit_bias rows (arrays cached at slot registration)
+            for slot in live:
+                bias = self._slot_bias.get(slot)
+                if bias is not None:
+                    logits = logits.at[slot, bias[0]].add(bias[1])
+            keys = make_row_keys(jnp.asarray(ctl["seeds"]),
+                                 jnp.asarray(ctl["gen_counts"]))
+            sampled_dev = sample(logits, keys, jnp.asarray(ctl["temps"]),
+                                 jnp.asarray(ctl["top_ks"]),
+                                 jnp.asarray(ctl["top_ps"]),
+                                 jnp.asarray(ctl["min_ps"]),
+                                 mode=self._sample_mode(
+                                     st.request.params for st in live.values()))
+            live_mask = np.zeros(B, bool)
+            live_mask[list(live)] = True
+            self._token_counts, self._output_counts = _bump_count_rows(
+                self._token_counts, self._output_counts, sampled_dev,
+                jnp.asarray(live_mask))
+        with self.spans.span("step.fetch", program="sample"):
+            sampled = np.asarray(sampled_dev)
+            if raw_logp is not None:
+                chosen_lp = np.asarray(raw_logp[jnp.arange(B), sampled_dev])
+                top_vals = (np.asarray(top_lp[0])
+                            if top_lp is not None else None)
+                top_ids = (np.asarray(top_lp[1])
+                           if top_lp is not None else None)
 
         self.sched.charge_decode(
             len(live) + sum(len(d) for d in spec_drafts.values()))
         outputs = list(failures)
         argmax_w = spec["argmax_w"] if spec is not None else None
-        for slot, st in live.items():
-            if argmax_w is not None and slot in spec_drafts:
-                drafts = spec_drafts[slot]
-                self.spec_proposed_total += len(drafts)
-                if ctl["temps"][slot] > 0.0:
-                    # sampled burst: delta-draft rejection sampling —
-                    # accept while u < p(draft) under the position's
-                    # filtered distribution; on first rejection emit the
-                    # draft-excluded replacement, on full acceptance the
-                    # bonus draw.  Distribution-exact (Leviathan et al.)
-                    # and deterministic for a given (seed, spec config).
-                    accepted = 0
-                    while (accepted < len(drafts)
-                           and float(spec["u_w"][slot, accepted])
-                           < float(spec["p_draft_w"][slot, accepted])):
-                        accepted += 1
-                    if accepted < len(drafts):
-                        tail = int(spec["repl_w"][slot, accepted])
+        with self.spans.span("step.emit") as sp:
+            for slot, st in live.items():
+                if argmax_w is not None and slot in spec_drafts:
+                    drafts = spec_drafts[slot]
+                    self.spec_proposed_total += len(drafts)
+                    if ctl["temps"][slot] > 0.0:
+                        # sampled burst: delta-draft rejection sampling —
+                        # accept while u < p(draft) under the position's
+                        # filtered distribution; on first rejection emit the
+                        # draft-excluded replacement, on full acceptance the
+                        # bonus draw.  Distribution-exact (Leviathan et al.)
+                        # and deterministic for a given (seed, spec config).
+                        accepted = 0
+                        while (accepted < len(drafts)
+                               and float(spec["u_w"][slot, accepted])
+                               < float(spec["p_draft_w"][slot, accepted])):
+                            accepted += 1
+                        if accepted < len(drafts):
+                            tail = int(spec["repl_w"][slot, accepted])
+                        else:
+                            tail = int(spec["full_w"][slot, len(drafts)])
+                        burst = drafts[:accepted] + [tail]
                     else:
-                        tail = int(spec["full_w"][slot, len(drafts)])
-                    burst = drafts[:accepted] + [tail]
-                else:
-                    # greedy burst: accepted drafts + the model's bonus
-                    # token.  argmax_w[slot, j] is the greedy token after
-                    # consuming window[:j+1], so acceptance walks the
-                    # window in order — bit-identical to sequential
-                    # greedy decode_steps.
-                    accepted = 0
-                    while (accepted < len(drafts)
-                           and drafts[accepted] == int(argmax_w[slot, accepted])):
-                        accepted += 1
-                    burst = drafts[:accepted] + [int(argmax_w[slot, accepted])]
-                for i, tok in enumerate(burst):
-                    st.tokens.append(tok)
-                    self.generation_tokens_total += 1
-                    if i < accepted:  # EMITTED drafts only (a stop token
-                        self.spec_accepted_total += 1  # mid-burst discards the rest)
-                    out = self._emit(st, tok)
-                    outputs.append(out)
-                    if out.finished:
-                        break
-                continue
-            token = int(sampled[slot])
-            st.tokens.append(token)
-            self.generation_tokens_total += 1
-            force_finish = (self._guided_advance(st.guided, token)
-                            if st.guided is not None else None)
-            lp = tops = None
-            n = st.request.params.logprobs
-            if raw_logp is not None and n is not None:
-                lp = float(chosen_lp[slot])
-                if n and top_ids is not None:
-                    tops = {int(t): float(v) for t, v in
-                            zip(top_ids[slot][:n], top_vals[slot][:n])}
-            outputs.append(self._emit(st, token, logprob=lp, top_logprobs=tops,
-                                      force_finish=force_finish))
+                        # greedy burst: accepted drafts + the model's bonus
+                        # token.  argmax_w[slot, j] is the greedy token after
+                        # consuming window[:j+1], so acceptance walks the
+                        # window in order — bit-identical to sequential
+                        # greedy decode_steps.
+                        accepted = 0
+                        while (accepted < len(drafts)
+                               and drafts[accepted] == int(argmax_w[slot, accepted])):
+                            accepted += 1
+                        burst = drafts[:accepted] + [int(argmax_w[slot, accepted])]
+                    for i, tok in enumerate(burst):
+                        st.tokens.append(tok)
+                        self.generation_tokens_total += 1
+                        if i < accepted:  # EMITTED drafts only (a stop token
+                            self.spec_accepted_total += 1  # mid-burst discards the rest)
+                        out = self._emit(st, tok)
+                        outputs.append(out)
+                        if out.finished:
+                            break
+                    continue
+                token = int(sampled[slot])
+                st.tokens.append(token)
+                self.generation_tokens_total += 1
+                force_finish = (self._guided_advance(st.guided, token)
+                                if st.guided is not None else None)
+                lp = tops = None
+                n = st.request.params.logprobs
+                if raw_logp is not None and n is not None:
+                    lp = float(chosen_lp[slot])
+                    if n and top_ids is not None:
+                        tops = {int(t): float(v) for t, v in
+                                zip(top_ids[slot][:n], top_vals[slot][:n])}
+                outputs.append(self._emit(st, token, logprob=lp, top_logprobs=tops,
+                                          force_finish=force_finish))
+            sp.note(tokens=len(outputs) - len(failures))
         return outputs
 
     def _ensure_decode_capacity(self, span: int = 1) -> tuple[list[StepOutput], int]:
@@ -4328,8 +4394,10 @@ class NativeEngine:
         # closes that admission's timing; later emits find nothing
         t = self._admit_t.pop(state.request.request_id, None)
         if t is not None:
-            self.admission_timings.append(
-                (t[1], self._clock() - t[0]))
+            prefill_s = self._clock() - t[0]
+            self.admission_timings.append((t[1], prefill_s))
+            self.queue_time.observe(t[1])
+            self.prefill_time.observe(prefill_s)
         finish_reason = force_finish
         if finish_reason is None and token in params.stop_token_ids:
             finish_reason = "stop"

@@ -203,6 +203,7 @@ class EngineMetrics:
         lines += self._render_kv_fabric(engine, labels)
         lines += self._render_evacuation(engine, labels)
         lines += self._render_scheduler(engine, labels)
+        lines += self._render_host(engine, labels)
         lines += self._render_aot(engine, labels)
         return "\n".join(lines) + "\n"
 
@@ -416,6 +417,54 @@ class EngineMetrics:
             "# TYPE fusioninfer:evac_unparked_total counter",
             f"fusioninfer:evac_unparked_total{{{labels}}} {engine.evac_unparked_total}",
         ]
+
+    @staticmethod
+    def _render_host(engine, labels: str) -> list[str]:
+        """Where the engine thread's time goes (utils/spans.py): self
+        seconds and counts per host span, the loop's wall and CPU clocks,
+        each request's queue and prefill wait, and jit seconds.  Separate
+        families, not a label: scrapers that sum a family's samples keep
+        each phase apart.  Engines without a span clock (test stubs) omit
+        the families."""
+        from fusioninfer_tpu.utils import spans
+
+        clock = getattr(engine, "spans", None)
+        if clock is None:
+            return []
+        lines = []
+
+        def counter(name: str, help_: str, value) -> None:
+            lines.extend([f"# HELP {name} {help_}", f"# TYPE {name} counter",
+                          f"{name}{{{labels}}} {value}"])
+
+        for span in spans.SPAN_NAMES:
+            family = "fusioninfer:host_" + span.replace(".", "_")
+            counter(family + "_seconds_total",
+                    f"Engine-thread self time inside {span} spans.",
+                    clock.ns[span] / 1e9)
+            counter(family + "_count_total", f"{span} spans closed.",
+                    clock.count[span])
+        counter("fusioninfer:engine_loop_seconds_total",
+                "Wall time since the engine loop started.",
+                clock.loop_ns / 1e9)
+        counter("fusioninfer:engine_thread_cpu_seconds_total",
+                "CPU time of the engine thread since the loop started.",
+                clock.cpu_ns / 1e9)
+        counter("fusioninfer:jit_seconds_total",
+                "Seconds of jax trace + lower + compile on jit-cache misses.",
+                spans.jit_totals["seconds"])
+        counter("fusioninfer:jit_events_total",
+                "jax trace, lower and compile events (jit-cache misses).",
+                spans.jit_totals["events"])
+        for name, help_, hist in (
+                ("vllm:request_queue_time_seconds",
+                 "Arrival to the pop for admission.", engine.queue_time),
+                ("vllm:request_prefill_time_seconds",
+                 "Pop for admission to first token, resumes included.",
+                 engine.prefill_time)):
+            lines += [f"# HELP {name} {help_}", f"# TYPE {name} histogram",
+                      *hist.render(name, labels)]
+        return lines
 
     @staticmethod
     def _render_scheduler(engine, labels: str) -> list[str]:

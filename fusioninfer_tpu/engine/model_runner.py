@@ -29,6 +29,7 @@ from fusioninfer_tpu.ops import masks
 from fusioninfer_tpu.models.config import ModelConfig
 from fusioninfer_tpu.models.quantization import embed_lookup, kv_quantize
 from fusioninfer_tpu.models.transformer import (
+    attn_out_proj,
     layer_forward,
     lm_head,
     mlp_block,
@@ -58,6 +59,7 @@ def _layer_unpack(inputs, has_lora: bool):
     return layer, layer_lora, next(it)
 
 
+@jax.named_scope("kv_write")
 def _scatter_kv(cache: dict, l, k, v, write_page, write_slot,
                 head_axis: int) -> dict:
     """Write fresh K/V (``[..., KV, Hd]`` with the head axis at
@@ -121,6 +123,7 @@ def _dequant_gather(ctx, scale_l, pages, flat_shape):
     return ctx.astype(jnp.float32) * sc[..., None]
 
 
+@jax.named_scope("attn")
 def _ragged_attn(mesh, q, cache, page_tables, row_starts, q_begins, q_lens,
                  k_scales, v_scales, *, layer, window, coalesce,
                  kv_splits, interpret):
@@ -313,12 +316,7 @@ def prefill_suffix(
                 jax.nn.softmax(scores, axis=-1).astype(dtype_ctx),
                 v_ctx,
             ).reshape(B, C, H * Hd).astype(x.dtype)
-        out_proj = attn @ layer["wo"]
-        if layer_lora is not None:
-            from fusioninfer_tpu.models.lora import lora_delta
-
-            out_proj = out_proj + lora_delta(layer_lora, "wo", attn, adapter_ids)
-        x = x + out_proj
+        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
         return (x + mlp_block(cfg, layer, x), cache), None
 
     (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
@@ -414,12 +412,7 @@ def _decode_step_impl(
             probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
             attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
                 B_, 1, H * Hd).astype(x.dtype)
-        out_proj = attn @ layer["wo"]
-        if layer_lora is not None:
-            from fusioninfer_tpu.models.lora import lora_delta
-
-            out_proj = out_proj + lora_delta(layer_lora, "wo", attn, adapter_ids)
-        x = x + out_proj
+        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
         return (x + mlp_block(cfg, layer, x), cache), None
 
     (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
@@ -529,17 +522,18 @@ def decode_burst(
             cfg, cache_cfg, params, cache, toks, pos, page_tables, act,
             mesh=mesh, lora=lora, adapter_ids=adapter_ids,
             coalesce=coalesce, kv_splits=kv_splits)
-        logits = apply_penalties(logits, tcounts, ocounts,
-                                 presence, frequency, repetition)
-        logits = jnp.where((gcounts < min_toks)[:, None] & suppress,
-                           -jnp.inf, logits)
-        keys = make_row_keys(seeds, gcounts)
-        sampled = sample(logits, keys, temps, top_ks, top_ps, min_ps,
-                         mode=sample_mode)
-        inc = act.astype(tcounts.dtype)
-        rows = jnp.arange(sampled.shape[0])
-        tcounts = tcounts.at[rows, sampled].add(inc)
-        ocounts = ocounts.at[rows, sampled].add(inc)
+        with jax.named_scope("sample"):
+            logits = apply_penalties(logits, tcounts, ocounts,
+                                     presence, frequency, repetition)
+            logits = jnp.where((gcounts < min_toks)[:, None] & suppress,
+                               -jnp.inf, logits)
+            keys = make_row_keys(seeds, gcounts)
+            sampled = sample(logits, keys, temps, top_ks, top_ps, min_ps,
+                             mode=sample_mode)
+            inc = act.astype(tcounts.dtype)
+            rows = jnp.arange(sampled.shape[0])
+            tcounts = tcounts.at[rows, sampled].add(inc)
+            ocounts = ocounts.at[rows, sampled].add(inc)
         step = act.astype(pos.dtype)
         next_tok = jnp.where(act, sampled, toks)
         return (cache, next_tok, pos + step, tcounts, ocounts,
@@ -675,12 +669,7 @@ def _window_forward_impl(
             attn = jnp.einsum("bkgct,kbtd->bckgd", probs, v_ctx).reshape(
                 B, C, H * Hd
             ).astype(x.dtype)
-        out_proj = attn @ layer["wo"]
-        if layer_lora is not None:
-            from fusioninfer_tpu.models.lora import lora_delta
-
-            out_proj = out_proj + lora_delta(layer_lora, "wo", attn, adapter_ids)
-        x = x + out_proj
+        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
         return (x + mlp_block(cfg, layer, x), cache), None
 
     (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
@@ -847,13 +836,7 @@ def fused_step(
                 probs = probs * v_sc
             attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
                 T, 1, H * Hd).astype(x.dtype)
-        out_proj = attn @ layer["wo"]
-        if layer_lora is not None:
-            from fusioninfer_tpu.models.lora import lora_delta
-
-            out_proj = out_proj + lora_delta(layer_lora, "wo", attn,
-                                             adapter_tok)
-        x = x + out_proj
+        x = x + attn_out_proj(layer, attn, layer_lora, adapter_tok)
         return (x + mlp_block(cfg, layer, x), cache), None
 
     (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))

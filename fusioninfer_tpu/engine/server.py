@@ -31,6 +31,7 @@ from fusioninfer_tpu.engine.sampler import SamplingParams
 from fusioninfer_tpu.engine.tokenizer import load_tokenizer
 from fusioninfer_tpu.models.config import get_preset
 from fusioninfer_tpu.resilience import RetryBudgetExhausted, RetryPolicy
+from fusioninfer_tpu.utils import spans
 
 logger = logging.getLogger("fusioninfer.server")
 
@@ -373,11 +374,16 @@ class EngineServer:
         idle_sleep = 0.002
         consecutive_failures = 0
         idle_streak = 0
+        # the engine's own clock, so loop and step spans add up on one
+        # thread (a test stub without one gets a clock nobody renders)
+        clock = getattr(self.engine, "spans", None) or spans.SpanClock()
         while not self._stop.is_set():
+            clock.tick()
             if not self.engine.has_work():
                 consecutive_failures = 0  # an old incident must not
                 if not getattr(self.engine, "is_multihost", False):
-                    time.sleep(idle_sleep)  # shorten a NEW request's window
+                    with clock.span("loop.idle"):
+                        time.sleep(idle_sleep)  # shorten a NEW request's window
                     continue
                 # multi-process mesh: step unconditionally — the event
                 # exchange at the top of step() is what keeps leader and
@@ -386,7 +392,8 @@ class EngineServer:
                 # running hundreds of tiny collectives per second; the
                 # first request after idle pays at most one long tick.
                 idle_streak += 1
-                time.sleep(min(idle_sleep * idle_streak, 0.025))
+                with clock.span("loop.idle"):
+                    time.sleep(min(idle_sleep * idle_streak, 0.025))
             else:
                 idle_streak = 0
             try:
@@ -438,43 +445,45 @@ class EngineServer:
                 else:
                     time.sleep(0.05)
                     continue
-            now = time.monotonic()
-            for out in outputs:
-                with self._lock:
-                    chan = self._channels.get(out.request_id)
-                    meta = self._req_meta.get(out.request_id)
-                if meta is not None:
-                    tname = meta.get("tier")
-                    if out.is_first_token:
-                        self.metrics.ttft.observe(now - meta["arrival"])
-                        if (self.boot_t0 is not None
-                                and self.metrics.cold_start_ttft_s is None):
-                            # the server's FIRST first-token: boot →
-                            # serving, the AOT warm-start gauge
-                            self.metrics.cold_start_ttft_s = (
-                                now - self.boot_t0)
-                        if tname is not None:
-                            self.metrics.tier_ttft[tname].observe(
-                                now - meta["arrival"])
-                    else:
-                        self.metrics.tpot.observe(now - meta["last_token_time"])
-                        if tname is not None:
-                            self.metrics.tier_tpot[tname].observe(
-                                now - meta["last_token_time"])
-                    meta["last_token_time"] = now
-                    if out.finished:
-                        self.metrics.e2e_latency.observe(now - meta["arrival"])
-                        # a finished request whose client drains slowly
-                        # keeps its channel registered — the watchdog
-                        # must not count it as stalled or expired
-                        meta["finished"] = True
-                if chan is not None:
-                    chan.put(out)
+            with clock.span("loop.publish", outputs=len(outputs)):
+                now = time.monotonic()
+                for out in outputs:
+                    with self._lock:
+                        chan = self._channels.get(out.request_id)
+                        meta = self._req_meta.get(out.request_id)
+                    if meta is not None:
+                        tname = meta.get("tier")
+                        if out.is_first_token:
+                            self.metrics.ttft.observe(now - meta["arrival"])
+                            if (self.boot_t0 is not None
+                                    and self.metrics.cold_start_ttft_s is None):
+                                # the server's FIRST first-token: boot →
+                                # serving, the AOT warm-start gauge
+                                self.metrics.cold_start_ttft_s = (
+                                    now - self.boot_t0)
+                            if tname is not None:
+                                self.metrics.tier_ttft[tname].observe(
+                                    now - meta["arrival"])
+                        else:
+                            self.metrics.tpot.observe(now - meta["last_token_time"])
+                            if tname is not None:
+                                self.metrics.tier_tpot[tname].observe(
+                                    now - meta["last_token_time"])
+                        meta["last_token_time"] = now
+                        if out.finished:
+                            self.metrics.e2e_latency.observe(now - meta["arrival"])
+                            # a finished request whose client drains slowly
+                            # keeps its channel registered — the watchdog
+                            # must not count it as stalled or expired
+                            meta["finished"] = True
+                    if chan is not None:
+                        chan.put(out)
             if getattr(self.engine, "multihost_shutdown", False):
                 # AFTER dispatching this step's outputs: the shutdown
                 # step may carry terminal tokens clients are waiting on
                 logger.info("multihost shutdown event; engine loop exits")
-                return
+                break
+        clock.tick()
 
     # -- watchdog ------------------------------------------------------------
 
@@ -775,11 +784,20 @@ class EngineServer:
             if self._profiling:
                 raise ValueError("a profile capture is already running")
             self._profiling = True
+        # the engine's own spans (utils/spans.py) stand where the Python
+        # tracer's per-call events stood: that tracer slows every thread
+        # for the whole capture and stalls every stream while stop_trace
+        # serialises its events
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         try:
-            jax.profiler.start_trace(out_dir)
+            jax.profiler.start_trace(out_dir, profiler_options=options)
+            spans.capturing = True
             self._profile_sleep(seconds)
+            spans.capturing = False
             jax.profiler.stop_trace()
         finally:
+            spans.capturing = False
             with self._lock:
                 self._profiling = False
         return {"status": "ok", "dir": out_dir, "seconds": seconds}

@@ -213,6 +213,7 @@ def _attention(q, k, v, mask):
     return out.reshape(B, S, H * Hd)
 
 
+@jax.named_scope("attn_qkv")
 def qkv_proj(
     cfg: ModelConfig, layer: Params, x: jax.Array, positions: jax.Array,
     lora: Params = None, adapter_ids: jax.Array = None,
@@ -249,6 +250,7 @@ def qkv_proj(
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def mlp_block(cfg: ModelConfig, layer: Params, x: jax.Array) -> jax.Array:
     """Pre-norm + FFN (dense SwiGLU or MoE), shared by every path.
 
@@ -263,6 +265,19 @@ def mlp_block(cfg: ModelConfig, layer: Params, x: jax.Array) -> jax.Array:
             layer["w_down"], cfg.n_experts_active,
         ).reshape(B, S, D)
     return swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+
+
+@jax.named_scope("attn_out")
+def attn_out_proj(layer: Params, attn: jax.Array, lora: Params = None,
+                  adapter_ids: Optional[jax.Array] = None) -> jax.Array:
+    """Attention output projection (+ its LoRA delta), shared by every
+    path; the residual is NOT added."""
+    out = attn @ layer["wo"]
+    if lora is not None:
+        from fusioninfer_tpu.models.lora import lora_delta
+
+        out = out + lora_delta(lora, "wo", attn, adapter_ids)
+    return out
 
 
 def layer_forward(
@@ -292,43 +307,39 @@ def layer_forward(
     layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
     q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids)
 
-    if kv is None:
-        if mask is not None:
-            raise ValueError(
-                "layer_forward(kv=None) is causal self-attention; it derives "
-                "its own mask — pass kv=(k, v) history to use a custom mask"
-            )
-        from fusioninfer_tpu.ops import dispatch, flash_attention
-
-        if dispatch.resolve_attn(cfg.attn_impl) == "flash" and dispatch.flash_seq_ok(S):
-            # fresh K/V over the full (causal) sequence: Pallas flash path
-            if mesh is not None:
-                from fusioninfer_tpu.ops.sharded import flash_attention_tp
-
-                attn = flash_attention_tp(
-                    mesh, q, k, v, causal=True,
-                    interpret=dispatch.kernel_interpret(),
-                    window=cfg.sliding_window,
+    with jax.named_scope("attn"):
+        if kv is None:
+            if mask is not None:
+                raise ValueError(
+                    "layer_forward(kv=None) is causal self-attention; it derives "
+                    "its own mask — pass kv=(k, v) history to use a custom mask"
                 )
+            from fusioninfer_tpu.ops import dispatch, flash_attention
+
+            if dispatch.resolve_attn(cfg.attn_impl) == "flash" and dispatch.flash_seq_ok(S):
+                # fresh K/V over the full (causal) sequence: Pallas flash path
+                if mesh is not None:
+                    from fusioninfer_tpu.ops.sharded import flash_attention_tp
+
+                    attn = flash_attention_tp(
+                        mesh, q, k, v, causal=True,
+                        interpret=dispatch.kernel_interpret(),
+                        window=cfg.sliding_window,
+                    )
+                else:
+                    attn = flash_attention(
+                        q, k, v, causal=True, interpret=dispatch.kernel_interpret(),
+                        window=cfg.sliding_window,
+                    )
             else:
-                attn = flash_attention(
-                    q, k, v, causal=True, interpret=dispatch.kernel_interpret(),
-                    window=cfg.sliding_window,
-                )
+                attn = _attention(q, k, v,
+                                  causal_mask(S, window=cfg.sliding_window))
         else:
-            attn = _attention(q, k, v,
-                              causal_mask(S, window=cfg.sliding_window))
-    else:
-        if mask is None:
-            raise ValueError("layer_forward with kv history requires a mask")
-        attn_k, attn_v = kv
-        attn = _attention(q, attn_k, attn_v, mask)
-    out_proj = attn @ layer["wo"]
-    if lora is not None:
-        from fusioninfer_tpu.models.lora import lora_delta
-
-        out_proj = out_proj + lora_delta(lora, "wo", attn, adapter_ids)
-    x = x + out_proj
+            if mask is None:
+                raise ValueError("layer_forward with kv history requires a mask")
+            attn_k, attn_v = kv
+            attn = _attention(q, attn_k, attn_v, mask)
+    x = x + attn_out_proj(layer, attn, lora, adapter_ids)
     return x + mlp_block(cfg, layer, x), (k, v)
 
 
@@ -354,6 +365,7 @@ def lm_head_operands(cfg: ModelConfig, params: Params):
     return params["embed"], True
 
 
+@jax.named_scope("lm_head")
 def lm_head(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     """Project hidden states to fp32 logits; tied embeddings fall back to
     the transposed embedding table."""

@@ -1,0 +1,249 @@
+"""Host spans and phase clocks of the engine thread (utils/spans.py):
+self-time arithmetic, the families on /metrics, no annotation outside a
+capture, a capture that holds the spans and no Python call trace, and
+the jit listener."""
+
+import glob
+import json
+import re
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from fusioninfer_tpu.engine.engine import NativeEngine
+from fusioninfer_tpu.engine.kv_cache import CacheConfig
+from fusioninfer_tpu.engine.server import EngineServer
+from fusioninfer_tpu.models.config import get_preset
+from fusioninfer_tpu.utils import spans
+
+CFG = get_preset("qwen3-tiny")
+CACHE = CacheConfig(n_pages=64, page_size=8, max_pages_per_seq=8)
+HOST_FAMILIES = [
+    f"fusioninfer:host_{name.replace('.', '_')}_{kind}_total"
+    for name in spans.SPAN_NAMES for kind in ("seconds", "count")]
+FAMILIES = HOST_FAMILIES + [
+    "fusioninfer:engine_loop_seconds_total",
+    "fusioninfer:engine_thread_cpu_seconds_total",
+    "fusioninfer:jit_seconds_total", "fusioninfer:jit_events_total",
+    "vllm:request_queue_time_seconds_sum",
+    "vllm:request_queue_time_seconds_count",
+    "vllm:request_prefill_time_seconds_sum",
+    "vllm:request_prefill_time_seconds_count"]
+
+
+class FakeClock:
+    def __init__(self):
+        self.t = 0
+
+    def __call__(self):
+        return self.t
+
+
+def test_nested_spans_add_self_times():
+    now = FakeClock()
+    clock = spans.SpanClock(now)
+    with clock.span("step"):                   # [0, 100)
+        now.t = 10
+        with clock.span("step.pack"):          # [10, 60)
+            now.t = 20
+            with clock.span("step.dispatch"):  # [20, 35)
+                now.t = 35
+            now.t = 40
+            with clock.span("step.fetch"):     # [40, 55)
+                now.t = 55
+            now.t = 60
+        now.t = 70
+        with clock.span("step.dispatch"):      # [70, 75), a second one
+            now.t = 75
+        now.t = 100
+    assert clock.stack == []
+    assert clock.ns["step.dispatch"] == 15 + 5
+    assert clock.ns["step.fetch"] == 15
+    assert clock.ns["step.pack"] == 50 - 15 - 15
+    assert clock.ns["step"] == 100 - 50 - 5
+    assert sum(clock.ns.values()) == 100  # self times add up
+    assert clock.count["step.dispatch"] == 2 and clock.count["step"] == 1
+    assert clock.count["loop.idle"] == 0  # pre-seeded, never opened
+
+
+def test_a_span_that_raises_still_closes():
+    now = FakeClock()
+    clock = spans.SpanClock(now)
+    with pytest.raises(ValueError):
+        with clock.span("step"):
+            with clock.span("step.emit"):
+                now.t = 7
+                raise ValueError("boom")
+    assert clock.stack == []
+    assert clock.ns["step.emit"] == 7 and clock.ns["step"] == 0
+
+
+def parse(text: str) -> dict:
+    out = {}
+    for line in text.splitlines():
+        if line and line[0] != "#" and "_bucket{" not in line:
+            name, _, value = line.rpartition(" ")
+            out[name.split("{", 1)[0]] = float(value)
+    return out
+
+
+def complete(srv, prompt: str, max_tokens: int) -> dict:
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{srv.port}/v1/completions",
+        data=json.dumps({"prompt": prompt, "max_tokens": max_tokens,
+                         "temperature": 0.0}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        return json.load(r)
+
+
+def metrics(srv) -> dict:
+    return parse(srv.metrics.render(srv.engine))
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A tiny engine served for a few dozen steps, then stopped: the
+    engine thread has made its last tick, so the totals stand still."""
+    srv = EngineServer(model="qwen3-tiny", host="127.0.0.1", port=0,
+                       max_batch_size=4, cache_cfg=CACHE)
+    srv.start()
+    try:
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/metrics", timeout=30) as r:
+            first = parse(r.read().decode())
+        for i in range(3):
+            complete(srv, "span %d " % i * (2 + i), 12)
+        mid = metrics(srv)
+        complete(srv, "one more", 8)
+    finally:
+        srv.stop()
+    return srv, first, mid, metrics(srv)
+
+
+def test_every_family_is_rendered_and_monotone(served):
+    _, first, mid, last = served
+    for family in FAMILIES:
+        assert family in first, family  # pre-seeded: there before any work
+        assert first[family] <= mid[family] <= last[family], family
+    for name in ("step", "step.admit", "step.pack", "step.dispatch",
+                 "step.fetch", "step.emit", "loop.publish", "loop.idle"):
+        family = f"fusioninfer:host_{name.replace('.', '_')}"
+        assert last[family + "_count_total"] > 0, name
+        assert last[family + "_seconds_total"] > 0, name
+    assert last["fusioninfer:host_step_count_total"] == \
+        last["fusioninfer:sched_steps_total"] >= 20
+    assert 0 < last["fusioninfer:engine_thread_cpu_seconds_total"] \
+        <= last["fusioninfer:engine_loop_seconds_total"]
+
+
+def test_span_seconds_cover_the_loop(served):
+    _, _, _, last = served
+    loop = last["fusioninfer:engine_loop_seconds_total"]
+    covered = sum(last[f] for f in HOST_FAMILIES if f.endswith("_seconds_total"))
+    assert 0.95 * loop <= covered <= loop, (covered, loop)
+
+
+def test_request_waits_count_first_tokens(served):
+    srv, _, _, last = served
+    assert last["vllm:request_queue_time_seconds_count"] == 4
+    assert last["vllm:request_prefill_time_seconds_count"] == 4
+    assert last["vllm:request_queue_time_seconds_count"] == \
+        last["vllm:time_to_first_token_seconds_count"]
+    assert last["vllm:request_prefill_time_seconds_sum"] > 0
+    # the deque the histograms are fed beside stays (bench.py reads it)
+    assert len(srv.engine.admission_timings) == 4
+
+
+def test_no_annotation_outside_a_capture(monkeypatch):
+    opened = []
+
+    class Counting:
+        def __init__(self, name, **attrs):
+            opened.append((name, attrs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return None
+
+        def set_metadata(self, **attrs):
+            opened.append(("note", attrs))
+
+    monkeypatch.setattr(spans, "TraceAnnotation", Counting)
+    clock = spans.SpanClock()
+    assert spans.capturing is False
+    with clock.span("step", step=1) as sp:
+        sp.note(tokens=3)
+    assert opened == [] and clock.count["step"] == 1
+    monkeypatch.setattr(spans, "capturing", True)
+    with clock.span("step", step=2) as sp:
+        sp.note(tokens=3)
+    assert opened == [("step", {"step": 2}), ("note", {"tokens": 3})]
+
+
+def test_a_capture_holds_the_spans_and_no_python_calls(tmp_path):
+    from jax.profiler import ProfileData
+
+    engine = NativeEngine(cfg=CFG, cache_cfg=CACHE, max_batch_size=4, seed=0)
+    srv = EngineServer(model="qwen3-tiny", host="127.0.0.1", port=0,
+                       engine=engine)
+    srv.enable_profiling = True
+    srv.profile_dir = str(tmp_path)
+    # the capture window: requests served while the trace runs
+    srv._profile_sleep = lambda _s: [complete(srv, "traced", 6)
+                                     for _ in range(2)]
+    srv.start()
+    try:
+        complete(srv, "warm", 6)  # compile outside the capture
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{srv.port}/debug/profile",
+            data=json.dumps({"seconds": 0.5}).encode(),
+            headers={"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=300) as r:
+            assert json.load(r)["status"] == "ok"
+        assert spans.capturing is False
+    finally:
+        srv.stop()
+    (xplane,) = glob.glob(str(tmp_path) + "/**/*.xplane.pb", recursive=True)
+    host = [p for p in ProfileData.from_file(xplane).planes
+            if p.name == "/host:CPU"]
+    names: dict[str, int] = {}
+    total = 0
+    for plane in host:
+        for line in plane.lines:
+            for ev in line.events:
+                total += 1
+                names[ev.name] = names.get(ev.name, 0) + 1
+    for name in ("step", "step.fetch", "loop.publish"):
+        assert names.get(name, 0) > 0, sorted(names)[:40]
+    # the default options trace every Python call of every thread:
+    # hundreds of thousands of events for this much work
+    assert total < 50_000, total
+    assert not [n for n in names if re.match(r"^\$.*\.py:\d+ ", n)], \
+        "per-Python-call events in the host plane"
+
+
+def test_jit_listener_counts_a_miss_and_nothing_on_a_hit():
+    from jax._src import monitoring
+
+    spans.watch_jit()
+    spans.watch_jit()  # registered once however often it is asked for
+    assert monitoring.get_event_duration_listeners().count(
+        spans._on_jit_event) == 1
+
+    @jax.jit
+    def fresh(x):
+        return x * 3 + 1
+
+    x = jnp.arange(5.0)
+    before = dict(spans.jit_totals)
+    fresh(x).block_until_ready()
+    first = dict(spans.jit_totals)
+    assert first["events"] >= before["events"] + 2  # trace, lower(, compile)
+    assert first["seconds"] > before["seconds"]
+    fresh(x).block_until_ready()
+    assert dict(spans.jit_totals) == first
