@@ -3645,23 +3645,27 @@ class NativeEngine:
         multi-token path); otherwise the span is chosen by the
         burst-ELIGIBLE rows alone — ineligible rows (``_row_bursts``)
         run the single-step leg of the same pass and never veto the
-        batch.  The decision reads only replicated scheduler state so
+        batch (they count only toward the foreseeable finish below).
+        The decision reads only replicated scheduler state so
         every process of a multi-host lockstep group computes the same
         span.
 
-        ADMISSION-AWARE: a burst amortizes host round trips exactly when
-        there is nothing else to schedule.  While the wait queue (or any
-        other admission work: mid-chunk prefills, PD-prefilled arrivals,
-        pending cancels) is non-empty, the span clamps to 1 so the next
-        admission pass runs after ONE decode step instead of up to
-        ``burst_steps`` of queue-wait — the burst resumes the moment the
-        queue is dry."""
+        ADMISSION-AWARE: a burst never delays an admission the host can
+        foresee.  While anything is ADMISSIBLE (`_admission_pending`)
+        the span clamps to 1, so the next admission pass runs after ONE
+        decode step.  A waiter that cannot get in yet (every slot taken
+        by work at least as urgent) clamps nothing by itself: it clamps
+        only while some row will exhaust its budget inside the span —
+        the slot the host can SEE freeing mid-burst
+        (`_waiter_slot_frees_within`).  An EOS nobody can foresee costs
+        a waiter at most one span, the lag an off-peak arrival already
+        pays.  A clamped span still pipelines (`_pipeline_ready`)."""
         k = self.burst_steps
         if k <= 1 or self.spec_k:
             return 1
-        eligible = [st for st in self.running.values()
-                    if st.n_generated < st.request.params.max_tokens
-                    and self._row_bursts(st)]
+        live = [st for st in self.running.values()
+                if st.n_generated < st.request.params.max_tokens]
+        eligible = [st for st in live if self._row_bursts(st)]
         if not eligible:
             return 1
         # only burst while it can amortize: every row short of the full
@@ -3670,26 +3674,54 @@ class NativeEngine:
         if max(st.request.params.max_tokens - st.n_generated
                for st in eligible) < k:
             return 1
-        if self._admission_pending():
+        if (self._admission_pending()
+                or self._waiter_slot_frees_within(live, k)):
             # counted only when a burst WOULD have dispatched but for
-            # the pending admission work — the clamp metric must track
-            # actual trade-offs, not idle chunk-prefill steps
+            # the admissible / foreseeable work — the clamp metric must
+            # track actual trade-offs, not idle chunk-prefill steps
             self.sched.burst_clamped_total += 1
             return 1
         return k
 
     def _admission_pending(self) -> bool:
-        """Any scheduler work besides decoding the current batch?  All
-        inputs are replicated state (the leader-only future maps are NOT
-        consulted): multi-host processes answer identically.  The
-        single-host ``_cancelled`` read is lock-free by design — a cancel
-        racing this check is caught by the next step's drain."""
-        return bool(
-            self.waiting or self.waiting_prefilled or self.prefilling
-            or self._cancelled or not self._slab_q.empty()
-            or not self._embed_q.empty()
-            or self._pd_pending or self._embed_pending
-        )
+        """Is there scheduler work the NEXT host turn could act on,
+        besides decoding the current batch?  The one predicate behind
+        both the span clamp and the dispatch-ahead gate.  Everything
+        but the wait queue counts by being there; the wait queue counts
+        only if its head could get in: a slot is available, or a
+        running row is strictly less urgent than the head (what
+        `_admit`'s `_preempt_youngest(than_key=...)` and
+        `_tier_budget_evict` would take — equal urgency waits for a
+        finish).  Pages are not priced here (`can_admit` builds a hash
+        chain): a free slot without pages stays pending, which only
+        costs overlap.  All inputs are replicated state (the leader-only
+        future maps are NOT consulted): multi-host processes answer
+        identically.  The single-host ``_cancelled`` read is lock-free
+        by design — a cancel racing this check is caught by the next
+        step's drain."""
+        if (self.waiting_prefilled or self.prefilling or self._cancelled
+                or not self._slab_q.empty() or not self._embed_q.empty()
+                or self._pd_pending or self._embed_pending):
+            return True
+        with self._lock:
+            if not self.waiting:
+                return False
+            head_key = _urgency(self.waiting.peek())
+        # nothing is mid-prefill here, so the running rows are the only
+        # victims a more urgent head could displace
+        return self._avail_slots() > 0 or any(
+            _urgency(st.request) > head_key for st in self.running.values())
+
+    def _waiter_slot_frees_within(self, rows, span: int,
+                                  inflight: int = 0) -> bool:
+        """With a request waiting, would a span-``span`` burst
+        dispatched after ``inflight`` more tokens outlast some row's
+        budget?  That finish frees the waiter's slot at a step the host
+        can name now, and a fused span would hold the admission back to
+        its end — so the span stays 1 (which still pipelines)."""
+        return span > 1 and bool(self.waiting) and min(
+            st.request.params.max_tokens - st.n_generated - inflight
+            for st in rows) < span
 
     def _dispatch_burst(self, ctl_i_dev, ctl_f_dev, page_tables_dev,
                         span: int, mode: str, lora):
@@ -3717,17 +3749,19 @@ class NativeEngine:
 
     def _pipeline_ready(self, snapshot: dict, span: int) -> bool:
         """May the successor burst dispatch from the device-side carry?
-        Only in steady state: no pending scheduler work of any kind and
-        the running set EXACTLY the snapshot (same objects) — any
-        admission, cancellation, finish or preemption since the
-        snapshot was taken breaks the chain and the next pass rebuilds
-        controls from host state."""
+        Whenever nothing is admissible and the running set is EXACTLY
+        the snapshot (same objects) — any admission, cancellation,
+        finish or preemption since the snapshot was taken breaks the
+        chain and the next pass rebuilds controls from host state.  A
+        queue that cannot be admitted from (full slots, nobody less
+        urgent) does not stop the chain: that is the case in which the
+        host's turn would otherwise be exposed on every step."""
         if (not self.pipeline_bursts or self._mh is not None
                 or self.spec_k):
             return False
         # same predicate as _burst_span's clamp — the two gates enforce
-        # one invariant (a burst never adds queue-wait) and must not
-        # drift as admission sources are added
+        # one invariant (a burst never delays an admission the host can
+        # foresee) and must not drift as admission sources are added
         if self._admission_pending():
             return False
         if len(self.running) != len(snapshot):
@@ -3735,9 +3769,14 @@ class NativeEngine:
         for s, st in snapshot.items():
             if self.running.get(s) is not st:
                 return False
+        # the successor inherits the span, so it answers the span gate's
+        # question too (host n_generated is stale by exactly the
+        # in-flight span here)
+        if self._waiter_slot_frees_within(snapshot.values(), span,
+                                          inflight=span):
+            return False
         # amortization: after the in-flight burst lands, at least one
-        # row must still have a full span of budget left (host
-        # n_generated is stale by exactly the in-flight span here)
+        # row must still have a full span of budget left
         return max(st.request.params.max_tokens - st.n_generated - span
                    for st in snapshot.values()) >= span
 

@@ -497,10 +497,10 @@ class EngineMetrics:
             "# HELP fusioninfer:sched_admission_deferred_total Admissions routed to chunked prefill because the step budget was spent.",
             "# TYPE fusioninfer:sched_admission_deferred_total counter",
             f"fusioninfer:sched_admission_deferred_total{{{labels}}} {sched.admission_deferred_total}",
-            "# HELP fusioninfer:sched_burst_clamped_total Decode bursts clamped to span 1 because admission work was pending.",
+            "# HELP fusioninfer:sched_burst_clamped_total Decode bursts clamped to span 1 because work was admissible or a waiter's slot would free inside the span; a clamped burst still pipelines (see sched_dispatch_ahead_total).",
             "# TYPE fusioninfer:sched_burst_clamped_total counter",
             f"fusioninfer:sched_burst_clamped_total{{{labels}}} {sched.burst_clamped_total}",
-            "# HELP fusioninfer:sched_dispatch_ahead_total Successor decode bursts dispatched before the in-flight fetch.",
+            "# HELP fusioninfer:sched_dispatch_ahead_total Successor decode bursts dispatched before the in-flight fetch: runs whenever nothing is admissible.",
             "# TYPE fusioninfer:sched_dispatch_ahead_total counter",
             f"fusioninfer:sched_dispatch_ahead_total{{{labels}}} {sched.dispatch_ahead_total}",
             "# HELP fusioninfer:sched_kv_restores_total KV pages restored from the host tier, charged against the step budget.",

@@ -68,7 +68,9 @@ class TokenBudget:
     # budget was spent (not because the prompt exceeded the chunk
     # threshold) — the admission-smoothing decision counter
     admission_deferred_total: int = 0
-    # decode bursts clamped to span 1 because admission work was pending
+    # decode bursts clamped to span 1 because work was admissible, or a
+    # waiter's slot would free inside the span (not "pipeline off":
+    # a clamped burst still dispatches ahead)
     burst_clamped_total: int = 0
     # successor bursts dispatched BEFORE the in-flight fetch (the
     # dispatch-ahead pipelining counter)

@@ -30,11 +30,13 @@ def make_engine(burst=1, cache=CACHE, cfg=CFG, **over):
     return NativeEngine(**kw)
 
 
-def run_to_completion(engine, max_steps=300):
+def run_to_completion(engine, max_steps=300, before_step=None):
     outputs, finished = {}, {}
-    for _ in range(max_steps):
+    for step in range(max_steps):
         if not engine.has_work():
             break
+        if before_step is not None:
+            before_step(engine, step)
         for out in engine.step():
             outputs.setdefault(out.request_id, []).append(out.token)
             if out.finished:
@@ -42,11 +44,11 @@ def run_to_completion(engine, max_steps=300):
     return outputs, finished
 
 
-def collect(burst, requests, cache=CACHE, **over):
+def collect(burst, requests, cache=CACHE, before_step=None, **over):
     engine = make_engine(burst, cache=cache, **over)
     for r in requests:
         engine.add_request(r)
-    outs, fins = run_to_completion(engine)
+    outs, fins = run_to_completion(engine, before_step=before_step)
     assert engine.num_running == 0
     return outs, fins
 
@@ -273,6 +275,51 @@ class TestBurstPipelining:
         # regardless of pipelining-induced scheduling differences
         assert piped["a"] == base["a"]
         assert piped["b"] == base["b"]
+
+    @pytest.mark.parametrize("case", [
+        "length", "sampled", "eos_mid_chain", "cancel_mid_chain",
+        "logprobs_row", "sliding_window"])
+    def test_standing_queue_identity(self, case):
+        """Two slots, six equal-priority requests: the queue stands on
+        full slots for most of the run and nothing in it is admissible,
+        so the chain runs behind it.  Every stream must match the
+        unpipelined engine's, whichever way a row leaves mid-chain."""
+        lengths = (21, 34, 27, 18, 25, 30)
+        temp = 0.8 if case == "sampled" else 0.0
+        stop = []
+        if case == "eos_mid_chain":
+            probe, _ = collect(1, [Request("p", [3, 4, 6], SamplingParams(
+                temperature=0.0, max_tokens=34))])
+            stop = [probe["p"][13]]
+        cfg = get_preset("mistral-tiny") if case == "sliding_window" else CFG
+
+        def reqs():
+            return [Request(f"q{i}", [2 + i, 4, 6], SamplingParams(
+                temperature=temp, seed=7 + i, max_tokens=n,
+                stop_token_ids=stop if i == 1 else [],
+                logprobs=1 if case == "logprobs_row" and i == 0 else None))
+                for i, n in enumerate(lengths)]
+
+        ahead_behind_queue = []
+
+        def before_step(engine, step):
+            if case == "cancel_mid_chain" and step == 7:
+                engine.cancel("q1")
+            if engine.num_waiting and not engine._admission_pending():
+                ahead_behind_queue.append(engine.sched.dispatch_ahead_total)
+
+        kw = dict(max_batch_size=2, cfg=cfg, before_step=before_step)
+        base, fb = collect(4, reqs(), pipeline_bursts=False, **kw)
+        assert not any(ahead_behind_queue)
+        piped, fp = collect(4, reqs(), pipeline_bursts=True, **kw)
+        assert ahead_behind_queue[-1] > 0, "the queue stopped the chain"
+        if case == "cancel_mid_chain":
+            assert len(piped.pop("q1")) < lengths[1]
+            base.pop("q1")
+        if case == "eos_mid_chain":
+            assert fp["q1"] == "stop" and len(piped["q1"]) < lengths[1]
+        assert piped == base
+        assert fp == fb
 
     def test_cancel_mid_flight(self):
         engine = make_engine(4, pipeline_bursts=True)

@@ -4,8 +4,9 @@ The invariants under test, in acceptance-criteria order:
 
 * a mid-prefill long prompt never blocks decode for more than one
   budgeted chunk (stall-free batching);
-* admission is never deferred by a decode burst while the wait queue is
-  non-empty (admission-aware spans);
+* a decode burst never delays an admission the host can foresee, and
+  dispatch-ahead runs whenever nothing is admissible — a full batch
+  with a standing queue included (admission-aware spans);
 * priority / preemption ordering is identical to the unbudgeted engine
   on the same schedule (the budget decides WHEN prefill tokens are
   spent, never who wins pages or slots);
@@ -42,7 +43,7 @@ def _run_all(engine, requests, max_steps=400):
             break
         for out in engine.step():
             assert not (out.finish_reason or "").startswith("error"), out
-            tokens[out.request_id].append(out.token)
+            tokens.setdefault(out.request_id, []).append(out.token)
     assert not engine.has_work(), "engine did not drain"
     return tokens
 
@@ -214,38 +215,152 @@ class TestStallFreeDecode:
 class TestAdmissionAwareBurst:
     CACHE = CacheConfig(n_pages=64, page_size=8, max_pages_per_seq=8)
 
-    def test_burst_never_defers_admission(self):
-        """With a full batch and a waiter, spans clamp to 1: the running
-        row advances exactly one token per step until the queue drains,
-        then bursts resume."""
-        engine = NativeEngine(CFG, cache_cfg=self.CACHE, max_batch_size=1,
-                              decode_burst_steps=8)
+    def _full_slot(self, **over):
+        """One slot, taken by a runner mid-stream with the queue dry."""
+        kw = dict(cache_cfg=self.CACHE, max_batch_size=1,
+                  decode_burst_steps=8)
+        kw.update(over)
+        engine = NativeEngine(CFG, **kw)
         engine.add_request(Request("run", [2, 4, 6],
                                    SamplingParams(max_tokens=60,
-                                                  temperature=0.0)))
-        engine.step()  # running; queue dry
+                                                  temperature=0.0),
+                                   priority=5))
+        engine.step()
+        return engine
+
+    def test_equal_priority_waiter_pipelines_and_is_not_delayed(self):
+        """A full batch with an equal-priority waiter has nothing
+        admissible: dispatch-ahead keeps running behind the queue, spans
+        fuse while no finish is in sight, and the waiter still gets in
+        on the step right after the runner's last token."""
+        engine = self._full_slot()
         engine.add_request(Request("wait", [9, 8],
                                    SamplingParams(max_tokens=4,
-                                                  temperature=0.0)))
-        # a burst dispatched while the queue WAS dry may still be in
-        # flight; it lands on the first step after arrival (the one-burst
-        # lag) — every later step must clamp to span 1
-        engine.step()
-        while engine.num_waiting:  # blocked on the single slot
-            per_step = {}
-            for o in engine.step():
-                per_step[o.request_id] = per_step.get(o.request_id, 0) + 1
-            if engine.num_waiting:
-                # invariant: no NEW burst while the wait queue is non-empty
-                assert per_step.get("run", 0) <= 1
-        assert engine.sched.burst_clamped_total > 0
-        # queue drained: the engine finishes the remaining work cleanly
-        for _ in range(200):
+                                                  temperature=0.0),
+                                   priority=5))
+        assert not engine._admission_pending()
+        ahead0 = engine.sched.dispatch_ahead_total
+        finished_at = first_at = None
+        spans = []
+        for step in range(200):
             if not engine.has_work():
                 break
+            per_step = 0
             for o in engine.step():
                 assert not (o.finish_reason or "").startswith("error"), o
+                per_step += o.request_id == "run"
+                if o.request_id == "run" and o.finished:
+                    finished_at = step
+                    ahead_while_blocked = (engine.sched.dispatch_ahead_total
+                                           - ahead0)
+                if o.request_id == "wait" and o.is_first_token:
+                    first_at = step
+            if finished_at in (None, step):
+                spans.append(per_step)
         assert not engine.has_work()
+        assert ahead_while_blocked > 0, "a standing queue stopped the chain"
+        assert 8 in spans, "no span fused while no finish was in sight"
+        # the foreseeable finish: once fewer than 8 tokens of budget are
+        # left, they go out one a step
+        assert spans[-4:] == [8, 1, 1, 1], spans
+        assert first_at == finished_at + 1
+        assert engine.preemptions_total == 0
+
+    @pytest.mark.parametrize("tiers", [False, True])
+    def test_more_urgent_waiter_clamps_and_preempts_next_step(self, tiers):
+        over = dict(token_budget=16) if tiers else {}
+        engine = self._full_slot(**over)
+        if tiers:
+            engine.set_slo_tiers({0: 0.7, 5: 0.3})
+        engine.step()  # a span-8 chain is running
+        assert engine._inflight is not None
+        engine.add_request(Request("urgent", [9, 8],
+                                   SamplingParams(max_tokens=4,
+                                                  temperature=0.0),
+                                   priority=0))
+        assert engine._admission_pending()
+        outs = engine.step()
+        assert engine.preemptions_total == 1
+        assert any(o.request_id == "urgent" and o.is_first_token
+                   for o in outs)
+        # the in-flight burst carried only the victim: nothing of it is
+        # emitted after the preemption
+        assert not any(o.request_id == "run" for o in outs)
+        assert len(_run_all(engine, [])["urgent"]) == 3
+
+    def test_free_slot_waiter_clamps_as_before(self):
+        """A waiter that finds a slot but no pages stays admissible work
+        (pages are not priced by the predicate): every step clamps to
+        one token and nothing is dispatched ahead."""
+        engine = self._full_slot(
+            max_batch_size=2,
+            cache_cfg=CacheConfig(n_pages=9, page_size=8,
+                                  max_pages_per_seq=8))
+        engine.add_request(Request("wait", list(range(1, 57)),
+                                   SamplingParams(max_tokens=4,
+                                                  temperature=0.0),
+                                   priority=5))
+        engine.step()  # lands a burst dispatched while the queue was dry
+        ahead0 = engine.sched.dispatch_ahead_total
+        clamped0 = engine.sched.burst_clamped_total
+        blocked_steps = 0
+        while engine.num_waiting and blocked_steps < 100:
+            assert engine._admission_pending()
+            outs = engine.step()
+            if engine.num_waiting:
+                blocked_steps += 1
+                assert sum(o.request_id == "run" for o in outs) <= 1
+                assert engine.sched.dispatch_ahead_total == ahead0
+        assert blocked_steps > 0, "the waiter was never blocked on pages"
+        assert engine.sched.burst_clamped_total > clamped0
+        _run_all(engine, [])
+
+    @pytest.mark.parametrize("source", [
+        "waiting_prefilled", "prefilling", "_cancelled", "_pd_pending",
+        "_embed_pending", "_slab_q", "_embed_q"])
+    def test_other_admission_sources_count_on_full_slots(self, source):
+        """Only the wait queue is judged by admissibility: PD arrivals,
+        mid-chunk prefills, cancels and the slab / embedding queues
+        clamp and stop the chain by being there."""
+        engine = self._full_slot()
+        assert not engine._admission_pending()
+        held = getattr(engine, source)
+        if hasattr(held, "put"):
+            held.put(object())
+        elif hasattr(held, "append"):
+            held.append(object())
+        elif isinstance(held, dict):
+            held["x"] = None
+        else:
+            held.add("x")
+        assert engine._admission_pending()
+        assert engine._burst_span() == 1
+        snapshot = dict(engine.running)
+        assert not engine._pipeline_ready(snapshot, 1)
+
+    @pytest.mark.parametrize("kind", ["multihost", "speculative", "burst1",
+                                      "pipeline_off"])
+    def test_paths_that_never_pipeline_stay_unpipelined(self, kind):
+        """Multi-host lockstep, speculative decoding, --decode-burst 1
+        and pipelining switched off keep stepping unpipelined behind a
+        standing queue; only the predicate's inputs changed."""
+        over = {"speculative": dict(speculative_k=2),
+                "burst1": dict(decode_burst_steps=1),
+                "pipeline_off": dict(pipeline_bursts=False)}.get(kind, {})
+        engine = self._full_slot(**over)
+        engine.add_request(Request("wait", [9, 8],
+                                   SamplingParams(max_tokens=4,
+                                                  temperature=0.0),
+                                   priority=5))
+        assert not engine._admission_pending()
+        if kind == "multihost":
+            engine._mh = object()  # pose as a multi-process engine
+            assert not engine._pipeline_ready(dict(engine.running), 1)
+            # replicated state only: nothing leader-side was read
+            assert not engine._admission_pending()
+            return
+        _run_all(engine, [])
+        assert engine.sched.dispatch_ahead_total == 0
 
     def test_spans_recorded_in_histogram(self):
         engine = NativeEngine(CFG, cache_cfg=self.CACHE, max_batch_size=2,
