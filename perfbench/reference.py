@@ -1,7 +1,7 @@
 """The plain reference: the configuration's forward pass in straightforward
 ``jax.numpy``, float32 at ``highest`` matmul precision, no kernels, no
-cache, no batching — and the weights, made from the seed by this file's
-own restatement of the published initialisation.
+cache, no batching — and the weights, made from the seed by the
+benchmark's own restatement of the served initialisation.
 
 It imports nothing of the program and takes nothing the program made.
 It runs as a child process of the harness once the window has closed
@@ -13,20 +13,36 @@ token's logit lies below the reference's best.
 
     python perfbench/reference.py <job.json> <out.json>
 
-Architecture (Qwen3, huggingface.co/Qwen/Qwen3-8B, modeling_qwen3.py):
-pre-norm decoder; RMSNorm; grouped-query attention with per-head
-RMSNorm on q and k before rotary embedding (half-rotation layout,
-theta from the config); causal softmax attention scaled by
-1/sqrt(head_dim); SwiGLU feed-forward; tied or untied output head.
-Departures: none in the mathematics.  Weights are random, not trained:
-each matrix is N(0, 1/fan_in) from ``jax.random.normal`` under the key
-``split(key(seed), 12)[slot]`` with the layer axis leading, rounded to
-the configuration's dtype; norm gains are 1.  That is the recipe the
-served model is documented to use for ``--seed``; it is restated here.
+This file is the judge and belongs to no architecture: which positions
+are compared, what a gap is, the final norm and the tied or untied output
+head over ``vocab_size`` as the configuration's file gives them, the
+int8 control, the padding, the job's reading and the timing.  The layers
+are an architecture's, found BY NAME: the configuration's published
+``model_type`` names ``perfbench/arch/<model_type>.py`` (``run.py``
+resolves it before it starts the server and hands the path on as the
+job's ``arch``).  A new architecture is a new file there, never an edit
+here.  Such a file imports nothing of the program, and no jax until it is
+called; it may import ``Q_BLOCK``, ``int8_round`` and ``rms_norm`` from
+this one.  It gives:
 
-Layers are placed over the machine's chips as pipeline stages (a model
-that needs four chips to serve needs them here too); each stage is one
-``lax.scan`` over its layers.
+- ``Forward(cfg, seed, devices)``: the weights, drawn from the seed by
+  the architecture's own restatement of the served initialisation, in
+  the configuration's dtype, placed over the job's devices as the file
+  sees fit;
+- ``Forward.hidden(padded, quant)``: the last layer's output ``[S, D]``
+  (before the final norm) of one padded sequence of ``S`` token ids, in
+  float32 at ``highest`` precision, on the device where ``head`` lies;
+  with ``quant`` every matrix is first rounded to per-output-channel
+  int8 (``int8_round``).  ``S`` is a power of two, at least 1024, so a
+  multiple of ``Q_BLOCK``; the tail past the real tokens is zeros, after
+  every real position, so a causal layer changes nothing before it.  A
+  sequence past what one chip holds in one program is computed in blocks
+  by the architecture's file;
+- ``Forward.head``: the output head's matrix as served, ``[V, D]`` (the
+  embedding) where ``tie_word_embeddings``, else ``[D, V]``;
+- the five counts that ``work.py`` looks up: ``matmul_params``,
+  ``token_flops``, ``prompt_flops``, ``kv_bytes_per_position``,
+  ``decode_kv_bytes``, each over the configuration's dict.
 
 The control (``"control": true`` in the job) is the same forward with
 every matrix rounded to symmetric per-output-channel int8 — the nearest
@@ -38,91 +54,22 @@ of the token that the lower precision puts first.
 from __future__ import annotations
 
 import json
-import math
 import sys
 import time
 from functools import partial
 
 import numpy as np
 
-# slot of each matrix in split(key(seed), 12); (shape, fan_in) by name
-SLOTS = {"wq": 0, "wk": 1, "wv": 2, "wo": 3, "w_gate": 5, "w_up": 6,
-         "w_down": 7, "embed": 8, "lm_head": 9}
+import work
+
 Q_BLOCK = 512       # queries per attention block (bounds the score tensor)
-SEQ_BUCKETS = (1024, 2048, 4096)  # padded lengths: three programs at most
 
 
-def sizes(cfg: dict) -> dict:
-    return {
-        "L": cfg["num_hidden_layers"], "D": cfg["hidden_size"],
-        "H": cfg["num_attention_heads"], "KV": cfg["num_key_value_heads"],
-        "Hd": cfg["head_dim"], "F": cfg["intermediate_size"],
-        "V": cfg["vocab_size"], "eps": cfg["rms_norm_eps"],
-        "theta": float(cfg["rope_theta"]),
-        "tied": bool(cfg["tie_word_embeddings"]),
-        "dtype": cfg["torch_dtype"],
-    }
-
-
-def layer_shapes(z: dict) -> dict:
-    L, D, H, KV, Hd, F = z["L"], z["D"], z["H"], z["KV"], z["Hd"], z["F"]
-    return {"wq": ((L, D, H * Hd), D), "wk": ((L, D, KV * Hd), D),
-            "wv": ((L, D, KV * Hd), D), "wo": ((L, H * Hd, D), H * Hd),
-            "w_gate": ((L, D, F), D), "w_up": ((L, D, F), D),
-            "w_down": ((L, F, D), F)}
-
-
-def make_weights(z: dict, seed: int, devices: list):
-    """(per-stage layer weights, embed, head): each stacked matrix is
-    drawn whole under one key and born sharded over the stages on its
-    layer axis (jax's counter-based generator gives every element the
-    same value however the array is split)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.sharding import SingleDeviceSharding
-
-    dtype = jnp.dtype(z["dtype"])
-    n = len(devices)
-    if z["L"] % n:
-        raise ValueError(f"{z['L']} layers do not divide over {n} stages")
-    mesh = Mesh(np.array(devices), ("stage",))
-    keys = jax.random.split(jax.random.key(seed), 12)
-
-    # The division by sqrt(fan_in) is a true division where the served
-    # model draws its weights op by op (one chip), and whatever XLA makes
-    # of a division by a constant where it draws them under one jit
-    # (several chips): about one element in 1e5 differs by one bfloat16
-    # step between the two, so the reference follows the same form.
-    folded = n > 1
-
-    def dense(k, denom, shape, fan_in):
-        if folded:
-            denom = jnp.sqrt(fan_in)
-        return (jax.random.normal(k, shape, jnp.float32) / denom).astype(dtype)
-
-    def draw(name, shape, fan_in, sharding):
-        make = jax.jit(partial(dense, shape=shape, fan_in=fan_in),
-                       out_shardings=sharding)
-        return make(keys[SLOTS[name]], jnp.sqrt(fan_in))
-
-    stacked = {
-        name: draw(name, shape, fan_in, NamedSharding(mesh, P("stage")))
-        for name, (shape, fan_in) in layer_shapes(z).items()}
-    stages = []
-    for d in devices:
-        stages.append({
-            name: next(s.data for s in arr.addressable_shards
-                       if s.device == d)
-            for name, arr in stacked.items()})
-    first, last = devices[0], devices[-1]
-    embed = draw("embed", (z["V"], z["D"]), z["D"],
-                 SingleDeviceSharding(first))
-    head = None
-    if not z["tied"]:
-        head = draw("lm_head", (z["D"], z["V"]), z["D"],
-                    SingleDeviceSharding(last))
-    return stages, embed, head
+def seq_bucket(n: int) -> int:
+    """The padded length of a sequence of ``n`` tokens: the least power
+    of two that holds it, 1024 at the least (1024, 2048 and 4096 for
+    contexts to 4096: three programs)."""
+    return max(1024, 1 << (n - 1).bit_length())
 
 
 def int8_round(w, axis: int):
@@ -140,86 +87,6 @@ def rms_norm(x, eps):
     from jax import lax
 
     return x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
-
-
-def rope(x, positions, theta):
-    """x [S, heads, Hd], rotate-half layout."""
-    import jax.numpy as jnp
-
-    hd = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = positions[:, None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(q, k, v):
-    """Causal softmax attention, q [S,H,Hd], k/v [S,KV,Hd], in blocks of
-    queries so the score tensor stays small."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    S, H, Hd = q.shape
-    KV = k.shape[1]
-    G = H // KV
-    nb = S // Q_BLOCK
-    qb = q.reshape(nb, Q_BLOCK, KV, G, Hd)
-    t = jnp.arange(S)
-
-    def block(args):
-        qi, b = args
-        pos = b * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.einsum("qkgd,tkd->kgqt", qi, k) / math.sqrt(Hd)
-        s = jnp.where(t[None, None, None, :] <= pos[None, None, :, None],
-                      s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("kgqt,tkd->qkgd", p, v)
-
-    out = lax.map(block, (qb, jnp.arange(nb)))
-    return out.reshape(S, H * Hd)
-
-
-def stage_forward(z: dict, quant: bool, x, layers):
-    """x [S, D] float32 through this stage's layers (one scan)."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    S = x.shape[0]
-    pos = jnp.arange(S)
-    H, KV, Hd = z["H"], z["KV"], z["Hd"]
-
-    def w_of(layer, name):
-        w = layer[name].astype(jnp.float32)
-        return int8_round(w, 0) if quant else w
-
-    def body(x, layer):
-        h = rms_norm(x, z["eps"])  # gain 1
-        q = (h @ w_of(layer, "wq")).reshape(S, H, Hd)
-        k = (h @ w_of(layer, "wk")).reshape(S, KV, Hd)
-        v = (h @ w_of(layer, "wv")).reshape(S, KV, Hd)
-        q = rope(rms_norm(q, z["eps"]), pos, z["theta"])
-        k = rope(rms_norm(k, z["eps"]), pos, z["theta"])
-        x = x + attention(q, k, v) @ w_of(layer, "wo")
-        h = rms_norm(x, z["eps"])
-        gate = jax.nn.silu(h @ w_of(layer, "w_gate"))
-        x = x + (gate * (h @ w_of(layer, "w_up"))) @ w_of(layer, "w_down")
-        return x, None
-
-    with jax.default_matmul_precision("highest"):
-        x, _ = lax.scan(body, x, layers)
-    return x
-
-
-def embed_tokens(quant: bool, embed, tokens):
-    import jax.numpy as jnp
-
-    rows = embed[tokens].astype(jnp.float32)
-    if quant:  # the embedding is read by row: one scale per row
-        rows = int8_round(rows, 1)
-    return rows
 
 
 def head_logits(z: dict, quant: bool, x, rows, embed_or_head):
@@ -254,14 +121,12 @@ def head_first(z: dict, quant: bool, x, rows, embed_or_head):
 
 
 class Reference:
-    def __init__(self, cfg: dict, seed: int, n_devices: int):
+    def __init__(self, arch, cfg: dict, seed: int, n_devices: int):
         import jax
 
-        self.z = sizes(cfg)
-        devices = jax.local_devices()[:n_devices]
-        self.devices = devices
-        self.stages, self.embed, self.head = make_weights(
-            self.z, seed, devices)
+        self.z = {"eps": cfg["rms_norm_eps"],
+                  "tied": bool(cfg["tie_word_embeddings"])}
+        self.model = arch.Forward(cfg, seed, jax.local_devices()[:n_devices])
         self._fns: dict = {}
 
     def _fn(self, what: str, quant: bool = False):
@@ -269,10 +134,7 @@ class Reference:
 
         key = (what, quant)
         if key not in self._fns:
-            f = {"embed": partial(embed_tokens, quant),
-                 "stage": partial(stage_forward, self.z, quant),
-                 "logits": partial(head_logits, self.z, quant),
-                 "first": partial(head_first, self.z, quant),
+            f = {"first": partial(head_first, self.z, quant),
                  "gaps": partial(head_gaps, self.z)}[what]
             self._fns[key] = jax.jit(f)
         return self._fns[key]
@@ -280,27 +142,10 @@ class Reference:
     def hidden(self, tokens: list[int], quant: bool):
         """The last layer's output [S, D] of one sequence (padded to a
         bucket), on the device that holds the output head."""
-        import jax
-        import jax.numpy as jnp
-
-        S = next((b for b in SEQ_BUCKETS if len(tokens) <= b), None)
-        if S is None:
-            raise ValueError(f"sequence of {len(tokens)} tokens is longer "
-                             f"than the largest bucket {SEQ_BUCKETS[-1]}")
-        padded = np.zeros((S,), np.int32)
+        padded = np.zeros((seq_bucket(len(tokens)),), np.int32)
         padded[:len(tokens)] = tokens  # the tail is after every real
         # position, and attention is causal: it changes nothing before it
-        x = self._fn("embed", quant)(
-            self.embed, jax.device_put(jnp.asarray(padded), self.devices[0]))
-        for dev, layers in zip(self.devices, self.stages):
-            x = self._fn("stage", quant)(jax.device_put(x, dev), layers)
-        return jax.device_put(x, self._head_device())
-
-    def _head_device(self):
-        return self.devices[0] if self.z["tied"] else self.devices[-1]
-
-    def _head(self):
-        return self.embed if self.z["tied"] else self.head
+        return self.model.hidden(padded, quant)
 
     def _rows(self, rows: list[int], fill: int = 0):
         import jax
@@ -308,22 +153,17 @@ class Reference:
 
         padded = np.full((256 * -(-len(rows) // 256),), fill, np.int32)
         padded[:len(rows)] = rows
-        return jax.device_put(jnp.asarray(padded), self._head_device())
+        return jax.device_put(jnp.asarray(padded),
+                              next(iter(self.model.head.devices())))
 
     def gaps(self, x, rows: list[int], tokens) -> np.ndarray:
         out = self._fn("gaps")(x, self._rows(rows), self._rows(tokens),
-                               self._head())
+                               self.model.head)
         return np.asarray(out)[:len(rows)]
 
     def first(self, x, rows: list[int], quant: bool) -> np.ndarray:
-        out = self._fn("first", quant)(x, self._rows(rows), self._head())
+        out = self._fn("first", quant)(x, self._rows(rows), self.model.head)
         return np.asarray(out)[:len(rows)]
-
-    def logits(self, tokens: list[int], rows: list[int], quant: bool):
-        """float32 logits [len(rows), V] of one sequence at ``rows``."""
-        out = self._fn("logits", quant)(
-            self.hidden(tokens, quant), self._rows(rows), self._head())
-        return out[:len(rows)]
 
 
 def gaps_of(ref: Reference, prompt_ids: list[int], served: list[int],
@@ -362,7 +202,8 @@ def main(argv: list[str]) -> int:
         print(f"reference: wanted {job['chips']} x {job['platform']}, found "
               f"{len(dev)} x {dev[0].platform}", file=sys.stderr)
         return 3
-    ref = Reference(job["config"], job["seed"], job["chips"])
+    ref = Reference(work.load_arch(job["arch"]), job["config"], job["seed"],
+                    job["chips"])
     t1 = time.monotonic()
     results = []
     for req in job["requests"]:

@@ -6,11 +6,17 @@
 A cell is a configuration under a traffic mix.  This file finds both, and
 the cell's metrics, BY NAME: ``perfbench/configs/<config>.json`` (the
 ``file`` of the configuration's entry), ``perfbench/traffic/<traffic>.json``
-and ``perfbench/metrics/<metric>.py``.  There is no registry: a later PR
-adds a cell, a mix or a metric by adding files and entries.
+and ``perfbench/metrics/<metric>.py`` — and the configuration's
+architecture, ``perfbench/arch/<model_type>.py`` by the ``model_type`` its
+file publishes: that architecture's plain forward, which ``reference.py``
+judges the served tokens by, and its work counts, which ``work.py`` looks
+up.  There is no registry and no default: a later PR adds a cell, a mix, a
+metric or an architecture by adding files and entries.
 
 What a run does, in order (everything before "window" is set-up):
 
+0. resolves the architecture's file, and fails at once, naming the path it
+   looked for, where the configuration names none or the file is missing;
 1. starts ``python -m fusioninfer_tpu.cli engine serve`` as a child with the
    configuration's flags and ``--seed`` (this process never imports jax),
    waits for ``/health`` and fails if the engine reports a demotion the
@@ -23,8 +29,9 @@ What a run does, in order (everything before "window" is set-up):
 4. closes the window (closed loop: clients hang up; open loop: counted
    requests are followed to their end under continuing load), reads the
    server's memory peak and stops it;
-5. runs the plain reference (``reference.py``) over a sample of the
-   requests the window finished and compares: ``correct``;
+5. runs the plain reference (``reference.py``, over the architecture's
+   forward) on a sample of the requests the window finished and compares:
+   ``correct``;
 6. prints the numbers compared beside their limits, then one JSON line.
 
 With ``--trace 0`` the line carries the cell's end-to-end metrics, with
@@ -57,6 +64,7 @@ import peaks  # noqa: E402
 import serverproc  # noqa: E402
 import stats  # noqa: E402
 import traffic  # noqa: E402
+import work  # noqa: E402
 import xtrace  # noqa: E402
 
 SERVER_SEED_MOD = 2147483629  # the server's --seed stays a positive int32
@@ -280,6 +288,11 @@ def measure(args, bench: dict, work_dir: str, launcher=None,
     run = Run()
     cell, entry = find_cell(bench, args.workload)
     config = load_json(os.path.join(args.bench_root, entry["file"]))
+    try:  # before any server: a cell nothing can judge measures nothing
+        arch_file = work.arch_path(config, os.path.join(
+            args.bench_root, "perfbench", "arch"))
+    except ValueError as e:
+        raise RunFailure(str(e)) from None
     mix = traffic.load(traffic.traffic_path(args.bench_root, cell["traffic"]))
     run.cell, run.config, run.mix = cell, config, mix
     run.seed, run.seconds = args.seed, float(args.seconds)
@@ -402,7 +415,8 @@ def measure(args, bench: dict, work_dir: str, launcher=None,
     sample = pick_sample(finished, int(config["correct"]["sample_requests"]),
                          args.seed)
     job = {
-        "config": config, "seed": args.seed % SERVER_SEED_MOD,
+        "config": config, "arch": arch_file,
+        "seed": args.seed % SERVER_SEED_MOD,
         "chips": run.chips, "platform": run.platform,
         "cache_dir": cache_dir, "control": control,
         "requests": [{"i": r.i, "prompt_ids": traffic.token_ids(r.prompt),
