@@ -82,7 +82,12 @@ from fusioninfer_tpu.engine.sampler import (
     spec_window_draws,
 )
 from fusioninfer_tpu.models.config import ModelConfig
-from fusioninfer_tpu.models.transformer import init_params, lm_head_operands
+from fusioninfer_tpu.models.transformer import (
+    MOE_STATS,
+    grouped_matmul_impl,
+    init_params,
+    lm_head_operands,
+)
 from fusioninfer_tpu.utils import spans
 
 logger = logging.getLogger("fusioninfer.engine")
@@ -313,6 +318,39 @@ class _PrefillingState:
     pos: int  # next global position to write (starts at the reused length)
 
 
+def latent_cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
+    """The message that refuses a deployment of a model with a latent
+    (MLA) cache, or None.  ``asked`` names what was asked for, a truthy
+    value meaning "yes".  What a latent page needs before these can
+    read it: a mesh rule for a cache with no head axis; int8 pages and
+    weights; adapters through the latent projections; a verify window
+    over latent pages; ONE frame format for transfer, fabric, host tier
+    and evacuation (ROADMAP D4); a checkpoint name map."""
+    if not cfg.is_mla:
+        return None
+    not_yet = {
+        "mesh": "a device mesh (--tensor-parallel-size / ep / sp)",
+        "int8_weights": "int8 weights (--quantization int8)",
+        "int8_kv": "int8 KV pages (--kv-cache-dtype int8)",
+        "lora": "LoRA adapters (--lora)",
+        "speculative": "speculative decoding (--speculative-ngram)",
+        "host_tier": "the host KV tier (--kv-host-tier-mb)",
+        "kv_transfer": "KV transfer between prefill and decode roles "
+                       "(--prefill-upstream, prefill slabs and streams)",
+        "kv_fabric": "the cross-engine KV fabric (--kv-peer)",
+        "evacuate": "evacuation (--evacuate-grace-s / --evacuate-peer)",
+        "checkpoint": "checkpoint loading (--load-hf / --load-checkpoint)",
+    }
+    unknown = set(asked) - set(not_yet)
+    if unknown:
+        raise KeyError(f"unknown feature names {sorted(unknown)}")
+    hit = [not_yet[name] for name, on in asked.items() if on]
+    if not hit:
+        return None
+    return (f"model {cfg.name} keeps a latent (MLA) KV cache, which does "
+            f"not support yet: {'; '.join(hit)}")
+
+
 class NativeEngine:
     def __init__(
         self,
@@ -408,6 +446,13 @@ class NativeEngine:
         process-local and would diverge the SPMD lockstep)."""
         self.cfg = cfg.validate()
         self.cache_cfg = (cache_cfg or CacheConfig()).validate()
+        refusal = latent_cache_refusal(
+            cfg, mesh=mesh is not None,
+            int8_weights=cfg.quantization == "int8",
+            int8_kv=self.cache_cfg.quantized, lora=lora_adapters,
+            speculative=speculative_k, host_tier=host_kv_tier is not None)
+        if refusal:
+            raise ValueError(refusal)
         self.max_batch_size = max_batch_size
         self.mesh = mesh
         # tp meshes spanning OS processes (one LWS group = one multi-host
@@ -657,6 +702,13 @@ class NativeEngine:
         # unpipelined bursting.
         self.pipeline_bursts = pipeline_bursts
         self._inflight = None
+        # called on the engine thread each time a model forward has been
+        # enqueued: from then on the device has work, so whatever the
+        # caller held back for the device's sake may go (the server
+        # holds a step's tokens while the device has nothing to run:
+        # the stream handlers they wake would contend for the
+        # interpreter lock with the very dispatch the device waits for)
+        self.on_forward_enqueued: Optional[Callable[[], None]] = None
         # ragged-dispatch compile discipline: descriptor rows and the
         # chunk lm_head group are pinned per engine (R = pow2(2B),
         # NC = pow2(B)), so the only varying jit-signature dimension of
@@ -686,6 +738,12 @@ class NativeEngine:
         self._kv_splits = (ops_pick_kv_splits(
             self.cache_cfg.max_pages_per_seq, self.cache_cfg.page_size)
             if kv_splits is None else kv_splits)
+        if cfg.is_mla:
+            self._kv_splits = 0  # the latent kernel has one grid
+        # the expert layers' counters, summed on the device in the pool
+        # tree (cache["moe_stats"], uint32) and read as differences
+        self.moe_stats_total = {name: 0 for name in MOE_STATS}
+        self._moe_stats_seen = np.zeros((len(MOE_STATS),), np.uint32)  # noqa:trace-dynamic-dim — fixed counter layout
         # AOT warm-start report (engine/aot.py::warmup stamps it; the
         # server renders it as fusioninfer:aot_cache_* metrics)
         self.aot_stats: dict = {}
@@ -745,7 +803,9 @@ class NativeEngine:
         cfg, cc = self.cfg, self.cache_cfg
         attention = ops_dispatch.resolve_attn(cfg.attn_impl)
         grid, splits = None, 0
-        if attention == "flash":
+        if attention == "flash" and cfg.is_mla:
+            grid = "latent"  # ops/mla_attention.py: one grid, no split
+        elif attention == "flash":
             tp = (self._kernel_mesh.shape["tp"]
                   if self._kernel_mesh is not None else 1)
             grid, splits = resolve_ragged_grid(
@@ -773,6 +833,12 @@ class NativeEngine:
             "page_size": cc.page_size,
             "max_pages_per_seq": cc.max_pages_per_seq,
             "kv_dtype": cc.kv_dtype,
+            "kv_layout": "latent" if cfg.is_mla else "heads",
+            # the one expert layer: sorted assignments through a grouped
+            # product over the held experts, no capacity, nothing dropped
+            "moe_experts": ("%s dropless %d/%d" % (
+                grouped_matmul_impl(), cfg.experts_held, cfg.n_experts)
+                if cfg.is_moe else None),
             "token_budget": self.token_budget,
             "decode_burst": self.burst_steps,
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
@@ -781,6 +847,26 @@ class NativeEngine:
                     } if self.aot_stats else None,
             "devices": [_device_memory(d) for d in devices],
         }
+
+    def _refuse_latent(self, **asked) -> None:
+        refusal = latent_cache_refusal(self.cfg, **asked)
+        if refusal:
+            raise ValueError(refusal)
+
+    def _drain_moe_stats(self) -> None:
+        """Fold the device's running expert counters into the host's
+        totals, when the newest pool tree is already computed: never a
+        wait (under dispatch-ahead the tree in hand is still in flight
+        on most steps; the sums are cumulative, so a later read loses
+        nothing)."""
+        stats = self.cache.get("moe_stats")
+        if stats is None or not stats.is_ready():
+            return
+        now = np.asarray(stats)
+        delta = now - self._moe_stats_seen  # uint32: wraps like the device
+        self._moe_stats_seen = now
+        for name, d in zip(MOE_STATS, delta.tolist()):
+            self.moe_stats_total[name] += d
 
     def set_token_byte_table(self, table) -> None:
         """Legacy single-byte form: [V] int32, token id → byte value or
@@ -871,6 +957,35 @@ class NativeEngine:
         self.set_token_budget(budget)
         return budget
 
+    def warm_chunk_forwards(self) -> int:
+        """Dispatch the one ragged forward once at every flat-token
+        bucket a budgeted chunk can have, on scratch pages released
+        before returning (as :meth:`calibrate_token_budget` does), and
+        return how many.  An AOT build leaves an executable in the
+        persistent cache; a program's FIRST live dispatch still traces,
+        lowers and loads it, seconds for a large model, and which bucket
+        a step's chunks add up to depends on what else is in flight, so
+        no client-side warm-up can be sure to reach them all: without
+        this the first step at a new bucket stalls every stream.
+        Single-process engines with a token budget only."""
+        budget = self.token_budget
+        if budget is None or self._mh is not None:
+            return 0
+        n_max = min(budget, self.buckets[-1])
+        sizes, t = [], 16
+        while t < 2 * n_max:  # one length per bucket: pow2_rows(n) == t
+            sizes.append(min(t, n_max))
+            t *= 2
+        probe = Request("__warm_probe__", [1] * n_max)
+        self.alloc.allocate(probe.request_id, n_max)
+        try:
+            for n in sorted(set(sizes)):
+                logits = self._suffix_forward(probe, probe.prompt_tokens, 0, n)
+            logits.block_until_ready()
+        finally:
+            self.alloc.release(probe.request_id)
+        return len(set(sizes))
+
     def aot_signatures(self):
         """The engine's serving entry points at ITS exact compile
         discipline, as ``(name, lower-and-compile thunk)`` pairs —
@@ -908,8 +1023,17 @@ class NativeEngine:
 
         sigs = []
         groups = sorted({pow2_rows(n) for n in range(1, B + 1)})
-        for bucket in self.buckets:
+        budget = self.token_budget
+        for shortest, bucket in zip([1] + [b + 1 for b in self.buckets],
+                                    self.buckets):
             for R in groups:
+                if budget is not None and R * shortest > budget:
+                    # under a token budget a fresh group's prompts sum to
+                    # at most the budget (longer ones chunk): R prompts of
+                    # this bucket cannot be admitted together, and at
+                    # long-context sizes the program would not fit
+                    continue
+
                 def lower_prefill(bucket=bucket, R=R):
                     return prefill.lower(
                         cfg, cc, self.params, self.cache,
@@ -1220,6 +1344,7 @@ class NativeEngine:
         Served inside :meth:`step` (engine thread owns the cache); resolves
         to a :class:`fusioninfer_tpu.engine.kv_transfer.KVSlab` — int8
         caches emit int8 slabs (scales ride the wire)."""
+        self._refuse_latent(kv_transfer=True)
         if request.lora:
             self._adapter_id(request)  # unknown adapter: client error NOW
         self._validate_guided(request)
@@ -1250,6 +1375,7 @@ class NativeEngine:
         (:class:`fusioninfer_tpu.engine.kv_fabric.KVFabric`): host-tier
         misses in ``_restore_host_blocks`` then consult the fleet before
         falling back to recompute."""
+        self._refuse_latent(kv_fabric=True)
         self._kv_fabric = fabric
 
     def request_prefill_stream(self, request: Request,
@@ -1267,6 +1393,7 @@ class NativeEngine:
         across hosts and must host-gather via a collective before any
         byte leaves, which serializes exactly what streaming hides —
         those meshes keep the slab path (the server falls back)."""
+        self._refuse_latent(kv_transfer=True)
         if self._mh is not None:
             raise ValueError(
                 "streamed prefill is single-process; multi-process "
@@ -1286,6 +1413,7 @@ class NativeEngine:
         activates the sequence when the stream assembles complete.  Any
         stream fault falls back to a local re-prefill of the same
         request — bit-identical output, only the TTFT differs."""
+        self._refuse_latent(kv_transfer=True)
         if self._mh is not None:
             raise ValueError(
                 "streamed PD admission is single-process; multi-process "
@@ -1308,6 +1436,7 @@ class NativeEngine:
     def add_prefilled_request(self, request: Request, slab) -> None:
         """Decode-worker side: admit a request whose prefill (KV + first
         token) was computed remotely; generation continues from there."""
+        self._refuse_latent(kv_transfer=True)
         if request.lora:
             # decode applies the adapter's deltas per step: it must be
             # loaded HERE too (the prefiller already prefilled under it)
@@ -2265,6 +2394,7 @@ class NativeEngine:
         are refused from this point on.  Single-process only: the park
         path writes the host tier, which a multi-host SPMD group
         refuses anyway — multi-host slices drain instead."""
+        self._refuse_latent(evacuate=True)
         if self._mh is not None:
             raise RuntimeError(
                 "evacuation is single-process only (the park path is "
@@ -2347,6 +2477,7 @@ class NativeEngine:
             finally:
                 self._in_step_body = False
                 self._last_step_end = self._clock()
+            self._drain_moe_stats()
             return [o for o in outputs if o is not None]
 
     def _admit_half(self) -> list[StepOutput]:
@@ -2847,7 +2978,8 @@ class NativeEngine:
         Sliding-window engines skip parking: trimmed page tables break
         the page↔block alignment the chain registration needs.
         Returns the number of pages parked (0 = nothing parkable)."""
-        if not self.prefix_caching or self.cfg.sliding_window is not None:
+        if (not self.prefix_caching or self.cfg.sliding_window is not None
+                or self.cfg.is_mla):  # latent pages: not parked yet (D4)
             return 0
         ps = self.cache_cfg.page_size
         pages = self.alloc.pages_of(request.request_id)
@@ -3110,18 +3242,18 @@ class NativeEngine:
                 kv_splits=self._kv_splits,
                 decode_hidden=decode_hidden,
             )
+        self._forward_enqueued()
         self.sched.charge_weight_pass()
         return logits, chunk_logits
 
     def _batched_window_forward(self, entries) -> "jax.Array":
         """ONE ragged multi-query forward for a batch of per-sequence
         token windows — ``entries`` is ``[(request, window_tokens,
-        start)]`` — returning last-real-token logits [B, V] (inert pad
-        entries: zero-length segments, trash-page tables).  The single
+        start)]`` — returning last-real-token logits [NC, V], entry i's in
+        row i (inert pad entries: zero-length segments, trash-page tables).  The single
         assembly point for both the prefix-cache-burst and
         chunked-prefill batch paths; raises on forward failure (the
         caller fails its own group)."""
-        B = len(entries)
         chunk_entries = [
             (toks, start, self.alloc.page_table_row(request.request_id),
              self._adapter_id(request))
@@ -3135,7 +3267,10 @@ class NativeEngine:
             self.cache_cfg.trash_page, rows=self._ragged_rows,
             chunk_rows=self._ragged_chunk_rows)
         lora = self.lora_set.stacked if self.lora_set is not None else None
-        return self._ragged_forward(packed, lora)[1][:B]
+        # all NC rows, the real ones first: a [:B] here would give every
+        # count of concurrent chunks a shape of its own, and each eager
+        # op on it a compile of its own inside the serving window
+        return self._ragged_forward(packed, lora)[1]
 
     def _prefill_suffix_batch(
         self, items: list[tuple[Request, list[int], bool, int]]
@@ -3339,6 +3474,7 @@ class NativeEngine:
                 self.alloc.release(request.request_id)
                 outputs.append(self._fail_admission(request, e))
             return outputs
+        self._forward_enqueued()
         self.sched.charge_weight_pass()
         self.sched.charge_prefill(sum(len(p) for _, p, _ in items))
         return self._activate_group(
@@ -3723,6 +3859,18 @@ class NativeEngine:
             st.request.params.max_tokens - st.n_generated - inflight
             for st in rows) < span
 
+    def forward_in_flight(self) -> bool:
+        """Is a dispatched model forward still unread: has the device
+        work of this engine's to run right now?  (The dispatched-ahead
+        successor burst; every other forward is read before its step
+        returns.)"""
+        return self._inflight is not None
+
+    def _forward_enqueued(self) -> None:
+        hook = self.on_forward_enqueued
+        if hook is not None:
+            hook()
+
     def _dispatch_burst(self, ctl_i_dev, ctl_f_dev, page_tables_dev,
                         span: int, mode: str, lora):
         """Dispatch one decode burst (async) → (sampled_dev, next_ctl)."""
@@ -3745,6 +3893,7 @@ class NativeEngine:
                 coalesce=dispatch.decode_coalesce(),
                 kv_splits=self._kv_splits,
             )
+        self._forward_enqueued()
         return sampled_dev, next_ctl
 
     def _pipeline_ready(self, snapshot: dict, span: int) -> bool:

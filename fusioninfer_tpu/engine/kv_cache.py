@@ -13,6 +13,15 @@ indexes leading dims — Mosaic requires the tiled trailing two dims stay
 whole (see :mod:`fusioninfer_tpu.ops.paged_attention`).  The kv-head
 axis is also the ``tp`` shard axis.
 
+A model with latent attention (``cfg.is_mla``) caches ONE row per
+position and layer, shared by every head: the pool is one array
+``cache["kv"]`` of ``[n_layers, 1, n_pages, page_size, W]`` (``W`` =
+``cfg.latent_row_width``: the row's 576 values in whole 128-lane tiles)
+behind the same pages, page tables and allocator.  A model with expert
+layers also carries their counters in ``cache["moe_stats"]``
+(``transformer.MOE_STATS``): the pool is the one tree every forward
+threads and donates, so the counts add up on the device and ride along.
+
 Host-side: a free-list allocator (:class:`PageAllocator`) — allocation is
 a Python-time concern, never traced.
 """
@@ -70,6 +79,17 @@ class CacheConfig:
 
 
 def init_kv_cache(cfg: ModelConfig, cache_cfg: CacheConfig) -> dict:
+    from fusioninfer_tpu.models.transformer import MOE_STATS
+
+    stats = ({"moe_stats": jnp.zeros((len(MOE_STATS),), jnp.uint32)}  # noqa:trace-dynamic-dim — fixed counter layout
+             if cfg.is_moe else {})
+    if cfg.is_mla:
+        if cache_cfg.quantized:
+            raise ValueError("int8 pages are not available for a latent "
+                             "(MLA) cache")
+        return {"kv": jnp.zeros(
+            (cfg.n_layers, 1, cache_cfg.n_pages, cache_cfg.page_size,
+             cfg.latent_row_width), cfg.jax_dtype), **stats}
     shape = (
         cfg.n_layers,
         cfg.n_kv_heads,
@@ -90,16 +110,22 @@ def init_kv_cache(cfg: ModelConfig, cache_cfg: CacheConfig) -> dict:
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(scale_shape, jnp.float32),
             "v_scale": jnp.zeros(scale_shape, jnp.float32),
+            **stats,
         }
     return {
         "k": jnp.zeros(shape, cfg.jax_dtype),
         "v": jnp.zeros(shape, cfg.jax_dtype),
+        **stats,
     }
 
 
 def page_bytes(cfg: ModelConfig, page_size: int,
                kv_dtype: str = "model") -> int:
-    """Device bytes one KV page costs (k + v, all layers)."""
+    """Device bytes one KV page costs (k + v, or the latent rows; all
+    layers).  A latent row is priced at the width it is stored at."""
+    if cfg.is_mla:
+        return (cfg.n_layers * page_size * cfg.latent_row_width
+                * jnp.dtype(cfg.jax_dtype).itemsize)
     if kv_dtype == "int8":
         per_token = cfg.head_dim * 1 + 4  # int8 values + one f32 scale
     else:

@@ -167,6 +167,16 @@ class EngineMetrics:
             "# HELP fusioninfer:fused_sampling_steps_total Decode steps sampled through the fused lm_head top-k path (no [rows, vocab] logits materialized).",
             "# TYPE fusioninfer:fused_sampling_steps_total counter",
             f"fusioninfer:fused_sampling_steps_total{{{labels}}} {getattr(engine, 'fused_sampling_steps_total', 0)}",
+            *[line for name, what in (
+                ("assignments", "(token, expert) assignments the routers of the expert layers made, over the routers' whole width"),
+                ("assignments_local", "Assignments to an expert held by this process, each computed (no capacity, none dropped)"),
+                ("expert_touches", "Held experts with at least one row, summed over expert layers and forward passes"),
+                ("layer_passes", "Forward passes through an expert layer"),
+            ) for line in (
+                f"# HELP fusioninfer:moe_{name}_total {what}.",
+                f"# TYPE fusioninfer:moe_{name}_total counter",
+                f"fusioninfer:moe_{name}_total{{{labels}}} {getattr(engine, 'moe_stats_total', {}).get(name, 0)}",
+            )],
             "# HELP vllm:num_preemptions_total Requests preempted to reclaim KV-cache pages.",
             "# TYPE vllm:num_preemptions_total counter",
             f"vllm:num_preemptions_total{{{labels}}} {engine.preemptions_total}",

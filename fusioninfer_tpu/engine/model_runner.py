@@ -27,29 +27,54 @@ from jax import lax
 from fusioninfer_tpu.engine.kv_cache import CacheConfig
 from fusioninfer_tpu.ops import masks
 from fusioninfer_tpu.models.config import ModelConfig
-from fusioninfer_tpu.models.quantization import embed_lookup, kv_quantize
+from fusioninfer_tpu.models.quantization import (
+    embed_lookup,
+    is_quantized,
+    kv_quantize,
+)
 from fusioninfer_tpu.models.transformer import (
+    EXPERT_MATRICES,
     attn_out_proj,
     layer_forward,
+    layer_stacks,
     lm_head,
+    mla_absorb_queries,
+    mla_attn_out,
+    mla_latent,
+    mla_queries,
     mlp_block,
     qkv_proj,
     rms_norm,
 )
 
 
-def _layer_xs(cfg, params, lora) -> tuple:
-    """Per-layer scan operands: weights (+ lora) + the layer index.  The
-    KV cache is deliberately NOT xs: it rides the scan CARRY as one
-    donated stacked pool per array, updated in place by
-    :func:`_scatter_kv` — threading it through xs→ys made XLA write a
+def _scan_layers(cfg, params, lora, body, carry):
+    """``lax.scan`` of the ONE layer ``body`` over each of the model's
+    layer stacks in turn (the leading dense layers, then the rest), the
+    layer index running on.  Per-layer scan operands: weights (+ lora) +
+    the layer index.  The KV cache is deliberately NOT xs: it rides the
+    scan CARRY as one donated stacked pool per array, updated in place
+    by :func:`_scatter_kv` — threading it through xs→ys made XLA write a
     fresh cache-sized ys every step (a full pool copy per decode step;
     measured step time scaled with pool size, round 5)."""
-    xs = [params["layers"]]
-    if lora is not None:
-        xs.append(lora)
-    xs.append(jnp.arange(cfg.n_layers))
-    return tuple(xs)
+    for stack, first in layer_stacks(cfg, params):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        # a stack of experts is not sliced by the scan: the grouped
+        # product reads layer l of it in place (transformer.grouped_matmul)
+        whole = {k: stack[k] for k in EXPERT_MATRICES
+                 if "router" in stack and not is_quantized(stack[k])}
+        xs = [{k: v for k, v in stack.items() if k not in whole}]
+        if lora is not None:
+            xs.append(lora)
+        xs.append(first + jnp.arange(n))
+
+        def stack_body(carry, inputs, whole=whole, first=first):
+            layer = {**inputs[0],
+                     **{k: (w, inputs[-1] - first) for k, w in whole.items()}}
+            return body(carry, (layer, *inputs[1:]))
+
+        carry, _ = lax.scan(stack_body, carry, tuple(xs))
+    return carry
 
 
 def _layer_unpack(inputs, has_lora: bool):
@@ -99,6 +124,67 @@ def _scatter_kv(cache: dict, l, k, v, write_page, write_slot,
             l, kvr, wp, ws].set(
             jnp.moveaxis(v_s, head_axis, 0))[:, :, :, None, :]
     return out
+
+
+@jax.named_scope("kv_write")
+def _scatter_latent(cache: dict, l, latent, write_page, write_slot) -> dict:
+    """Write fresh latent rows (``[..., rank + rope]``, index maps
+    ``write_page`` / ``write_slot`` of the leading shape) into layer
+    ``l`` of the pool ``[L, 1, n_pages, ps, W]`` IN PLACE, zero-padded
+    to the stored width (:func:`_scatter_kv`'s index form: a scalar
+    ``l`` then an adjacent block of advanced indices)."""
+    pool = cache["kv"]
+    pad = pool.shape[-1] - latent.shape[-1]
+    rows = jnp.pad(latent.astype(pool.dtype),
+                   ((0, 0),) * (latent.ndim - 1) + ((0, pad),))
+    return {**cache, "kv": pool.at[l, 0, write_page, write_slot].set(rows)}
+
+
+def _add_moe_stats(cache: dict, stats) -> dict:
+    """Add one expert layer's counters to the pool tree's running sums
+    (``cache["moe_stats"]``, uint32 and wrapping; the engine reads
+    differences)."""
+    if stats is None:
+        return cache
+    return {**cache, "moe_stats": cache["moe_stats"] + stats}
+
+
+def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
+                    write_slot, page_tables, row_starts, q_begins, q_lens,
+                    *, use_kernel, interpret):
+    """Latent attention of flat tokens ``x`` [T, 1, D] over their rows'
+    pages, the one body of decode and chunk rows alike: project, write
+    each token's latent row, attend in the absorbed form straight over
+    the latent pages → (cache, attention output [T, 1, D], residual NOT
+    added)."""
+    from fusioninfer_tpu.ops.mla_attention import (
+        mla_ragged_paged_attention,
+        reference_mla_ragged_paged_attention,
+    )
+
+    pos2 = positions[:, None]
+    h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+    q_nope, q_rope = mla_queries(cfg, layer, h, pos2)
+    latent = mla_latent(cfg, layer, h, pos2)
+    cache = _scatter_latent(cache, l, latent[:, 0], write_page, write_slot)
+    q_lat, q_rope = mla_absorb_queries(cfg, layer, q_nope[:, 0], q_rope[:, 0])
+    with jax.named_scope("attn"):
+        args = (q_lat, q_rope, cache["kv"], page_tables, row_starts,
+                q_begins, q_lens)
+        if use_kernel:
+            o_lat = mla_ragged_paged_attention(
+                *args, layer=l, rank=cfg.kv_lora_rank, interpret=interpret)
+        else:
+            o_lat = reference_mla_ragged_paged_attention(
+                *args, layer=l, rank=cfg.kv_lora_rank)
+    return cache, mla_attn_out(cfg, layer, o_lat)[:, None, :]
+
+
+def _refuse_latent(cfg, what: str) -> None:
+    if cfg.is_mla:
+        raise NotImplementedError(
+            f"{what} does not read a latent (MLA) cache: the engine's "
+            "forwards are prefill, decode_burst and fused_step")
 
 
 def _cache_layer(cache: dict, l):
@@ -192,19 +278,26 @@ def prefill(
         cache_cfg.trash_page,
     )  # [B, S]
     slot_of_token = jnp.broadcast_to(token_idx % ps, (B, S))
+    live = token_idx < true_lens[:, None]  # [B, S]
 
     def body(carry, inputs):
         x, cache = carry
         layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
-        out, (k, v) = layer_forward(cfg, layer, x, positions, mesh=mesh,
-                                    lora=layer_lora, adapter_ids=adapter_ids)
-        # stacked head-major cache [L, KV, n_pages, ps, Hd]; k is
-        # [B, S, KV, Hd] → in-place scatter at layer l, [B, S] maps
-        cache = _scatter_kv(cache, l, k, v, page_of_token, slot_of_token,
-                            head_axis=2)
-        return (out, cache), None
+        out, kv, stats = layer_forward(
+            cfg, layer, x, positions, mesh=mesh, lora=layer_lora,
+            adapter_ids=adapter_ids, live=live)
+        if cfg.is_mla:  # a fresh prompt attends in the expanded form and
+            # caches what decode will read: the latent rows [B, S, .]
+            cache = _scatter_latent(cache, l, kv, page_of_token,
+                                    slot_of_token)
+        else:
+            # stacked head-major cache [L, KV, n_pages, ps, Hd]; k is
+            # [B, S, KV, Hd] → in-place scatter at layer l, [B, S] maps
+            cache = _scatter_kv(cache, l, *kv, page_of_token, slot_of_token,
+                                head_axis=2)
+        return (out, _add_moe_stats(cache, stats)), None
 
-    (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
+    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = x[jnp.arange(B), jnp.maximum(true_lens - 1, 0)]  # [B, D]
     return cache, lm_head(cfg, params, last)
@@ -249,6 +342,7 @@ def prefill_suffix(
     """
     from fusioninfer_tpu.ops import dispatch
 
+    _refuse_latent(cfg, "prefill_suffix")
     B, C = tokens.shape
     ps = cache_cfg.page_size
     mp = page_row.shape[0]
@@ -317,9 +411,10 @@ def prefill_suffix(
                 v_ctx,
             ).reshape(B, C, H * Hd).astype(x.dtype)
         x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        return (x + mlp_block(cfg, layer, x), cache), None
+        y, stats = mlp_block(cfg, layer, x, (offs < true_len)[None])
+        return (x + y, _add_moe_stats(cache, stats)), None
 
-    (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
+    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = x[jnp.arange(B), jnp.maximum(true_len - 1, 0)]
     return cache, lm_head(cfg, params, last)
@@ -373,6 +468,17 @@ def _decode_step_impl(
 
         layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
         B_, S_, D_ = x.shape
+        if cfg.is_mla:
+            # B rows of one token each through the latent kernel: the
+            # same body (and bits) the fused step scores decode rows with
+            cache, attn_out = _mla_attn_block(
+                cfg, layer, x, positions, cache, l, write_page, write_slot,
+                page_tables, positions, jnp.arange(B_, dtype=jnp.int32),
+                active.astype(jnp.int32), use_kernel=use_kernel,
+                interpret=dispatch.kernel_interpret())
+            x = x + attn_out
+            y, stats = mlp_block(cfg, layer, x, active[:, None])
+            return (x + y, _add_moe_stats(cache, stats)), None
         q, k, v = qkv_proj(cfg, layer, x, pos, layer_lora, adapter_ids)
 
         # write this step's K/V into each sequence's page slot (stacked
@@ -413,9 +519,10 @@ def _decode_step_impl(
             attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
                 B_, 1, H * Hd).astype(x.dtype)
         x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        return (x + mlp_block(cfg, layer, x), cache), None
+        y, stats = mlp_block(cfg, layer, x, active[:, None])
+        return (x + y, _add_moe_stats(cache, stats)), None
 
-    (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
+    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = lm_head(cfg, params, x[:, 0])
     return cache, logits
@@ -600,6 +707,7 @@ def _window_forward_impl(
     """
     from fusioninfer_tpu.ops import dispatch
 
+    _refuse_latent(cfg, "verify_step")
     B, C = tokens.shape
     ps = cache_cfg.page_size
     mp = page_tables.shape[1]
@@ -670,9 +778,10 @@ def _window_forward_impl(
                 B, C, H * Hd
             ).astype(x.dtype)
         x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        return (x + mlp_block(cfg, layer, x), cache), None
+        y, stats = mlp_block(cfg, layer, x, live)
+        return (x + y, _add_moe_stats(cache, stats)), None
 
-    (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
+    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if sel is not None:
         idx = jnp.clip(sel.astype(jnp.int32), 0, C - 1)  # [B, W]
@@ -785,6 +894,14 @@ def fused_step(
         from fusioninfer_tpu.models.quantization import maybe_dequantize_tree
 
         layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
+        if cfg.is_mla:
+            cache, attn_out = _mla_attn_block(
+                cfg, layer, x, positions, cache, l, write_page, write_slot,
+                page_tables, row_starts, q_begins, q_lens,
+                use_kernel=use_kernel, interpret=dispatch.kernel_interpret())
+            x = x + attn_out
+            y, stats = mlp_block(cfg, layer, x, live[:, None])
+            return (x + y, _add_moe_stats(cache, stats)), None
         q, k, v = qkv_proj(cfg, layer, x, pos2, layer_lora, adapter_tok)
 
         # stacked head-major cache [L, KV, n_pages, ps, Hd]; k[:, 0] is
@@ -837,9 +954,10 @@ def fused_step(
             attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
                 T, 1, H * Hd).astype(x.dtype)
         x = x + attn_out_proj(layer, attn, layer_lora, adapter_tok)
-        return (x + mlp_block(cfg, layer, x), cache), None
+        y, stats = mlp_block(cfg, layer, x, live[:, None])
+        return (x + y, _add_moe_stats(cache, stats)), None
 
-    (x, cache), _ = lax.scan(body, (x, cache), _layer_xs(cfg, params, lora))
+    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     h = x[:, 0]  # [T, D]
     idx = jnp.clip(sel.astype(jnp.int32), 0, T - 1)  # [B, W]

@@ -352,6 +352,8 @@ class EngineServer:
         self.evacuate_grace_s = evacuate_grace_s
         self.evacuate_peers = list(evacuate_peers or ())
         self._inflight = 0  # HTTP handlers mid-request (drain waits)
+        self._held_outputs: list = []  # see _engine_loop
+        self._loop_clock = spans.SpanClock()
         self._httpd: ThreadingHTTPServer | None = None
         self._engine_thread: threading.Thread | None = None
         self._watchdog_thread: threading.Thread | None = None
@@ -377,9 +379,23 @@ class EngineServer:
         # the engine's own clock, so loop and step spans add up on one
         # thread (a test stub without one gets a clock nobody renders)
         clock = getattr(self.engine, "spans", None) or spans.SpanClock()
+        self._loop_clock = clock
+        # a step's tokens wake one stream handler each, and those contend
+        # for the interpreter lock with the engine thread's next dispatch.
+        # Where a step returns with nothing left on the device (no burst
+        # dispatched ahead), the device would sit idle through all of
+        # that: the loop holds the tokens until the next forward has been
+        # enqueued (the engine calls back), and lets them go at once
+        # where the device has work or no step follows.  One process
+        # only: a lockstep group's step can wait on its peers first.
+        hold = (hasattr(self.engine, "on_forward_enqueued")
+                and not getattr(self.engine, "is_multihost", False))
+        if hold:
+            self.engine.on_forward_enqueued = self._publish_held
         while not self._stop.is_set():
             clock.tick()
             if not self.engine.has_work():
+                self._publish_held()
                 consecutive_failures = 0  # an old incident must not
                 if not getattr(self.engine, "is_multihost", False):
                     with clock.span("loop.idle"):
@@ -400,6 +416,7 @@ class EngineServer:
                 outputs = self.engine.step()
                 consecutive_failures = 0
             except Exception as e:
+                self._publish_held()
                 consecutive_failures += 1
                 logger.exception("engine step failed (%d consecutive)",
                                  consecutive_failures)
@@ -445,45 +462,61 @@ class EngineServer:
                 else:
                     time.sleep(0.05)
                     continue
-            with clock.span("loop.publish", outputs=len(outputs)):
-                now = time.monotonic()
-                for out in outputs:
-                    with self._lock:
-                        chan = self._channels.get(out.request_id)
-                        meta = self._req_meta.get(out.request_id)
-                    if meta is not None:
-                        tname = meta.get("tier")
-                        if out.is_first_token:
-                            self.metrics.ttft.observe(now - meta["arrival"])
-                            if (self.boot_t0 is not None
-                                    and self.metrics.cold_start_ttft_s is None):
-                                # the server's FIRST first-token: boot →
-                                # serving, the AOT warm-start gauge
-                                self.metrics.cold_start_ttft_s = (
-                                    now - self.boot_t0)
-                            if tname is not None:
-                                self.metrics.tier_ttft[tname].observe(
-                                    now - meta["arrival"])
-                        else:
-                            self.metrics.tpot.observe(now - meta["last_token_time"])
-                            if tname is not None:
-                                self.metrics.tier_tpot[tname].observe(
-                                    now - meta["last_token_time"])
-                        meta["last_token_time"] = now
-                        if out.finished:
-                            self.metrics.e2e_latency.observe(now - meta["arrival"])
-                            # a finished request whose client drains slowly
-                            # keeps its channel registered — the watchdog
-                            # must not count it as stalled or expired
-                            meta["finished"] = True
-                    if chan is not None:
-                        chan.put(out)
+            self._publish_held()  # the step before's, if no forward went out
+            if hold and outputs and not self.engine.forward_in_flight():
+                self._held_outputs = outputs
+            else:
+                self._publish(outputs)
             if getattr(self.engine, "multihost_shutdown", False):
                 # AFTER dispatching this step's outputs: the shutdown
                 # step may carry terminal tokens clients are waiting on
                 logger.info("multihost shutdown event; engine loop exits")
                 break
+        self._publish_held()
         clock.tick()
+
+    def _publish_held(self) -> None:
+        """Let go of the tokens the loop held back (engine thread)."""
+        held, self._held_outputs = self._held_outputs, []
+        if held:
+            self._publish(held)
+
+    def _publish(self, outputs: list) -> None:
+        """Hand a step's outputs to their requests' stream handlers and
+        stamp the serving histograms (engine thread)."""
+        with self._loop_clock.span("loop.publish", outputs=len(outputs)):
+            now = time.monotonic()
+            for out in outputs:
+                with self._lock:
+                    chan = self._channels.get(out.request_id)
+                    meta = self._req_meta.get(out.request_id)
+                if meta is not None:
+                    tname = meta.get("tier")
+                    if out.is_first_token:
+                        self.metrics.ttft.observe(now - meta["arrival"])
+                        if (self.boot_t0 is not None
+                                and self.metrics.cold_start_ttft_s is None):
+                            # the server's FIRST first-token: boot →
+                            # serving, the AOT warm-start gauge
+                            self.metrics.cold_start_ttft_s = (
+                                now - self.boot_t0)
+                        if tname is not None:
+                            self.metrics.tier_ttft[tname].observe(
+                                now - meta["arrival"])
+                    else:
+                        self.metrics.tpot.observe(now - meta["last_token_time"])
+                        if tname is not None:
+                            self.metrics.tier_tpot[tname].observe(
+                                now - meta["last_token_time"])
+                    meta["last_token_time"] = now
+                    if out.finished:
+                        self.metrics.e2e_latency.observe(now - meta["arrival"])
+                        # a finished request whose client drains slowly
+                        # keeps its channel registered — the watchdog
+                        # must not count it as stalled or expired
+                        meta["finished"] = True
+                if chan is not None:
+                    chan.put(out)
 
     # -- watchdog ------------------------------------------------------------
 
@@ -2584,6 +2617,10 @@ def serve_from_args(args) -> int:
                         len(report["errors"]),
                         len(report["errors"]) + report["entries"],
                         "\n  ".join(report["errors"])))
+            t_warm = time.monotonic()
+            n_warm = engine.warm_chunk_forwards()
+            logger.info("chunk forwards dispatched once at %d flat-token "
+                        "buckets in %.1fs", n_warm, time.monotonic() - t_warm)
     server = EngineServer(
         model=model_name,
         host=args.host,
@@ -2618,6 +2655,18 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     params = None
     if load_hf and load_ckpt:
         raise SystemExit("--load-hf and --load-checkpoint are mutually exclusive")
+    from fusioninfer_tpu.engine.engine import latent_cache_refusal
+
+    if load_hf or load_ckpt:
+        # models/loader.py has no name map for latent attention: a preset
+        # that keeps a latent cache is refused before anything is read
+        try:
+            refusal = latent_cache_refusal(get_preset(args.model),
+                                           checkpoint=True)
+        except KeyError:
+            refusal = None
+        if refusal:
+            raise SystemExit(refusal)
     if load_hf:
         from fusioninfer_tpu.models.loader import config_from_hf, load_hf_checkpoint
 
@@ -2651,6 +2700,22 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     else:
         cfg = get_preset(args.model)
         model_name = args.model
+    # what a latent (MLA) cache does not support yet exits HERE, by the
+    # flag's name, before any weight is drawn
+    refusal = latent_cache_refusal(
+        cfg,
+        mesh=args.tensor_parallel_size != 1 or jax.process_count() > 1,
+        int8_weights=quant == "int8",
+        int8_kv=getattr(args, "kv_cache_dtype", "auto") == "int8",
+        lora=getattr(args, "lora", None),
+        speculative=getattr(args, "speculative_ngram", 0),
+        host_tier=getattr(args, "kv_host_tier_mb", 0),
+        kv_transfer=getattr(args, "prefill_upstream", None),
+        kv_fabric=getattr(args, "kv_peer", None),
+        evacuate=(getattr(args, "evacuate_grace_s", 0)
+                  or getattr(args, "evacuate_peer", None)))
+    if refusal:
+        raise SystemExit(refusal)
     if quant != "none" and cfg.quantization == "none":
         import dataclasses
 

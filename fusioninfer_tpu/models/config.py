@@ -48,6 +48,40 @@ class ModelConfig:
     # paged prefill/suffix, decode, verify — as a static mask bound, so
     # kernels skip out-of-window pages instead of reading them.
     sliding_window: int | None = None
+    # Router of a mixture-of-experts layer.  ``norm_topk``: the chosen
+    # experts' weights are a softmax over the chosen logits (Qwen3-MoE);
+    # False = the softmax over ALL experts is kept as it is and scaled by
+    # ``routed_scaling`` (DeepSeek-V2).  ``n_group`` > 1: group-limited
+    # greedy selection, the experts in ``n_group`` equal groups, a token
+    # keeps the ``topk_group`` groups whose best expert scores highest and
+    # chooses its ``n_experts_active`` among those.
+    norm_topk: bool = True
+    routed_scaling: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    # shared experts: one SwiGLU of width n_shared_experts x expert_d_ff
+    # that every token goes through, beside the routed ones
+    n_shared_experts: int = 0
+    # the leading ``first_k_dense`` layers keep a dense SwiGLU of width
+    # d_ff; the layers after them are expert layers (two weight stacks)
+    first_k_dense: int = 0
+    # this process's share of the routed experts (expert parallelism told
+    # to the layer): it routes over all ``n_experts`` and computes the
+    # part of the result that experts [expert_offset, expert_offset +
+    # n_experts_held) give.  0 held = all of them.
+    n_experts_held: int = 0
+    expert_offset: int = 0
+    # Multi-head latent attention (DeepSeek-V2): kv_lora_rank > 0.  Queries
+    # go through a q_lora_rank bottleneck; a position's cache is ONE row
+    # of kv_lora_rank + qk_rope_dim values shared by every head.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling as (factor, original_max_position, beta_fast,
+    # beta_slow, mscale, mscale_all_dim); None = plain rotary embedding
+    rope_yarn: tuple | None = None
 
     @property
     def jax_dtype(self):
@@ -61,6 +95,36 @@ class ModelConfig:
     def expert_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one cached latent row: compressed KV + shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_row_width(self) -> int:
+        """Width a latent row is STORED at: ``latent_dim`` rounded up to
+        whole 128-lane tiles (576 -> 640; the rest is zeros).  The device
+        pads the minor dimension to that anyway; stating it lets a page
+        move as whole tiles."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
+    def n_dense_layers(self) -> int:
+        """Layers with a dense FFN: all of them without experts."""
+        return min(self.first_k_dense, self.n_layers) if self.is_moe else self.n_layers
+
+    @property
+    def attn_out_dim(self) -> int:
+        return self.n_heads * (self.v_head_dim if self.is_mla else self.head_dim)
+
     def validate(self) -> "ModelConfig":
         assert self.n_heads % self.n_kv_heads == 0, "GQA requires n_heads % n_kv_heads == 0"
         assert self.d_model % self.n_heads == 0 or self.head_dim, "need explicit head_dim"
@@ -68,6 +132,26 @@ class ModelConfig:
         assert self.sliding_window is None or self.sliding_window >= 1
         if self.is_moe:
             assert self.n_experts_active <= self.n_experts
+            assert self.n_experts % self.n_group == 0, "n_group must divide n_experts"
+            assert 1 <= self.topk_group <= self.n_group
+            assert (self.n_experts_active
+                    <= self.topk_group * (self.n_experts // self.n_group)), \
+                "the kept groups hold fewer experts than a token chooses"
+            assert 0 <= self.expert_offset and (
+                self.expert_offset + self.experts_held <= self.n_experts), \
+                "held experts lie outside the router's width"
+        else:
+            assert not (self.first_k_dense or self.n_shared_experts
+                        or self.n_experts_held), "expert fields without experts"
+        if self.is_mla:
+            assert min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
+                       self.v_head_dim) > 0, "MLA needs all of its widths"
+            assert self.qk_rope_dim % 2 == 0
+            assert self.sliding_window is None and not self.qk_norm, \
+                "latent attention has neither a window nor per-head QK norm"
+        else:
+            assert not self.first_k_dense, \
+                "leading dense layers are drawn only for a latent-attention model"
         return self
 
 
@@ -205,5 +289,76 @@ register_preset(
         tie_embeddings=False,
         rope_theta=500_000.0,
         max_seq_len=8192,
+    )
+)
+
+# DeepSeek-V2 (huggingface.co/deepseek-ai/DeepSeek-V2 config.json): latent
+# attention, a leading dense layer, then shared + group-routed experts.
+_DEEPSEEK_V2 = dict(
+    qk_norm=False,
+    tie_embeddings=False,
+    rope_theta=10_000.0,
+    n_experts_active=6,
+    norm_topk=False,
+    routed_scaling=16.0,
+    n_shared_experts=2,
+    first_k_dense=1,
+    rope_yarn=(40.0, 4096, 32.0, 1.0, 0.707, 0.707),
+)
+
+# One chip's share of a four-chip expert-parallel deployment at published
+# widths (PERF.md section 4): layer 0 and four expert layers, experts
+# 0-39 of the 160 (groups 0 and 1 of 8), a quarter of the vocabulary.
+register_preset(
+    ModelConfig(
+        name="deepseek-v2-ep4",
+        vocab_size=25_600,
+        d_model=5120,
+        n_layers=5,
+        n_heads=128,
+        n_kv_heads=128,
+        head_dim=192,  # qk_nope_dim + qk_rope_dim
+        d_ff=12_288,
+        max_seq_len=163_840,
+        n_experts=160,
+        moe_d_ff=1536,
+        n_group=8,
+        topk_group=3,
+        n_experts_held=40,
+        expert_offset=0,
+        kv_lora_rank=512,
+        q_lora_rank=1536,
+        qk_nope_dim=128,
+        qk_rope_dim=64,
+        v_head_dim=128,
+        **_DEEPSEEK_V2,
+    )
+)
+
+# The same architecture at a test size: 16 experts in 4 groups (best 2),
+# 3 a token, experts 4-7 held; 1 dense + 2 expert layers.
+register_preset(
+    ModelConfig(
+        name="deepseek-v2-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        head_dim=48,
+        d_ff=256,
+        max_seq_len=4096,
+        n_experts=16,
+        moe_d_ff=64,
+        n_group=4,
+        topk_group=2,
+        n_experts_held=4,
+        expert_offset=4,
+        kv_lora_rank=64,
+        q_lora_rank=96,
+        qk_nope_dim=32,
+        qk_rope_dim=16,
+        v_head_dim=32,
+        **{**_DEEPSEEK_V2, "n_experts_active": 3, "routed_scaling": 4.0},
     )
 )

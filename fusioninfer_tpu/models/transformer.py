@@ -13,6 +13,7 @@ the KV-cache-aware serving paths (paged prefill/decode) live in
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -61,22 +62,182 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) 
     return (gate * (x @ w_up)) @ w_down
 
 
-# dense MoE computes every expert on every token: exact, but its FLOPs
-# scale with E — past this expert count the capacity-dispatch path wins
-DENSE_MOE_MAX_EXPERTS = 16
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_correction_range(beta_fast: float, beta_slow: float, dim: int,
+                          theta: float, original_max: int) -> tuple[int, int]:
+    """The rotary pairs between which YaRN blends interpolated and
+    original frequencies (``find_correction_range``)."""
+
+    def correction_dim(n_rot: float) -> float:
+        return (dim * math.log(original_max / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(correction_dim(beta_fast)), 0),
+            min(math.ceil(correction_dim(beta_slow)), dim - 1))
+
+
+def yarn_frequencies(dim: int, theta: float, yarn: tuple) -> jax.Array:
+    """Inverse frequencies [dim/2] of a YaRN-scaled rotary embedding:
+    ``theta^(-2j/dim)`` blended with the same / factor by the linear ramp
+    between the correction range's ends."""
+    factor, original_max, beta_fast, beta_slow = yarn[:4]
+    low, high = yarn_correction_range(beta_fast, beta_slow, dim, theta,
+                                      original_max)
+    extra = rope_frequencies(dim, theta)
+    span = (high - low) if high != low else 0.001
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / span, 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope_pairs(x: jax.Array, positions: jax.Array,
+                     freqs: jax.Array, scale: float = 1.0) -> jax.Array:
+    """Rotary embedding over interleaved pairs ``(2i, 2i+1)``, the
+    rotated values written half by half (evens, then odds) as
+    DeepSeek-V2's modeling code leaves them; ``scale`` multiplies cos
+    and sin.  x: [..., seq, heads, dim]; positions: [..., seq]."""
+    angles = positions[..., :, None].astype(jnp.float32) * freqs
+    cos = (jnp.cos(angles) * scale)[..., None, :]
+    sin = (jnp.sin(angles) * scale)[..., None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+# -- multi-head latent attention (DeepSeek-V2) -------------------------------
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-1/2`` times YaRN's ``mscale(factor, all_dim)^2``."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_yarn is not None:
+        scale *= yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array) -> jax.Array:
+    if cfg.rope_yarn is None:
+        return apply_rope_pairs(
+            x, positions, rope_frequencies(cfg.qk_rope_dim, cfg.rope_theta))
+    factor, mscale, all_dim = (cfg.rope_yarn[0], cfg.rope_yarn[4],
+                               cfg.rope_yarn[5])
+    return apply_rope_pairs(
+        x, positions,
+        yarn_frequencies(cfg.qk_rope_dim, cfg.rope_theta, cfg.rope_yarn),
+        yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim))
+
+
+@jax.named_scope("mla_q")
+def mla_queries(cfg: ModelConfig, layer: Params, h: jax.Array,
+                positions: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Normed input ``h`` [B, S, D] → per-head queries: the no-position
+    part [B, S, H, nope] and the rotated rope part [B, S, H, rope]."""
+    B, S, _ = h.shape
+    c_q = rms_norm(h @ layer["wq_a"], layer["q_a_norm"], cfg.rms_eps)
+    q = (c_q @ layer["wq_b"]).reshape(
+        B, S, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
+    return (q[..., :cfg.qk_nope_dim],
+            _mla_rope(cfg, q[..., cfg.qk_nope_dim:], positions))
+
+
+@jax.named_scope("mla_kv")
+def mla_latent(cfg: ModelConfig, layer: Params, h: jax.Array,
+               positions: jax.Array) -> jax.Array:
+    """Normed input ``h`` [B, S, D] → the row a position caches
+    [B, S, kv_lora_rank + rope]: the compressed KV after its norm, then
+    the one rope key every head shares, after rotation."""
+    r = cfg.kv_lora_rank
+    ckv = h @ layer["wkv_a"]
+    c = rms_norm(ckv[..., :r], layer["kv_a_norm"], cfg.rms_eps)
+    k_rope = _mla_rope(cfg, ckv[..., None, r:], positions)[..., 0, :]
+    return jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _wkv_b_heads(cfg: ModelConfig, layer: Params) -> jax.Array:
+    return layer["wkv_b"].reshape(
+        cfg.kv_lora_rank, cfg.n_heads, cfg.qk_nope_dim + cfg.v_head_dim)
+
+
+@jax.named_scope("mla_q")
+def mla_absorb_queries(cfg: ModelConfig, layer: Params, q_nope: jax.Array,
+                       q_rope: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The absorbed form's queries against a latent row, softmax scale
+    folded in before the one rounding: ``q_nope W_UK^T`` [..., H, rank]
+    and the rope part [..., H, rope]."""
+    scale = mla_softmax_scale(cfg)
+    w_uk = _wkv_b_heads(cfg, layer)[..., :cfg.qk_nope_dim]  # [r, H, nope]
+    q_lat = jnp.einsum("...hn,rhn->...hr", q_nope, w_uk,
+                       preferred_element_type=jnp.float32)
+    return ((q_lat * scale).astype(q_nope.dtype),
+            (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype))
+
+
+@jax.named_scope("mla_out")
+def mla_attn_out(cfg: ModelConfig, layer: Params, o_lat: jax.Array) -> jax.Array:
+    """Attention-weighted latent rows [..., H, rank] → per-head values
+    through ``W_UV`` → the output projection [..., D] (residual NOT
+    added)."""
+    w_uv = _wkv_b_heads(cfg, layer)[..., cfg.qk_nope_dim:]  # [r, H, v]
+    o = jnp.einsum("...hr,rhv->...hv", o_lat, w_uv)
+    return o.reshape(*o.shape[:-2], cfg.attn_out_dim) @ layer["wo"]
+
+
+def mla_expand_kv(cfg: ModelConfig, layer: Params,
+                  latent: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Latent rows [B, S, rank + rope] → the published (expanded) keys
+    [B, S, H, nope + rope] and values [B, S, H, v]."""
+    B, S, _ = latent.shape
+    r, H = cfg.kv_lora_rank, cfg.n_heads
+    kv = (latent[..., :r] @ layer["wkv_b"]).reshape(
+        B, S, H, cfg.qk_nope_dim + cfg.v_head_dim)
+    k_rope = jnp.broadcast_to(latent[..., None, r:],
+                              (B, S, H, cfg.qk_rope_dim))
+    k = jnp.concatenate([kv[..., :cfg.qk_nope_dim], k_rope], axis=-1)
+    return k, kv[..., cfg.qk_nope_dim:]
+
+
+def _mla_fresh_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array,
+                         v: jax.Array) -> jax.Array:
+    """Causal attention of a whole fresh sequence in the expanded form →
+    [B, S, H * v].  The flash kernel wants one head width, so keys and
+    queries are zero-padded to it (the products are unchanged) and the
+    values' padding is cut from the output."""
+    from fusioninfer_tpu.ops import dispatch, flash_attention
+
+    B, S, H, Dk = q.shape
+    Dv = v.shape[-1]
+    scale = mla_softmax_scale(cfg)
+    if dispatch.resolve_attn(cfg.attn_impl) == "flash" and dispatch.flash_seq_ok(S):
+        width = -(-Dk // 128) * 128
+
+        def pad(x):
+            return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+
+        out = flash_attention(pad(q), pad(k), pad(v), causal=True,
+                              sm_scale=scale,
+                              interpret=dispatch.kernel_interpret())
+        return out.reshape(B, S, H, width)[..., :Dv].reshape(B, S, H * Dv)
+    scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
+    scores = jnp.where(causal_mask(S), scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhst,bthd->bshd", probs, v).reshape(B, S, H * Dv)
+
+
+# -- mixture of experts ------------------------------------------------------
 
 
 def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array, w_up: jax.Array,
             w_down: jax.Array, n_active: int) -> jax.Array:
-    """Token-choice top-k mixture of experts, dense-compute formulation.
-
-    Every expert runs on every token and results are combined with the
-    (renormalized) top-k router weights.  Exact and static-shaped — the
-    right choice at small expert counts (≤ ``DENSE_MOE_MAX_EXPERTS``,
-    e.g. the tiny test presets); large-E models like qwen3-30b-a3b
-    route through :func:`moe_ffn_sparse`, whose FLOPs track the ACTIVE
-    experts.  The expert axis is shardable over the mesh's ``ep`` axis
-    either way.
+    """Token-choice top-k mixture of experts, dense-compute formulation:
+    every expert runs on every token and results are combined with the
+    top-k router weights renormalised over the chosen.  Exact and
+    static-shaped; its FLOPs scale with E, so it serves as the tests'
+    oracle for :func:`moe_layer` and nothing serves through it.
 
     x: [tokens, d_model]; router_w: [d_model, E];
     w_gate/w_up: [E, d_model, d_ff]; w_down: [E, d_ff, d_model]
@@ -93,69 +254,179 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array, w_up: jax.Arra
     return jnp.einsum("ted,te->td", per_expert, weights.astype(x.dtype))
 
 
-def moe_capacity(n_tokens: int, n_active: int, n_experts: int,
-                 capacity_factor: float = 2.0) -> int:
-    """Static per-expert token capacity (Switch/GShard): expected load
-    ``T·k/E`` times a slack factor, floored at 4 so tiny decode batches
-    never drop."""
-    import math
+@jax.named_scope("moe_route")
+def moe_route(cfg: ModelConfig, h: jax.Array,
+              router_w: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Each token's experts and their weights, over the router's whole
+    width whatever share is held here → (ids [T, k] int32, weights
+    [T, k] float32).
 
-    return max(4, int(math.ceil(n_tokens * n_active / n_experts * capacity_factor)))
+    ``norm_topk``: top-k of the logits, weights a softmax over the
+    chosen.  Otherwise the scores are a softmax over all experts, kept
+    as they are and scaled by ``routed_scaling``.  With ``n_group`` > 1
+    the choice is group-limited greedy: a group scores as its best
+    expert, the best ``topk_group`` groups stay and the rest are masked
+    out before the top-k."""
+    logits = h.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
+    scores = logits if cfg.norm_topk else jax.nn.softmax(logits, axis=-1)
+    choose = scores
+    if cfg.n_group > 1:
+        T, E = scores.shape
+        per_group = E // cfg.n_group
+        group_best = scores.reshape(T, cfg.n_group, per_group).max(axis=-1)
+        _, kept = lax.top_k(group_best, cfg.topk_group)  # [T, topk_group]
+        keep = jnp.zeros((T, cfg.n_group), bool).at[
+            jnp.arange(T)[:, None], kept].set(True)
+        choose = jnp.where(jnp.repeat(keep, per_group, axis=1), scores,
+                           -jnp.inf)
+    top_vals, top_idx = lax.top_k(choose, cfg.n_experts_active)
+    weights = (jax.nn.softmax(top_vals, axis=-1) if cfg.norm_topk
+               else top_vals * cfg.routed_scaling)
+    return top_idx.astype(jnp.int32), weights
 
 
-def moe_ffn_sparse(x: jax.Array, router_w: jax.Array, w_gate: jax.Array,
-                   w_up: jax.Array, w_down: jax.Array, n_active: int,
-                   capacity_factor: float = 2.0) -> jax.Array:
-    """Capacity-based sparse MoE (the Switch/GShard dispatch, XLA-style).
+# megablox tiles (rows, contraction, columns) of the grouped product on
+# the chip: of the four tried at the served shapes (decode pass: 384 rows,
+# 36 experts touched; chunk pass: 3072 rows) the fastest on both matrices
+# (PERF.md section 6, PR 29); rows are padded to whole tiles
+GMM_TILING = (128, 2560, 768)
 
-    FLOPs scale with the ACTIVE experts, not E: each token's top-k
-    assignments scatter into a static ``[E, C, D]`` dispatch buffer
-    (``C`` = :func:`moe_capacity`), every expert runs one batched matmul
-    over its buffer, and results gather back weighted by the renormalized
-    router scores.  All shapes are static — capacity overflow *drops*
-    that (token, expert) assignment, the standard trade the slack factor
-    makes rare.  The leading expert axis of both the buffer and the
-    weights shards over ``ep``.
 
-    x: [tokens, d_model] → [tokens, d_model]
-    """
-    T, D = x.shape
-    E = router_w.shape[-1]
-    k = n_active
-    C = moe_capacity(T, k, E, capacity_factor)
+def grouped_matmul_impl() -> str:
+    """``"megablox_gmm"`` (the Pallas grouped matmul) on a TPU,
+    ``"ragged_dot"`` (XLA's, the oracle) elsewhere: resolved at trace
+    time like the attention kernels."""
+    from fusioninfer_tpu.ops import dispatch
 
-    logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))  # [T, E]
-    top_vals, top_idx = lax.top_k(logits, k)  # [T, k]
-    weights = jax.nn.softmax(top_vals, axis=-1)  # renormalized over chosen
+    return "megablox_gmm" if dispatch.is_tpu_backend() else "ragged_dot"
 
-    flat_e = top_idx.reshape(-1)  # [T*k] expert id per assignment
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # [T*k, E]
-    # slot of each assignment within its expert's buffer: how many prior
-    # assignments chose the same expert
-    prior = jnp.cumsum(onehot, axis=0) - onehot
-    slot = jnp.take_along_axis(prior, flat_e[:, None], axis=1)[:, 0]  # [T*k]
-    keep = slot < C
-    slot = jnp.where(keep, slot, 0)  # clamped; masked contributions add zero
 
-    x_rep = jnp.repeat(x, k, axis=0)  # [T*k, D]
-    contrib = x_rep * keep[:, None].astype(x.dtype)
-    dispatch = jnp.zeros((E, C, D), x.dtype).at[flat_e, slot].add(contrib)
+def grouped_matmul(xs: jax.Array, w, group_sizes: jax.Array,
+                   out_dtype) -> jax.Array:
+    """Rows of ``xs`` [A, K], sorted by group, each through its group's
+    matrix of ``w`` [G, K, N] → [A, N]; rows past the groups' total are
+    zeros.  On the chip a Pallas grouped matmul that visits only the
+    tiles of groups that have rows (measured against ``lax.ragged_dot``
+    there: equal on a decode pass, twice as fast on a chunk pass).
 
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", dispatch, w_gate))
-    up = jnp.einsum("ecd,edf->ecf", dispatch, w_up)
-    out_e = jnp.einsum("ecf,efd->ecd", gate * up, w_down)  # [E, C, D]
+    ``w`` may be ``(stack [L, G, K, N], l)``: layer ``l`` of a stack,
+    read IN PLACE.  A kernel's operand is a buffer of its own, so a
+    layer sliced out of its stack by the layer scan is first copied,
+    gigabytes a pass for a stack of experts; the kernel is handed the
+    whole stack as ``L * G`` groups instead, every group outside layer
+    ``l`` empty (empty groups are not visited)."""
+    stack_layer = None
+    if isinstance(w, tuple):
+        stack, stack_layer = w
+        w = stack.reshape(-1, *stack.shape[2:])
+    if grouped_matmul_impl() == "ragged_dot":
+        if stack_layer is not None:
+            w = lax.dynamic_index_in_dim(stack, stack_layer, 0, keepdims=False)
+        return lax.ragged_dot(xs, w, group_sizes,
+                              preferred_element_type=jnp.dtype(out_dtype))
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    gathered = out_e[flat_e, slot]  # [T*k, D]
-    w_flat = (weights.reshape(-1) * keep).astype(x.dtype)
-    return (gathered * w_flat[:, None]).reshape(T, k, D).sum(axis=1)
+    if stack_layer is not None:
+        group_sizes = lax.dynamic_update_slice(
+            jnp.zeros((w.shape[0],), group_sizes.dtype), group_sizes,
+            (stack_layer * group_sizes.shape[0],))
+    A = xs.shape[0]
+    pad = -A % GMM_TILING[0]
+    out = gmm(jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs, w, group_sizes,
+              preferred_element_type=jnp.dtype(out_dtype),
+              tiling=GMM_TILING)[:A]
+    # the kernel leaves rows past the last group unwritten
+    rows = lax.broadcasted_iota(jnp.int32, (A, 1), 0)
+    return jnp.where(rows < jnp.sum(group_sizes), out, 0)
+
+
+EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
+
+
+# counters a pass through an expert layer adds (engine: fusioninfer:moe_*)
+MOE_STATS = ("assignments", "assignments_local", "expert_touches",
+             "layer_passes")
+
+
+def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
+              live: Optional[jax.Array] = None) -> tuple[jax.Array, jax.Array]:
+    """The ONE expert layer: route over all ``n_experts``, compute the
+    part of the result that the experts held here give, with no capacity
+    and no assignment dropped, plus the shared experts every token goes
+    through → (y [T, D], stats uint32 [4] in :data:`MOE_STATS` order).
+
+    Assignments are sorted by held expert (the others, and those of
+    tokens not ``live``, sort past the last group and weigh nothing),
+    their tokens' rows gathered, and each expert's rows go through its
+    own matrices in one grouped product; the weighted results return to
+    token order and sum in float32.  What the experts held elsewhere
+    would add is left out: on one process of an expert-parallel group
+    that is its share before the exchange."""
+    T, D = h.shape
+    w_gate = layer["w_gate"]  # [held, D, F], or (stack [L, held, D, F], l)
+    k = cfg.n_experts_active
+    held = (w_gate[0].shape[1] if isinstance(w_gate, tuple)
+            else w_gate.shape[0])
+    top_idx, top_w = moe_route(cfg, h, layer["router"])
+    with jax.named_scope("moe_experts"):
+        local = top_idx - cfg.expert_offset
+        mine = (local >= 0) & (local < held)
+        if live is not None:
+            mine = mine & live[:, None]
+        group = jnp.where(mine, local, held).reshape(T * k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+        xs = h[order // k]  # [A, D] rows in expert order
+        act = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, h.dtype))
+        act = act * grouped_matmul(xs, layer["w_up"], sizes, h.dtype)
+        ys = grouped_matmul(act, layer["w_down"], sizes, jnp.float32)
+        weight = jnp.where(mine, top_w, 0.0).reshape(T * k)[order]
+        ys = ys * weight[:, None]
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = ys[back].reshape(T, k, D).sum(axis=1)
+    if "ws_gate" in layer:
+        with jax.named_scope("moe_shared"):
+            y = y + swiglu(h, layer["ws_gate"], layer["ws_up"],
+                           layer["ws_down"]).astype(jnp.float32)
+    n_live = T if live is None else jnp.sum(live)
+    stats = jnp.stack([n_live * k, jnp.sum(sizes), jnp.sum(sizes > 0),
+                       jnp.ones((), jnp.int32)]).astype(jnp.uint32)
+    return y.astype(h.dtype), stats
 
 
 # -- parameter init ----------------------------------------------------------
 
 
+@partial(jax.jit, static_argnames=("shape", "dtype"))
+def _draw(key: jax.Array, denom: jax.Array, shape: tuple, dtype) -> jax.Array:
+    """One seeded matrix, N(0, 1/fan_in) rounded to ``dtype``: normal →
+    scale → cast under one jit, so no float32 copy outlives the call.
+    ``denom`` = sqrt(fan_in) is an ARGUMENT: a true division, the same
+    bits as the op-by-op form (a constant would let XLA multiply by its
+    reciprocal, which rounds about one element in 1e5 otherwise)."""
+    return (jax.random.normal(key, shape, jnp.float32) / denom).astype(dtype)
+
+
+@partial(jax.jit, static_argnames=("shape",), donate_argnums=(0,))
+def _draw_into(buf: jax.Array, i, key: jax.Array, denom: jax.Array,
+               shape: tuple) -> jax.Array:
+    """Layer ``i`` of a stacked matrix drawn in place: the stack is born
+    whole and filled a layer at a time, so the float32 draw in flight is
+    one layer's, not the stack's."""
+    return buf.at[i].set(_draw(key, denom, shape, buf.dtype))
+
+
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Random-init parameters, layer weights stacked on axis 0."""
+    """Random-init parameters, layer weights stacked on axis 0.
+
+    One homogeneous stack (``params["layers"]``) is drawn a whole matrix
+    at a time under ``split(key, 12)[slot]``.  A model with latent
+    attention (and with it, leading dense layers) is drawn by
+    :func:`_init_stacks`."""
     cfg.validate()
+    if cfg.is_mla:
+        return _init_stacks(cfg, key)
     dtype = cfg.jax_dtype
     L, D, H, KV, Hd, F = (
         cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff,
@@ -163,7 +434,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     keys = jax.random.split(key, 12)
 
     def dense(k, shape, fan_in):
-        return (jax.random.normal(k, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
+        return _draw(k, jnp.sqrt(fan_in), shape, dtype)
 
     layers: Params = {
         "attn_norm": jnp.ones((L, D), dtype),
@@ -177,8 +448,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         layers["q_norm"] = jnp.ones((L, Hd), dtype)
         layers["k_norm"] = jnp.ones((L, Hd), dtype)
     if cfg.is_moe:
-        E, EF = cfg.n_experts, cfg.expert_d_ff
-        layers["router"] = dense(keys[4], (L, D, E), D).astype(jnp.float32)
+        E, EF = cfg.experts_held, cfg.expert_d_ff
+        layers["router"] = dense(keys[4], (L, D, cfg.n_experts), D).astype(jnp.float32)
         layers["w_gate"] = dense(keys[5], (L, E, D, EF), D)
         layers["w_up"] = dense(keys[6], (L, E, D, EF), D)
         layers["w_down"] = dense(keys[7], (L, E, EF, D), EF)
@@ -195,6 +466,99 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[9], (D, cfg.vocab_size), D)
     return params
+
+
+# the key of every matrix a two-stack model draws: fold_in(fold_in(key,
+# slot), layer index within its stack); embed and lm_head fold the slot only
+STACK_SLOTS = {
+    "embed": 1, "lm_head": 2, "wo": 13,
+    "wq_a": 14, "wq_b": 15, "wkv_a": 16, "wkv_b": 17,
+    "w_gate": 20, "w_up": 21, "w_down": 22, "router": 23,
+    "ws_gate": 24, "ws_up": 25, "ws_down": 26,
+}
+DENSE_STACK_SLOT_OFFSET = 100  # the leading dense layers' matrices
+
+
+def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
+    """name -> (one layer's shape, fan_in) of the seeded matrices of a
+    layer of the dense stack or of the expert stack."""
+    D, H = cfg.d_model, cfg.n_heads
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    out = {
+        "wq_a": ((D, cfg.q_lora_rank), D),
+        "wq_b": ((cfg.q_lora_rank, H * qk), cfg.q_lora_rank),
+        "wkv_a": ((D, cfg.latent_dim), D),
+        "wkv_b": ((cfg.kv_lora_rank, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                  cfg.kv_lora_rank),
+        "wo": ((cfg.attn_out_dim, D), cfg.attn_out_dim),
+    }
+    if not experts:
+        F = cfg.d_ff
+        out.update(w_gate=((D, F), D), w_up=((D, F), D), w_down=((F, D), F))
+        return out
+    E, EF = cfg.experts_held, cfg.expert_d_ff
+    out.update(router=((D, cfg.n_experts), D),
+               w_gate=((E, D, EF), D), w_up=((E, D, EF), D),
+               w_down=((E, EF, D), EF))
+    if cfg.n_shared_experts:
+        SF = cfg.n_shared_experts * EF
+        out.update(ws_gate=((D, SF), D), ws_up=((D, SF), D),
+                   ws_down=((SF, D), SF))
+    return out
+
+
+def _init_stacks(cfg: ModelConfig, key: jax.Array) -> Params:
+    """Seeded weights of a latent-attention model, in up to two stacks:
+    ``params["dense_layers"]`` (the leading ``first_k_dense`` layers)
+    and ``params["layers"]`` (the expert layers after them).  Every
+    matrix is drawn a LAYER at a time into its stack (:func:`_draw_into`):
+    a stack of held experts is gigabytes, and its float32 draw beside
+    the weights already resident would not fit the chip."""
+    dtype = cfg.jax_dtype
+    D = cfg.d_model
+
+    def stack(n: int, experts: bool, slot_offset: int) -> Params:
+        layers: Params = {
+            "attn_norm": jnp.ones((n, D), dtype),
+            "mlp_norm": jnp.ones((n, D), dtype),
+            "q_a_norm": jnp.ones((n, cfg.q_lora_rank), dtype),
+            "kv_a_norm": jnp.ones((n, cfg.kv_lora_rank), dtype),
+        }
+        for name, (shape, fan_in) in stack_matrix_shapes(cfg, experts).items():
+            k_m = jax.random.fold_in(key, STACK_SLOTS[name] + slot_offset)
+            buf = jnp.zeros((n, *shape), dtype)
+            for i in range(n):
+                buf = _draw_into(buf, i, jax.random.fold_in(k_m, i),
+                                 jnp.sqrt(fan_in), shape)
+            # the router is read in float32 (top-k is sensitive to the
+            # logits' rounding); its values stay the rounded draw's
+            layers[name] = buf.astype(jnp.float32) if name == "router" else buf
+        return layers
+
+    nd = cfg.n_dense_layers
+    params: Params = {
+        "embed": _draw(jax.random.fold_in(key, STACK_SLOTS["embed"]),
+                       jnp.sqrt(D), (cfg.vocab_size, D), dtype),
+        "layers": stack(cfg.n_layers - nd, cfg.is_moe, 0),
+        "final_norm": jnp.ones((D,), dtype),
+    }
+    if nd and cfg.is_moe:
+        params["dense_layers"] = stack(nd, False, DENSE_STACK_SLOT_OFFSET)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _draw(
+            jax.random.fold_in(key, STACK_SLOTS["lm_head"]), jnp.sqrt(D),
+            (D, cfg.vocab_size), dtype)
+    return params
+
+
+def layer_stacks(cfg: ModelConfig, params: Params) -> list[tuple[Params, int]]:
+    """The model's layer stacks in order, each with the index of its
+    first layer: the leading dense layers (where the model has them),
+    then ``params["layers"]``."""
+    if "dense_layers" in params:
+        return [(params["dense_layers"], 0),
+                (params["layers"], cfg.n_dense_layers)]
+    return [(params["layers"], 0)]
 
 
 # -- forward -----------------------------------------------------------------
@@ -250,21 +614,23 @@ def qkv_proj(
     return q, k, v
 
 
-@jax.named_scope("mlp")
-def mlp_block(cfg: ModelConfig, layer: Params, x: jax.Array) -> jax.Array:
-    """Pre-norm + FFN (dense SwiGLU or MoE), shared by every path.
+def mlp_block(cfg: ModelConfig, layer: Params, x: jax.Array,
+              live: Optional[jax.Array] = None):
+    """Pre-norm + FFN, shared by every path → ``(y, stats)``: a dense
+    SwiGLU (stats None), or the expert layer where the layer's tree
+    holds a router (:func:`moe_layer`; stats its counters).
 
-    x: [B, S, D] → [B, S, D] (residual NOT added).  Callers dequantize
+    x: [B, S, D] → [B, S, D] (residual NOT added).  ``live`` [B, S]
+    marks real tokens: padding chooses no expert.  Callers dequantize
     the layer tree once at block entry (see qkv_proj invariant)."""
     B, S, D = x.shape
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
-    if cfg.is_moe:
-        ffn = moe_ffn if cfg.n_experts <= DENSE_MOE_MAX_EXPERTS else moe_ffn_sparse
-        return ffn(
-            h.reshape(B * S, D), layer["router"], layer["w_gate"], layer["w_up"],
-            layer["w_down"], cfg.n_experts_active,
-        ).reshape(B, S, D)
-    return swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+    if "router" in layer:
+        y, stats = moe_layer(cfg, layer, h.reshape(B * S, D),
+                             None if live is None else live.reshape(B * S))
+        return y.reshape(B, S, D), stats
+    with jax.named_scope("mlp"):
+        return swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"]), None
 
 
 @jax.named_scope("attn_out")
@@ -290,8 +656,12 @@ def layer_forward(
     mesh=None,
     lora: Params = None,
     adapter_ids: Optional[jax.Array] = None,
-) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
-    """One transformer block. Returns (output, (k, v)) for cache management.
+    live: Optional[jax.Array] = None,
+):
+    """One transformer block → ``(output, kv, stats)``: ``kv`` is what a
+    position caches, (k, v) or with latent attention the latent rows
+    [B, S, rank + rope]; ``stats`` the expert layer's counters (None for
+    a dense FFN).  ``live`` [B, S] marks real tokens for the experts.
 
     x: [B, S, D]; positions: [B, S]; mask broadcastable to [B, 1, S, T].
     ``kv=None`` means fresh causal self-attention — the mask is derived
@@ -305,6 +675,22 @@ def layer_forward(
     B, S, D = x.shape
 
     layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
+    if cfg.is_mla:
+        if kv is not None or mesh is not None or lora is not None:
+            raise NotImplementedError(
+                "latent attention runs fresh causal sequences on one device "
+                "without adapters; cached context goes through model_runner")
+        h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        q_nope, q_rope = mla_queries(cfg, layer, h, positions)
+        latent = mla_latent(cfg, layer, h, positions)
+        with jax.named_scope("attn"):
+            k, v = mla_expand_kv(cfg, layer, latent)
+            attn = _mla_fresh_attention(
+                cfg, jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
+        with jax.named_scope("mla_out"):
+            x = x + attn @ layer["wo"]
+        y, stats = mlp_block(cfg, layer, x, live)
+        return x + y, latent, stats
     q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids)
 
     with jax.named_scope("attn"):
@@ -340,7 +726,8 @@ def layer_forward(
             attn_k, attn_v = kv
             attn = _attention(q, attn_k, attn_v, mask)
     x = x + attn_out_proj(layer, attn, lora, adapter_ids)
-    return x + mlp_block(cfg, layer, x), (k, v)
+    y, stats = mlp_block(cfg, layer, x, live)
+    return x + y, (k, v), stats
 
 
 def causal_mask(S: int, dtype=jnp.bool_, window: int | None = None) -> jax.Array:
@@ -390,10 +777,10 @@ def hidden_states(cfg: ModelConfig, params: Params,
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
     def body(x, layer):
-        out, _ = layer_forward(cfg, layer, x, positions)
-        return out, None
+        return layer_forward(cfg, layer, x, positions)[0], None
 
-    x, _ = lax.scan(body, x, params["layers"])
+    for stack, _first in layer_stacks(cfg, params):
+        x, _ = lax.scan(body, x, stack)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 

@@ -2,6 +2,7 @@
 
 * :mod:`flash_attention` — blockwise prefill/training attention.
 * :mod:`paged_attention` — paged decode attention over the KV cache.
+* :mod:`mla_attention` — ragged paged attention over latent (MLA) pages.
 * :mod:`dispatch` — trace-time kernel/reference selection.
 """
 
@@ -13,6 +14,10 @@ from fusioninfer_tpu.ops.dispatch import (  # noqa: F401
 from fusioninfer_tpu.ops.flash_attention import (  # noqa: F401
     flash_attention,
     reference_attention,
+)
+from fusioninfer_tpu.ops.mla_attention import (  # noqa: F401
+    mla_ragged_paged_attention,
+    reference_mla_ragged_paged_attention,
 )
 from fusioninfer_tpu.ops.paged_attention import (  # noqa: F401
     KV_SPLIT_CHUNKS,

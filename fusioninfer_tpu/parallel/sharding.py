@@ -209,11 +209,14 @@ def sharded_kv_cache(cfg: ModelConfig, cache_cfg, mesh: Mesh,
     def build():
         return init_kv_cache(cfg, cache_cfg)
 
-    shardings = {
-        name: jax.sharding.NamedSharding(
-            mesh, kv_scale_spec(rules) if name.endswith("_scale")
-            else kv_cache_spec(rules))
-        for name in jax.eval_shape(build)}
+    def spec(name):
+        if name == "moe_stats":  # the expert layers' counters: replicated
+            return (rules or default_rules()).spec(None)
+        return (kv_scale_spec(rules) if name.endswith("_scale")
+                else kv_cache_spec(rules))
+
+    shardings = {name: jax.sharding.NamedSharding(mesh, spec(name))
+                 for name in jax.eval_shape(build)}
     return jax.jit(build, out_shardings=shardings)()
 
 

@@ -258,6 +258,31 @@ ENTRY_POINTS: dict[str, dict] = {
         "runtime": "fusioninfer_tpu.ops.paged_attention:"
                    "ragged_paged_attention_kvsplit",
     },
+    "fusioninfer_tpu/ops/mla_attention.py::mla_ragged_paged_attention": {
+        "kind": "jit",
+        "family": "kernels",
+        "static_argnums": (),
+        "static_argnames": ("rank", "interpret", "block_q"),
+        "runtime": "fusioninfer_tpu.ops.mla_attention:"
+                   "mla_ragged_paged_attention",
+    },
+    # -- models/transformer.py: seeded initialisation, one signature per
+    # matrix shape of the model being built (start-up only; the ledger
+    # skips them: their count is the model's, not a serving family's)
+    "fusioninfer_tpu/models/transformer.py::_draw": {
+        "kind": "jit",
+        "family": "model",
+        "static_argnums": (),
+        "static_argnames": ("shape", "dtype"),
+        "runtime": None,
+    },
+    "fusioninfer_tpu/models/transformer.py::_draw_into": {
+        "kind": "jit",
+        "family": "model",
+        "static_argnums": (),
+        "static_argnames": ("shape",),
+        "runtime": None,
+    },
     "fusioninfer_tpu/ops/lm_head_topk.py::lm_head_topk": {
         "kind": "jit",
         "family": "sampler",

@@ -164,12 +164,16 @@ class TestSignatures:
         assert "burst/s1-greedy" in names and "burst/s4-greedy" in names
 
     def test_prefill_entries_follow_bucket_and_group_discipline(self):
-        e = tiny_engine()
-        names = {n for n, _ in e.aot_signatures()}
+        names = {n for n, _ in tiny_engine(token_budget=None).aot_signatures()}
         # buckets [32, 64] x pow2 groups {1, 2}
         for bucket in (32, 64):
             for rows in (1, 2):
                 assert f"prefill/b{bucket}r{rows}" in names
+        # under a token budget of 32 a prompt of 33-64 tokens is chunked
+        # and never prefilled whole: its bucket is not built
+        names = {n for n, _ in tiny_engine().aot_signatures()}
+        assert {"prefill/b32r1", "prefill/b32r2"} <= names
+        assert not any(n.startswith("prefill/b64") for n in names)
 
 
 class TestWarmup:

@@ -1,0 +1,129 @@
+"""Tier-1 pin of the benchmark's yardstick (``perfbench/tests`` is not
+collected by ``pytest tests/``): an architecture's file is found by the
+``model_type`` its configuration publishes, and the five work counts of
+BOTH architecture files at their configurations are these, to the
+integer: ``step_mfu`` and the roofline shares divide by them, so a count
+that drifts moves a metric with no change in the program."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "perfbench")
+
+
+@pytest.fixture(scope="module")
+def work():
+    sys.path.insert(0, BENCH)
+    import work
+
+    return work
+
+
+def config_of(name):
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("config, model_type", [
+    ("qwen3-1.7b", "qwen3"), ("qwen3-tiny-cpu", "qwen3"),
+    ("deepseek-v2-ep4", "deepseek_v2"), ("deepseek-v2-tiny-cpu", "deepseek_v2")])
+def test_a_configuration_resolves_to_its_architectures_file(work, config,
+                                                            model_type):
+    cfg = config_of(config)
+    assert cfg["model_type"] == model_type
+    path = work.arch_path(cfg)
+    assert path == os.path.join(BENCH, "arch", model_type + ".py")
+    mod = work.load_arch(path)
+    assert callable(mod.Forward)
+    for count in ("matmul_params", "token_flops", "prompt_flops",
+                  "kv_bytes_per_position", "decode_kv_bytes"):
+        assert callable(getattr(mod, count)), count
+
+
+def test_every_configuration_of_the_benchmark_resolves(work):
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert [c["name"] for c in bench["configs"]] == ["qwen3-1.7b",
+                                                     "deepseek-v2-ep4"]
+    for entry in bench["configs"]:
+        with open(os.path.join(ROOT, entry["file"])) as f:
+            cfg = json.load(f)
+        assert os.path.isfile(work.arch_path(cfg))
+        assert work.matmul_params(cfg) > 0 and work.decode_kv_bytes(cfg, [1]) > 0
+
+
+def test_an_unknown_model_type_raises_with_the_path(work):
+    cfg = dict(config_of("qwen3-tiny-cpu"), model_type="no_such_arch")
+    with pytest.raises(ValueError, match="arch/no_such_arch.py"):
+        work.token_flops(cfg, 10)
+    del cfg["model_type"]
+    with pytest.raises(ValueError, match="no model_type"):
+        work.matmul_params(cfg)
+
+
+def test_the_counts_of_qwen3_1_7b(work):
+    cfg = config_of("qwen3-1.7b")
+    assert work.matmul_params(cfg) == 1_720_451_072
+    assert work.token_flops(cfg, 1000) == 3_670_278_144
+    assert work.token_flops(cfg, 1000, with_head=False) == 3_047_948_288
+    assert work.prompt_flops(cfg, 512) == 1_473_854_832_640
+    assert work.kv_bytes_per_position(cfg) == 114_688
+    assert work.kv_bytes_per_position(cfg, kv_dtype_bytes=1) == 57_344
+    assert work.decode_kv_bytes(cfg, [100, 200]) == 34_406_400
+
+
+def test_the_counts_of_deepseek_v2_ep4(work):
+    """One chip's share: 5 x 149 225 472 of attention, the dense layer's
+    3 x 5120 x 12288, per expert layer the router (5120 x 160), two
+    shared experts and the EXPECTED 6 x 40 / 160 = 1.5 held routed experts
+    of 3 x 5120 x 1536, the head at 25 600; attention counted in the
+    published (expanded) form, 2 x 128 x (192 + 128) = 81 920 FLOP a
+    cached position and layer; a latent row of 576 values a layer."""
+    cfg = config_of("deepseek-v2-ep4")
+    mod = work.load_arch(work.arch_path(cfg))
+    assert mod._attention_params(mod.sizes(cfg)) == 149_225_472
+    assert work.matmul_params(cfg) == 1_399_521_280
+    assert work.token_flops(cfg, 1000) == 3_208_642_560
+    assert work.token_flops(cfg, 1000, with_head=False) == 2_946_498_560
+    assert (work.token_flops(cfg, 1001) - work.token_flops(cfg, 1000)
+            == 5 * 81_920)
+    assert work.prompt_flops(cfg, 512) == 1_352_946_155_520
+    assert work.kv_bytes_per_position(cfg) == 5 * 576 * 2 == 5760
+    assert work.kv_bytes_per_position(cfg, kv_dtype_bytes=1) == 2880
+    assert work.decode_kv_bytes(cfg, [100, 200]) == 1_728_000
+    # the latent kernel's arithmetic: 2 x 128 x (576 + 512) = 278 528 FLOP
+    # a cached position and layer, 241.8 FLOP a byte of latent row
+    assert mod.decode_attn_flops(cfg, [100, 200]) == 5 * 278_528 * 300
+    assert mod.decode_attn_flops(cfg, [1]) / work.decode_kv_bytes(
+        cfg, [1]) == pytest.approx(241.8, abs=0.1)
+
+
+def test_the_counts_of_the_tiny_deepseek_configuration(work):
+    cfg = config_of("deepseek-v2-tiny-cpu")
+    mod = work.load_arch(work.arch_path(cfg))
+    assert work.matmul_params(cfg) == 524_288
+    assert work.token_flops(cfg, 10) == 1_067_776
+    assert work.kv_bytes_per_position(cfg) == 3 * 80 * 2
+    assert mod.decode_attn_flops(cfg, [10]) == 3 * 2 * 4 * (80 + 64) * 10
+
+
+def test_reading_the_counts_imports_neither_jax_nor_the_program():
+    code = (
+        "import json, sys\n"
+        f"sys.path.insert(0, {BENCH!r})\n"
+        "import work\n"
+        "bad = []\n"
+        "for name in ('qwen3-1.7b', 'deepseek-v2-ep4'):\n"
+        f"    cfg = json.load(open({os.path.join(BENCH, 'configs')!r} + '/' + name + '.json'))\n"
+        "    assert work.prompt_flops(cfg, 8) > 0\n"
+        "bad = [m for m in sys.modules if m.split('.')[0] in "
+        "('jax', 'jaxlib', 'fusioninfer_tpu')]\n"
+        "print(json.dumps(bad))\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60, check=True)
+    assert json.loads(out.stdout.strip().splitlines()[-1]) == []

@@ -247,3 +247,96 @@ def test_jit_listener_counts_a_miss_and_nothing_on_a_hit():
     assert first["seconds"] > before["seconds"]
     fresh(x).block_until_ready()
     assert dict(spans.jit_totals) == first
+
+
+# -- the loop holds a step's tokens until the device has work ----------------
+
+class _ScriptedEngine:
+    """An engine double that plays a script of steps on the loop's own
+    thread and records, at each point, what the server has published."""
+
+    class _Cfg:
+        vocab_size = 512
+
+    cfg = _Cfg()
+    guided_enabled = True  # skips the guided-vocab bootstrap
+
+    def __init__(self, script, hook: bool = True):
+        self.script = list(script)
+        self.seen: list = []  # (label, tokens published so far)
+        self.chan = None
+        self.in_flight = False
+        if hook:
+            self.on_forward_enqueued = None
+
+    def add_request(self, request):
+        pass
+
+    def cancel(self, request_id):
+        pass
+
+    def has_work(self):
+        return bool(self.script)
+
+    def forward_in_flight(self):
+        return self.in_flight
+
+    def fail_all(self, reason, retry_after_s=None):
+        return []
+
+    def _published(self):
+        return self.chan.q.qsize() if self.chan is not None else 0
+
+    def step(self):
+        token, enqueues, self.in_flight = self.script.pop(0)
+        self.seen.append(("enter", self._published()))
+        hook = getattr(self, "on_forward_enqueued", None)
+        if enqueues and hook is not None:
+            hook()
+        self.seen.append(("forward enqueued" if enqueues else "no forward",
+                          self._published()))
+        from fusioninfer_tpu.engine.engine import StepOutput
+
+        return [StepOutput(request_id=self.rid, token=token,
+                           finished=not self.script)]
+
+
+def _play(script, hook=True):
+    from fusioninfer_tpu.engine.sampler import SamplingParams
+    from fusioninfer_tpu.engine.tokenizer import ByteTokenizer
+
+    engine = _ScriptedEngine([], hook=hook)
+    srv = EngineServer(model="stub", host="127.0.0.1", port=0, engine=engine,
+                       tokenizer=ByteTokenizer())
+    engine.chan = srv.submit([1, 2, 3], SamplingParams(max_tokens=len(script)))
+    with srv._lock:
+        engine.rid = next(iter(srv._channels))
+    engine.script = list(script)  # has_work() turns true: the loop steps
+    srv.start()
+    try:
+        got = [engine.chan.q.get(timeout=10.0).token for _ in script]
+    finally:
+        srv.stop()
+    return engine.seen, got
+
+
+@pytest.mark.parametrize("case", [
+    # (token, does the step enqueue a forward, is one left in flight)
+    pytest.param(([(7, True, False), (8, True, False), (9, True, False)],
+                  [0, 0, 0, 1, 1, 2]), id="held-until-the-next-forward"),
+    pytest.param(([(7, True, True), (8, True, True), (9, True, False)],
+                  [0, 0, 1, 1, 2, 2]), id="at-once-with-a-burst-in-flight"),
+    pytest.param(([(7, True, False), (8, False, False), (9, True, False)],
+                  [0, 0, 0, 0, 1, 2]), id="a-step-without-a-forward"),
+])
+def test_tokens_wait_for_the_next_forward_only_while_the_device_is_idle(case):
+    script, want = case
+    seen, got = _play(script)
+    assert got == [t for t, _, _ in script]  # all delivered, in order
+    assert [n for _, n in seen] == want, seen
+
+
+def test_an_engine_without_the_hook_is_published_at_once():
+    seen, got = _play([(7, True, False), (8, True, False)], hook=False)
+    assert got == [7, 8]
+    assert [n for _, n in seen] == [0, 0, 1, 1]

@@ -495,3 +495,26 @@ class TestCalibration:
         _run_all(engine, [Request("c", [1, 2, 3],
                                   SamplingParams(max_tokens=2,
                                                  temperature=0.0))])
+
+
+def test_warm_chunk_forwards_dispatches_every_flat_token_bucket_once():
+    """Start-up dispatches the chunk forward at every flat-token bucket a
+    budgeted chunk can have (16 .. pow2(budget)), on scratch pages it
+    gives back: afterwards no bucket is new to the jit cache, so no
+    stream stalls on a first dispatch inside the serving window."""
+    from fusioninfer_tpu.engine.kv_cache import CacheConfig
+    from fusioninfer_tpu.engine.model_runner import fused_step
+    from fusioninfer_tpu.models.config import get_preset
+
+    eng = NativeEngine(get_preset("qwen3-tiny"), cache_cfg=CacheConfig(
+        n_pages=17, page_size=32, max_pages_per_seq=8), max_batch_size=2,
+        token_budget=96)
+    before = fused_step._cache_size()
+    assert eng.warm_chunk_forwards() == 4  # 16, 32, 64 and 96 -> T = 128
+    assert fused_step._cache_size() == before + 4
+    assert eng.alloc.used_pages == 0 and not eng.has_work()
+    eng.warm_chunk_forwards()
+    assert fused_step._cache_size() == before + 4
+    no_budget = NativeEngine(get_preset("qwen3-tiny"), cache_cfg=CacheConfig(
+        n_pages=17, page_size=32, max_pages_per_seq=8), max_batch_size=2)
+    assert no_budget.warm_chunk_forwards() == 0

@@ -72,13 +72,15 @@ def test_gradient_step_reduces_loss(tiny):
     assert abs(float(loss0) - np.log(cfg.vocab_size)) < 1.5
 
 
-class TestSparseMoE:
-    """Capacity-dispatch MoE (moe_ffn_sparse): FLOPs track active experts;
-    must agree with the exact dense formulation when capacity is ample."""
+class TestExpertLayer:
+    """The ONE expert layer (``moe_layer``): sorted assignments through a
+    grouped product, no capacity, nothing dropped.  It must agree with the
+    exact dense formulation (``moe_ffn``, every expert on every token) at
+    every expert count, token count and skew: the cases the capacity
+    dispatch it replaced either matched only "with ample capacity" or
+    dropped assignments in."""
 
     def _weights(self, E=8, D=16, F=32, seed=0):
-        import jax
-
         ks = jax.random.split(jax.random.key(seed), 4)
         router = jax.random.normal(ks[0], (D, E), jnp.float32)
         w_gate = jax.random.normal(ks[1], (E, D, F), jnp.float32) / 4
@@ -86,56 +88,75 @@ class TestSparseMoE:
         w_down = jax.random.normal(ks[3], (E, F, D), jnp.float32) / 4
         return router, w_gate, w_up, w_down
 
-    def test_matches_dense_with_ample_capacity(self):
-        import jax
+    def _cfg(self, E, k):
+        return ModelConfig(
+            name="moe-case", d_model=16, n_experts=E, n_experts_active=k,
+            moe_d_ff=32, dtype="float32").validate()
 
-        from fusioninfer_tpu.models.transformer import moe_ffn, moe_ffn_sparse
+    @pytest.mark.parametrize("E, k, T, seed", [
+        (8, 2, 12, 9),     # was: matches dense "with ample capacity"
+        (8, 2, 64, 3),     # was: tight capacity 0.5 dropped; now exact
+        (32, 4, 40, 1),    # was: past the 16-expert switch, capacity path
+        (128, 8, 1, 2),    # was: the capacity floor for one decode token
+        (128, 8, 96, 4),   # qwen3-30b-a3b's router shape, a chunk of tokens
+        (4, 2, 7, 5),      # moe-tiny's
+    ], ids=["ample", "tight", "past-16-experts", "one-decode-token",
+            "128-experts-chunk", "moe-tiny"])
+    def test_matches_the_dense_formulation_with_nothing_dropped(
+            self, E, k, T, seed):
+        from fusioninfer_tpu.models.transformer import moe_ffn, moe_layer
 
-        router, g, u, d = self._weights()
-        x = jax.random.normal(jax.random.key(9), (12, 16), jnp.float32)
-        dense = moe_ffn(x, router, g, u, d, n_active=2)
-        # capacity >= T guarantees zero drops -> identical math
-        sparse = moe_ffn_sparse(x, router, g, u, d, n_active=2,
-                                capacity_factor=float(12 * 8))
+        router, g, u, d = self._weights(E=E)
+        x = jax.random.normal(jax.random.key(seed), (T, 16), jnp.float32)
+        dense = moe_ffn(x, router, g, u, d, n_active=k)
+        layer = {"router": router, "w_gate": g, "w_up": u, "w_down": d}
+        got, stats = moe_layer(self._cfg(E, k), layer, x)
         np.testing.assert_allclose(
-            np.asarray(sparse), np.asarray(dense), atol=1e-4, rtol=1e-4
-        )
+            np.asarray(got), np.asarray(dense), atol=1e-4, rtol=1e-4)
+        # every assignment is local and computed: none dropped
+        assert stats.tolist()[:2] == [T * k, T * k] and int(stats[3]) == 1
+        assert 1 <= int(stats[2]) <= min(E, T * k)
 
-    def test_tight_capacity_drops_but_stays_finite(self):
-        import jax
-
-        from fusioninfer_tpu.models.transformer import moe_ffn_sparse
+    def test_a_skewed_router_drops_nothing(self):
+        """64 tokens that all choose the same two of 8 experts: 8 times
+        the even load on each.  A 2.0x capacity kept a quarter of them."""
+        from fusioninfer_tpu.models.transformer import moe_ffn, moe_layer
 
         router, g, u, d = self._weights()
-        x = jax.random.normal(jax.random.key(3), (64, 16), jnp.float32)
-        out = moe_ffn_sparse(x, router, g, u, d, n_active=2, capacity_factor=0.5)
-        assert out.shape == (64, 16)
-        assert bool(jnp.isfinite(out).all())
+        router = jnp.zeros_like(router).at[0, 3].set(9.0).at[0, 6].set(7.0)
+        x = jnp.abs(jax.random.normal(jax.random.key(3), (64, 16))) + 0.1
+        layer = {"router": router, "w_gate": g, "w_up": u, "w_down": d}
+        got, stats = moe_layer(self._cfg(8, 2), layer, x)
+        assert stats.tolist() == [128, 128, 2, 1]
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(moe_ffn(x, router, g, u, d, 2)),
+            atol=1e-4, rtol=1e-4)
 
-    def test_large_expert_count_routes_sparse(self):
-        from fusioninfer_tpu.models.config import ModelConfig
-        from fusioninfer_tpu.models.transformer import (
-            DENSE_MOE_MAX_EXPERTS,
-            forward,
-            init_params,
-        )
+    @pytest.mark.parametrize("n_experts", [4, 32, 128])
+    def test_every_expert_count_serves_through_the_one_layer(self, n_experts):
+        from fusioninfer_tpu.models import transformer
 
         cfg = ModelConfig(
             name="moe-many", vocab_size=128, d_model=32, n_layers=2,
             n_heads=4, n_kv_heads=2, head_dim=8, d_ff=64,
-            n_experts=32, n_experts_active=4, moe_d_ff=32,
+            n_experts=n_experts, n_experts_active=4, moe_d_ff=32,
             dtype="float32", attn_impl="reference",
         ).validate()
-        assert cfg.n_experts > DENSE_MOE_MAX_EXPERTS
-        import jax
-
+        for gone in ("moe_ffn_sparse", "moe_capacity", "DENSE_MOE_MAX_EXPERTS"):
+            assert not hasattr(transformer, gone)
         params = init_params(cfg, jax.random.key(0))
         logits = forward(cfg, params, jnp.asarray([[1, 2, 3, 4]]))
         assert logits.shape == (1, 4, 128)
         assert bool(jnp.isfinite(logits).all())
 
-    def test_moe_capacity_floor(self):
-        from fusioninfer_tpu.models.transformer import moe_capacity
+    def test_no_moe_preset_has_a_capacity(self):
+        import inspect
 
-        assert moe_capacity(1, 8, 128) == 4  # decode-step floor
-        assert moe_capacity(1024, 8, 128, 2.0) == 128
+        from fusioninfer_tpu.models.transformer import moe_layer
+
+        assert "capacity" not in " ".join(
+            inspect.signature(moe_layer).parameters)
+        for name in list_presets():
+            cfg = get_preset(name)
+            if cfg.is_moe:
+                assert 1 <= cfg.experts_held <= cfg.n_experts
