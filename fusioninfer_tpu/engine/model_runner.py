@@ -209,16 +209,36 @@ def _dequant_gather(ctx, scale_l, pages, flat_shape):
     return ctx.astype(jnp.float32) * sc[..., None]
 
 
+def _ragged_walks(cfg, cache, mesh, use_kernel, n_tokens, page_tables,
+                  row_starts, q_begins, q_lens, kv_splits):
+    """The ragged kernel's walk lists for one forward's rows, built
+    BEFORE the layer scan: every layer scores the same rows, and what a
+    scan body computes XLA leaves inside its loop.  None where the
+    ragged kernel does not run (portable branch, latent cache) and under
+    a serving mesh, where each shard's kernel builds its own."""
+    if not use_kernel or cfg.is_mla or mesh is not None:
+        return None
+    from fusioninfer_tpu.ops.paged_attention import ragged_walk_lists
+
+    q = jax.ShapeDtypeStruct((n_tokens, cfg.n_heads, cfg.head_dim),
+                             cfg.jax_dtype)
+    return ragged_walk_lists(
+        q, cache["k"], cache["v"], page_tables, row_starts, q_begins,
+        q_lens, cache.get("k_scale"), window=cfg.sliding_window,
+        kv_splits=kv_splits)
+
+
 @jax.named_scope("attn")
 def _ragged_attn(mesh, q, cache, page_tables, row_starts, q_begins, q_lens,
                  k_scales, v_scales, *, layer, window, coalesce,
-                 kv_splits, interpret):
+                 kv_splits, interpret, walks=None):
     """The ONE ragged-kernel dispatch every model-path forward routes
     through: tp shard_map when a serving mesh is given, the flash-decode
     KV-split grid when the engine's static heuristic engaged it
     (``kv_splits > 0``, :func:`ops.paged_attention.pick_kv_splits`),
     else the single-walk grid — so no forward can reacquire a private
-    kernel-selection policy."""
+    kernel-selection policy.  ``walks``: :func:`_ragged_walks` of the
+    same rows."""
     from fusioninfer_tpu.ops import (
         ragged_paged_attention,
         ragged_paged_attention_kvsplit,
@@ -236,11 +256,12 @@ def _ragged_attn(mesh, q, cache, page_tables, row_starts, q_begins, q_lens,
         return ragged_paged_attention_kvsplit(
             q, cache["k"], cache["v"], page_tables, row_starts,
             q_begins, q_lens, k_scales, v_scales, layer=layer,
-            kv_splits=kv_splits, interpret=interpret, window=window)
+            kv_splits=kv_splits, interpret=interpret, window=window,
+            walks=walks)
     return ragged_paged_attention(
         q, cache["k"], cache["v"], page_tables, row_starts, q_begins,
         q_lens, k_scales, v_scales, layer=layer, interpret=interpret,
-        window=window, coalesce=coalesce)
+        window=window, coalesce=coalesce, walks=walks)
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",), donate_argnums=(3,))
@@ -360,6 +381,13 @@ def prefill_suffix(
     )
     write_slot = (start + offs) % ps
 
+    # the ONE ragged kernel's degenerate descriptors: a single row of
+    # true_len tokens starting mid-sequence
+    row = (page_row[None], jnp.reshape(start, (1,)).astype(jnp.int32),
+           jnp.zeros((1,), jnp.int32),
+           jnp.reshape(true_len, (1,)).astype(jnp.int32))
+    walks = _ragged_walks(cfg, cache, mesh, use_kernel, C, *row, kv_splits)
+
     # context mask over the gathered [mp * ps] positions (portable branch)
     ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T]
     attend = masks.attend(positions[0][:, None], ctx_idx,
@@ -380,17 +408,11 @@ def prefill_suffix(
         ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
 
         if use_kernel:
-            # the ONE ragged kernel, degenerate descriptors: a single
-            # row of true_len tokens starting mid-sequence
             attn = _ragged_attn(
-                mesh, q[0], cache, page_row[None],
-                jnp.reshape(start, (1,)).astype(jnp.int32),
-                jnp.zeros((1,), jnp.int32),
-                jnp.reshape(true_len, (1,)).astype(jnp.int32),
-                ks_s, vs_s, layer=l,
+                mesh, q[0], cache, *row, ks_s, vs_s, layer=l,
                 window=cfg.sliding_window, coalesce=coalesce,
                 kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(),
+                interpret=dispatch.kernel_interpret(), walks=walks,
             )[None]  # [1, C, H*Hd]
         else:
             k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
@@ -455,6 +477,12 @@ def _decode_step_impl(
     )
     write_slot = positions % ps
 
+    # the ONE ragged kernel's degenerate descriptors: B rows of one
+    # token each (q_len = active)
+    rows = (page_tables, positions, jnp.arange(B, dtype=jnp.int32),
+            active.astype(jnp.int32))
+    walks = _ragged_walks(cfg, cache, mesh, use_kernel, B, *rows, kv_splits)
+
     # attention mask over the gathered [mp * ps] context (reference path)
     ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T]
     attend = masks.attend(positions[:, None], ctx_idx,
@@ -489,16 +517,13 @@ def _decode_step_impl(
         ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
 
         if use_kernel:
-            # the ONE ragged kernel, degenerate descriptors: B rows of
-            # one token each (q_len = active) — the same kernel (and
-            # bits) the fused mixed-batch path scores decode rows with
+            # the same kernel (and bits) the fused mixed-batch path
+            # scores decode rows with
             attn = _ragged_attn(
-                mesh, q[:, 0], cache, page_tables, positions,
-                jnp.arange(B_, dtype=jnp.int32),
-                active.astype(jnp.int32), ks_s, vs_s, layer=l,
+                mesh, q[:, 0], cache, *rows, ks_s, vs_s, layer=l,
                 window=cfg.sliding_window, coalesce=coalesce,
                 kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(),
+                interpret=dispatch.kernel_interpret(), walks=walks,
             )[:, None, :]  # [B, 1, H*Hd]
         else:
             # portable path: gather pages [KV, B, mp, ps, Hd] -> [KV, B, T, Hd]
@@ -727,6 +752,13 @@ def _window_forward_impl(
     )
     write_slot = positions % ps
 
+    # the ONE ragged kernel on the flattened window rectangle: row b's
+    # segment sits at flat offset b*C with its real count — padding
+    # columns belong to no row
+    q_begins = jnp.arange(B, dtype=jnp.int32) * C
+    walks = _ragged_walks(cfg, cache, mesh, use_kernel, B * C, page_tables,
+                          starts, q_begins, counts, kv_splits)
+
     # portable-path mask over the gathered [mp * ps] context
     ctx_idx = jnp.arange(mp * ps)[None, None, :]  # [1, 1, T]
     attend = masks.attend(positions[:, :, None], ctx_idx,
@@ -747,16 +779,12 @@ def _window_forward_impl(
         ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
 
         if use_kernel:
-            # the ONE ragged kernel on the flattened window rectangle:
-            # row b's segment sits at flat offset b*C with its real
-            # count — padding columns belong to no row
             qf = q.reshape(B * C, H, Hd)
-            q_begins = jnp.arange(B, dtype=jnp.int32) * C
             attn = _ragged_attn(
                 mesh, qf, cache, page_tables, starts, q_begins, counts,
                 ks_s, vs_s, layer=l, window=cfg.sliding_window,
                 coalesce=coalesce, kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(),
+                interpret=dispatch.kernel_interpret(), walks=walks,
             ).reshape(B, C, H * Hd)
         else:
             k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
@@ -882,6 +910,9 @@ def fused_step(
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)[:, None, :]
     pos2 = positions[:, None]  # [T, 1]
 
+    walks = _ragged_walks(cfg, cache, mesh, use_kernel, T, page_tables,
+                          row_starts, q_begins, q_lens, kv_splits)
+
     # portable-path mask over each token's own gathered [mp * ps] context
     ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T_ctx]
     attend = masks.attend(positions[:, None], ctx_idx,
@@ -915,7 +946,7 @@ def fused_step(
                 mesh, q[:, 0], cache, page_tables, row_starts, q_begins,
                 q_lens, ks_s, vs_s, layer=l, window=cfg.sliding_window,
                 coalesce=coalesce, kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(),
+                interpret=dispatch.kernel_interpret(), walks=walks,
             )[:, None, :]  # [T, 1, H*Hd]
         else:
             # portable flat gather: decode_step's einsum with the flat

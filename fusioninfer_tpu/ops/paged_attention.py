@@ -2,9 +2,10 @@
 
 Each sequence's KV context lives in non-contiguous cache pages
 (:mod:`fusioninfer_tpu.engine.kv_cache`); these kernels stream exactly
-the live pages HBM→VMEM with double-buffered DMA and an online softmax
-— no materialized ``cache[page_tables]`` gather (which copies the whole
-context through HBM every step, the portable-baseline cost in
+the live pages HBM→VMEM ahead of an online softmax (the ragged grids as
+one page stream a program column, the standalone kernels two slots a
+walk) — no materialized ``cache[page_tables]`` gather (which copies the
+whole context through HBM every step, the portable-baseline cost in
 :mod:`fusioninfer_tpu.engine.model_runner`).
 
 The engine's entire model path routes through ONE of them:
@@ -128,15 +129,18 @@ _COALESCE_VMEM_SCRATCH_BUDGET = 8 * 1024 * 1024
 
 
 def coalesced_scratch_bytes(page_size: int, Hd: int, kv_heads: int,
-                            k_dtype, v_dtype, quantized: bool) -> int:
-    """Bytes of VMEM scratch the coalesced grid allocates: two slots of
-    ``[KV, ps, Hd]`` K and V page buffers (+ two f32 ``[KV, 1, ps]``
-    scale rows per slot when the cache is int8)."""
+                            k_dtype, v_dtype, quantized: bool,
+                            slots: int = 2) -> int:
+    """Bytes of VMEM scratch a coalesced grid allocates: ``slots`` slots
+    (two for the decode grid; the ragged grids pass their ring,
+    ``RAGGED_RING_SLOTS``) of ``[KV, ps, Hd]`` K and V page buffers
+    (+ two f32 ``[KV, 1, ps]`` scale rows per slot when the cache is
+    int8)."""
     per_slot = kv_heads * page_size * Hd * (
         jnp.dtype(k_dtype).itemsize + jnp.dtype(v_dtype).itemsize)
     if quantized:
         per_slot += 2 * kv_heads * page_size * jnp.dtype(jnp.float32).itemsize
-    return 2 * per_slot
+    return slots * per_slot
 
 
 def coalesce_fits_vmem(page_size: int, Hd: int, kv_heads: int,
@@ -153,49 +157,62 @@ def coalesce_fits_vmem(page_size: int, Hd: int, kv_heads: int,
 
 
 def _page_specs_scratch(page_size, Hd, k_dtype, v_dtype, quantized,
-                        heads: int | None = None):
+                        heads: int | None = None, slots: int = 2):
     """(in_specs for page operands, scratch shapes) shared by ALL the
     paged kernels — quantized adds scale operands, scale buffers, and
     two more DMA semaphores per slot.  ``heads``: the coalesced grid
-    buffers all KV heads of a page per slot (``[2, KV, ps, Hd]``);
-    per-head grids pass None (``[2, ps, Hd]``)."""
+    buffers all KV heads of a page per slot (``[slots, KV, ps, Hd]``);
+    per-head grids pass None (``[slots, ps, Hd]``).  ``slots``: 2 for
+    the double-buffered decode / suffix / verify walks; the ragged
+    grids pass their ring (``RAGGED_RING_SLOTS``)."""
     lead = () if heads is None else (heads,)
     page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quantized else 2)
     scratch = [
-        pltpu.VMEM((2, *lead, page_size, Hd), k_dtype),
-        pltpu.VMEM((2, *lead, page_size, Hd), v_dtype),
+        pltpu.VMEM((slots, *lead, page_size, Hd), k_dtype),
+        pltpu.VMEM((slots, *lead, page_size, Hd), v_dtype),
     ]
     if quantized:
         scratch += [
-            pltpu.VMEM((2, *lead, 1, page_size), jnp.float32),
-            pltpu.VMEM((2, *lead, 1, page_size), jnp.float32),
+            pltpu.VMEM((slots, *lead, 1, page_size), jnp.float32),
+            pltpu.VMEM((slots, *lead, 1, page_size), jnp.float32),
         ]
-    scratch.append(pltpu.SemaphoreType.DMA((2, 4 if quantized else 2)))
+    scratch.append(pltpu.SemaphoreType.DMA((slots, 4 if quantized else 2)))
     return page_specs, scratch
 
 
-def _scores(q, k, k_scale):
+def _scores(q, k, k_scale, sm_scale=None):
     """q·kᵀ with the int8 page scale folded in AFTER the dot
-    (q·(s·k8) == s·(q·k8)) — pages never materialize dequantized."""
+    (q·(s·k8) == s·(q·k8)) — pages never materialize dequantized.
+    ``q`` and ``k`` are 2-d (one head) or carry a leading KV axis the
+    dot batches over.  ``sm_scale`` (the ragged grids' native-dtype
+    path, :func:`_ragged_q`): ``q`` comes unscaled in the pages' own
+    16-bit dtype, the dot takes both as stored and accumulates in
+    float32, and the scale multiplies the float32 scores."""
+    if sm_scale is None and k.dtype != jnp.float32:
+        k = k.astype(jnp.float32)
+    lead = tuple(range(q.ndim - 2))
     s = jax.lax.dot_general(
-        q, k.astype(jnp.float32) if k.dtype != jnp.float32 else k,
-        (((1,), (1,)), ((), ())),
+        q, k, (((q.ndim - 1,), (k.ndim - 1,)), (lead, lead)),
         preferred_element_type=jnp.float32,
     )
+    if sm_scale is not None:
+        s = s * sm_scale
     if k_scale is not None:
-        s = s * k_scale  # [1, ps] broadcasts over rows
+        s = s * k_scale  # [(KV,) 1, ps] broadcasts over rows
     return s
 
 
 def _weighted_values(pexp, v, v_scale):
-    """pexp·v with the int8 value scale folded into the probabilities."""
+    """pexp·v with the int8 value scale folded into the probabilities;
+    2-d operands or a leading KV axis the dot batches over."""
     if v_scale is not None:
-        pexp = pexp * v_scale  # [1, ps] broadcast
+        pexp = pexp * v_scale  # [(KV,) 1, ps] broadcast
         v = v.astype(jnp.float32)
     else:
         pexp = pexp.astype(v.dtype)
+    lead = tuple(range(pexp.ndim - 2))
     return jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())),
+        pexp, v, (((pexp.ndim - 1,), (v.ndim - 2,)), (lead, lead)),
         preferred_element_type=jnp.float32,
     )
 
@@ -857,8 +874,8 @@ RAGGED_BLOCK_Q = 8
 def ragged_fits_vmem(block_q: int, page_size: int, Hd: int, kv_heads: int,
                      group: int, q_dtype, k_dtype, v_dtype,
                      quantized: bool, budget: int | None = None) -> bool:
-    """True when the coalesced ragged grid's VMEM footprint — the
-    double-buffered [2, KV, ps, Hd] page scratch PLUS the q and out
+    """True when the coalesced ragged grid's VMEM footprint — the page
+    ring [RAGGED_RING_SLOTS, KV, ps, Hd] PLUS the q and out
     tiles [block_q, KV, G, Hd] — fits the conservative budget; callers
     fall back to the per-head grid (page scratch KV× smaller, tiles
     per-head) otherwise.  Same contract as :func:`coalesce_fits_vmem`,
@@ -866,7 +883,8 @@ def ragged_fits_vmem(block_q: int, page_size: int, Hd: int, kv_heads: int,
     if budget is None:
         budget = _COALESCE_VMEM_SCRATCH_BUDGET
     pages = coalesced_scratch_bytes(page_size, Hd, kv_heads,
-                                    k_dtype, v_dtype, quantized)
+                                    k_dtype, v_dtype, quantized,
+                                    slots=RAGGED_RING_SLOTS)
     tiles = 2 * block_q * kv_heads * group * Hd * jnp.dtype(q_dtype).itemsize
     return pages + tiles <= budget
 
@@ -889,108 +907,295 @@ def _ragged_block_rows(q_begins: jax.Array, q_lens: jax.Array,
     return jnp.stack([first, n], axis=1)
 
 
-def _ragged_row(r, t0, block_q, q, row_refs, layer_ref, page_refs,
-                bufs, sem, o_ref, *, page_size, quantized, window,
-                per_head_g=None, page_lo=None, page_hi=None, partial=None):
-    """Score one row's pages against the current q tile and merge the
-    row's live token rows into ``o_ref`` — the shared body of both
-    ragged grids (``per_head_g``: a head index for the per-head grid,
-    None for the coalesced grid whose dots batch over KV).
+# -- the ragged grids' page stream -------------------------------------
+#
+# A ragged grid's work is a sequence of WALKS: one per (q tile, row of
+# the tile, page chunk of the program), each over pages ``[first, end)``
+# of its row's table, most of them short (a decode row at 800 tokens is
+# 7 pages over two chunks).  A walk that starts its own first copy and
+# waits for it pays the DMA's whole latency with nothing to score, and
+# on one TensorCore nothing else hides it.  So the walks of a COLUMN —
+# the programs that share the leading grid index (a KV split, a head)
+# and run their tiles in order — are one page stream:
+#
+# * The wrapper lists each column's NON-EMPTY walks in program order
+#   (:func:`_ragged_walks`, scalar-prefetched): the scorer loops over its
+#   tile's slice of the list, the fetch cursor runs down the same list,
+#   so both sides see one sequence and the kernel enumerates nothing.
+# * The cursor runs ``slots - 1`` pages ahead of the scorer THROUGH walk,
+#   row, tile and program boundaries: ring, DMA semaphores and cursor
+#   live in scratch, which persists across the sequential steps of the
+#   tile axis.  The column's first program primes it; the leading axis
+#   stays ``"parallel"`` and the stream never crosses it.  The only cold
+#   wait of a column is its first page.
+# * The k-th copy started is the k-th waited for, in slot ``k % slots``;
+#   every copy started is waited exactly once by the column's last tile.
+#   Only pages a listed walk will score are fetched: nothing past a row's
+#   live pages, nothing for inert rows or empty chunks.
+# * Arithmetic is untouched: a walk scores the same pages in the same
+#   order with fresh accumulators; which slot held a page decides no bit.
+
+# ring slots of the stream: the scorer waits for a page with up to
+# ``RAGGED_RING_SLOTS - 1`` later pages in flight.  A slot is one page of
+# K and V for the program's heads (512 KiB at 8 KV heads x 128 x 128
+# bfloat16); the *_fits_vmem guards count the ring.
+RAGGED_RING_SLOTS = 3
+
+# words of a column's stream state (SMEM scratch): copies started, pages
+# taken by the scorer, and the fetch cursor (its walk, its next page)
+_STARTED, _TAKEN, _F_WALK, _F_PAGE = range(4)
+_STREAM_STATE = pltpu.SMEM((4,), jnp.int32)
+
+
+def _div(a, b: int):
+    """``a // b`` for non-negative ``a``: one truncating division, where
+    ``//`` traces a sign-correcting dozen operations."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _ragged_walks(q_begins, q_lens, row_starts, *, nb: int, block_q: int,
+                  page_size: int, window, n_cols: int = 1, cpp: int = 1,
+                  chunk_pages: int | None = None):
+    """Each column's non-empty walks in program order, as flat int32
+    arrays for scalar prefetch: ``tile_walks`` [n_cols * (nb + 1)] —
+    column ``s``'s walks of tile ``t`` are entries ``[tile_walks[s *
+    (nb + 1) + t], tile_walks[s * (nb + 1) + t + 1])`` of its list — and
+    the lists ``w_row`` (``row * cpp + chunk slot``), ``w_first``,
+    ``w_end`` [n_cols * W], ``W = (nb + R) * cpp`` entries a column.
+
+    A walk is (tile, row of the tile, chunk of the column): column ``s``
+    owns page chunks ``[s * cpp, (s + 1) * cpp)`` of ``chunk_pages``
+    pages each (None: one unclipped chunk).  Entries past a column's
+    count are zeros and never read as walks.  Compare, select and sum
+    over small index grids only: nothing here gathers, sorts or loops."""
+    R = q_begins.shape[0]
+    # (tile, row) pairs in program order.  Rows lie in flat order, so
+    # tile-major order is row-major order: row r's pairs are the
+    # n_tiles[r] entries after those of the rows before it; consecutive
+    # rows share at most one tile, so nb + R bounds their number
+    P = nb + R
+    t_first = _div(q_begins, block_q)
+    n_tiles = jnp.where(
+        q_lens > 0,
+        _div(jnp.maximum(q_begins + q_lens - 1, 0), block_q) - t_first + 1,
+        0)
+    after = jax.lax.cumsum(n_tiles, axis=0)
+    p = jax.lax.iota(jnp.int32, P)[:, None]
+    mine = (p >= (after - n_tiles)[None, :]) & (p < after[None, :])  # [P, R]
+    qb, ql, st, r, t = jnp.sum(jnp.where(mine[None], jnp.stack([
+        q_begins, q_lens, row_starts, jax.lax.iota(jnp.int32, R),
+        t_first - (after - n_tiles)])[:, None, :], 0), axis=2)
+    t = t + p[:, 0]  # a pair past the last has no row: q_len 0, no pages
+    # the pages the row's tokens inside the tile attend: the causal page
+    # span, cut below by the sliding window
+    lo = jnp.maximum(qb, t * block_q)
+    hi = jnp.minimum(qb + ql, (t + 1) * block_q)
+    end = jnp.where(hi > lo, _div(st + hi - qb + (page_size - 1), page_size),
+                    0)[:, None]
+    first = (jnp.zeros_like(end) if window is None else _div(
+        jnp.maximum(st + lo - qb - (window - 1), 0), page_size)[:, None])
+    if chunk_pages is not None:
+        lo = jax.lax.iota(jnp.int32, n_cols * cpp) * chunk_pages
+        first = jnp.maximum(first, lo)
+        end = jnp.minimum(end, lo + chunk_pages)
+    W = P * cpp
+
+    def by_column(x):  # [P, n_cols * cpp] -> [n_cols, W]
+        return jnp.swapaxes(jnp.broadcast_to(
+            x, (P, n_cols * cpp)).reshape(P, n_cols, cpp), 0, 1).reshape(
+                n_cols, W)
+
+    first, end, tile = by_column(first), by_column(end), by_column(t[:, None])
+    row_slot = by_column(
+        r[:, None] * cpp
+        + jax.lax.rem(jax.lax.iota(jnp.int32, n_cols * cpp), jnp.int32(cpp)))
+    some = end > first  # [n_cols, W]
+    # the k-th entry of a column's list is its k-th non-empty walk
+    nth = jax.lax.cumsum(some.astype(jnp.int32), axis=1)
+    k = jax.lax.iota(jnp.int32, W)[:, None]
+    pick = some[:, None, :] & (nth[:, None, :] == k + 1)  # [n_cols, W, W]
+    w_row, w_first, w_end = jnp.sum(jnp.where(
+        pick[None], jnp.stack([row_slot, first, end])[:, :, None, :], 0),
+        axis=3)
+    tiles = jax.lax.iota(jnp.int32, nb + 1)[:, None]
+    tile_walks = jnp.sum(
+        some[:, None, :] & (tile[:, None, :] < tiles), axis=2,
+        dtype=jnp.int32)  # [n_cols, nb + 1]
+    return (tile_walks.reshape(-1), w_row.reshape(-1), w_first.reshape(-1),
+            w_end.reshape(-1))
+
+
+class _PageStream:
+    """One column's page stream (comment above): trace-time glue over the
+    column's walk list, the ring and the SMEM cursor, shared by the three
+    ragged kernels.  ``col`` of ``n_cols``: which list of the walk arrays
+    is the column's (0 of 1 where every column shares one); ``heads``: a
+    head index for the per-head grid, ``slice(None)`` for the coalesced
+    grids; ``cpp``: chunk slots a row has in this column."""
+
+    def __init__(self, state, tile_walks_ref, walk_refs, col, n_cols,
+                 table_ref, layer_ref, page_refs, bufs, sem, heads, cpp):
+        self.state = state
+        self.tile_walks_ref = tile_walks_ref
+        self.w_row, self.w_first, self.w_end = walk_refs
+        tiles1 = tile_walks_ref.shape[0] // n_cols  # tiles + 1
+        self.per_col = self.w_row.shape[0] // n_cols
+        self.tiles0 = col * tiles1
+        self.walks0 = col * self.per_col
+        self.n_walks = tile_walks_ref[self.tiles0 + tiles1 - 1]
+        self.table_ref = table_ref
+        self.layer_ref = layer_ref
+        self.page_refs = page_refs
+        self.bufs = bufs
+        self.sem = sem
+        self.heads = heads
+        self.cpp = cpp
+        self.slots = bufs[0].shape[0]
+
+    def tile(self, t):
+        """The walks ``[lo, hi)`` of tile ``t`` in the column's list."""
+        return (self.tile_walks_ref[self.tiles0 + t],
+                self.tile_walks_ref[self.tiles0 + t + 1])
+
+    def row_slot(self, w):
+        """``(row, chunk slot)`` of walk ``w``."""
+        rs = self.w_row[self.walks0 + w]
+        if self.cpp == 1:
+            return rs, 0
+        return _div(rs, self.cpp), jax.lax.rem(rs, self.cpp)
+
+    def dma(self, slot, r, p):
+        """The copies of page ``p`` of row ``r`` into ring slot ``slot``."""
+        k_pages_ref, v_pages_ref, scale_refs = self.page_refs
+        k_buf, v_buf, scale_bufs = self.bufs
+        return _page_dma(slot, self.layer_ref[0], self.heads,
+                         self.table_ref[r, p], k_pages_ref, v_pages_ref,
+                         k_buf, v_buf, self.sem, scale_refs, scale_bufs)
+
+    def fetch(self):
+        """Start the copies of the cursor's page, if the column has one
+        left, and move the cursor one page on."""
+        st = self.state
+        w = st[_F_WALK]
+
+        @pl.when(w < self.n_walks)
+        def _start():
+            p, k = st[_F_PAGE], st[_STARTED]
+            for cp in self.dma(jax.lax.rem(k, self.slots),
+                               self.row_slot(w)[0], p):
+                cp.start()
+            st[_STARTED] = k + 1
+            last = p + 1 >= self.w_end[self.walks0 + w]
+            nxt = jnp.minimum(w + 1, self.per_col - 1)
+            st[_F_WALK] = jax.lax.select(last, w + 1, w)
+            st[_F_PAGE] = jax.lax.select(
+                last, self.w_first[self.walks0 + nxt], p + 1)
+
+    def prime(self):
+        """The column's first program: ``slots - 1`` pages in flight;
+        every later page is fetched by the scorer, one per page taken."""
+        st = self.state
+        st[_STARTED] = 0
+        st[_TAKEN] = 0
+        st[_F_WALK] = 0
+        st[_F_PAGE] = self.w_first[self.walks0]
+        jax.lax.fori_loop(0, self.slots - 1,
+                          lambda _, c: (self.fetch(), c)[1], 0)
+
+    def take(self, r, p):
+        """The scorer's side, once per page of a walk in order: keep the
+        stream ``slots - 1`` ahead, then name the slot that holds page
+        ``p`` of row ``r`` and its copies to wait on."""
+        self.fetch()
+        k = self.state[_TAKEN]
+        self.state[_TAKEN] = k + 1
+        slot = jax.lax.rem(k, self.slots)
+        return slot, self.dma(slot, r, p)
+
+
+def _native_scores(q_dtype, k_dtype) -> bool:
+    """True when the score dot takes its operands as stored: the query
+    and the K pages share a 16-bit float dtype.  Products of two such
+    values are exact in float32 and the MXU accumulates in float32, so
+    it is the float32 path's mathematics with one rounding fewer (``q *
+    sm_scale`` is never rounded) at a fraction of its MXU passes.
+    float32 pages keep the float32 path bit for bit; int8 pages keep
+    theirs (their dtype differs from the query's)."""
+    return (jnp.dtype(q_dtype) == jnp.dtype(k_dtype)
+            and jnp.issubdtype(q_dtype, jnp.floating)
+            and jnp.dtype(q_dtype).itemsize == 2)
+
+
+def _ragged_q(q_ref, k_dtype, sm_scale, shape):
+    """The q tile as the score dot takes it, ``shape``d for the grid:
+    ``(q, None)`` pre-scaled in float32, or ``(q, sm_scale)`` as stored
+    where :func:`_native_scores` holds (the scale then multiplies the
+    float32 scores)."""
+    if _native_scores(q_ref.dtype, k_dtype):
+        return shape(q_ref[...]), sm_scale
+    return shape(q_ref[...].astype(jnp.float32) * sm_scale), None
+
+
+def _ragged_walk(stream, w, t0, q, o_ref, *, block_q, page_size, quantized,
+                 window, row_refs, sm_scale=None, partial=None):
+    """Score walk ``w`` of the column's list against the current q tile
+    and merge the row's live token rows into ``o_ref`` — the shared body
+    of the three ragged grids (per-head: ``q`` is [R, Hd] and the dots
+    are one head's; coalesced: ``q`` is [KV, R, Hd] and they batch over
+    KV).
 
     Per-token bit-identity across tile compositions is load-bearing
     (split and fused engine dispatches pack the same row at different
     flat offsets): each token row's accumulators are fresh per
-    (tile, row), fully-masked pages contribute exactly 0 (``exp``
+    (tile, row, chunk), fully-masked pages contribute exactly 0 (``exp``
     underflows to +0.0 and the first real page's ``alpha`` is exactly
     0.0), and every dot/reduction is row-wise — so a token's output
-    bits depend only on its row's content, never on tile neighbors.
+    bits depend only on its row's content, never on tile neighbors, nor
+    on which ring slot the stream brought a page in.  A walk with no
+    page is not in the list: scoring none would merge exactly what the
+    program initialised its tokens to.
 
-    ``page_lo``/``page_hi`` restrict the walk to a virtual-chunk page
-    window and ``partial=(slot, m_ref, l_ref, acc_ref)`` redirects the
-    epilogue to emit the walk's raw ``(m, l, unnormalized acc)`` at
-    chunk ``slot`` instead of the normalized output — the KV-split
-    grid's flash-decode partials (coalesced layout only).  With the
-    defaults the traced operations are exactly the single-walk path's."""
-    page_tables_ref, row_starts_ref, q_begins_ref, q_lens_ref = row_refs
-    k_pages_ref, v_pages_ref, scale_refs = page_refs
-    k_buf, v_buf, scale_bufs = bufs
+    ``sm_scale``: set when ``q`` is unscaled in the pages' own dtype
+    (:func:`_ragged_q`).  ``partial=(m_ref, l_ref, acc_ref)`` redirects
+    the epilogue to emit the walk's raw ``(m, l, unnormalized acc)`` at
+    the walk's chunk slot instead of the normalized output — the
+    KV-split grid's flash-decode partials (coalesced layout only)."""
+    row_starts_ref, q_begins_ref, q_lens_ref = row_refs
+    k_buf, v_buf, scale_bufs = stream.bufs
     ks_buf, vs_buf = scale_bufs if quantized else (None, None)
+    per_head = q.ndim == 2
+    r, c = stream.row_slot(w)
+    first = stream.w_first[stream.walks0 + w]
+    end = stream.w_end[stream.walks0 + w]
     qb = q_begins_ref[r]
     ql = q_lens_ref[r]
     st = row_starts_ref[r]
-    if partial is None:
-        G = o_ref.shape[2]
-        Hd = o_ref.shape[3]
-    else:
-        G = partial[3].shape[3]
-        Hd = partial[3].shape[4]
+    G, Hd = (o_ref if partial is None else partial[2]).shape[-2:]
     R = block_q * G
     # flat token id of each of the R q rows (G head rows per token)
-    tok = t0 + jax.lax.broadcasted_iota(jnp.int32, (R, page_size), 0) // G
+    tok = t0 + _div(
+        jax.lax.broadcasted_iota(jnp.int32, (R, page_size), 0), G)
     live = (tok >= qb) & (tok < qb + ql)  # [R, ps]
     pos = st + tok - qb
-    lo = jnp.maximum(qb, t0)
-    hi = jnp.minimum(qb + ql, t0 + block_q)
-    # causal page span of the row's tokens inside THIS tile
-    n_used = jnp.where(hi > lo, pl.cdiv(st + hi - qb, page_size), 0)
-    first = (jnp.maximum(st + lo - qb - (window - 1), 0) // page_size
-             if window is not None else 0)
-    if page_lo is not None:
-        first = jnp.maximum(first, page_lo)
-        n_used = jnp.minimum(n_used, page_hi)
-    g = slice(None) if per_head_g is None else per_head_g
-
-    def dma(slot, p):
-        return _page_dma(slot, layer_ref[0], g, page_tables_ref[r, p],
-                         k_pages_ref, v_pages_ref, k_buf, v_buf, sem,
-                         scale_refs, scale_bufs)
-
-    # `n_used > first` (not `> 0`): a KV-split chunk window can sit
-    # entirely past the row's live pages, and page `first` would then
-    # index beyond the row's table
-    @pl.when(n_used > first)
-    def _start_first():
-        for c in dma(first % 2, first):
-            c.start()
 
     def body(p, carry):
         m, l, acc = carry
-        slot = p % 2
-
-        @pl.when(p + 1 < n_used)
-        def _prefetch_next():
-            for c in dma((p + 1) % 2, p + 1):
-                c.start()
-
-        copies = dma(slot, p)
+        slot, copies = stream.take(r, p)
         # split waits (VERDICT #8): K (+ its scale row) lands first and
         # the score matmul + online-softmax update run while V's copy is
-        # still in flight — including on the FINAL page, where waiting
-        # for both copies up front serialized the whole epilogue behind
-        # the last DMA
+        # still in flight
         copies[0].wait()
         if quantized:
             copies[2].wait()
-        k = k_buf[slot]
-        if k.dtype != jnp.float32:
-            k = k.astype(jnp.float32)
-        if per_head_g is None:
-            # ONE batched dot over all KV heads ([KV, R, Hd] x
-            # [KV, ps, Hd] -> [KV, R, ps]) instead of the coalesced
-            # decode kernel's KV tiny per-head dots (VERDICT #8)
-            s = jax.lax.dot_general(
-                q, k, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            if quantized:
-                s = s * ks_buf[slot]  # [KV, 1, ps] broadcasts over R
-        else:
-            s = _scores(q, k_buf[slot],
-                        ks_buf[slot] if quantized else None)  # [R, ps]
+        # coalesced: ONE batched dot over all KV heads ([KV, R, Hd] x
+        # [KV, ps, Hd] -> [KV, R, ps]) instead of KV tiny per-head dots
+        # (VERDICT #8); per-head: [R, Hd] x [ps, Hd] -> [R, ps]
+        s = _scores(q, k_buf[slot], ks_buf[slot] if quantized else None,
+                    sm_scale)
         ctx = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (R, page_size), 1)
         keep = live & attend(pos, ctx, window)
-        s = jnp.where(keep if per_head_g is not None else keep[None],
-                      s, NEG_INF)
+        s = jnp.where(keep if per_head else keep[None], s, NEG_INF)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m, m_cur)
         pexp = jnp.exp(s - m_new)
@@ -999,32 +1204,21 @@ def _ragged_row(r, t0, block_q, q, row_refs, layer_ref, page_refs,
         copies[1].wait()
         if quantized:
             copies[3].wait()
-        if per_head_g is None:
-            v = v_buf[slot]
-            if quantized:
-                pexp = pexp * vs_buf[slot]
-                v = v.astype(jnp.float32)
-            else:
-                pexp = pexp.astype(v.dtype)
-            pv = jax.lax.dot_general(
-                pexp, v, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)  # [KV, R, Hd]
-        else:
-            pv = _weighted_values(pexp, v_buf[slot],
-                                  vs_buf[slot] if quantized else None)
+        pv = _weighted_values(pexp, v_buf[slot],
+                              vs_buf[slot] if quantized else None)
         return m_new, l_new, acc * alpha + pv
 
-    lead = () if per_head_g is not None else (q.shape[0],)
+    lead = q.shape[:-2]
     m0 = jnp.full((*lead, R, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((*lead, R, 1), jnp.float32)
     a0 = jnp.zeros((*lead, R, Hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first, n_used, body, (m0, l0, a0))
+    m, l, acc = jax.lax.fori_loop(first, end, body, (m0, l0, a0))
     lt = live[:, 0].reshape(block_q, G)[:, :1]  # [bq, 1] token liveness
     if partial is not None:
         # KV-split partials: the walk's raw (m, l, unnormalized acc) at
         # chunk slot `c` — normalization happens after the cross-chunk
         # log-sum-exp combine in the wrapper (coalesced layout only)
-        c, m_ref, l_ref, acc_ref = partial
+        m_ref, l_ref, acc_ref = partial
         KV = q.shape[0]
         accw = jnp.moveaxis(acc.reshape(KV, block_q, G, Hd), 0, 1)
         mw = jnp.moveaxis(m.reshape(KV, block_q, G), 0, 1)  # [bq, KV, G]
@@ -1034,13 +1228,26 @@ def _ragged_row(r, t0, block_q, q, row_refs, layer_ref, page_refs,
         l_ref[c] = jnp.where(lt[:, :, None], lw, l_ref[c])
         return
     out = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-    if per_head_g is None:
+    if per_head:
+        out = out.reshape(block_q, G, Hd)
+        o_ref[:, 0] = jnp.where(lt[:, :, None], out, o_ref[:, 0])
+    else:
         KV = q.shape[0]
         out = jnp.moveaxis(out.reshape(KV, block_q, G, Hd), 0, 1)
         o_ref[...] = jnp.where(lt[:, None, :, None], out, o_ref[...])
-    else:
-        out = out.reshape(block_q, G, Hd)
-        o_ref[:, 0] = jnp.where(lt[:, :, None], out, o_ref[:, 0])
+
+
+def _ragged_tile(stream, t, t0, q, o_ref, **walk_kw):
+    """One program of a column: prime the stream at the column's first
+    tile, then score the tile's walks in list order."""
+    pl.when(t == 0)(stream.prime)
+    lo, hi = stream.tile(t)
+
+    def walk_body(w, carry):
+        _ragged_walk(stream, w, t0, q, o_ref, **walk_kw)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, walk_body, 0)
 
 
 def _ragged_kernel_coalesced(
@@ -1049,7 +1256,10 @@ def _ragged_kernel_coalesced(
     row_starts_ref,  # [R] int32 — global position of each row's token 0
     q_begins_ref,  # [R] int32 — flat offset of each row's segment
     q_lens_ref,  # [R] int32 — row token count (0 = inert row)
-    block_rows_ref,  # [nb, 2] int32 — (first_row, n_rows) per q tile
+    tile_walks_ref,  # [nb + 1] int32 — _ragged_walks: one column
+    w_row_ref,  # [W] int32
+    w_first_ref,  # [W] int32
+    w_end_ref,  # [W] int32
     layer_ref,  # [1] int32
     # inputs: q_ref [block_q, KV, G, Hd] VMEM tile of the flat axis
     q_ref,
@@ -1064,25 +1274,26 @@ def _ragged_kernel_coalesced(
 ):
     """Ragged grid ``(nb,)``: one program per flat q tile covers every
     KV head (one ``[KV, ps, Hd]`` DMA per page, batched score/value
-    dots), looping over the rows whose segments intersect the tile."""
+    dots), scoring the tile's walks; one page stream runs through all
+    the tiles."""
+    *rest, state = rest
     scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
         rest, quantized)
     t = pl.program_id(0)
-    first_row, n_rows = block_rows_ref[t, 0], block_rows_ref[t, 1]
     KV, G, Hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    q = jnp.moveaxis(q_ref[...].astype(jnp.float32) * sm_scale,
-                     1, 0).reshape(KV, block_q * G, Hd)
+    q, score_scale = _ragged_q(
+        q_ref, k_pages_ref.dtype, sm_scale,
+        lambda x: jnp.moveaxis(x, 1, 0).reshape(KV, block_q * G, Hd))
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-    row_refs = (page_tables_ref, row_starts_ref, q_begins_ref, q_lens_ref)
-
-    def row_body(j, _):
-        _ragged_row(first_row + j, t * block_q, block_q, q, row_refs,
-                    layer_ref, (k_pages_ref, v_pages_ref, scale_refs),
-                    (k_buf, v_buf, scale_bufs), sem, o_ref,
-                    page_size=page_size, quantized=quantized, window=window)
-        return _
-
-    jax.lax.fori_loop(0, n_rows, row_body, 0)
+    stream = _PageStream(
+        state, tile_walks_ref, (w_row_ref, w_first_ref, w_end_ref), 0, 1,
+        page_tables_ref, layer_ref,
+        (k_pages_ref, v_pages_ref, scale_refs), (k_buf, v_buf, scale_bufs),
+        sem, slice(None), 1)
+    _ragged_tile(stream, t, t * block_q, q, o_ref, block_q=block_q,
+                 page_size=page_size, quantized=quantized, window=window,
+                 row_refs=(row_starts_ref, q_begins_ref, q_lens_ref),
+                 sm_scale=score_scale)
 
 
 def _ragged_kernel(
@@ -1091,7 +1302,10 @@ def _ragged_kernel(
     row_starts_ref,
     q_begins_ref,
     q_lens_ref,
-    block_rows_ref,
+    tile_walks_ref,
+    w_row_ref,
+    w_first_ref,
+    w_end_ref,
     layer_ref,
     # inputs: q_ref [block_q, 1, G, Hd] VMEM tile
     q_ref,
@@ -1104,27 +1318,29 @@ def _ragged_kernel(
     quantized: bool,
     window: int | None,
 ):
-    """Ragged grid ``(nb, KV)``: the VMEM-guard escape hatch — per-head
-    ``[ps, Hd]`` page copies and per-head dots, KV× smaller scratch."""
+    """Ragged grid ``(KV, nb)``: the VMEM-guard escape hatch — per-head
+    ``[ps, Hd]`` page copies and per-head dots, KV× smaller scratch; a
+    head's tiles run in order and share one page stream over the one
+    walk list every head has."""
+    *rest, state = rest
     scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
         rest, quantized)
-    t = pl.program_id(0)
-    g = pl.program_id(1)
-    first_row, n_rows = block_rows_ref[t, 0], block_rows_ref[t, 1]
+    g = pl.program_id(0)
+    t = pl.program_id(1)
     G, Hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[:, 0].astype(jnp.float32).reshape(block_q * G, Hd) * sm_scale
+    q, score_scale = _ragged_q(
+        q_ref, k_pages_ref.dtype, sm_scale,
+        lambda x: x[:, 0].reshape(block_q * G, Hd))
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-    row_refs = (page_tables_ref, row_starts_ref, q_begins_ref, q_lens_ref)
-
-    def row_body(j, _):
-        _ragged_row(first_row + j, t * block_q, block_q, q, row_refs,
-                    layer_ref, (k_pages_ref, v_pages_ref, scale_refs),
-                    (k_buf, v_buf, scale_bufs), sem, o_ref,
-                    page_size=page_size, quantized=quantized, window=window,
-                    per_head_g=g)
-        return _
-
-    jax.lax.fori_loop(0, n_rows, row_body, 0)
+    stream = _PageStream(
+        state, tile_walks_ref, (w_row_ref, w_first_ref, w_end_ref), 0, 1,
+        page_tables_ref, layer_ref,
+        (k_pages_ref, v_pages_ref, scale_refs), (k_buf, v_buf, scale_bufs),
+        sem, g, 1)
+    _ragged_tile(stream, t, t * block_q, q, o_ref, block_q=block_q,
+                 page_size=page_size, quantized=quantized, window=window,
+                 row_refs=(row_starts_ref, q_begins_ref, q_lens_ref),
+                 sm_scale=score_scale)
 
 
 @functools.partial(
@@ -1148,6 +1364,7 @@ def ragged_paged_attention(
     block_q: int = RAGGED_BLOCK_Q,
     coalesce: bool | None = None,
     layer: jax.Array | int | None = None,
+    walks=None,
 ) -> jax.Array:
     """The one true ragged paged-attention kernel → [T, H·Hd].
 
@@ -1171,8 +1388,9 @@ def ragged_paged_attention(
     VMEM guard (:func:`ragged_fits_vmem`) demotes oversized
     configurations automatically.  Per-token output
     bits are independent of tile composition and flat offset (see
-    ``_ragged_row``), so split and fused engine dispatches scoring the
-    same row are bit-identical.
+    ``_ragged_walk``), so split and fused engine dispatches scoring the
+    same row are bit-identical.  ``walks``: the rows' walk lists where
+    the caller built them once for many calls (:func:`ragged_walk_lists`).
     """
     T, H, Hd = q.shape
     k_pages, v_pages, k_scales, v_scales, layer_arr = _as_stacked(
@@ -1195,15 +1413,18 @@ def ragged_paged_attention(
         q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
     nb = Tp // block_q
     qg = q.reshape(Tp, KV, G, Hd)
-    block_rows = _ragged_block_rows(q_begins.astype(jnp.int32),
-                                    q_lens.astype(jnp.int32), nb, block_q)
+    if walks is None:
+        walks = ragged_walk_lists(
+            q, k_pages, v_pages, page_tables, row_starts, q_begins, q_lens,
+            k_scales, window=window, block_q=block_q)
+    walks = _check_walks(walks, 1, nb)
 
     if coalesce:
         page_specs, scratch = _page_specs_scratch(
             page_size, Hd, k_pages.dtype, v_pages.dtype, quantized,
-            heads=KV)
+            heads=KV, slots=RAGGED_RING_SLOTS)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
+            num_scalar_prefetch=9,
             grid=(nb,),
             in_specs=[
                 pl.BlockSpec(
@@ -1216,29 +1437,34 @@ def ragged_paged_attention(
                 (block_q, KV, G, Hd), lambda t, *_: (t, 0, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            scratch_shapes=scratch,
+            scratch_shapes=[*scratch, _STREAM_STATE],
         )
         body = _ragged_kernel_coalesced
+        semantics = ("arbitrary",)
     else:
+        # heads lead, tiles run innermost: a head's tiles are sequential
+        # grid steps, which is what carries its page stream across them
         page_specs, scratch = _page_specs_scratch(
-            page_size, Hd, k_pages.dtype, v_pages.dtype, quantized)
+            page_size, Hd, k_pages.dtype, v_pages.dtype, quantized,
+            slots=RAGGED_RING_SLOTS)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6,
-            grid=(nb, KV),
+            num_scalar_prefetch=9,
+            grid=(KV, nb),
             in_specs=[
                 pl.BlockSpec(
-                    (block_q, 1, G, Hd), lambda t, g, *_: (t, g, 0, 0),
+                    (block_q, 1, G, Hd), lambda g, t, *_: (t, g, 0, 0),
                     memory_space=pltpu.VMEM,
                 ),
                 *page_specs,
             ],
             out_specs=pl.BlockSpec(
-                (block_q, 1, G, Hd), lambda t, g, *_: (t, g, 0, 0),
+                (block_q, 1, G, Hd), lambda g, t, *_: (t, g, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            scratch_shapes=scratch,
+            scratch_shapes=[*scratch, _STREAM_STATE],
         )
         body = _ragged_kernel
+        semantics = ("parallel", "arbitrary")
     kernel = functools.partial(
         body,
         block_q=block_q, page_size=page_size, sm_scale=sm_scale,
@@ -1246,14 +1472,17 @@ def ragged_paged_attention(
     )
     operands = [page_tables.astype(jnp.int32), row_starts.astype(jnp.int32),
                 q_begins.astype(jnp.int32), q_lens.astype(jnp.int32),
-                block_rows, layer_arr, qg, k_pages, v_pages]
+                *walks, layer_arr, qg, k_pages, v_pages]
     if quantized:
         operands += [k_scales, v_scales]
+    # the tile axis carries the page stream from step to step: it runs
+    # in order on one core
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Tp, KV, G, Hd), q.dtype),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
     )(*operands)
     return out.reshape(Tp, H * Hd)[:T]
 
@@ -1317,13 +1546,14 @@ def kvsplit_fits_vmem(block_q: int, page_size: int, Hd: int, kv_heads: int,
                       quantized: bool, kv_splits: int,
                       budget: int | None = None) -> bool:
     """True when one KV-split program's VMEM footprint — the coalesced
-    page scratch, the q tile, and its ``chunks_per_program`` f32 partial
+    page ring, the q tile, and its ``chunks_per_program`` f32 partial
     blocks (acc + m + l) — fits the conservative budget; the wrapper
     demotes to the single-walk grid otherwise."""
     if budget is None:
         budget = _COALESCE_VMEM_SCRATCH_BUDGET
     pages = coalesced_scratch_bytes(page_size, Hd, kv_heads,
-                                    k_dtype, v_dtype, quantized)
+                                    k_dtype, v_dtype, quantized,
+                                    slots=RAGGED_RING_SLOTS)
     q_tile = block_q * kv_heads * group * Hd * jnp.dtype(q_dtype).itemsize
     cpp = KV_SPLIT_CHUNKS // max(1, kv_splits)
     partials = cpp * block_q * kv_heads * group * (Hd + 2) * 4
@@ -1357,13 +1587,51 @@ def resolve_ragged_grid(page_size: int, Hd: int, kv_heads: int, group: int,
     return "per-head", 0
 
 
+def ragged_walk_lists(q, k_pages, v_pages, page_tables, row_starts,
+                      q_begins, q_lens, k_scales=None, *, window=None,
+                      kv_splits: int = 0, block_q: int = RAGGED_BLOCK_Q):
+    """The walk lists (:func:`_ragged_walks`) a ragged dispatch of these
+    rows prefetches, for the grid the same operands resolve to
+    (``kv_splits`` as requested: 0 for :func:`ragged_paged_attention`).
+    Only shapes and dtypes of ``q`` [T, H, Hd] and of the pages are read.
+    A caller that scores the same rows many times — every layer of a
+    scan — builds them ONCE and passes ``walks=``: what a scan body
+    computes stays inside XLA's loop, layer after layer."""
+    T, H, Hd = q.shape
+    KV, page_size = k_pages.shape[-4], k_pages.shape[-2]
+    S = 0
+    if kv_splits > 0:
+        S = resolve_ragged_grid(
+            page_size, Hd, KV, H // KV, q.dtype, k_pages.dtype,
+            v_pages.dtype, k_scales is not None, coalesce=True,
+            kv_splits=kv_splits, block_q=block_q)[1]
+    i32 = jnp.int32
+    return _ragged_walks(
+        q_begins.astype(i32), q_lens.astype(i32), row_starts.astype(i32),
+        nb=-(-T // block_q), block_q=block_q, page_size=page_size,
+        window=window, n_cols=max(S, 1),
+        cpp=KV_SPLIT_CHUNKS // S if S else 1,
+        chunk_pages=-(-page_tables.shape[1] // KV_SPLIT_CHUNKS) if S else None)
+
+
+def _check_walks(walks, n_cols: int, nb: int):
+    if walks[0].shape != (n_cols * (nb + 1),):
+        raise ValueError(
+            f"walks= were built for another grid: {walks[0].shape[0]} tile "
+            f"offsets, this dispatch has {n_cols} x ({nb} + 1)")
+    return tuple(walks)
+
+
 def _ragged_kernel_kvsplit(
-    # scalar prefetch (the single-walk ragged layout)
+    # scalar prefetch (the single-walk ragged layout, a walk list a split)
     page_tables_ref,  # [R, mp] int32 (SMEM)
     row_starts_ref,  # [R] int32
     q_begins_ref,  # [R] int32
     q_lens_ref,  # [R] int32
-    block_rows_ref,  # [nb, 2] int32
+    tile_walks_ref,  # [S * (nb + 1)] int32 — _ragged_walks
+    w_row_ref,  # [S * W] int32
+    w_first_ref,  # [S * W] int32
+    w_end_ref,  # [S * W] int32
     layer_ref,  # [1] int32
     # inputs: q_ref [block_q, KV, G, Hd] VMEM tile of the flat axis
     q_ref,
@@ -1375,46 +1643,40 @@ def _ragged_kernel_kvsplit(
     sm_scale: float,
     quantized: bool,
     window: int | None,
-    chunk_pages: int,
+    n_splits: int,
     chunks_per_prog: int,
 ):
-    """KV-split grid ``(S, nb)``: program ``(s, t)`` walks its
-    ``chunks_per_prog`` virtual page-chunks for every row intersecting
-    tile ``t`` and emits per-chunk ``(m, l, acc)`` partials — the same
-    coalesced page streaming and per-page math as the single walk,
-    restricted to each chunk's page window with fresh accumulators."""
+    """KV-split grid ``(S, nb)``: program ``(s, t)`` scores split ``s``'s
+    walks of tile ``t`` — each a row's pages inside one of the split's
+    ``chunks_per_prog`` virtual page-chunks — and emits per-chunk ``(m,
+    l, acc)`` partials: the same coalesced page streaming and per-page
+    math as the single walk, restricted to each chunk's page window with
+    fresh accumulators.  A split's tiles share one page stream."""
     if quantized:
         (ks_ref, vs_ref, acc_ref, m_ref, l_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sem) = rest
+         k_buf, v_buf, ks_buf, vs_buf, sem, state) = rest
         scale_refs, scale_bufs = (ks_ref, vs_ref), (ks_buf, vs_buf)
     else:
-        acc_ref, m_ref, l_ref, k_buf, v_buf, sem = rest
+        acc_ref, m_ref, l_ref, k_buf, v_buf, sem, state = rest
         scale_refs = scale_bufs = None
     s = pl.program_id(0)
     t = pl.program_id(1)
-    first_row, n_rows = block_rows_ref[t, 0], block_rows_ref[t, 1]
     KV, G, Hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    q = jnp.moveaxis(q_ref[...].astype(jnp.float32) * sm_scale,
-                     1, 0).reshape(KV, block_q * G, Hd)
+    q, score_scale = _ragged_q(
+        q_ref, k_pages_ref.dtype, sm_scale,
+        lambda x: jnp.moveaxis(x, 1, 0).reshape(KV, block_q * G, Hd))
     acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
     m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, m_ref.dtype)
     l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
-    row_refs = (page_tables_ref, row_starts_ref, q_begins_ref, q_lens_ref)
-
-    def row_body(j, _):
-        for c in range(chunks_per_prog):  # static unroll: ref slots
-            chunk = s * chunks_per_prog + c
-            _ragged_row(first_row + j, t * block_q, block_q, q, row_refs,
-                        layer_ref, (k_pages_ref, v_pages_ref, scale_refs),
-                        (k_buf, v_buf, scale_bufs), sem, None,
-                        page_size=page_size, quantized=quantized,
-                        window=window,
-                        page_lo=chunk * chunk_pages,
-                        page_hi=(chunk + 1) * chunk_pages,
-                        partial=(c, m_ref, l_ref, acc_ref))
-        return _
-
-    jax.lax.fori_loop(0, n_rows, row_body, 0)
+    stream = _PageStream(
+        state, tile_walks_ref, (w_row_ref, w_first_ref, w_end_ref), s,
+        n_splits, page_tables_ref, layer_ref,
+        (k_pages_ref, v_pages_ref, scale_refs), (k_buf, v_buf, scale_bufs),
+        sem, slice(None), chunks_per_prog)
+    _ragged_tile(stream, t, t * block_q, q, None, block_q=block_q,
+                 page_size=page_size, quantized=quantized, window=window,
+                 row_refs=(row_starts_ref, q_begins_ref, q_lens_ref),
+                 sm_scale=score_scale, partial=(m_ref, l_ref, acc_ref))
 
 
 @functools.partial(
@@ -1438,6 +1700,7 @@ def ragged_paged_attention_kvsplit(
     window: int | None = None,
     block_q: int = RAGGED_BLOCK_Q,
     layer: jax.Array | int | None = None,
+    walks=None,
 ) -> jax.Array:
     """Flash-decode ragged paged attention → [T, H·Hd]: the one true
     ragged kernel's descriptor contract with the serial page walk
@@ -1464,10 +1727,8 @@ def ragged_paged_attention_kvsplit(
             q, k_pages, v_pages, page_tables, row_starts, q_begins,
             q_lens, k_scales, v_scales, sm_scale=sm_scale,
             interpret=interpret, window=window, block_q=block_q,
-            coalesce=True, layer=layer_arr)
-    mp = page_tables.shape[1]
+            coalesce=True, layer=layer_arr, walks=walks)
     chunks = KV_SPLIT_CHUNKS
-    chunk_pages = -(-mp // chunks)
     cpp = chunks // S
 
     Tp = -(-T // block_q) * block_q
@@ -1475,13 +1736,17 @@ def ragged_paged_attention_kvsplit(
         q = jnp.pad(q, ((0, Tp - T), (0, 0), (0, 0)))
     nb = Tp // block_q
     qg = q.reshape(Tp, KV, G, Hd)
-    block_rows = _ragged_block_rows(q_begins.astype(jnp.int32),
-                                    q_lens.astype(jnp.int32), nb, block_q)
+    if walks is None:
+        walks = ragged_walk_lists(
+            q, k_pages, v_pages, page_tables, row_starts, q_begins, q_lens,
+            k_scales, window=window, kv_splits=S, block_q=block_q)
+    walks = _check_walks(walks, S, nb)
 
     page_specs, scratch = _page_specs_scratch(
-        page_size, Hd, k_pages.dtype, v_pages.dtype, quantized, heads=KV)
+        page_size, Hd, k_pages.dtype, v_pages.dtype, quantized, heads=KV,
+        slots=RAGGED_RING_SLOTS)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=9,
         grid=(S, nb),
         in_specs=[
             pl.BlockSpec(
@@ -1505,24 +1770,24 @@ def ragged_paged_attention_kvsplit(
                 memory_space=pltpu.VMEM,
             ),
         ),
-        scratch_shapes=scratch,
+        scratch_shapes=[*scratch, _STREAM_STATE],
     )
     kernel = functools.partial(
         _ragged_kernel_kvsplit,
         block_q=block_q, page_size=page_size, sm_scale=sm_scale,
         quantized=quantized, window=window,
-        chunk_pages=chunk_pages, chunks_per_prog=cpp,
+        n_splits=S, chunks_per_prog=cpp,
     )
     operands = [page_tables.astype(jnp.int32), row_starts.astype(jnp.int32),
                 q_begins.astype(jnp.int32), q_lens.astype(jnp.int32),
-                block_rows, layer_arr, qg, k_pages, v_pages]
+                *walks, layer_arr, qg, k_pages, v_pages]
     if quantized:
         operands += [k_scales, v_scales]
-    # the split axis carries no cross-program dependency (each program
-    # owns distinct chunk blocks): declare it parallel so Mosaic may
-    # partition it across cores where the part exposes more than one
-    # (megacore generations); on a single-TensorCore v5e the point is
-    # the per-program page chains pipelining instead of one serial chain
+    # the split axis carries no cross-program dependency (each split owns
+    # distinct chunk blocks and its own page stream): declare it parallel
+    # so Mosaic may partition it across cores where the part exposes more
+    # than one (megacore generations); the tile axis carries a split's
+    # stream from step to step and runs in order
     acc_p, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

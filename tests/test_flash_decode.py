@@ -48,7 +48,13 @@ from fusioninfer_tpu.ops.paged_attention import (
     reference_ragged_paged_attention,
 )
 
-from test_paged_attention import _MIXED, _ragged_setup
+from test_paged_attention import (
+    _MIXED,
+    _STREAM_CASES,
+    _ragged_setup,
+    _stream_run,
+    _stream_variant,
+)
 
 CFG = get_preset("qwen3-tiny")
 CACHE = CacheConfig(n_pages=33, page_size=16, max_pages_per_seq=4)
@@ -147,6 +153,40 @@ class TestKVSplitKernel:
                                                qb, ql)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("case", list(_STREAM_CASES))
+    def test_page_stream_split_count_bit_identity(self, case):
+        """Each split's page stream runs through other walks of other
+        rows, tile after tile; the partial a (tile, row, chunk) emits
+        is the same bits whichever split's stream brought its pages:
+        splits {1, 2, 4, 8} agree bit for bit on rows that meet every
+        boundary (inert rows, chunks past a row's live pages, a row over
+        three tiles, a tile of eight decode rows, one-page rows), and
+        with the oracle."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args = _ragged_setup(**_STREAM_CASES[case], seed=2)
+        outs = {s: _stream_run(pa, f"split{s}", args) for s in (1, 2, 4, 8)}
+        for s in (2, 4, 8):
+            np.testing.assert_array_equal(outs[s], outs[1])
+        ref = reference_ragged_paged_attention(*args)
+        np.testing.assert_allclose(outs[8], np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5)
+
+    @pytest.mark.parametrize("kv_splits", [1, 2, 8])
+    @pytest.mark.parametrize("variant", ["window", "int8", "bf16"])
+    def test_page_stream_ring_depth_bit_identity(self, variant, kv_splits,
+                                                 monkeypatch):
+        """Ring depth 2 against the shipped one at every split count,
+        for sliding-window, int8-page and bfloat16 (native dot) rows."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args, scales, kw = _stream_variant(variant)
+        grid = f"split{kv_splits}"
+        shipped = _stream_run(pa, grid, args, scales, **kw)
+        monkeypatch.setattr(pa, "RAGGED_RING_SLOTS", 2)
+        np.testing.assert_array_equal(
+            _stream_run(pa, grid, args, scales, **kw), shipped)
 
     def test_pick_kv_splits_heuristic(self):
         """Static config decides: below the context floor the single

@@ -574,6 +574,34 @@ class TestKVSplitHW:
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=6e-2, rtol=6e-2)
 
+    @pytest.mark.parametrize("slots", [2, 5])
+    def test_page_stream_ring_depth_bits(self, slots, monkeypatch):
+        """The page stream runs copies ahead through walk, row and tile
+        boundaries — a race or a wrong slot shows only with real DMAs:
+        decode rows, an inert row and a chunk row over five tiles give
+        the same bits at another ring depth, on every grid."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        helper = TestRaggedPagedAttentionHW()
+        args = helper._ragged(
+            q_lens=[1, 1, 0, 40, 1], starts=[4000, 129, 0, 2500, 7],
+            seed=47, n_pages=129, mp=32)
+
+        def run():
+            return [np.asarray(f(*args, interpret=False, **kw), np.float32)
+                    for f, kw in (
+                        (pa.ragged_paged_attention_kvsplit.__wrapped__,
+                         {"kv_splits": 8}),
+                        (pa.ragged_paged_attention.__wrapped__,
+                         {"coalesce": True}),
+                        (pa.ragged_paged_attention.__wrapped__,
+                         {"coalesce": False}))]
+
+        shipped = run()
+        monkeypatch.setattr(pa, "RAGGED_RING_SLOTS", slots)
+        for got, want in zip(run(), shipped):
+            np.testing.assert_array_equal(got, want)
+
     def test_offset_invariance_bits_kvsplit(self):
         """The interpret=False twin of the split-axis extension of
         test_offset_and_neighbor_invariance_bit_identity."""

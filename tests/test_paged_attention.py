@@ -352,6 +352,232 @@ class TestRaggedKernel:
         assert list(np.asarray(off)[:4]) == [0, 0, 1, 2]
 
 
+# rows whose walks meet every boundary the page stream runs through
+# (tile length 8, pages of 16 tokens)
+_STREAM_CASES = {
+    "mixed": _MIXED,
+    "inert_rows": dict(q_lens=[0, 1, 0, 0, 5, 0], starts=[0, 20, 0, 0, 3, 0]),
+    # 8-page tables, 1-2 live pages: most KV-split chunks lie past a row
+    "chunk_past_live": dict(q_lens=[1, 3], starts=[10, 17], mp=8),
+    "row_spans_three_tiles": dict(q_lens=[2, 20, 1], starts=[5, 9, 33]),
+    "tile_of_eight_decode_rows": dict(
+        q_lens=[1] * 8, starts=[3, 17, 40, 63, 0, 31, 16, 50], n_pages=25),
+    "one_page_rows": dict(q_lens=[1, 1, 4], starts=[0, 3, 2]),
+}
+
+
+def _stream_variant(variant):
+    """``(operands, scale operands, kwargs)`` of a sliding-window, an
+    int8-page or a bfloat16 (native score dot) dispatch."""
+    from fusioninfer_tpu.models.quantization import kv_quantize
+
+    if variant == "window":
+        return (_ragged_setup(q_lens=[1, 6, 2], starts=[60, 24, 40], mp=6,
+                              seed=5), (), {"window": 24})
+    if variant == "int8":
+        q, kp, vp, *rest = _ragged_setup(**_MIXED, seed=11)
+        (k8, k_s), (v8, v_s) = kv_quantize(kp), kv_quantize(vp)
+        return ((q, k8, v8, *rest),
+                (k_s[:, :, None, :], v_s[:, :, None, :]), {})
+    return (_ragged_setup(q_lens=[1, 12, 1], starts=[30, 9, 47], KV=2, G=4,
+                          dtype=jnp.bfloat16, seed=7), (), {})
+
+
+def _stream_run(pa, grid, args, scales=(), **kw):
+    """One ragged dispatch through the un-jitted wrapper (so a patched
+    ring depth is what is traced): ``grid`` is ``per-head``,
+    ``coalesced`` or ``split<S>``."""
+    if grid.startswith("split"):
+        return np.asarray(pa.ragged_paged_attention_kvsplit.__wrapped__(
+            *args, *scales, kv_splits=int(grid[5:]), interpret=True, **kw))
+    return np.asarray(pa.ragged_paged_attention.__wrapped__(
+        *args, *scales, coalesce=grid == "coalesced", interpret=True, **kw))
+
+
+class TestRaggedPageStream:
+    """The ragged grids' page stream (one per program column, through
+    walk, row and tile boundaries): against the oracle, bit for bit with
+    itself across ring depths, and its walk lists against a plain
+    enumeration."""
+
+    @pytest.mark.parametrize("grid", ["per-head", "coalesced", "split8"])
+    @pytest.mark.parametrize("case", list(_STREAM_CASES))
+    def test_matches_oracle(self, case, grid):
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args = _ragged_setup(**_STREAM_CASES[case], seed=3)
+        out = _stream_run(pa, grid, args)
+        ref = reference_ragged_paged_attention(*args)
+        np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5,
+                                   rtol=2e-5)
+
+    @pytest.mark.parametrize("grid", ["per-head", "coalesced", "split8"])
+    @pytest.mark.parametrize("case", list(_STREAM_CASES))
+    def test_ring_depth_decides_no_bit(self, case, grid, monkeypatch):
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args = _ragged_setup(**_STREAM_CASES[case], seed=4)
+        shipped = _stream_run(pa, grid, args)
+        monkeypatch.setattr(pa, "RAGGED_RING_SLOTS", 2)
+        np.testing.assert_array_equal(_stream_run(pa, grid, args), shipped)
+
+    @pytest.mark.parametrize("grid", ["per-head", "coalesced", "split4"])
+    @pytest.mark.parametrize("variant", ["window", "int8", "bf16"])
+    def test_ring_depth_decides_no_bit_variants(self, variant, grid,
+                                                monkeypatch):
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args, scales, kw = _stream_variant(variant)
+        shipped = _stream_run(pa, grid, args, scales, **kw)
+        for slots in (2, 5):
+            monkeypatch.setattr(pa, "RAGGED_RING_SLOTS", slots)
+            np.testing.assert_array_equal(
+                _stream_run(pa, grid, args, scales, **kw), shipped)
+
+    @pytest.mark.parametrize("grid", ["per-head", "coalesced", "split8"])
+    def test_native_dot_matches_upcast_dot(self, grid, monkeypatch):
+        """16-bit queries and pages feed the score dot as stored
+        (float32 accumulation; only ``q * sm_scale`` is no longer
+        rounded): against the same kernel with the operands upcast first
+        it stays within the latent kernel's tolerance
+        (tests/test_deepseek_v2.py, 2e-5) beyond one rounding step of
+        the bfloat16 output."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args, _, _ = _stream_variant("bf16")
+        assert pa._native_scores(args[0].dtype, args[1].dtype)
+        assert not pa._native_scores(jnp.float32, jnp.float32)
+        assert not pa._native_scores(jnp.bfloat16, jnp.int8)
+        native = _stream_run(pa, grid, args).astype(np.float32)
+        monkeypatch.setattr(pa, "_native_scores", lambda *_: False)
+        upcast = _stream_run(pa, grid, args).astype(np.float32)
+        np.testing.assert_allclose(native, upcast, atol=2e-5,
+                                   rtol=2.0 ** -8)
+
+    @pytest.mark.parametrize("window", [None, 24])
+    @pytest.mark.parametrize("splits", [0, 1, 4, 8])
+    @pytest.mark.parametrize("case", ["mixed", "inert_rows",
+                                      "chunk_past_live",
+                                      "row_spans_three_tiles"])
+    def test_walk_lists_match_plain_enumeration(self, case, splits, window):
+        """Each column's list is its non-empty (tile, row, chunk) spans
+        in program order, and only those: the scorer and the fetch
+        cursor both run down it."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        q, kp, vp, tables, starts, qb, ql = _ragged_setup(
+            **_STREAM_CASES[case])
+        bq, ps, mp = pa.RAGGED_BLOCK_Q, kp.shape[2], tables.shape[1]
+        nb = -(-q.shape[0] // bq)
+        n_cols, cpp = max(splits, 1), (8 // splits if splits else 1)
+        chunk_pages = -(-mp // 8) if splits else mp
+        got = [np.asarray(a) for a in pa.ragged_walk_lists(
+            q, kp, vp, tables, starts, qb, ql, window=window,
+            kv_splits=splits)]
+        tile_walks = got[0].reshape(n_cols, nb + 1)
+        lists = [a.reshape(n_cols, -1) for a in got[1:]]
+        st, b, n = (np.asarray(a) for a in (starts, qb, ql))
+        for col in range(n_cols):
+            want, offsets = [], [0]
+            for t in range(nb):
+                for r in range(len(n)):
+                    lo, hi = max(b[r], t * bq), min(b[r] + n[r], (t + 1) * bq)
+                    if hi <= lo:
+                        continue
+                    end = -(-(st[r] + hi - b[r]) // ps)
+                    first = (max(st[r] + lo - b[r] - (window - 1), 0) // ps
+                             if window else 0)
+                    for c in range(cpp):
+                        ch = col * cpp + c
+                        f = max(first, ch * chunk_pages)
+                        e = min(end, (ch + 1) * chunk_pages)
+                        if e > f:
+                            want.append((r * cpp + c, f, e))
+                offsets.append(len(want))
+            assert list(tile_walks[col]) == offsets
+            assert [tuple(a[col, k] for a in lists)
+                    for k in range(len(want))] == want
+
+    def test_walks_of_another_grid_are_refused(self):
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        args = _ragged_setup(**_MIXED)
+        walks = pa.ragged_walk_lists(*args, kv_splits=4)
+        with pytest.raises(ValueError, match="another grid"):
+            ragged_paged_attention(*args, interpret=True, walks=walks)
+        out = pa.ragged_paged_attention_kvsplit(
+            *args, kv_splits=4, interpret=True, walks=walks)
+        ref = reference_ragged_paged_attention(*args)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+
+class TestStartupBudget:
+    """A program that carries the kernel pays for its traced size at
+    every warm start: its first dispatch traces and lowers it again
+    though the executable comes from the compile cache (PERF.md, PR 31:
+    on the chip's host a first dispatch of the kernel alone is 0.37 s of
+    trace + lower and 0.04 s of retrieve + load).  PR 30's stream
+    enumerated walks inside the kernel (580 equations at two ring slots,
+    more with every slot) and cost every start 4.9 s."""
+
+    def test_kvsplit_kernel_lowers_within_budget(self):
+        """Traced and lowered for ("tpu",) at ``qwen3-1.7b``'s cell
+        shapes ([28, 8, 744, 128, 128] bfloat16 pages, 32 rows x 32 table
+        pages, T 16 and 512, 8 splits), measured on this sandbox's CPU:
+        the parent (PR 29's two-slot walk) is a kernel of 187 equations
+        in a module of 47.6 k characters, lowered in 0.20 s; this tree
+        183 equations in 59.2 k (the walk lists are plain XLA operations
+        outside the kernel) in 0.20 s.  Held: the kernel within 1.2 x
+        the parent's equations and the module within 1.3 x its
+        characters, whatever the ring's depth."""
+        from fusioninfer_tpu.ops import paged_attention as pa
+
+        KV, G, Hd, ps, n_pages, L, mp, R = 8, 2, 128, 128, 744, 28, 32, 32
+        pool = jax.ShapeDtypeStruct((L, KV, n_pages, ps, Hd), jnp.bfloat16)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+
+        def run(q, kp, vp, tables, st, qb, ql, layer):
+            return pa.ragged_paged_attention_kvsplit.__wrapped__(
+                q, kp, vp, tables, st, qb, ql, kv_splits=8, layer=layer)
+
+        def equations(jaxpr):
+            n = 0
+            for eqn in jaxpr.eqns:
+                n += 1
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else [v]:
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            n += equations(sub)
+            return n
+
+        def kernel_of(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    return eqn.params["jaxpr"]
+            raise AssertionError("no pallas_call at the top level")
+
+        sizes = {}
+        for slots in (pa.RAGGED_RING_SLOTS, 6):
+            for T in (16, 512):
+                q = jax.ShapeDtypeStruct((T, KV * G, Hd), jnp.bfloat16)
+                with pytest.MonkeyPatch.context() as patch:
+                    patch.setattr(pa, "RAGGED_RING_SLOTS", slots)
+                    traced = jax.jit(run).trace(
+                        q, pool, pool, i32(R, mp), i32(R), i32(R), i32(R),
+                        i32())
+                    module = traced.lower(
+                        lowering_platforms=("tpu",)).as_text()
+                sizes[slots, T] = (equations(kernel_of(traced.jaxpr.jaxpr)),
+                                   len(module))
+        for key, (kernel, module) in sizes.items():
+            assert kernel <= 1.2 * 187, (key, kernel)
+            assert module <= 1.3 * 47_600, (key, module)
+        # the ring's depth is a scratch shape, not traced code
+        assert sizes[6, 512][0] == sizes[pa.RAGGED_RING_SLOTS, 512][0]
+
+
 class TestRaggedVmemGuard:
     def test_fits_vmem_adds_tile_term(self):
         from fusioninfer_tpu.ops.paged_attention import (

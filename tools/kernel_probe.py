@@ -1,0 +1,328 @@
+"""The ragged paged-attention kernel alone, on the chip, at the serving
+cells' shape: what a call, a row and a page cost, and what a program
+that carries the kernel pays at its first dispatch (ISSUE 31).
+
+    chiprun -- python tools/kernel_probe.py
+    chiprun -- python tools/kernel_probe.py \
+        --variant parent=.archive_parent/fusioninfer_tpu/ops/paged_attention.py \
+        --variant ring2=fusioninfer_tpu/ops/paged_attention.py@RAGGED_RING_SLOTS=2
+    JAX_PLATFORMS=cpu python tools/kernel_probe.py --tiny   # rehearsal: control flow only
+
+Shape: 8 KV heads x group 2 x 128, page 128, a stacked pool of 744 pages
+x 2 layers (bfloat16), 32 rows x 32-page tables, ``kv_splits`` 8
+(``--kv-splits 0``: the single-walk grid), ``block_q`` 8: what
+``qwen3-1.7b``'s cells trace.  Cases:
+
+* ``decode_r<n>_<ctx>``: n in {1, 2, 8, 16} decode rows at contexts of
+  0.8 k and 3.8 k tokens (7 and 30 pages a row);
+* ``chunk24_at800``, ``chunk448_at0``, ``chunk448_at1024``: one chunk
+  row (beside no decode rows).
+
+A timing is the median over 5 repeats of ONE jitted loop of 50 kernel
+calls (the layer alternates, each call's query is the output of the one
+before it, the rows' walk lists are built once outside the loop as a
+forward builds them outside its layer scan, ``block_until_ready``
+fences the loop) divided by 50.  The
+fit ``us = c + a * rows + b * pages`` over the decode cases says what a
+row's boundaries cost (``a``) and what a page costs (``b``, against
+``page_us_at_hbm_peak``).  ``cold_waits``: page copies a call waits for
+with nothing else in flight — one per column that has a walk with the
+page stream, one per walk without it (``walks``); both counted from the
+tree's own walk lists.
+
+``first_dispatch``: in a process of its own that finds the program in
+the persistent compile cache (a process before it put it there), the
+seconds of trace, lower, compile (= retrieve + load) and first run of
+the kernel alone at the decode (T 16) and the chunk (T 512) shape.
+
+``--variant name=path[@CONST=int]`` loads ANOTHER copy of
+``paged_attention.py`` under its own name, optionally with one module
+constant set before anything is traced, so one call compares kernels on
+one chip.  Every process but the first is a child (``--child``): the
+parent never touches jax, because a chip belongs to one process at a
+time.  The last line printed is one JSON object; the same goes to
+``--out``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import os
+import statistics
+import subprocess
+import sys
+import time
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [REPO, os.path.join(REPO, "perfbench")]
+
+TREE = "fusioninfer_tpu/ops/paged_attention.py"
+CALLS, REPEATS = 50, 5
+REAL = dict(KV=8, G=2, Hd=128, page=128, n_pages=744, layers=2, table=32,
+            rows=32, contexts=(800, 3800), decode_rows=(1, 2, 8, 16),
+            chunks=((24, 800), (448, 0), (448, 1024)), kv_splits=8)
+TINY = dict(KV=2, G=2, Hd=64, page=16, n_pages=40, layers=2, table=8,
+            rows=8, contexts=(40, 100), decode_rows=(1, 4),
+            chunks=((12, 30),), kv_splits=8)
+
+
+def _load(name: str, spec: str):
+    path, _, setting = spec.partition("@")
+    s = importlib.util.spec_from_file_location(
+        f"_probe_pa_{name}", os.path.join(REPO, path))
+    mod = importlib.util.module_from_spec(s)
+    sys.modules[s.name] = mod
+    s.loader.exec_module(mod)
+    if setting:
+        attr, value = setting.split("=")
+        if not hasattr(mod, attr):
+            raise SystemExit(f"{path} has no constant {attr}")
+        setattr(mod, attr, int(value))
+    return mod
+
+
+def _case(shape: dict, q_lens: list[int], starts: list[int]):
+    """Host descriptors of one call: the given rows first, inert rows
+    up to ``shape['rows']``, every live row on pages of its own."""
+    import numpy as np
+
+    R, mp, ps = shape["rows"], shape["table"], shape["page"]
+    ql = np.zeros(R, np.int32)
+    st = np.zeros(R, np.int32)
+    ql[:len(q_lens)], st[:len(starts)] = q_lens, starts
+    qb = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
+    tables = np.zeros((R, mp), np.int32)
+    nxt = 0
+    for r in range(len(q_lens)):
+        need = -(-int(st[r] + ql[r]) // ps)
+        tables[r, :need] = (nxt + np.arange(need)) % (shape["n_pages"] - 1)
+        nxt += need
+    T = max(16, 1 << (int(ql.sum()) - 1).bit_length())  # the pow2 bucket
+    return T, tables, st, qb, ql
+
+
+def _cases(shape: dict) -> dict:
+    out = {}
+    for ctx in shape["contexts"]:
+        for n in shape["decode_rows"]:
+            out[f"decode_r{n}_{ctx}"] = _case(shape, [1] * n, [ctx - 1] * n)
+    for n, at in shape["chunks"]:
+        out[f"chunk{n}_at{at}"] = _case(shape, [n], [at])
+    return out
+
+
+def _walk_counts(tree, shape: dict, T: int, st, qb, ql) -> dict:
+    """Pages fetched, walks and columns-with-a-walk of one call, from the
+    tree's walk lists (a chunk row's every tile walks its causal span)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    S = shape["kv_splits"]
+    nb = T // tree.RAGGED_BLOCK_Q
+    split = dict(n_cols=S, cpp=tree.KV_SPLIT_CHUNKS // S, chunk_pages=-(
+        -shape["table"] // tree.KV_SPLIT_CHUNKS)) if S else {}
+    tile_walks, _, first, end = (np.asarray(a) for a in tree._ragged_walks(
+        jnp.asarray(qb), jnp.asarray(ql), jnp.asarray(st), nb=nb,
+        block_q=tree.RAGGED_BLOCK_Q, page_size=shape["page"], window=None,
+        **split))
+    per_col = tile_walks.reshape(max(S, 1), nb + 1)[:, -1]
+    return {"pages": int((end - first).sum()), "walks": int(per_col.sum()),
+            "columns": int((per_col > 0).sum())}
+
+
+def _operands(shape: dict, T: int):
+    import jax
+    import jax.numpy as jnp
+
+    KV, G, Hd = shape["KV"], shape["G"], shape["Hd"]
+    pool = (shape["layers"], KV, shape["n_pages"], shape["page"], Hd)
+    ks = jax.random.split(jax.random.key(0), 3)
+    return (jax.random.normal(ks[0], (T, KV * G, Hd), jnp.bfloat16),
+            jax.random.normal(ks[1], pool, jnp.bfloat16),
+            jax.random.normal(ks[2], pool, jnp.bfloat16))
+
+
+def _kernel(mod, shape: dict, interpret: bool):
+    def call(q, kp, vp, tables, st, qb, ql, layer, **kw):
+        if not shape["kv_splits"]:  # the single-walk grid (ROADMAP S2's A/B)
+            return mod.ragged_paged_attention(
+                q, kp, vp, tables, st, qb, ql, coalesce=True,
+                interpret=interpret, layer=layer, **kw)
+        return mod.ragged_paged_attention_kvsplit(
+            q, kp, vp, tables, st, qb, ql, kv_splits=shape["kv_splits"],
+            interpret=interpret, layer=layer, **kw)
+    return call
+
+
+def child_time(mod, shape: dict, interpret: bool) -> dict:
+    """µs a call of every case, the fit, and the error against the jnp
+    oracle on the mixed case."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    tree = _load("tree_lists", TREE)
+    call = _kernel(mod, shape, interpret)
+    L = shape["layers"]
+
+    @jax.jit
+    def many(q, kp, vp, tables, st, qb, ql):
+        kw = {}
+        if hasattr(mod, "ragged_walk_lists"):
+            # as the engine does it: the rows' walk lists are built once
+            # a forward, outside the loop over layers
+            kw["walks"] = mod.ragged_walk_lists(
+                q, kp, vp, tables, st, qb, ql, kv_splits=shape["kv_splits"])
+
+        def body(i, q):
+            return call(q, kp, vp, tables, st, qb, ql,
+                        jnp.int32(i % L), **kw).reshape(q.shape)
+        return jax.lax.fori_loop(0, CALLS, body, q)
+
+    import peaks  # perfbench/peaks.py: the one table of chip peaks
+
+    peak = ({"hbm_bytes_per_s": 819e9} if interpret  # a rehearsal's stand-in
+            else peaks.peaks_for(jax.devices()[0].device_kind))
+    page_bytes = 2 * shape["KV"] * shape["page"] * shape["Hd"] * 2
+    page_us = page_bytes / peak["hbm_bytes_per_s"] * 1e6
+    out, fit_rows = {}, []
+    for name, (T, tables, st, qb, ql) in _cases(shape).items():
+        q, kp, vp = _operands(shape, T)
+        args = (q, kp, vp, *map(jnp.asarray, (tables, st, qb, ql)))
+        many(*args).block_until_ready()
+        times = []
+        for _ in range(REPEATS):
+            t0 = time.perf_counter()
+            many(*args).block_until_ready()
+            times.append((time.perf_counter() - t0) / CALLS * 1e6)
+        counts = _walk_counts(tree, shape, T, st, qb, ql)
+        pages = counts.pop("pages")
+        us = statistics.median(times)
+        out[name] = {"us_per_call": us, "pages_read": pages,
+                     "roofline_pct": 100 * pages * page_us / us,
+                     "cold_waits": counts}
+        if name.startswith("decode"):
+            fit_rows.append((int((ql > 0).sum()), pages, us))
+        print(f"  {name}: {us:.1f} us, {pages} pages, "
+              f"{out[name]['roofline_pct']:.1f} % of the memory roofline",
+              flush=True)
+    A = np.array([[1.0, r, p] for r, p, _ in fit_rows])
+    c, a, b = np.linalg.lstsq(A, np.array([u for *_, u in fit_rows]),
+                              rcond=None)[0]
+    out["fit"] = {"c_us_per_call": c, "a_us_per_row": a, "b_us_per_page": b,
+                  "page_us_at_hbm_peak": page_us}
+    # the oracle, on a call that mixes decode rows and a chunk row
+    ctx = shape["contexts"][0]
+    n, at = shape["chunks"][0]
+    T, tables, st, qb, ql = _case(shape, [1, 1, n, 1], [ctx, 5, at, ctx // 2])
+    q, kp, vp = _operands(shape, T)
+    d = tuple(map(jnp.asarray, (tables, st, qb, ql)))
+    got = jax.jit(call)(q, kp, vp, *d, jnp.int32(1))
+    want = tree.reference_ragged_paged_attention(q, kp[1], vp[1], *d)
+    live = np.asarray(tree.ragged_token_rows(d[2], d[3], T)[2])
+    out["max_abs_err_vs_oracle"] = float(np.abs(
+        np.asarray(got, np.float32) - np.asarray(want, np.float32))[live].max())
+    return out
+
+
+def child_first(mod, shape: dict, interpret: bool) -> dict:
+    """Seconds of each stage of a first dispatch of the kernel alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from fusioninfer_tpu.engine import aot
+
+    aot.configure_cache(min_compile_seconds=0.0)
+    call = _kernel(mod, shape, interpret)
+    out = {}
+    for T in (16, 32 if shape["page"] < 128 else 512):
+        _, tables, st, qb, ql = _case(shape, [1], [shape["contexts"][0]])
+        q, kp, vp = _operands(shape, T)
+        args = (q, kp, vp, *map(jnp.asarray, (tables, st, qb, ql)),
+                jnp.int32(0))
+        jax.block_until_ready(args)
+        t = [time.perf_counter()]
+        traced = jax.jit(call).trace(*args)
+        t.append(time.perf_counter())
+        lowered = traced.lower()
+        t.append(time.perf_counter())
+        compiled = lowered.compile()
+        t.append(time.perf_counter())
+        compiled(*args).block_until_ready()
+        t.append(time.perf_counter())
+        out[f"t{T}"] = dict(zip(("trace_s", "lower_s", "compile_s", "run_s"),
+                                (b - a for a, b in zip(t, t[1:]))),
+                            module_chars=len(lowered.as_text()))
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--variant", action="append", default=[],
+                    help="name=path/to/paged_attention.py[@CONST=int]")
+    ap.add_argument("--no-tree", action="store_true",
+                    help="the variants only")
+    ap.add_argument("--time-only", action="append", default=[],
+                    metavar="NAME", help="no first dispatch for this variant")
+    ap.add_argument("--tiny", action="store_true",
+                    help="CPU rehearsal: tiny shapes, interpret kernels")
+    ap.add_argument("--kv-splits", type=int, default=8,
+                    help="0: the single-walk grid, for ROADMAP S2's A/B")
+    ap.add_argument("--out", default="chiprun_out/kernel_probe/probe.json")
+    ap.add_argument("--child", nargs=3, metavar=("KIND", "NAME", "SPEC"),
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    shape = dict(TINY if args.tiny else REAL, kv_splits=args.kv_splits)
+
+    if args.child:
+        import jax
+
+        kind, name, spec = args.child
+        if not args.tiny and jax.default_backend() != "tpu":
+            raise SystemExit("kernel_probe: no TPU (--tiny rehearses on the CPU)")
+        fn = child_time if kind == "time" else child_first
+        res = fn(_load(name, spec), shape, interpret=args.tiny)
+        res["device"] = jax.devices()[0].device_kind
+        print(json.dumps(res))
+        return 0
+
+    variants = ([] if args.no_tree else [("tree", TREE)]) + [
+        tuple(v.split("=", 1)) for v in args.variant]
+    report = {"shape": {k: v for k, v in shape.items()},
+              "calls": CALLS, "repeats": REPEATS, "variants": {}}
+    os.makedirs(os.path.dirname(os.path.join(REPO, args.out)), exist_ok=True)
+    for name, spec in variants:
+        entry = report["variants"][name] = {"spec": spec}
+        # "first" twice: the first process leaves the programs in the
+        # persistent cache, the second is the warm start that is reported
+        for kind, key in (("time", "time"), ("first", "first_dispatch_cold"),
+                          ("first", "first_dispatch")):
+            if kind == "first" and name in args.time_only:
+                continue
+            print(f"== {name} {key}", flush=True)
+            cmd = [sys.executable, os.path.abspath(__file__), "--child",
+                   kind, name, spec, "--kv-splits", str(args.kv_splits)] + (
+                       ["--tiny"] if args.tiny else [])
+            p = subprocess.run(cmd, cwd=REPO, stdout=subprocess.PIPE, text=True)
+            lines = p.stdout.strip().splitlines()
+            print("\n".join(lines[:-1]), flush=True)
+            if p.returncode:
+                entry[key] = {"failed": p.returncode}
+                continue
+            entry[key] = json.loads(lines[-1])
+            report["device"] = entry[key].pop("device")
+            if kind == "first":
+                for prog, v in entry[key].items():
+                    print(f"  {prog}: " + ", ".join(
+                        f"{k} {x:.3f}" for k, x in v.items()
+                        if k.endswith("_s")), flush=True)
+        with open(os.path.join(REPO, args.out), "w") as f:
+            json.dump(report, f, indent=1)
+    print(json.dumps(report))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
