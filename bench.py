@@ -119,7 +119,7 @@ def stratified_lens(batch: int, span_tokens: int, tail: int,
     strata from ``base`` up to ``span_tokens - tail`` (room for the
     timed window).  ``max(batch - 1, 1)``: a ``batch == 1`` leg
     (BENCH_MODEL debug runs) yields ``[base]`` instead of
-    ZeroDivisionError-ing the whole record (ADVICE r5)."""
+    ZeroDivisionError-ing the whole record."""
     return [base + (span_tokens - base - tail) * i // max(batch - 1, 1)
             for i in range(batch)]
 
@@ -255,10 +255,8 @@ def run_admissions(cfg, cache_cfg, max_batch_size: int = 8,
 def run_kernel_microbench(jax, on_tpu: bool,
                           calibration_gflops: float | None) -> dict:
     """Raw attention-op microbench with dispersion (same reps/IQR shape
-    as the decode legs): the ONE ragged kernel against (a) the portable
-    flat-gather baseline and (b) the retired padded-rectangle layout —
-    the verify kernel over ``[rows, C]`` with every decode row padded to
-    the chunk bucket — at a mixed decode+chunk shape.  Ratios > 1 mean
+    as the decode legs): the ONE ragged kernel against the portable
+    flat-gather baseline at a mixed decode+chunk shape.  Ratios > 1 mean
     the ragged kernel wins; ``mfu_box`` is the ragged leg's attention
     FLOP/s over this box's calibrated matmul ceiling (VERDICT #8).  On
     CPU the kernels run in interpret mode: the ratios there prove the
@@ -268,7 +266,6 @@ def run_kernel_microbench(jax, on_tpu: bool,
     import numpy as np
 
     from fusioninfer_tpu.ops.paged_attention import (
-        paged_verify_attention,
         ragged_paged_attention,
         reference_ragged_paged_attention,
     )
@@ -313,14 +310,6 @@ def run_kernel_microbench(jax, on_tpu: bool,
     starts_d = jnp.asarray(starts)
     q_begins_d = jnp.asarray(q_begins)
     q_lens_d = jnp.asarray(q_lens)
-    # the retired rectangle: every row padded to the chunk bucket C
-    C = 1 << (int(chunk) - 1).bit_length()
-    q_rect = np.zeros((R, C, H, Hd), np.float32)
-    qn = np.asarray(q, np.float32)
-    for r in range(R):
-        q_rect[r, : q_lens[r]] = qn[q_begins[r]: q_begins[r] + q_lens[r]]
-    q_rect_d = jnp.asarray(q_rect, dt)
-    counts_d = jnp.asarray(q_lens)
 
     gather = jax.jit(reference_ragged_paged_attention)
 
@@ -330,20 +319,16 @@ def run_kernel_microbench(jax, on_tpu: bool,
             interpret=interpret),
         "gather": lambda: gather(q, k_pages, v_pages, tables_d, starts_d,
                                  q_begins_d, q_lens_d),
-        "padded_rect": lambda: paged_verify_attention(
-            q_rect_d, k_pages, v_pages, tables_d, starts_d, counts_d,
-            interpret=interpret),
     }
     out: dict = {
         "shape": {"kv_heads": KV, "group": G, "head_dim": Hd,
                   "page_size": ps, "decode_rows": b_dec, "chunk": chunk,
-                  "flat_tokens": T, "rect_bucket": C, "iters": iters,
+                  "flat_tokens": T, "iters": iters,
                   "interpret": interpret},
         "note": ("ragged = one flat ragged kernel (decode rows + chunk "
-                 "row, zero padding); padded_rect = the retired "
-                 "[rows, C] layout through the verify kernel; gather = "
-                 "portable flat-gather baseline.  calls/s medians; "
-                 "interpret=True legs prove plumbing, not speed"),
+                 "row, zero padding); gather = portable flat-gather "
+                 "baseline.  calls/s medians; interpret=True legs prove "
+                 "plumbing, not speed"),
     }
     rates: dict = {}
     for name, fn in legs.items():
@@ -371,9 +356,6 @@ def run_kernel_microbench(jax, on_tpu: bool,
             out[f"{name}_error"] = f"{type(e).__name__}: {str(e)[:400]}"
     if rates.get("ragged") and rates.get("gather"):
         out["ragged_vs_gather"] = round(rates["ragged"] / rates["gather"], 3)
-    if rates.get("ragged") and rates.get("padded_rect"):
-        out["ragged_vs_padded"] = round(
-            rates["ragged"] / rates["padded_rect"], 3)
     if rates.get("ragged"):
         # causal attention FLOPs of the REAL tokens only (the ragged
         # kernel's whole point): 4·H·Hd per (token, visible position)
@@ -734,9 +716,8 @@ def run_http(cfg, max_batch_size: int, cache_cfg, n_requests: int,
             bucket *= 2
         _warm(32, 0.0)
         if shared_prefix_len:
-            # the shared-prefix leg exercises the separately-jitted
-            # prefill_suffix (cache-hit) signature: warm it with two
-            # requests sharing a prefix
+            # the shared-prefix leg's cache hit is a suffix row of the
+            # chunk forward: warm it with two requests sharing a prefix
             for tail in (" tail", " cont"):  # 2nd = cache hit → suffix
                 body = json.dumps({
                     "model": cfg.name,
@@ -1022,7 +1003,7 @@ def main() -> None:
 
         decode: dict = {}
         # interpretability anchor for every kernel-vs-gather speedup in
-        # this record (ADVICE r5 #4): the portable gather baseline pays a
+        # this record: the portable gather baseline pays a
         # per-layer dynamic-slice of the stacked KV pool
         # (model_runner._cache_layer) before its cache[page_tables]
         # gather, while the Pallas kernels read the stacked pools in
@@ -1189,8 +1170,8 @@ def main() -> None:
                 "error": f"{type(e).__name__}: {str(e)[:200]}"}
 
         # raw-kernel microbench: the ragged kernel's own evidence leg
-        # (ragged-vs-gather, ragged-vs-padded-rectangle, mfu_box with
-        # dispersion) — independent of the full-model decode legs above
+        # (ragged-vs-gather, mfu_box with dispersion) — independent of
+        # the full-model decode legs above
         try:
             record["kernel_microbench"] = run_kernel_microbench(
                 jax, on_tpu, record.get("calibration_gflops"))

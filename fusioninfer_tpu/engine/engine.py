@@ -848,7 +848,7 @@ class NativeEngine:
             "devices": [_device_memory(d) for d in devices],
         }
 
-    def _refuse_latent(self, **asked) -> None:
+    def _refuse_if_latent(self, **asked) -> None:
         refusal = latent_cache_refusal(self.cfg, **asked)
         if refusal:
             raise ValueError(refusal)
@@ -1344,7 +1344,7 @@ class NativeEngine:
         Served inside :meth:`step` (engine thread owns the cache); resolves
         to a :class:`fusioninfer_tpu.engine.kv_transfer.KVSlab` — int8
         caches emit int8 slabs (scales ride the wire)."""
-        self._refuse_latent(kv_transfer=True)
+        self._refuse_if_latent(kv_transfer=True)
         if request.lora:
             self._adapter_id(request)  # unknown adapter: client error NOW
         self._validate_guided(request)
@@ -1375,7 +1375,7 @@ class NativeEngine:
         (:class:`fusioninfer_tpu.engine.kv_fabric.KVFabric`): host-tier
         misses in ``_restore_host_blocks`` then consult the fleet before
         falling back to recompute."""
-        self._refuse_latent(kv_fabric=True)
+        self._refuse_if_latent(kv_fabric=True)
         self._kv_fabric = fabric
 
     def request_prefill_stream(self, request: Request,
@@ -1393,7 +1393,7 @@ class NativeEngine:
         across hosts and must host-gather via a collective before any
         byte leaves, which serializes exactly what streaming hides —
         those meshes keep the slab path (the server falls back)."""
-        self._refuse_latent(kv_transfer=True)
+        self._refuse_if_latent(kv_transfer=True)
         if self._mh is not None:
             raise ValueError(
                 "streamed prefill is single-process; multi-process "
@@ -1413,7 +1413,7 @@ class NativeEngine:
         activates the sequence when the stream assembles complete.  Any
         stream fault falls back to a local re-prefill of the same
         request — bit-identical output, only the TTFT differs."""
-        self._refuse_latent(kv_transfer=True)
+        self._refuse_if_latent(kv_transfer=True)
         if self._mh is not None:
             raise ValueError(
                 "streamed PD admission is single-process; multi-process "
@@ -1436,7 +1436,7 @@ class NativeEngine:
     def add_prefilled_request(self, request: Request, slab) -> None:
         """Decode-worker side: admit a request whose prefill (KV + first
         token) was computed remotely; generation continues from there."""
-        self._refuse_latent(kv_transfer=True)
+        self._refuse_if_latent(kv_transfer=True)
         if request.lora:
             # decode applies the adapter's deltas per step: it must be
             # loaded HERE too (the prefiller already prefilled under it)
@@ -2394,7 +2394,7 @@ class NativeEngine:
         are refused from this point on.  Single-process only: the park
         path writes the host tier, which a multi-host SPMD group
         refuses anyway — multi-host slices drain instead."""
-        self._refuse_latent(evacuate=True)
+        self._refuse_if_latent(evacuate=True)
         if self._mh is not None:
             raise RuntimeError(
                 "evacuation is single-process only (the park path is "
@@ -2816,8 +2816,8 @@ class NativeEngine:
                     self._reserve_prefill(suffix_len,
                                           prio=request.priority)
                     if suffix_len <= _SUFFIX_BATCH_WINDOW:
-                        # short suffix: batch with other hits through one
-                        # verify_step forward (the common prefix-cache
+                        # short suffix: batch with other hits as rows of one
+                        # fused_step forward (the common prefix-cache
                         # burst — N requests sharing a prompt, tails
                         # differing by a few tokens)
                         short_hits.append((request, prefix, resumed, reused))
@@ -3341,8 +3341,8 @@ class NativeEngine:
 
     def _advance_prefilling(self) -> list[StepOutput]:
         """Advance EVERY mid-prefill sequence one budgeted chunk per
-        step in one batched multi-query forward (the q-tiled verify
-        kernel) — prefilling sequences progress together at full MXU
+        step in one batched multi-query forward (chunk rows of
+        ``fused_step``) — prefilling sequences progress together at full MXU
         utilization instead of serializing across steps.  Chunk sizes
         come from the step's remaining token budget split over the
         in-flight prefills (``_chunk_budget``): they shrink under decode

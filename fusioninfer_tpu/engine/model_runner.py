@@ -1,19 +1,25 @@
-"""KV-cache-aware prefill / decode execution.
+"""KV-cache-aware forwards: the three programs the engine dispatches.
 
-Two jitted entry points with fully static shapes (XLA compiles each
-(bucket, batch) signature once and caches it):
+Each is jitted with fully static shapes (XLA compiles each signature
+once and caches it) and runs ONE layer body under :func:`_scan_layers`:
 
-* :func:`prefill` — one sequence, prompt padded to a bucket length; runs
-  the causal forward while scattering fresh K/V into the sequence's cache
-  pages; returns logits at the last real token.
-* :func:`decode_step` — the continuous-batching hot loop: B sequences ×
-  one token; writes each token's K/V into its page slot, gathers each
-  sequence's pages, attends, returns next-token logits for the whole
-  batch.
+* :func:`prefill` — B whole prompts padded to one bucket: the causal
+  flash forward, scattering fresh K/V (or latent rows) into each
+  sequence's cache pages; returns logits at each last real token.
+* :func:`decode_burst` — the continuous-batching hot loop: ``n_steps``
+  decode + sample steps over B sequences × one token with on-device
+  token feedback.  Its step is :func:`_decode_step_impl`;
+  :func:`decode_step` is the thin jit of that step which tests compare
+  a burst against.
+* :func:`fused_step` — one weight pass over a flat ragged token axis:
+  decode rows, speculative windows, budgeted prefill chunks and
+  cache-hit suffixes are rows of it.
 
-The gather-based paged attention here is the portable baseline;
-:mod:`fusioninfer_tpu.ops.paged_attention` provides the Pallas TPU kernel
-that reads pages in place.
+Paged attention has two branches in each: the Pallas ragged family of
+:mod:`fusioninfer_tpu.ops.paged_attention` (or the latent kernel of
+:mod:`fusioninfer_tpu.ops.mla_attention`), which reads pages in place,
+through :func:`_ragged_attn`; and the gather-based portable baseline
+written out here.
 """
 
 from __future__ import annotations
@@ -180,13 +186,6 @@ def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
     return cache, mla_attn_out(cfg, layer, o_lat)[:, None, :]
 
 
-def _refuse_latent(cfg, what: str) -> None:
-    if cfg.is_mla:
-        raise NotImplementedError(
-            f"{what} does not read a latent (MLA) cache: the engine's "
-            "forwards are prefill, decode_burst and fused_step")
-
-
 def _cache_layer(cache: dict, l):
     """Materialize ONE layer's pools (portable/gather attention branch
     only — the Pallas kernels read the stacked pools in place via their
@@ -321,124 +320,6 @@ def prefill(
     x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = x[jnp.arange(B), jnp.maximum(true_lens - 1, 0)]  # [B, D]
-    return cache, lm_head(cfg, params, last)
-
-
-@partial(jax.jit, static_argnums=(0, 1),
-         static_argnames=("mesh", "coalesce", "kv_splits"),
-         donate_argnums=(3,))
-def prefill_suffix(
-    cfg: ModelConfig,
-    cache_cfg: CacheConfig,
-    params,
-    cache: dict,
-    tokens: jax.Array,  # [1, C] suffix padded to bucket
-    start: jax.Array,  # scalar int32: global position of tokens[0]
-    true_len: jax.Array,  # scalar int32: real suffix length
-    page_row: jax.Array,  # [max_pages_per_seq] — prefix pages already filled
-    mesh=None,  # tp-only serving mesh: shard_map'd kernels per TP shard
-    lora=None,  # stacked AdapterSet tree; the cached prefix pages were
-    adapter_ids: jax.Array = None,  # written under THIS adapter (the
-    # engine namespaces the prefix cache per adapter)
-    coalesce: bool = None,  # ragged-grid variant (ops/dispatch.py);
-    # the engine resolves the env var eagerly per call
-    kv_splits: int = 0,  # flash-decode KV-split grid (0 = single walk);
-    # static per engine (pick_kv_splits over the cache config)
-):
-    """Prefill a prompt SUFFIX against cached prefix pages (the automatic
-    prefix-caching path): token i sits at global position ``start + i``,
-    writes its K/V into the sequence's pages, and attends over the page
-    context (shared prefix pages are read, never written).  Returns
-    (cache, logits at the last real suffix token [1, V]).
-
-    Attention dispatch mirrors ``decode_step``: on the kernel path the
-    ONE ragged kernel streams pages in place
-    (:func:`fusioninfer_tpu.ops.ragged_paged_attention`, a single-row
-    descriptor set), per tensor-parallel shard when a tp-only ``mesh``
-    is given; the portable branch gathers the page context and relies
-    on XLA SPMD.
-    This is the data path behind the router's flagship prefix-cache
-    strategy (reference ``pkg/router/strategy.go:51-77`` routes for cache
-    hits; the hit's compute happens here).
-    """
-    from fusioninfer_tpu.ops import dispatch
-
-    _refuse_latent(cfg, "prefill_suffix")
-    B, C = tokens.shape
-    ps = cache_cfg.page_size
-    mp = page_row.shape[0]
-    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    quantized = cache_cfg.quantized
-    dtype_ctx = jnp.float32 if quantized else cache["k"].dtype
-    use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
-
-    x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)  # [1, C, D]
-    offs = jnp.arange(C)
-    positions = (start + offs)[None, :]  # [1, C]
-
-    write_page = jnp.where(
-        offs < true_len, page_row[(start + offs) // ps], cache_cfg.trash_page
-    )
-    write_slot = (start + offs) % ps
-
-    # the ONE ragged kernel's degenerate descriptors: a single row of
-    # true_len tokens starting mid-sequence
-    row = (page_row[None], jnp.reshape(start, (1,)).astype(jnp.int32),
-           jnp.zeros((1,), jnp.int32),
-           jnp.reshape(true_len, (1,)).astype(jnp.int32))
-    walks = _ragged_walks(cfg, cache, mesh, use_kernel, C, *row, kv_splits)
-
-    # context mask over the gathered [mp * ps] positions (portable branch)
-    ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T]
-    attend = masks.attend(positions[0][:, None], ctx_idx,
-                          cfg.sliding_window)  # [C, T]
-
-    def body(carry, inputs):
-        x, cache = carry
-        layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
-        from fusioninfer_tpu.models.quantization import maybe_dequantize_tree
-
-        layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
-        q, k, v = qkv_proj(cfg, layer, x, positions, layer_lora, adapter_ids)
-
-        # stacked head-major cache [L, KV, n_pages, ps, Hd]; k[0] is
-        # [C, KV, Hd] → in-place scatter at layer l
-        cache = _scatter_kv(cache, l, k[0], v[0], write_page, write_slot,
-                            head_axis=1)
-        ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
-
-        if use_kernel:
-            attn = _ragged_attn(
-                mesh, q[0], cache, *row, ks_s, vs_s, layer=l,
-                window=cfg.sliding_window, coalesce=coalesce,
-                kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(), walks=walks,
-            )[None]  # [1, C, H*Hd]
-        else:
-            k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
-            k_ctx = k_cache_l[:, page_row].reshape(KV, mp * ps, Hd)
-            v_ctx = v_cache_l[:, page_row].reshape(KV, mp * ps, Hd)
-            if quantized:
-                k_ctx = _dequant_gather(k_ctx, ks_l, page_row, (KV, mp * ps))
-                v_ctx = _dequant_gather(v_ctx, vs_l, page_row, (KV, mp * ps))
-
-            group = H // KV
-            qg = q.reshape(B, C, KV, group, Hd)
-            scores = jnp.einsum("bskgd,ktd->bkgst", qg, k_ctx).astype(jnp.float32)
-            scores = scores / jnp.sqrt(Hd)
-            scores = jnp.where(attend[None, None, None, :, :], scores, -1e30)
-            attn = jnp.einsum(
-                "bkgst,ktd->bskgd",
-                jax.nn.softmax(scores, axis=-1).astype(dtype_ctx),
-                v_ctx,
-            ).reshape(B, C, H * Hd).astype(x.dtype)
-        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        y, stats = mlp_block(cfg, layer, x, (offs < true_len)[None])
-        return (x + y, _add_moe_stats(cache, stats)), None
-
-    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    last = x[jnp.arange(B), jnp.maximum(true_len - 1, 0)]
     return cache, lm_head(cfg, params, last)
 
 
@@ -684,148 +565,6 @@ def decode_burst(
         [toks_f, pos_f, ctl_i[:, 2], ctl_i[:, 3], gcounts_f,
          ctl_i[:, 5], ctl_i[:, 6], ctl_i[:, 7]], axis=1)
     return cache, sampled_all, token_counts, output_counts, next_ctl_i
-
-
-def _window_forward_impl(
-    cfg: ModelConfig,
-    cache_cfg: CacheConfig,
-    params,
-    cache: dict,
-    tokens: jax.Array,  # [B, C] — last sampled token + draft tokens, padded
-    starts: jax.Array,  # [B] int32: global position of tokens[:, 0]
-    counts: jax.Array,  # [B] int32: real window length (0 = inactive slot)
-    page_tables: jax.Array,  # [B, max_pages_per_seq]
-    mesh=None,  # tp-only serving mesh: shard_map'd kernels per TP shard
-    lora=None,  # stacked AdapterSet tree ([L, N, ...] per projection)
-    adapter_ids: jax.Array = None,  # [B] int32; 0 = base model
-    last_only: bool = False,  # logits at counts-1 only → [B, V]
-    sel: jax.Array = None,  # [B, W] per-row positions to project → [B, W, V]
-    coalesce: bool = None,  # ragged-grid variant, resolved by the engine
-    kv_splits: int = 0,  # flash-decode KV-split grid (0 = single walk)
-):
-    """Speculative-verification forward: score a C-token window per
-    sequence in ONE pass → (cache, logits [B, C, V]); with ``last_only``
-    (the batched-suffix-prefill caller) only each sequence's LAST real
-    position projects through lm_head → [B, V], so a wide window never
-    materializes a [B, C, vocab] logits tensor it won't read.  With
-    ``sel`` (the fused mixed-batch step) each row projects its OWN
-    per-row window positions through lm_head → [B, W, V]: decode rows
-    read position 0 (or their spec window), prefill-chunk rows read
-    their chunk's last real token — one lm_head over W columns instead
-    of C.
-
-    ``logits[b, i]`` is the model's next-token distribution after
-    consuming ``tokens[b, :i+1]`` — exactly what ``i+1`` sequential
-    ``decode_step`` calls would produce, at one weight-read instead of C
-    (decode is weight-bandwidth-bound, which is the whole speculative
-    win).  K/V for every real window token is scattered into the
-    sequence's pages; positions at/past ``counts[b]`` write the trash
-    page.  Rejected draft tokens need no rollback: their slots are
-    overwritten the next time those positions are written, and attention
-    masks by true length so stale entries are never read.
-
-    The capability matches vLLM's spec-decode scorer (delegated by the
-    reference, SURVEY §0 — the operator only passes engine flags
-    through); the TPU realization flattens the window rectangle into
-    the ONE ragged kernel (:func:`fusioninfer_tpu.ops.
-    ragged_paged_attention`) on the head-major page layout.
-    """
-    from fusioninfer_tpu.ops import dispatch
-
-    _refuse_latent(cfg, "verify_step")
-    B, C = tokens.shape
-    ps = cache_cfg.page_size
-    mp = page_tables.shape[1]
-    H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    quantized = cache_cfg.quantized
-    use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
-
-    x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)  # [B, C, D]
-    offs = jnp.arange(C)[None, :]  # [1, C]
-    positions = starts[:, None] + offs  # [B, C]
-
-    live = offs < counts[:, None]  # [B, C]
-    write_page = jnp.where(
-        live,
-        jnp.take_along_axis(page_tables, positions // ps, axis=1),
-        cache_cfg.trash_page,
-    )
-    write_slot = positions % ps
-
-    # the ONE ragged kernel on the flattened window rectangle: row b's
-    # segment sits at flat offset b*C with its real count — padding
-    # columns belong to no row
-    q_begins = jnp.arange(B, dtype=jnp.int32) * C
-    walks = _ragged_walks(cfg, cache, mesh, use_kernel, B * C, page_tables,
-                          starts, q_begins, counts, kv_splits)
-
-    # portable-path mask over the gathered [mp * ps] context
-    ctx_idx = jnp.arange(mp * ps)[None, None, :]  # [1, 1, T]
-    attend = masks.attend(positions[:, :, None], ctx_idx,
-                          cfg.sliding_window)  # [B, C, T]
-
-    def body(carry, inputs):
-        x, cache = carry
-        layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
-        from fusioninfer_tpu.models.quantization import maybe_dequantize_tree
-
-        layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
-        q, k, v = qkv_proj(cfg, layer, x, positions, layer_lora, adapter_ids)
-
-        # stacked head-major cache [L, KV, n_pages, ps, Hd]; k is
-        # [B, C, KV, Hd] → in-place scatter at layer l
-        cache = _scatter_kv(cache, l, k, v, write_page, write_slot,
-                            head_axis=2)
-        ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
-
-        if use_kernel:
-            qf = q.reshape(B * C, H, Hd)
-            attn = _ragged_attn(
-                mesh, qf, cache, page_tables, starts, q_begins, counts,
-                ks_s, vs_s, layer=l, window=cfg.sliding_window,
-                coalesce=coalesce, kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(), walks=walks,
-            ).reshape(B, C, H * Hd)
-        else:
-            k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
-            k_ctx = k_cache_l[:, page_tables].reshape(KV, B, mp * ps, Hd)
-            v_ctx = v_cache_l[:, page_tables].reshape(KV, B, mp * ps, Hd)
-            if quantized:
-                k_ctx = _dequant_gather(k_ctx, ks_l, page_tables,
-                                        (KV, B, mp * ps))
-                v_ctx = _dequant_gather(v_ctx, vs_l, page_tables,
-                                        (KV, B, mp * ps))
-            group = H // KV
-            qg = q.reshape(B, C, KV, group, Hd)
-            scores = jnp.einsum(
-                "bckgd,kbtd->bkgct", qg, k_ctx
-            ).astype(jnp.float32) / jnp.sqrt(Hd)
-            scores = jnp.where(attend[:, None, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
-            attn = jnp.einsum("bkgct,kbtd->bckgd", probs, v_ctx).reshape(
-                B, C, H * Hd
-            ).astype(x.dtype)
-        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        y, stats = mlp_block(cfg, layer, x, live)
-        return (x + y, _add_moe_stats(cache, stats)), None
-
-    x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    if sel is not None:
-        idx = jnp.clip(sel.astype(jnp.int32), 0, C - 1)  # [B, W]
-        picked = jnp.take_along_axis(x, idx[:, :, None], axis=1)  # [B, W, D]
-        return cache, lm_head(cfg, params, picked)  # [B, W, V]
-    if last_only:
-        last = x[jnp.arange(B), jnp.maximum(counts - 1, 0)]  # [B, D]
-        return cache, lm_head(cfg, params, last)
-    logits = lm_head(cfg, params, x)  # [B, C, V]
-    return cache, logits
-
-
-verify_step = partial(
-    jax.jit, static_argnums=(0, 1),
-    static_argnames=("mesh", "last_only", "coalesce", "kv_splits"),
-    donate_argnums=(3,))(_window_forward_impl)
 
 
 @partial(jax.jit, static_argnums=(0, 1),

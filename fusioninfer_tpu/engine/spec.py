@@ -4,8 +4,9 @@ Model-free speculation (vLLM's ``[ngram]`` speculative method, which the
 reference only orchestrates via engine flags — SURVEY §0): the last
 ``n`` tokens of a sequence are matched against its own earlier context
 (prompt + generated so far); on a hit, the tokens that followed the
-match are proposed as drafts.  The engine verifies all drafts in one
-:func:`fusioninfer_tpu.engine.model_runner.verify_step` forward — decode
+match are proposed as drafts.  The engine verifies all drafts as one
+window row (``q_len = 1 + drafts``) of
+:func:`fusioninfer_tpu.engine.model_runner.fused_step` — decode
 is weight-bandwidth-bound, so scoring ``k+1`` positions costs roughly
 one decode step, and every accepted draft is a free token.  Strongest on
 extractive workloads (summarization, RAG, code edits) where the output

@@ -1,8 +1,13 @@
 """Pallas TPU kernels for the hot attention ops, with jnp oracles.
 
-* :mod:`flash_attention` — blockwise prefill/training attention.
-* :mod:`paged_attention` — paged decode attention over the KV cache.
+* :mod:`flash_attention` — blockwise whole-prompt prefill / training
+  attention.
+* :mod:`paged_attention` — the ragged paged family over the KV cache:
+  one flat token axis for decode rows, windows, chunks and suffixes,
+  three grids (coalesced, per-head, KV-split) chosen by
+  ``resolve_ragged_grid`` / ``pick_kv_splits``.
 * :mod:`mla_attention` — ragged paged attention over latent (MLA) pages.
+* :mod:`sharded` — the tensor-parallel ``shard_map`` wrappers.
 * :mod:`dispatch` — trace-time kernel/reference selection.
 """
 
@@ -23,16 +28,10 @@ from fusioninfer_tpu.ops.paged_attention import (  # noqa: F401
     KV_SPLIT_CHUNKS,
     RAGGED_BLOCK_Q,
     kvsplit_fits_vmem,
-    paged_decode_attention,
-    paged_prefill_attention,
-    paged_verify_attention,
     pick_kv_splits,
     ragged_fits_vmem,
     ragged_paged_attention,
     ragged_paged_attention_kvsplit,
     ragged_token_rows,
-    reference_paged_attention,
-    reference_paged_prefill_attention,
-    reference_paged_verify_attention,
     reference_ragged_paged_attention,
 )

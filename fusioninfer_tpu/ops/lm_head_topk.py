@@ -13,16 +13,18 @@ draw from the candidates (:func:`engine.sampler.sample_topk`); rows
 needing the full distribution — logprobs, guided masks, logit_bias,
 min_p — take the unfused path explicitly.
 
-Bit-identity with the unfused path is exact, not approximate, and rests
-on two verified properties: XLA computes a ``[D, block]`` slice matmul
-bit-identically to the same columns of the full ``[D, V]`` matmul (each
-output element is the same contraction), and ``lax.top_k`` breaks value
-ties toward the lower index — so the running merge (carry candidates
+The running top-k is exact, not approximate: ``lax.top_k`` breaks value
+ties toward the lower index, so the running merge (carry candidates
 first, block candidates after, both idx-ascending within equal values)
 selects exactly the k best under the strict total order (value desc,
 vocab index asc), the same set and order ``lax.top_k`` returns over the
-full penalized logits.  Both paths then share ONE candidate sampler, so
-a seeded stream cannot depend on which path produced it.
+blocks' penalized logits laid side by side.  Against the unfused path's
+ONE ``[D, V]`` matmul each block element is the same contraction, but a
+backend may sum it in another order at another width: jax 0.9's CPU
+backend differs by one float32 ulp of the summed terms at 128- and
+256-column blocks (33 of 3 885 elements) and by none at 250 or 4096, the
+serving width.  Both paths then share ONE candidate sampler
+(``tests/test_flash_decode.py::TestLmHeadTopk`` pins all three).
 
 The TP variant (:func:`fusioninfer_tpu.ops.sharded.lm_head_topk_tp`)
 runs this per vocab shard and merges candidates with a collective

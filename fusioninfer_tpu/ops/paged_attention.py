@@ -1,43 +1,43 @@
-"""Paged attention as Pallas TPU kernels — one ragged kernel serves all.
+"""Paged attention as Pallas TPU kernels: one ragged family.
 
 Each sequence's KV context lives in non-contiguous cache pages
-(:mod:`fusioninfer_tpu.engine.kv_cache`); these kernels stream exactly
-the live pages HBM→VMEM ahead of an online softmax (the ragged grids as
-one page stream a program column, the standalone kernels two slots a
-walk) — no materialized ``cache[page_tables]`` gather (which copies the
-whole context through HBM every step, the portable-baseline cost in
-:mod:`fusioninfer_tpu.engine.model_runner`).
+(:mod:`fusioninfer_tpu.engine.kv_cache`); the kernels stream exactly the
+live pages HBM→VMEM ahead of an online softmax, as one page stream a
+program column — no materialized ``cache[page_tables]`` gather (which
+copies the whole context through HBM every step, the portable-baseline
+cost in :mod:`fusioninfer_tpu.engine.model_runner`).
 
-The engine's entire model path routes through ONE of them:
-:func:`ragged_paged_attention`, a flat ragged-concat grid whose per-row
-``(start, q_begin, q_len)`` descriptors cover decode rows, speculative
-verify windows, budgeted prefill chunks and cache-hit suffixes with no
-per-row rectangle padding and no kernel switch between row kinds (the
-Ragged Paged Attention layout, PAPERS.md).  The earlier decode /
-suffix / verify kernels below remain as standalone primitives — bench
-baselines and compat callers.
+Every paged forward of the engine scores through
+:func:`ragged_paged_attention` or :func:`ragged_paged_attention_kvsplit`:
+a flat ragged-concat token axis whose per-row ``(start, q_begin,
+q_len)`` descriptors cover decode rows, speculative verify windows,
+budgeted prefill chunks and cache-hit suffixes with no per-row rectangle
+padding and no kernel switch between row kinds (the Ragged Paged
+Attention layout, PAPERS.md).  Three grids share one walk body
+(:func:`_ragged_walk`) and one page stream (:class:`_PageStream`);
+:func:`resolve_ragged_grid` picks among them from what it can observe
+(the VMEM footprint of the shapes and dtypes) and
+:func:`pick_kv_splits` from the cache's static context bound:
+
+* **coalesced**, grid ``(tiles,)``: one program a q tile covers every KV
+  head — one ``[KV, ps, Hd]`` copy a page, score and value dots batched
+  over KV.
+* **per-head**, grid ``(KV, tiles)``: the coalesced grid's VMEM
+  fallback — ``[ps, Hd]`` copies and one head's dots, KV× less scratch.
+* **KV-split**, grid ``(splits, tiles)``: flash-decode partials over
+  fixed virtual page chunks, combined left to right by the wrapper.
 
 Equivalent capability in the reference is vLLM's CUDA PagedAttention,
 which FusionInfer only orchestrates (SURVEY §0); here it is an in-repo
 TPU kernel.
 
-Layout: pages are **head-major** ``[KV, n_pages, page_size, Hd]``.  Two
-decode grids share the math (``dispatch.decode_coalesce`` picks; default
-coalesced):
-
-* **coalesced** (default): grid ``(B,)`` — one program per sequence
-  DMAs each page once for ALL KV heads (``k_pages.at[:, page]`` →
-  ``[KV, ps, Hd]``, slot scratch ``[2, KV, ps, Hd]``).  KV× fewer DMA
-  issues.
-* **per-head**: grid ``(B, KV)`` — the ``G = H // KV`` query heads of a
-  group attend together, one ``[ps, Hd]`` copy per (sequence, head).
-
-Head-major matters for Mosaic either way: both DMAs
-(``.at[g, page]`` and ``.at[:, page]``) slice only *leading* dims, so
-every copy is whole ``[page_size, Hd]`` tiles of the (8,128)-tiled
-memref.  The previous ``[n_pages, ps, KV, Hd]`` layout sliced the tiled
-second-to-minor dim to width 1 per head, which Mosaic rejects ("Slice
-shape along dimension 2 must be aligned to tiling (8)").
+Layout: pages are **head-major** ``[KV, n_pages, page_size, Hd]``.  That
+matters for Mosaic: both page copies (``.at[g, page]`` and
+``.at[:, page]``) slice only *leading* dims, so every copy is whole
+``[page_size, Hd]`` tiles of the (8,128)-tiled memref.  The previous
+``[n_pages, ps, KV, Hd]`` layout sliced the tiled second-to-minor dim to
+width 1 per head, which Mosaic rejects ("Slice shape along dimension 2
+must be aligned to tiling (8)").
 """
 
 from __future__ import annotations
@@ -89,8 +89,8 @@ def _page_dma(slot, layer, g, page, k_pages_ref, v_pages_ref, k_buf, v_buf,
 
 def _as_stacked(k_pages, v_pages, k_scales, v_scales, layer):
     """Normalize page operands to the layer-stacked ``[L, KV, …]`` form
-    the kernels use internally.  4-d single-layer arrays (standalone
-    callers, oracles, tests) wrap to ``L=1`` with ``layer=0`` — a free
+    the kernels use internally.  4-d single-layer arrays (oracles,
+    tests, probes) wrap to ``L=1`` with ``layer=0`` — a free
     reshape; 5-d arrays require an explicit ``layer``."""
     if k_pages.ndim == 4:
         if layer is not None:
@@ -115,8 +115,8 @@ def _split_rest(rest, quantized):
     return None, o_ref, k_buf, v_buf, None, sem
 
 
-# VMEM ceiling for the coalesced grids' double-buffered page scratch
-# plus their q/out tiles and partial blocks, as the *_fits_vmem guards
+# VMEM ceiling for the coalesced grids' page ring plus their q/out
+# tiles and partial blocks, as the *_fits_vmem guards
 # count them.  No pallas_call here sets ``vmem_limit_bytes``, so the
 # bound that matters is Mosaic's default scoped-VMEM limit: compiled
 # for a v5e target (libtpu 0.0.34), footprints of 8.5 MiB by this
@@ -130,12 +130,11 @@ _COALESCE_VMEM_SCRATCH_BUDGET = 8 * 1024 * 1024
 
 def coalesced_scratch_bytes(page_size: int, Hd: int, kv_heads: int,
                             k_dtype, v_dtype, quantized: bool,
-                            slots: int = 2) -> int:
-    """Bytes of VMEM scratch a coalesced grid allocates: ``slots`` slots
-    (two for the decode grid; the ragged grids pass their ring,
-    ``RAGGED_RING_SLOTS``) of ``[KV, ps, Hd]`` K and V page buffers
-    (+ two f32 ``[KV, 1, ps]`` scale rows per slot when the cache is
-    int8)."""
+                            slots: int) -> int:
+    """Bytes of VMEM scratch a coalesced grid allocates: ``slots`` ring
+    slots (``RAGGED_RING_SLOTS``) of ``[KV, ps, Hd]`` K and V page
+    buffers (+ two f32 ``[KV, 1, ps]`` scale rows per slot when the
+    cache is int8)."""
     per_slot = kv_heads * page_size * Hd * (
         jnp.dtype(k_dtype).itemsize + jnp.dtype(v_dtype).itemsize)
     if quantized:
@@ -143,28 +142,14 @@ def coalesced_scratch_bytes(page_size: int, Hd: int, kv_heads: int,
     return slots * per_slot
 
 
-def coalesce_fits_vmem(page_size: int, Hd: int, kv_heads: int,
-                       k_dtype, v_dtype, quantized: bool,
-                       budget: int | None = None) -> bool:
-    """True when the coalesced grid's double-buffered scratch fits the
-    conservative VMEM budget; callers fall back to the per-head grid
-    otherwise.  ``budget`` resolves at CALL time so tests (and future
-    per-generation tables) can tune the module default."""
-    if budget is None:
-        budget = _COALESCE_VMEM_SCRATCH_BUDGET
-    return coalesced_scratch_bytes(
-        page_size, Hd, kv_heads, k_dtype, v_dtype, quantized) <= budget
-
-
 def _page_specs_scratch(page_size, Hd, k_dtype, v_dtype, quantized,
-                        heads: int | None = None, slots: int = 2):
-    """(in_specs for page operands, scratch shapes) shared by ALL the
-    paged kernels — quantized adds scale operands, scale buffers, and
-    two more DMA semaphores per slot.  ``heads``: the coalesced grid
-    buffers all KV heads of a page per slot (``[slots, KV, ps, Hd]``);
-    per-head grids pass None (``[slots, ps, Hd]``).  ``slots``: 2 for
-    the double-buffered decode / suffix / verify walks; the ragged
-    grids pass their ring (``RAGGED_RING_SLOTS``)."""
+                        slots: int, heads: int | None = None):
+    """(in_specs for page operands, scratch shapes) shared by the three
+    ragged grids — quantized adds scale operands, scale buffers, and
+    two more DMA semaphores per slot.  ``slots``: the page ring
+    (``RAGGED_RING_SLOTS``).  ``heads``: the coalesced grids buffer all
+    KV heads of a page per slot (``[slots, KV, ps, Hd]``); the per-head
+    grid passes None (``[slots, ps, Hd]``)."""
     lead = () if heads is None else (heads,)
     page_specs = [pl.BlockSpec(memory_space=pl.ANY)] * (4 if quantized else 2)
     scratch = [
@@ -217,648 +202,7 @@ def _weighted_values(pexp, v, v_scale):
     )
 
 
-def _paged_kernel_coalesced(
-    # scalar prefetch
-    page_tables_ref,  # [B, mp] int32 (SMEM)
-    lengths_ref,  # [B] int32 — context length incl. the current token
-    layer_ref,  # [1] int32 — which layer of the stacked pools
-    # inputs: q_ref [1, KV, G, Hd] VMEM block; k/v pages [L, KV,
-    # n_pages, ps, Hd] in ANY; when quantized, scale refs
-    # [L, KV, n_pages, 1, ps]
-    q_ref,
-    k_pages_ref,
-    v_pages_ref,
-    *rest,
-    max_pages: int,
-    page_size: int,
-    sm_scale: float,
-    quantized: bool,
-    window: int | None,
-):
-    """Decode attention, grid ``(B,)``: ONE program per sequence covers
-    every KV head, so each page costs one ``[KV, ps, Hd]`` DMA instead of
-    the per-(sequence, head) kernel's KV separate ``[ps, Hd]`` copies.
-    The grid kernel's page loop is DMA-issue-bound at decode shapes (the
-    per-page matmuls are tiny); issuing 1/KV as many, KV× larger copies
-    amortizes that.  MXU cost is unchanged — the per-head ``[G, ps]``
-    score dots pad to the same 8×128 tile either way."""
-    scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
-        rest, quantized)
-    ks_buf, vs_buf = scale_bufs if quantized else (None, None)
-    b = pl.program_id(0)
-    length = lengths_ref[b]
-    n_used = pl.cdiv(length, page_size)
-    first = (jnp.maximum(length - window, 0) // page_size
-             if window is not None else 0)
-
-    def dma(slot, p):
-        # g = slice(None): one copy covers every KV head of the page
-        return _page_dma(slot, layer_ref[0], slice(None),
-                         page_tables_ref[b, p],
-                         k_pages_ref, v_pages_ref, k_buf, v_buf, sem,
-                         scale_refs, scale_bufs)
-
-    @pl.when(n_used > 0)
-    def _start_first():
-        for c in dma(first % 2, first):
-            c.start()
-
-    KV, G, Hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
-    R = KV * G
-    q = q_ref[0].astype(jnp.float32) * sm_scale  # [KV, G, Hd]
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = p % 2
-
-        @pl.when(p + 1 < n_used)
-        def _prefetch_next():
-            for c in dma((p + 1) % 2, p + 1):
-                c.start()
-
-        for c in dma(slot, p):
-            c.wait()
-        s = jnp.concatenate(
-            [_scores(q[g], k_buf[slot, g],
-                     ks_buf[slot, g] if quantized else None)
-             for g in range(KV)], axis=0)  # [R, ps]
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
-        )
-        s = jnp.where(attend(length - 1, pos, window), s, NEG_INF)
-
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(pexp, axis=1, keepdims=True)
-        pv = jnp.concatenate(
-            [_weighted_values(pexp[g * G:(g + 1) * G], v_buf[slot, g],
-                              vs_buf[slot, g] if quantized else None)
-             for g in range(KV)], axis=0)  # [R, Hd]
-        return m_new, l_new, acc * alpha + pv
-
-    m0 = jnp.full((R, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((R, 1), jnp.float32)
-    a0 = jnp.zeros((R, Hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first, n_used, body, (m0, l0, a0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(
-        o_ref.dtype).reshape(KV, G, Hd)
-
-
-def _paged_kernel(
-    # scalar prefetch
-    page_tables_ref,  # [B, mp] int32 (SMEM)
-    lengths_ref,  # [B] int32 — context length incl. the current token
-    layer_ref,  # [1] int32 — which layer of the stacked pools
-    # inputs: q_ref [1, 1, G, Hd] VMEM block; k/v pages [L, KV, n_pages,
-    # ps, Hd] in ANY; when quantized, k/v scale refs [L, KV, n_pages, 1,
-    # ps]; outputs+scratch via *rest (layout depends on `quantized`)
-    q_ref,
-    k_pages_ref,
-    v_pages_ref,
-    *rest,
-    max_pages: int,
-    page_size: int,
-    sm_scale: float,
-    quantized: bool,
-    window: int | None,
-):
-    scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
-        rest, quantized)
-    ks_buf, vs_buf = scale_bufs if quantized else (None, None)
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    length = lengths_ref[b]
-    n_used = pl.cdiv(length, page_size)  # live pages for this sequence
-    # sliding window: the single query (position length-1) attends only
-    # to positions >= length - window, so earlier pages are never read
-    first = (jnp.maximum(length - window, 0) // page_size
-             if window is not None else 0)
-
-    def dma(slot, p):
-        return _page_dma(slot, layer_ref[0], g, page_tables_ref[b, p],
-                         k_pages_ref, v_pages_ref, k_buf, v_buf, sem,
-                         scale_refs, scale_bufs)
-
-    @pl.when(n_used > 0)
-    def _start_first():
-        for c in dma(first % 2, first):
-            c.start()
-
-    G, Hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # [G, Hd]
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = p % 2
-
-        @pl.when(p + 1 < n_used)
-        def _prefetch_next():
-            for c in dma((p + 1) % 2, p + 1):
-                c.start()
-
-        for c in dma(slot, p):
-            c.wait()
-        k = k_buf[slot]  # [ps, Hd]
-        v = v_buf[slot]
-        ks = ks_buf[slot] if quantized else None  # [1, ps]
-        vs = vs_buf[slot] if quantized else None
-
-        s = _scores(q, k, ks)  # [G, ps]
-        pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (G, page_size), 1
-        )
-        s = jnp.where(attend(length - 1, pos, window), s, NEG_INF)
-
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_new = acc * alpha + _weighted_values(pexp, v, vs)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((G, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((G, 1), jnp.float32)
-    a0 = jnp.zeros((G, Hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first, n_used, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("sm_scale", "interpret", "window", "coalesce")
-)
-def paged_decode_attention(
-    q: jax.Array,  # [B, H, Hd] — one query token per sequence
-    k_pages: jax.Array,  # [KV, n_pages, ps, Hd] or stacked [L, KV, …]
-    v_pages: jax.Array,
-    page_tables: jax.Array,  # [B, max_pages] int32
-    lengths: jax.Array,  # [B] int32, context length incl. current token
-    k_scales: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] (int8)
-    v_scales: jax.Array | None = None,
-    *,
-    sm_scale: float | None = None,
-    interpret: bool = False,
-    window: int | None = None,
-    coalesce: bool | None = None,
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Batched one-token attention over paged KV → [B, H·Hd].
-
-    Inactive batch slots should pass ``lengths = 0`` (output is zeros).
-    With int8 pages, pass the per-(page, token) f32 scale arrays — the
-    kernel streams them alongside the pages and folds dequantization
-    into the score/probability matrices.  ``window``: Mistral-style
-    sliding window — out-of-window pages are skipped, not just masked.
-    ``coalesce``: one program per sequence with one [KV, ps, Hd] DMA per
-    page (KV× fewer DMA issues) vs the per-(sequence, head) grid; both
-    compute identical math per row.  ``None`` defers to
-    :func:`fusioninfer_tpu.ops.dispatch.decode_coalesce`.
-    ``layer`` + 5-d pages: read layer ``layer`` of the model's FULL
-    stacked cache in place — the layer-scan carries one donated pool and
-    no per-layer slice is ever materialized (the in-place-cache design,
-    round 5).
-    """
-    B, H, Hd = q.shape
-    k_pages, v_pages, k_scales, v_scales, layer_arr = _as_stacked(
-        k_pages, v_pages, k_scales, v_scales, layer)
-    KV, _, page_size, _ = k_pages.shape[1:]
-    G = H // KV
-    max_pages = page_tables.shape[1]
-    sm_scale = sm_scale if sm_scale is not None else Hd ** -0.5
-    quantized = k_scales is not None
-    if coalesce is None:
-        from fusioninfer_tpu.ops import dispatch
-
-        coalesce = dispatch.decode_coalesce()
-    if coalesce and not coalesce_fits_vmem(
-            page_size, Hd, KV, k_pages.dtype, v_pages.dtype, quantized):
-        # the coalesced double-buffered scratch would blow the VMEM
-        # budget at this (KV, page_size, Hd): take the per-head grid
-        # (KV× smaller slots) instead of failing Mosaic allocation
-        coalesce = False
-
-    qg = q.reshape(B, KV, G, Hd)
-
-    if coalesce:
-        page_specs, scratch = _page_specs_scratch(
-            page_size, Hd, k_pages.dtype, v_pages.dtype, quantized,
-            heads=KV)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, KV, G, Hd), lambda b, *_: (b, 0, 0, 0),
-                    memory_space=pltpu.VMEM,
-                ),
-                *page_specs,
-            ],
-            out_specs=pl.BlockSpec(
-                (1, KV, G, Hd), lambda b, *_: (b, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=scratch,
-        )
-        body = _paged_kernel_coalesced
-    else:
-        page_specs, scratch = _page_specs_scratch(
-            page_size, Hd, k_pages.dtype, v_pages.dtype, quantized)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, KV),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, G, Hd), lambda b, g, *_: (b, g, 0, 0),
-                    memory_space=pltpu.VMEM,
-                ),
-                *page_specs,
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, Hd), lambda b, g, *_: (b, g, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            scratch_shapes=scratch,
-        )
-        body = _paged_kernel
-    kernel = functools.partial(
-        body,
-        max_pages=max_pages, page_size=page_size, sm_scale=sm_scale,
-        quantized=quantized, window=window,
-    )
-    operands = [page_tables.astype(jnp.int32), lengths.astype(jnp.int32),
-                layer_arr, qg, k_pages, v_pages]
-    if quantized:
-        operands += [k_scales, v_scales]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, Hd), q.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(B, H * Hd)
-
-
-def _suffix_kernel(
-    # scalar prefetch
-    page_row_ref,  # [mp] int32 (SMEM) — ONE sequence's page table
-    meta_ref,  # [2] int32: (start, true_len)
-    layer_ref,  # [1] int32 — which layer of the stacked pools
-    # inputs: q_ref [block_q, 1, G, Hd] VMEM block; k/v pages in ANY;
-    # when quantized, scale refs [L, KV, n_pages, 1, ps] then out/scratch
-    q_ref,
-    k_pages_ref,
-    v_pages_ref,
-    *rest,
-    block_q: int,
-    page_size: int,
-    sm_scale: float,
-    quantized: bool,
-    window: int | None,
-):
-    scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
-        rest, quantized)
-    ks_buf, vs_buf = scale_bufs if quantized else (None, None)
-    g = pl.program_id(0)
-    i = pl.program_id(1)  # q tile
-    start = meta_ref[0]
-    true_len = meta_ref[1]
-
-    # real queries in this tile and the pages their causal window covers
-    n_q_real = jnp.clip(true_len - i * block_q, 0, block_q)
-    max_pos = start + i * block_q + n_q_real - 1  # last real query's position
-    n_used = jnp.where(n_q_real > 0, pl.cdiv(max_pos + 1, page_size), 0)
-    # sliding window: the tile's FIRST query bounds the earliest page any
-    # of its rows may read (positions >= first_pos - window + 1)
-    first = (jnp.maximum(start + i * block_q - window + 1, 0) // page_size
-             if window is not None else 0)
-
-    def dma(slot, p):
-        return _page_dma(slot, layer_ref[0], g, page_row_ref[p],
-                         k_pages_ref, v_pages_ref,
-                         k_buf, v_buf, sem, scale_refs, scale_bufs)
-
-    @pl.when(n_used > 0)
-    def _start_first():
-        for c in dma(first % 2, first):
-            c.start()
-
-    G, Hd = q_ref.shape[2], q_ref.shape[3]
-    R = block_q * G  # flattened (query, group-head) rows
-    q = q_ref[:, 0].astype(jnp.float32).reshape(R, Hd) * sm_scale
-    # global position of each flattened row's query token
-    row_pos = start + i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (R, page_size), 0
-    ) // G
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = p % 2
-
-        @pl.when(p + 1 < n_used)
-        def _prefetch_next():
-            for c in dma((p + 1) % 2, p + 1):
-                c.start()
-
-        for c in dma(slot, p):
-            c.wait()
-        k = k_buf[slot]  # [ps, Hd]
-        v = v_buf[slot]
-        ks = ks_buf[slot] if quantized else None
-        vs = vs_buf[slot] if quantized else None
-
-        s = _scores(q, k, ks)  # [R, ps]
-        ctx_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (R, page_size), 1
-        )
-        s = jnp.where(attend(row_pos, ctx_pos, window), s, NEG_INF)
-
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_new = acc * alpha + _weighted_values(pexp, v, vs)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((R, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((R, 1), jnp.float32)
-    a0 = jnp.zeros((R, Hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first, n_used, body, (m0, l0, a0))
-    out = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-    o_ref[:, 0] = out.reshape(block_q, G, Hd)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("sm_scale", "block_q", "interpret", "window")
-)
-def paged_prefill_attention(
-    q: jax.Array,  # [C, H, Hd] — suffix queries, padded to bucket C
-    k_pages: jax.Array,  # [KV, n_pages, ps, Hd] or stacked [L, KV, …]
-    v_pages: jax.Array,
-    page_row: jax.Array,  # [max_pages] int32 — ONE sequence's pages
-    start: jax.Array,  # scalar int32: global position of q[0]
-    true_len: jax.Array,  # scalar int32: real (unpadded) suffix length
-    k_scales: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] (int8)
-    v_scales: jax.Array | None = None,
-    *,
-    sm_scale: float | None = None,
-    block_q: int = 128,
-    interpret: bool = False,
-    window: int | None = None,
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Suffix-prefill attention over paged KV → [C, H·Hd].
-
-    The prefix-cache *hit* path: query token ``i`` sits at global
-    position ``start + i`` and attends causally over the sequence's
-    pages (prefix pages written by earlier requests + this suffix's own
-    pages, already scattered by the caller).  Same double-buffered
-    page-streaming structure as the decode kernel, extended to a query
-    tile per program; the causal wavefront bounds each tile's page loop
-    (``n_used = cdiv(tile's last real position + 1, ps)``), so early
-    tiles never touch late pages.  Rows at/past ``true_len`` are padding;
-    their output is unspecified and must be discarded by the caller.
-    """
-    C, H, Hd = q.shape
-    k_pages, v_pages, k_scales, v_scales, layer_arr = _as_stacked(
-        k_pages, v_pages, k_scales, v_scales, layer)
-    KV, _, page_size, _ = k_pages.shape[1:]
-    G = H // KV
-    sm_scale = sm_scale if sm_scale is not None else Hd ** -0.5
-    block_q = min(block_q, C)
-    if C % block_q:
-        raise ValueError(f"suffix bucket {C} not divisible by block_q {block_q}")
-    n_qt = C // block_q
-    quantized = k_scales is not None
-
-    qg = q.reshape(C, KV, G, Hd)
-    meta = jnp.stack([jnp.int32(start), jnp.int32(true_len)])
-
-    page_specs, scratch = _page_specs_scratch(
-        page_size, Hd, k_pages.dtype, v_pages.dtype, quantized)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(KV, n_qt),
-        in_specs=[
-            pl.BlockSpec(
-                (block_q, 1, G, Hd), lambda g, i, *_: (i, g, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            *page_specs,
-        ],
-        out_specs=pl.BlockSpec(
-            (block_q, 1, G, Hd), lambda g, i, *_: (i, g, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=scratch,
-    )
-    kernel = functools.partial(
-        _suffix_kernel,
-        block_q=block_q, page_size=page_size, sm_scale=sm_scale,
-        quantized=quantized, window=window,
-    )
-    operands = [page_row.astype(jnp.int32), meta, layer_arr, qg,
-                k_pages, v_pages]
-    if quantized:
-        operands += [k_scales, v_scales]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, KV, G, Hd), q.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(C, H * Hd)
-
-
-def _verify_kernel(
-    # scalar prefetch
-    page_tables_ref,  # [B, mp] int32 (SMEM)
-    starts_ref,  # [B] int32 — global position of each sequence's query 0
-    counts_ref,  # [B] int32 — real queries this step (0 = inactive slot)
-    layer_ref,  # [1] int32 — which layer of the stacked pools
-    # inputs: q_ref [C, 1, G, Hd] VMEM block; k/v pages in ANY; when
-    # quantized, scale refs [L, KV, n_pages, 1, ps] then out/scratch
-    q_ref,
-    k_pages_ref,
-    v_pages_ref,
-    *rest,
-    n_q: int,  # q-TILE length (block) — `sliding` is the sliding window
-    page_size: int,
-    sm_scale: float,
-    quantized: bool,
-    sliding: int | None,
-):
-    scale_refs, o_ref, k_buf, v_buf, scale_bufs, sem = _split_rest(
-        rest, quantized)
-    ks_buf, vs_buf = scale_bufs if quantized else (None, None)
-    b = pl.program_id(0)
-    g = pl.program_id(1)
-    i = pl.program_id(2)  # q tile within the window
-    start = starts_ref[b]
-    count = counts_ref[b]
-    # real queries in THIS tile, and the pages their causal span covers
-    n_q_real = jnp.clip(count - i * n_q, 0, n_q)
-    max_pos = start + i * n_q + n_q_real - 1
-    n_used = jnp.where(n_q_real > 0, pl.cdiv(max_pos + 1, page_size), 0)
-    # sliding window: the tile's FIRST query bounds the earliest page
-    first = (jnp.maximum(start + i * n_q - sliding + 1, 0) // page_size
-             if sliding is not None else 0)
-
-    def dma(slot, p):
-        return _page_dma(slot, layer_ref[0], g, page_tables_ref[b, p],
-                         k_pages_ref, v_pages_ref, k_buf, v_buf, sem,
-                         scale_refs, scale_bufs)
-
-    @pl.when(n_used > 0)
-    def _start_first():
-        for c in dma(first % 2, first):
-            c.start()
-
-    G, Hd = q_ref.shape[2], q_ref.shape[3]
-    R = n_q * G
-    q = q_ref[:, 0].astype(jnp.float32).reshape(R, Hd) * sm_scale
-    row_pos = start + i * n_q + jax.lax.broadcasted_iota(
-        jnp.int32, (R, page_size), 0
-    ) // G
-
-    def body(p, carry):
-        m, l, acc = carry
-        slot = p % 2
-
-        @pl.when(p + 1 < n_used)
-        def _prefetch_next():
-            for c in dma((p + 1) % 2, p + 1):
-                c.start()
-
-        for c in dma(slot, p):
-            c.wait()
-        k = k_buf[slot]
-        v = v_buf[slot]
-        ks = ks_buf[slot] if quantized else None
-        vs = vs_buf[slot] if quantized else None
-
-        s = _scores(q, k, ks)  # [R, ps]
-        ctx_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (R, page_size), 1
-        )
-        s = jnp.where(attend(row_pos, ctx_pos, sliding), s, NEG_INF)
-
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m, m_cur)
-        pexp = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(pexp, axis=1, keepdims=True)
-        acc_new = acc * alpha + _weighted_values(pexp, v, vs)
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((R, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((R, 1), jnp.float32)
-    a0 = jnp.zeros((R, Hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(first, n_used, body, (m0, l0, a0))
-    out = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
-    o_ref[:, 0] = out.reshape(n_q, G, Hd)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("sm_scale", "interpret", "window", "block_q")
-)
-def paged_verify_attention(
-    q: jax.Array,  # [B, C, H, Hd] — C-token query window per sequence
-    k_pages: jax.Array,  # [KV, n_pages, ps, Hd] or stacked [L, KV, …]
-    v_pages: jax.Array,
-    page_tables: jax.Array,  # [B, max_pages] int32
-    starts: jax.Array,  # [B] int32 — global position of q[:, 0]
-    counts: jax.Array,  # [B] int32 — real window length (0 = inactive)
-    k_scales: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] (int8)
-    v_scales: jax.Array | None = None,
-    *,
-    sm_scale: float | None = None,
-    interpret: bool = False,
-    window: int | None = None,
-    block_q: int = 128,
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Batched multi-query paged attention → [B, C, H·Hd].
-
-    The general ragged middle ground between the single-query decode
-    kernel and the single-sequence suffix kernel: every sequence attends
-    a window of up to C queries at per-sequence positions
-    ``starts[b] + i`` over its own pages, causally; windows longer than
-    ``block_q`` tile over the q axis with the causal wavefront bounding
-    each tile's page loop.  Serves BOTH speculative verification (small
-    C) and batched suffix prefill (C up to a bucket).  Rows at/past
-    ``counts[b]`` are padding with unspecified output; ``counts[b] = 0``
-    marks an inactive slot (output zeros).  Equivalent capability in the
-    reference stack is vLLM's multi-query scorer / ragged attention
-    (delegated, SURVEY §0); here it is an in-repo TPU kernel sharing the
-    decode kernel's head-major page layout.
-    """
-    B, C, H, Hd = q.shape
-    k_pages, v_pages, k_scales, v_scales, layer_arr = _as_stacked(
-        k_pages, v_pages, k_scales, v_scales, layer)
-    KV, _, page_size, _ = k_pages.shape[1:]
-    G = H // KV
-    sm_scale = sm_scale if sm_scale is not None else Hd ** -0.5
-    quantized = k_scales is not None
-    block_q = min(block_q, C)
-    if C % block_q:
-        raise ValueError(f"window {C} not divisible by block_q {block_q}")
-    n_qt = C // block_q
-
-    qg = q.reshape(B * C, KV, G, Hd)
-
-    page_specs, scratch = _page_specs_scratch(
-        page_size, Hd, k_pages.dtype, v_pages.dtype, quantized)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, KV, n_qt),
-        in_specs=[
-            pl.BlockSpec(
-                (block_q, 1, G, Hd),
-                lambda b, g, i, *_, n=n_qt: (b * n + i, g, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            *page_specs,
-        ],
-        out_specs=pl.BlockSpec(
-            (block_q, 1, G, Hd),
-            lambda b, g, i, *_, n=n_qt: (b * n + i, g, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        scratch_shapes=scratch,
-    )
-    kernel = functools.partial(
-        _verify_kernel,
-        n_q=block_q, page_size=page_size, sm_scale=sm_scale,
-        quantized=quantized, sliding=window,
-    )
-    operands = [page_tables.astype(jnp.int32), starts.astype(jnp.int32),
-                counts.astype(jnp.int32), layer_arr, qg, k_pages, v_pages]
-    if quantized:
-        operands += [k_scales, v_scales]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * C, KV, G, Hd), q.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out.reshape(B, C, H * Hd)
-
-
-# -- the one true ragged kernel ---------------------------------------
-#
-# ``ragged_paged_attention`` serves decode rows (q_len=1), speculative
-# verify windows (q_len=1+drafts), budgeted prefill chunks
-# (q_len=chunk) and cache-hit suffixes from ONE grid over a flat
-# ragged-concat token axis — no per-row rectangle padding and no
-# kernel switch between row kinds (the Ragged Paged Attention shape,
-# PAPERS.md).  The decode/verify/suffix kernels above remain as
-# standalone primitives (bench baselines, compat callers); the engine's
-# model path routes everything here.
+# -- the ragged grids --------------------------------------------------
 
 # q-tile length over the FLAT token axis.  Per (tile, row) the kernel
 # scores all block_q tokens of the tile against the row's pages and
@@ -866,7 +210,7 @@ def paged_verify_attention(
 # tile is bounded by block_q; larger tiles amortize the page loop for
 # long chunk rows.  8 = one f32 sublane tile: the decode-heavy default.
 # Static per process — per-row results are independent of tile
-# composition (see _ragged_row below), so one value per process keeps
+# composition (see _ragged_walk below), so one value per process keeps
 # split and fused dispatches bit-identical.
 RAGGED_BLOCK_Q = 8
 
@@ -878,8 +222,8 @@ def ragged_fits_vmem(block_q: int, page_size: int, Hd: int, kv_heads: int,
     ring [RAGGED_RING_SLOTS, KV, ps, Hd] PLUS the q and out
     tiles [block_q, KV, G, Hd] — fits the conservative budget; callers
     fall back to the per-head grid (page scratch KV× smaller, tiles
-    per-head) otherwise.  Same contract as :func:`coalesce_fits_vmem`,
-    extended with the tile term the flat-q layout adds."""
+    per-head) otherwise.  ``budget`` resolves at CALL time so tests
+    (and future per-generation tables) can tune the module default."""
     if budget is None:
         budget = _COALESCE_VMEM_SCRATCH_BUDGET
     pages = coalesced_scratch_bytes(page_size, Hd, kv_heads,
@@ -1859,10 +1203,13 @@ def reference_ragged_paged_attention(q, k_pages, v_pages, page_tables,
     return out.reshape(T, H * Hd).astype(q.dtype)
 
 
-def reference_paged_verify_attention(q, k_pages, v_pages, page_tables,
+def reference_window_rows_attention(q, k_pages, v_pages, page_tables,
                                      starts, counts, window=None):
-    """Gathered-context jnp oracle for the verify window.  Padding rows
-    (``i >= counts[b]``) and inactive slots are zeroed."""
+    """Gathered-context jnp oracle for window rows as a ``[B, C]``
+    rectangle (row ``b``: ``counts[b]`` tokens from ``starts[b]``) — the
+    tests' second oracle for speculative windows, written without the
+    flat token axis.  Padding rows (``i >= counts[b]``) and inactive
+    slots are zeroed."""
     B, C, H, Hd = q.shape
     KV, _, ps, _ = k_pages.shape
     G = H // KV
@@ -1883,11 +1230,13 @@ def reference_paged_verify_attention(q, k_pages, v_pages, page_tables,
     return out.reshape(B, C, H * Hd).astype(q.dtype)
 
 
-def reference_paged_prefill_attention(q, k_pages, v_pages, page_row, start,
+def reference_chunk_row_attention(q, k_pages, v_pages, page_row, start,
                                       true_len, window=None):
-    """Gathered-context jnp oracle for the suffix path (same math as
-    ``prefill_suffix``'s portable branch).  Padding rows are zeroed for
-    deterministic comparison."""
+    """Gathered-context jnp oracle for ONE chunk row (a cache-hit
+    suffix or a chunk from the middle of a prompt: ``true_len`` tokens
+    from position ``start`` over ``page_row``) — the tests' second
+    oracle for chunk rows.  Padding rows are zeroed for deterministic
+    comparison."""
     C, H, Hd = q.shape
     KV, _, ps, _ = k_pages.shape
     G = H // KV
@@ -1907,9 +1256,11 @@ def reference_paged_prefill_attention(q, k_pages, v_pages, page_row, start,
     return out.reshape(C, H * Hd).astype(q.dtype)
 
 
-def reference_paged_attention(q, k_pages, v_pages, page_tables, lengths,
+def reference_decode_rows_attention(q, k_pages, v_pages, page_tables, lengths,
                               window=None):
-    """Gather-based jnp oracle (same math as the engine's portable path)."""
+    """Gather-based jnp oracle for decode rows (one token a sequence at
+    position ``lengths - 1``; same math as the engine's portable path) —
+    the tests' second oracle for decode rows."""
     B, H, Hd = q.shape
     KV, _, ps, _ = k_pages.shape
     G = H // KV

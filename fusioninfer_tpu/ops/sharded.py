@@ -33,9 +33,6 @@ from jax.sharding import Mesh
 from fusioninfer_tpu.ops.flash_attention import flash_attention
 from fusioninfer_tpu.ops.paged_attention import (
     _as_stacked,
-    paged_decode_attention,
-    paged_prefill_attention,
-    paged_verify_attention,
     ragged_paged_attention,
     ragged_paged_attention_kvsplit,
 )
@@ -48,9 +45,8 @@ _RULES = default_rules()
 _KV_SPEC = _sharding.kv_cache_spec(_RULES)
 _SCALE_SPEC = _sharding.kv_scale_spec(_RULES)
 # replicated descriptor shapes (each shard sees every row/token id)
-_ROW_SPEC = _RULES.spec("rows")  # [R] starts / lengths / counts
+_ROW_SPEC = _RULES.spec("rows")  # [R] row starts / begins / lengths
 _TABLE_SPEC = _RULES.spec("rows", "pages")  # [R, mp] page tables
-_SCALAR_SPEC = _RULES.spec()  # scalar operands
 
 
 def tp_compatible(mesh: Mesh, n_heads: int, n_kv_heads: int) -> bool:
@@ -88,53 +84,6 @@ def flash_attention_tp(
         check_vma=False,
     )
     return fn(q, k, v)
-
-
-def paged_decode_attention_tp(
-    mesh: Mesh,
-    q: jax.Array,  # [B, H, Hd] — H sharded over tp
-    k_pages: jax.Array,  # [(L,) KV, n_pages, ps, Hd] — KV sharded over tp
-    v_pages: jax.Array,
-    page_tables: jax.Array,  # [B, mp] replicated
-    lengths: jax.Array,  # [B] replicated
-    k_scale: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] — int8
-    v_scale: jax.Array | None = None,
-    *,
-    interpret: bool = False,
-    window: int | None = None,
-    coalesce: bool | None = None,  # resolved by the engine per call
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Per-shard paged decode attention → [B, H·Hd] sharded on features."""
-    k_pages, v_pages, k_scale, v_scale, layer = _as_stacked(
-        k_pages, v_pages, k_scale, v_scale, layer)
-    in_specs = [
-        _RULES.spec("rows", "heads", "head_dim"),
-        _KV_SPEC,
-        _KV_SPEC,
-        _TABLE_SPEC,
-        _ROW_SPEC,
-        _ROW_SPEC,
-    ]
-    args = [q, k_pages, v_pages, page_tables, lengths, layer]
-    if k_scale is not None:
-        in_specs += [_SCALE_SPEC, _SCALE_SPEC]
-        args += [k_scale, v_scale]
-
-    def run(q, kp, vp, pt, ln, l, *scales):
-        ks, vs = scales if scales else (None, None)
-        return paged_decode_attention(q, kp, vp, pt, ln, ks, vs,
-                                      interpret=interpret, window=window,
-                                      coalesce=coalesce, layer=l)
-
-    fn = shard_map(
-        run,
-        mesh=mesh,
-        in_specs=tuple(in_specs),
-        out_specs=_RULES.spec("rows", "heads"),
-        check_vma=False,
-    )
-    return fn(*args)
 
 
 def ragged_paged_attention_tp(
@@ -265,101 +214,3 @@ def lm_head_topk_tp(
     )
     return fn(h, head, token_counts, output_counts, presence, frequency,
               repetition, early, suppress)
-
-
-def paged_prefill_attention_tp(
-    mesh: Mesh,
-    q: jax.Array,  # [C, H, Hd] — H sharded over tp
-    k_pages: jax.Array,  # [(L,) KV, n_pages, ps, Hd] — KV sharded over tp
-    v_pages: jax.Array,
-    page_row: jax.Array,  # [mp] replicated
-    start: jax.Array,  # scalar replicated
-    true_len: jax.Array,  # scalar replicated
-    k_scale: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] — int8
-    v_scale: jax.Array | None = None,
-    *,
-    interpret: bool = False,
-    window: int | None = None,
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Per-shard suffix-prefill attention → [C, H·Hd] sharded on features."""
-    k_pages, v_pages, k_scale, v_scale, layer = _as_stacked(
-        k_pages, v_pages, k_scale, v_scale, layer)
-    in_specs = [
-        _RULES.spec("tokens", "heads", "head_dim"),
-        _KV_SPEC,
-        _KV_SPEC,
-        _RULES.spec("pages"),
-        _SCALAR_SPEC,
-        _SCALAR_SPEC,
-        _ROW_SPEC,
-    ]
-    args = [q, k_pages, v_pages, page_row, start, true_len, layer]
-    if k_scale is not None:
-        in_specs += [_SCALE_SPEC, _SCALE_SPEC]
-        args += [k_scale, v_scale]
-
-    def run(q, kp, vp, row, st, tl, l, *scales):
-        ks, vs = scales if scales else (None, None)
-        return paged_prefill_attention(q, kp, vp, row, st, tl, ks, vs,
-                                       interpret=interpret, window=window,
-                                       layer=l)
-
-    fn = shard_map(
-        run,
-        mesh=mesh,
-        in_specs=tuple(in_specs),
-        out_specs=_RULES.spec("tokens", "heads"),
-        check_vma=False,
-    )
-    return fn(*args)
-
-
-def paged_verify_attention_tp(
-    mesh: Mesh,
-    q: jax.Array,  # [B, C, H, Hd] — H sharded over tp
-    k_pages: jax.Array,  # [(L,) KV, n_pages, ps, Hd] — KV sharded over tp
-    v_pages: jax.Array,
-    page_tables: jax.Array,  # [B, mp] replicated
-    starts: jax.Array,  # [B] replicated
-    counts: jax.Array,  # [B] replicated
-    k_scale: jax.Array | None = None,  # [(L,) KV, n_pages, 1, ps] — int8
-    v_scale: jax.Array | None = None,
-    *,
-    interpret: bool = False,
-    window: int | None = None,
-    layer: jax.Array | int | None = None,
-) -> jax.Array:
-    """Per-shard verify-window attention → [B, C, H·Hd] sharded on features."""
-    k_pages, v_pages, k_scale, v_scale, layer = _as_stacked(
-        k_pages, v_pages, k_scale, v_scale, layer)
-    in_specs = [
-        # the C verify-window axis is replicated by construction (None),
-        # like the rows: only the head axes shard on the tp-only mesh
-        _RULES.spec("rows", None, "heads", "head_dim"),
-        _KV_SPEC,
-        _KV_SPEC,
-        _TABLE_SPEC,
-        _ROW_SPEC,
-        _ROW_SPEC,
-        _ROW_SPEC,
-    ]
-    args = [q, k_pages, v_pages, page_tables, starts, counts, layer]
-    if k_scale is not None:
-        in_specs += [_SCALE_SPEC, _SCALE_SPEC]
-        args += [k_scale, v_scale]
-
-    def run(q, kp, vp, pt, st, ct, l, *scales):
-        ks, vs = scales if scales else (None, None)
-        return paged_verify_attention(q, kp, vp, pt, st, ct, ks, vs,
-                                      interpret=interpret, window=window,
-                                      layer=l)
-
-    fn = shard_map(
-        run,
-        mesh=mesh,
-        in_specs=tuple(in_specs),
-        out_specs=_RULES.spec("rows", None, "heads"),
-        check_vma=False,
-    )
-    return fn(*args)

@@ -44,7 +44,7 @@ from __future__ import annotations
 # signature family (a shape that skipped its bucket, a weak-type flip,
 # an env knob resolved at trace time) trips the gate.  Measured on this
 # round's fast tier: kernels 32, sampler 26, fused 26, prefill 17,
-# kvsplit 12, engine-helpers 7, decode/verify 0 — the flash-decode PR
+# kvsplit 12, engine-helpers 7, decode 0 — the flash-decode PR
 # grew fused (the decode_hidden fused-sampling variants beside the
 # logits variants), sampler (sample_topk + lm_head_topk + the "topk"
 # sample mode) and added the kvsplit family (the split-count axis of
@@ -54,7 +54,6 @@ from __future__ import annotations
 FAMILY_BUDGETS: dict[str, int] = {
     "decode": 16,
     "prefill": 24,
-    "verify": 12,
     "fused": 36,
     "sampler": 40,
     "engine-helpers": 12,
@@ -76,13 +75,6 @@ ENTRY_POINTS: dict[str, dict] = {
         "static_argnames": ("mesh",),
         "runtime": "fusioninfer_tpu.engine.model_runner:prefill",
     },
-    "fusioninfer_tpu/engine/model_runner.py::prefill_suffix": {
-        "kind": "jit",
-        "family": "prefill",
-        "static_argnums": (0, 1),
-        "static_argnames": ("mesh", "coalesce", "kv_splits"),
-        "runtime": "fusioninfer_tpu.engine.model_runner:prefill_suffix",
-    },
     "fusioninfer_tpu/engine/model_runner.py::decode_step": {
         "kind": "jit",
         "family": "decode",
@@ -98,14 +90,6 @@ ENTRY_POINTS: dict[str, dict] = {
         "static_argnames": ("mesh", "n_steps", "sample_mode", "coalesce",
                             "kv_splits"),
         "runtime": "fusioninfer_tpu.engine.model_runner:decode_burst",
-    },
-    "fusioninfer_tpu/engine/model_runner.py::verify_step": {
-        "kind": "jit",
-        "family": "verify",
-        "impl": "_window_forward_impl",
-        "static_argnums": (0, 1),
-        "static_argnames": ("mesh", "last_only", "coalesce", "kv_splits"),
-        "runtime": "fusioninfer_tpu.engine.model_runner:verify_step",
     },
     "fusioninfer_tpu/engine/model_runner.py::fused_step": {
         "kind": "jit",
@@ -216,30 +200,6 @@ ENTRY_POINTS: dict[str, dict] = {
         "runtime": "fusioninfer_tpu.models.transformer:embed_sequences",
     },
     # -- ops/: the Pallas kernels ---------------------------------------
-    "fusioninfer_tpu/ops/paged_attention.py::paged_decode_attention": {
-        "kind": "jit",
-        "family": "kernels",
-        "static_argnums": (),
-        "static_argnames": ("sm_scale", "interpret", "window", "coalesce"),
-        "runtime": "fusioninfer_tpu.ops.paged_attention:"
-                   "paged_decode_attention",
-    },
-    "fusioninfer_tpu/ops/paged_attention.py::paged_prefill_attention": {
-        "kind": "jit",
-        "family": "kernels",
-        "static_argnums": (),
-        "static_argnames": ("sm_scale", "block_q", "interpret", "window"),
-        "runtime": "fusioninfer_tpu.ops.paged_attention:"
-                   "paged_prefill_attention",
-    },
-    "fusioninfer_tpu/ops/paged_attention.py::paged_verify_attention": {
-        "kind": "jit",
-        "family": "kernels",
-        "static_argnums": (),
-        "static_argnames": ("sm_scale", "interpret", "window", "block_q"),
-        "runtime": "fusioninfer_tpu.ops.paged_attention:"
-                   "paged_verify_attention",
-    },
     "fusioninfer_tpu/ops/paged_attention.py::ragged_paged_attention": {
         "kind": "jit",
         "family": "kernels",
@@ -305,11 +265,6 @@ ENTRY_POINTS: dict[str, dict] = {
         "family": "kernels",
         "runtime": None,
     },
-    "fusioninfer_tpu/ops/sharded.py::paged_decode_attention_tp": {
-        "kind": "shard_map",
-        "family": "kernels",
-        "runtime": None,
-    },
     "fusioninfer_tpu/ops/sharded.py::ragged_paged_attention_tp": {
         "kind": "shard_map",
         "family": "kernels",
@@ -318,16 +273,6 @@ ENTRY_POINTS: dict[str, dict] = {
     "fusioninfer_tpu/ops/sharded.py::lm_head_topk_tp": {
         "kind": "shard_map",
         "family": "sampler",
-        "runtime": None,
-    },
-    "fusioninfer_tpu/ops/sharded.py::paged_prefill_attention_tp": {
-        "kind": "shard_map",
-        "family": "kernels",
-        "runtime": None,
-    },
-    "fusioninfer_tpu/ops/sharded.py::paged_verify_attention_tp": {
-        "kind": "shard_map",
-        "family": "kernels",
         "runtime": None,
     },
     # -- parallel/: factory-built jits (one cache per factory call) -----

@@ -42,7 +42,7 @@ class TestRaggedDecode:
 
 class TestStratifiedLensGuard:
     def test_batch_one_does_not_divide_by_zero(self):
-        """The long-context stratified-lengths divisor (ADVICE r5): a
+        """The long-context stratified-lengths divisor: a
         batch == 1 TPU leg must produce a valid single-length list —
         exercised through the bench helper main() actually calls."""
         assert bench.stratified_lens(1, 128 * 16, 200) == [256]
@@ -62,8 +62,7 @@ class TestBenchRecordChecker:
         return {"kernel_microbench": {
             "ragged": {"calls_per_s": 10.0, "rel_iqr": 0.01},
             "gather": {"calls_per_s": 5.0, "rel_iqr": 0.01},
-            "padded_rect": {"calls_per_s": 5.0, "rel_iqr": 0.01},
-            "ragged_vs_gather": 2.0, "ragged_vs_padded": 2.0,
+            "ragged_vs_gather": 2.0,
             "mfu_box": 0.3,
             "longctx": {
                 "kvsplit_vs_singlewalk": 2.1,
@@ -152,7 +151,7 @@ class TestBenchRecordChecker:
         assert any("scheduler.weight_passes" in p for p in problems)
 
     def test_missing_kernel_microbench_flagged(self):
-        """The ragged-kernel leg (r06): dispersion + both ratio fields
+        """The ragged-kernel leg (r06): dispersion + the ratio field
         + mfu_box must land in every record."""
         from tools.check_bench_record import check_record
 
@@ -160,11 +159,11 @@ class TestBenchRecordChecker:
         del rec["kernel_microbench"]
         assert any("kernel_microbench" in p for p in check_record(rec))
         rec = self._good()
-        del rec["kernel_microbench"]["ragged_vs_padded"]
+        del rec["kernel_microbench"]["ragged_vs_gather"]
         del rec["kernel_microbench"]["mfu_box"]
         del rec["kernel_microbench"]["ragged"]["rel_iqr"]
         problems = check_record(rec)
-        assert any("ragged_vs_padded" in p for p in problems)
+        assert any("ragged_vs_gather" in p for p in problems)
         assert any("mfu_box" in p for p in problems)
         assert any("rel_iqr" in p for p in problems)
 

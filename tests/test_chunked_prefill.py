@@ -12,6 +12,8 @@ keyed per-request (seed, generated-index), so scheduling must never
 change any sequence's tokens.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -370,20 +372,36 @@ class TestBatchedChunkAdvance:
 class TestChunkedWithSpec:
     def test_chunked_and_speculative_compose(self):
         """Chunked prefill + speculative decoding together stay token-
-        identical to the plain engine (greedy)."""
-        rng = np.random.default_rng(31)
-        reqs = lambda: [  # noqa: E731
-            Request(request_id="rep", prompt_tokens=[5, 6, 7] * 20,
-                    params=SamplingParams(max_tokens=10, temperature=0.0)),
-            Request(request_id="rand",
-                    prompt_tokens=rng.integers(1, CFG.vocab_size, 90).tolist(),
-                    params=SamplingParams(max_tokens=6, temperature=0.0)),
-        ]
-        plain = NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4)
-        both = NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4,
-                            prefill_chunk_size=16, speculative_k=4)
-        rng = np.random.default_rng(31)
-        a = _run_all(plain, reqs())
-        rng = np.random.default_rng(31)
-        b = _run_all(both, reqs())
-        assert a == b
+        identical to the plain engine (greedy).
+
+        Chunked windows reduce in another order than one whole-prompt
+        forward (docs/design/pd-disaggregation.md: an odd bf16 ulp in
+        the KV), and in bfloat16 this prompt's third token is a near
+        tie — log-probabilities -5.6476 and -5.6496, an eighth of a
+        bfloat16 ulp of the logits apart — which chunking ALONE flips
+        (605 -> 1750) with speculation on or off.  So the identity with
+        the plain engine is pinned in float32, where the reordering is
+        five orders of magnitude under that margin, and in bfloat16
+        what is pinned is that speculation changes no token of the
+        chunked engine's."""
+        def reqs():
+            rng = np.random.default_rng(31)
+            return [
+                Request(request_id="rep", prompt_tokens=[5, 6, 7] * 20,
+                        params=SamplingParams(max_tokens=10,
+                                              temperature=0.0)),
+                Request(request_id="rand",
+                        prompt_tokens=rng.integers(1, CFG.vocab_size,
+                                                   90).tolist(),
+                        params=SamplingParams(max_tokens=6,
+                                              temperature=0.0)),
+            ]
+
+        def run(cfg, **kw):
+            return _run_all(NativeEngine(cfg, cache_cfg=_cache_cfg(),
+                                         max_batch_size=4, **kw), reqs())
+
+        both = dict(prefill_chunk_size=16, speculative_k=4)
+        f32 = dataclasses.replace(CFG, dtype="float32")
+        assert run(f32, **both) == run(f32)
+        assert run(CFG, **both) == run(CFG, prefill_chunk_size=16)

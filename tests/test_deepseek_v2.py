@@ -649,23 +649,6 @@ def test_a_running_latent_engine_refuses_transfer_fabric_and_evacuation():
         latent_cache_refusal(tiny_cfg(), no_such_feature=True)
 
 
-@pytest.mark.parametrize("program", ["prefill_suffix", "verify_step"])
-def test_forwards_the_engine_does_not_reach_raise_for_a_latent_model(program):
-    cfg = tiny_cfg()
-    cc, cache = cache_for(cfg)
-    params = jax.eval_shape(lambda: tf.init_params(cfg, jax.random.key(0)))
-    row = jnp.zeros((cc.max_pages_per_seq,), jnp.int32)
-    with pytest.raises(NotImplementedError, match="latent"):
-        if program == "prefill_suffix":
-            mr.prefill_suffix.lower(cfg, cc, params, cache,
-                                    jnp.zeros((1, 16), jnp.int32), 0, 4, row)
-        else:
-            mr.verify_step.lower(cfg, cc, params, cache,
-                                 jnp.zeros((1, 4), jnp.int32),
-                                 jnp.zeros((1,), jnp.int32),
-                                 jnp.ones((1,), jnp.int32), row[None])
-
-
 # ---- the latent kernel compiles for the chip at published widths -----------
 
 @pytest.fixture(scope="module")

@@ -374,22 +374,65 @@ class TestLmHeadTopk:
         rep = jnp.asarray(1.0 + rng.random(N) * 0.3, jnp.float32)
         early = jnp.asarray(rng.random(N) < 0.5)
         sup = jnp.asarray(rng.random((N, V)) < 0.01)
-        logits = apply_penalties((h @ w).astype(jnp.float32), tc, oc,
-                                 pres, freq, rep)
-        logits = jnp.where(early[:, None] & sup, -jnp.inf, logits)
-        return h, w, tc, oc, pres, freq, rep, early, sup, logits
+        chain = (h, w, tc, oc, pres, freq, rep, early, sup)
+        return (*chain, self._penalized(*chain))
+
+    @staticmethod
+    def _product(h, mat, block_v=None):
+        """``h @ mat``; ``block_v``: taken in column blocks of that
+        width.  Each element is the same contraction either way, but a
+        backend may sum it in another order at another width (jax 0.9's
+        CPU backend: [5, 32] @ [32, 777] differs from its own 128- and
+        256-column blocks by one float32 step in 33 of 3 885 elements,
+        from 16-column blocks by up to three, from 250- and 4096-column
+        blocks in none)."""
+        if block_v is None:
+            return h @ mat
+        return jnp.concatenate(
+            [h @ mat[:, lo:lo + block_v]
+             for lo in range(0, mat.shape[1], block_v)], axis=1)
+
+    @classmethod
+    def _penalized(cls, h, mat, tc, oc, pres, freq, rep, early, sup,
+                   block_v=None):
+        """The unfused chain's penalized logits over ``_product``."""
+        logits = apply_penalties(
+            cls._product(h, mat, block_v).astype(jnp.float32), tc, oc, pres,
+            freq, rep)
+        return jnp.where(early[:, None] & sup, -jnp.inf, logits)
+
+    def _assert_candidates(self, got, chain, mat, block_v, k=LM_HEAD_TOPK):
+        """``got`` = the blocked kernel's ``(vals, idx)``.  The running
+        top-k is exact: bit for bit ``lax.top_k`` over the penalized
+        logits of the same column blocks, values AND indices, ties
+        included.  Against the ONE full product it picks the same
+        indices in the same order, and the two products (all that
+        differs between the paths) lie at every candidate within one
+        float32 ulp of what the dot sums, ``|h| @ |mat|`` (a sum that
+        cancels has a small value and large terms: an ulp of the value
+        is not the scale of a reordering)."""
+        h, _, *rest = chain
+        bv, bi = (np.asarray(x) for x in got)
+        fv, fi = jax.lax.top_k(
+            self._penalized(h, mat, *rest, block_v=block_v), k)
+        np.testing.assert_array_equal(bv, np.asarray(fv))
+        np.testing.assert_array_equal(bi, np.asarray(fi))
+        _, fi = jax.lax.top_k(self._penalized(h, mat, *rest), k)
+        np.testing.assert_array_equal(bi, np.asarray(fi))
+        one, blocks, terms = (
+            np.take_along_axis(np.asarray(x), bi, 1)
+            for x in (self._product(h, mat), self._product(h, mat, block_v),
+                      jnp.abs(h) @ jnp.abs(mat)))
+        assert (np.abs(blocks - one) <= np.spacing(terms)).all()
 
     @pytest.mark.parametrize("block_v", [128, 250, 4096])
     def test_blocked_candidates_match_full_topk_bits(self, block_v):
         """The tentpole's exactness claim: the vocab-blocked running
         top-k equals lax.top_k over the full penalized logits — values
         AND indices, ties included — at any block width."""
-        h, w, tc, oc, pres, freq, rep, early, sup, logits = self._chain()
-        fv, fi = jax.lax.top_k(logits, LM_HEAD_TOPK)
-        bv, bi = lm_head_topk(h, w, tc, oc, pres, freq, rep, early, sup,
-                              tied=False, block_v=block_v)
-        np.testing.assert_array_equal(np.asarray(bv), np.asarray(fv))
-        np.testing.assert_array_equal(np.asarray(bi), np.asarray(fi))
+        *chain, _ = self._chain()
+        got = lm_head_topk(*chain, tied=False, block_v=block_v)
+        self._assert_candidates(got, chain, chain[1], block_v)
 
     def test_quantized_and_tied_heads(self):
         from fusioninfer_tpu.models.quantization import (
@@ -398,7 +441,8 @@ class TestLmHeadTopk:
             quantize_rows,
         )
 
-        h, w, tc, oc, pres, freq, rep, early, sup, _ = self._chain()
+        *chain, _ = self._chain()
+        h, w, *rest = chain
         for head, tied, mat in [
             (w.T, True, w),
             (quantize_int8(w), False,
@@ -406,14 +450,8 @@ class TestLmHeadTopk:
             (quantize_rows(w.T), True,
              dequantize(quantize_rows(w.T), jnp.float32).T),
         ]:
-            logits = apply_penalties((h @ mat).astype(jnp.float32), tc,
-                                     oc, pres, freq, rep)
-            logits = jnp.where(early[:, None] & sup, -jnp.inf, logits)
-            fv, fi = jax.lax.top_k(logits, LM_HEAD_TOPK)
-            bv, bi = lm_head_topk(h, head, tc, oc, pres, freq, rep,
-                                  early, sup, tied=tied, block_v=256)
-            np.testing.assert_array_equal(np.asarray(bv), np.asarray(fv))
-            np.testing.assert_array_equal(np.asarray(bi), np.asarray(fi))
+            got = lm_head_topk(h, head, *rest, tied=tied, block_v=256)
+            self._assert_candidates(got, chain, mat, 256)
 
     def test_sample_topk_parity_with_sample(self):
         """sample(mode="topk") over full logits == sample_topk over the
@@ -468,12 +506,12 @@ class TestLmHeadTopk:
 
     def test_vocab_smaller_than_cap(self):
         """V < LM_HEAD_TOPK clamps the candidate set to V exactly like
-        full top_k would."""
-        h, w, tc, oc, pres, freq, rep, early, sup, logits = self._chain(
-            V=40)
-        fv, fi = jax.lax.top_k(logits, 40)
-        bv, bi = lm_head_topk(h, w, tc, oc, pres, freq, rep, early, sup,
-                              tied=False, block_v=16)
+        full top_k would (16-column blocks: three float32 steps from the
+        one full product on this backend, so only the blocks' own logits
+        are compared, bit for bit)."""
+        *chain, _ = self._chain(V=40)
+        fv, fi = jax.lax.top_k(self._penalized(*chain, block_v=16), 40)
+        bv, bi = lm_head_topk(*chain, tied=False, block_v=16)
         np.testing.assert_array_equal(np.asarray(bv), np.asarray(fv))
         np.testing.assert_array_equal(np.asarray(bi), np.asarray(fi))
 

@@ -348,11 +348,11 @@ class TestEquivalence:
 
 
 class TestRaggedIsTheOnlyLayout:
-    """Once ragged is default there must be NO path back to the padded
-    ``[rows, C]`` rectangle: the packer module exports only the flat
-    layout, the model path's sources never name the retired packer, and
-    a kernel-path engine drain never reaches the legacy padded kernels
-    (they survive only as standalone bench baselines)."""
+    """There is NO path back to the padded ``[rows, C]`` rectangle: the
+    packer module exports only the flat layout, the model path's sources
+    never name the retired packer, and the decode / suffix / verify
+    kernels, their sharded wrappers and the two forwards that called
+    them are gone from every module that held them."""
 
     def test_padded_rectangle_packer_is_gone(self):
         import fusioninfer_tpu.engine.fused as fused
@@ -368,39 +368,29 @@ class TestRaggedIsTheOnlyLayout:
 
         for mod in (eng, mr):
             assert "pack_mixed_batch" not in inspect.getsource(mod)
-        src = inspect.getsource(mr)
-        # the model path's kernel branches all call the one ragged
-        # kernel; the standalone decode/verify/suffix kernels are
-        # bench/compat surface only
-        assert "paged_verify_attention(" not in src
-        assert "paged_decode_attention(" not in src
-        assert "paged_prefill_attention(" not in src
+        # three layer-scan forwards, and the thin jit of the burst's step
+        for gone in ("prefill_suffix", "verify_step",
+                     "_window_forward_impl", "_refuse_latent"):
+            assert not hasattr(mr, gone), gone
+        for kept in ("prefill", "decode_step", "decode_burst", "fused_step"):
+            assert hasattr(mr, kept), kept
 
-    def test_kernel_path_never_calls_legacy_kernels(self, monkeypatch):
-        """A kernel-path (interpret) mixed drain with the legacy padded
-        kernels booby-trapped: decode, chunks and suffixes must all
-        score through ragged_paged_attention alone."""
-        import dataclasses
-
+    def test_legacy_kernels_are_gone(self):
+        """One paged-attention family: the ragged wrappers are the only
+        paged kernels ``ops`` holds, ``ragged_paged_attention_tp`` the
+        only sharded one."""
+        import fusioninfer_tpu.ops as ops
         import fusioninfer_tpu.ops.paged_attention as pa
+        import fusioninfer_tpu.ops.sharded as sharded
 
-        def bomb(*a, **k):
-            raise AssertionError("legacy padded kernel reached from "
-                                 "the engine model path")
-
-        for name in ("paged_verify_attention", "paged_decode_attention",
-                     "paged_prefill_attention"):
-            monkeypatch.setattr(pa, name, bomb)
-        cfg = dataclasses.replace(CFG, attn_impl="flash")
-        engine = NativeEngine(cfg, cache_cfg=_cache_cfg(), max_batch_size=2,
-                              token_budget=16, fused_step=True)
-        _run_all(engine, [
-            Request("s", [1, 2, 3],
-                    SamplingParams(max_tokens=2, temperature=0.0)),
-            Request("long", list(range(1, 28)),
-                    SamplingParams(max_tokens=1, temperature=0.0)),
-        ])
-        assert engine.sched.fused_steps_total > 0
+        for kind in ("decode", "prefill", "verify"):
+            name = f"paged_{kind}_attention"
+            assert not hasattr(pa, name), name
+            assert not hasattr(ops, name), name
+            assert not hasattr(sharded, name + "_tp"), name
+        assert not hasattr(pa, "coalesce_fits_vmem")
+        assert [n for n in dir(sharded) if n.endswith("_attention_tp")] == [
+            "flash_attention_tp", "ragged_paged_attention_tp"]
 
 
 class TestWeightPassLedger:

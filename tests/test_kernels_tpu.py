@@ -37,24 +37,6 @@ if _ON_TPU_TIER:
             "or fails")
 
 
-def _paged_setup(B, H, KV, Hd, ps, n_pages, mp, lengths, dtype, seed=0):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, Hd), dtype)
-    k_pages = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), dtype)
-    v_pages = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), dtype)
-    rng = np.random.default_rng(seed)
-    tables = np.full((B, mp), n_pages - 1, np.int32)
-    perm = iter(rng.permutation(n_pages - 1))
-    for b, ln in enumerate(lengths):
-        for i in range(-(-int(ln) // ps) if ln else 0):
-            tables[b, i] = next(perm)
-    return q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32))
-
-
 def _int8_pages(kp, vp):
     """bf16 pages → (int8 pages, [KV, n_pages, 1, ps] scale rows, the
     dequantized bf16 pages an oracle reads)."""
@@ -67,221 +49,6 @@ def _int8_pages(kp, vp):
     kd = (k8.astype(jnp.float32) * ksc[..., None]).astype(jnp.bfloat16)
     vd = (v8.astype(jnp.float32) * vsc[..., None]).astype(jnp.bfloat16)
     return (k8, v8), (ksc[:, :, None, :], vsc[:, :, None, :]), (kd, vd)
-
-
-class TestPagedAttentionHW:
-    @pytest.mark.parametrize("coalesce", [True, False])
-    def test_bench_shapes_bf16(self, coalesce):
-        """The exact round-2 failure config: [257, ...] bf16 page pool,
-        KV=8, Hd=128, ps=128 — must COMPILE (interpret=False) and match
-        the gather oracle.  BOTH decode grids compile here: the default
-        coalesced (B,) grid and the per-head (B, KV) escape hatch
-        (FUSIONINFER_DECODE_COALESCE=0) — a Mosaic bump that breaks the
-        non-default grid must fail in this tier, not at serve time."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp = 8, 16, 8, 128, 128, 257, 8
-        lengths = [129, 1000, 7, 1, 0, 128, 255, 513]  # non-multiples of 8 included
-        q, kp, vp, tables, ln = _paged_setup(
-            B, H, KV, Hd, ps, n_pages, mp, lengths, jnp.bfloat16
-        )
-        out = paged_decode_attention(q, kp, vp, tables, ln, interpret=False,
-                                     coalesce=coalesce)
-        out.block_until_ready()
-        ref = reference_paged_attention(q, kp, vp, tables, ln)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=5e-2, rtol=5e-2,
-        )
-
-    def test_bench_shapes_int8_kv(self):
-        """int8 pages + [KV, n_pages, 1, ps] scale rows at the bench
-        config — the quantized DMA/scale-fold path must compile under
-        Mosaic and match the dequantized-page oracle."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp = 8, 16, 8, 128, 128, 257, 8
-        lengths = [129, 1000, 7, 1, 0, 128, 255, 513]
-        q, kp, vp, tables, ln = _paged_setup(
-            B, H, KV, Hd, ps, n_pages, mp, lengths, jnp.bfloat16
-        )
-        pages8, scales, deq = _int8_pages(kp, vp)
-        out = paged_decode_attention(q, *pages8, tables, ln, *scales,
-                                     interpret=False)
-        out.block_until_ready()
-        ref = reference_paged_attention(q, *deq, tables, ln)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=6e-2, rtol=6e-2,
-        )
-
-    def test_bench_shapes_sliding_window(self):
-        """Mistral-style banded decode attention at bench shapes: the
-        kernel must skip out-of-window pages AND compile under Mosaic."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp = 8, 16, 8, 128, 128, 257, 8
-        lengths = [129, 1000, 7, 1, 0, 128, 255, 513]
-        q, kp, vp, tables, ln = _paged_setup(
-            B, H, KV, Hd, ps, n_pages, mp, lengths, jnp.bfloat16, seed=7
-        )
-        out = paged_decode_attention(q, kp, vp, tables, ln,
-                                     window=300, interpret=False)
-        out.block_until_ready()
-        ref = reference_paged_attention(q, kp, vp, tables, ln, window=300)
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=5e-2, rtol=5e-2,
-        )
-
-    def test_inactive_rows_zero(self):
-        from fusioninfer_tpu.ops.paged_attention import paged_decode_attention
-
-        q, kp, vp, tables, ln = _paged_setup(
-            4, 16, 8, 128, 128, 33, 4, [0, 200, 0, 64], jnp.bfloat16
-        )
-        out = paged_decode_attention(q, kp, vp, tables, ln, interpret=False)
-        out = np.asarray(out, np.float32)
-        assert np.allclose(out[0], 0.0) and np.allclose(out[2], 0.0)
-        assert not np.allclose(out[1], 0.0)
-
-
-class TestPagedVerifyAttentionHW:
-    def test_verify_window_bench_shapes_bf16(self):
-        """Speculative verify window (C=8) at the bench decode config:
-        bf16 head-major pages, per-sequence starts/counts, interpret=False."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 8, 8, 16, 8, 128, 128, 257, 8
-        ks = jax.random.split(jax.random.key(5), 3)
-        q = jax.random.normal(ks[0], (B, C, H, Hd), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        rng = np.random.default_rng(5)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 17, 127, 129, 500, 900, 1, 1015], np.int32)
-        counts = np.asarray([8, 5, 1, 0, 8, 3, 7, 8], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), interpret=False,
-        )
-        out.block_until_ready()
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts))
-        got = np.asarray(out, np.float32).copy()
-        for b in range(B):
-            got[b, counts[b]:] = 0.0  # padding rows unspecified
-        np.testing.assert_allclose(
-            got, np.asarray(ref, np.float32), atol=5e-2, rtol=5e-2,
-        )
-
-    def test_verify_window_non_lane_multiple_c5(self):
-        """C=5 (the dryrun's --speculative-ngram k=4 → k+1 window): a
-        q-tile whose second-minor dim is NOT a multiple of 8.  Mosaic
-        layout rejections at such shapes must surface here, not in
-        production (ADVICE r3)."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 8, 5, 16, 8, 128, 128, 257, 8
-        ks = jax.random.split(jax.random.key(9), 3)
-        q = jax.random.normal(ks[0], (B, C, H, Hd), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        rng = np.random.default_rng(9)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 17, 127, 129, 500, 900, 1, 1018], np.int32)
-        counts = np.asarray([5, 3, 1, 0, 5, 2, 4, 5], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), interpret=False,
-        )
-        out.block_until_ready()
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts))
-        got = np.asarray(out, np.float32).copy()
-        for b in range(B):
-            got[b, counts[b]:] = 0.0
-        np.testing.assert_allclose(
-            got, np.asarray(ref, np.float32), atol=5e-2, rtol=5e-2,
-        )
-
-
-class TestBatchedWindowHW:
-    def test_q_tiled_batched_suffix_bf16(self):
-        """The batched-suffix / chunk-advance mode: per-sequence windows
-        longer than block_q, tiled over q, at bench head shapes."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 4, 256, 16, 8, 128, 128, 257, 8
-        ks = jax.random.split(jax.random.key(11), 3)
-        q = jax.random.normal(ks[0], (B, C, H, Hd), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        rng = np.random.default_rng(11)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 301, 512, 77], np.int32)
-        counts = np.asarray([256, 129, 1, 0], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), interpret=False, block_q=128)
-        out.block_until_ready()
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts))
-        got = np.asarray(out, np.float32).copy()
-        for b in range(B):
-            got[b, counts[b]:] = 0.0
-        np.testing.assert_allclose(
-            got, np.asarray(ref, np.float32), atol=5e-2, rtol=5e-2)
-
-
-class TestPagedPrefillAttentionHW:
-    def test_suffix_bench_shapes_bf16(self):
-        """Prefix-cache-hit path at bench shapes: suffix queries mid-stream
-        over a bf16 page pool, interpret=False.  Must compile under Mosaic
-        and match the gather oracle (the decode kernel's round-2 failure
-        mode applies equally here)."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_prefill_attention,
-            reference_paged_prefill_attention,
-        )
-
-        C, H, KV, Hd, ps, n_pages, mp = 256, 16, 8, 128, 128, 65, 16
-        ks = jax.random.split(jax.random.key(3), 3)
-        q = jax.random.normal(ks[0], (C, H, Hd), jnp.bfloat16)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.bfloat16)
-        row = jnp.asarray(np.random.default_rng(3).permutation(n_pages - 1)[:mp])
-        start, true_len = jnp.int32(901), jnp.int32(189)  # non-multiples of 8
-        out = paged_prefill_attention(q, kp, vp, row, start, true_len,
-                                      interpret=False)
-        out.block_until_ready()
-        ref = reference_paged_prefill_attention(q, kp, vp, row, start, true_len)
-        got = np.asarray(out, np.float32).copy()
-        got[189:] = 0.0  # pad rows are unspecified; oracle zeroes them
-        np.testing.assert_allclose(
-            got, np.asarray(ref, np.float32), atol=5e-2, rtol=5e-2,
-        )
 
 
 class TestFlashAttentionHW:
@@ -363,40 +130,6 @@ class TestDecodeStepHW:
         logits.block_until_ready()
         assert logits.shape == (B, cfg.vocab_size)
         assert bool(jnp.isfinite(logits.astype(jnp.float32)).all())
-
-
-class TestStackedLayerHW:
-    def test_stacked_pools_layer_indexing(self):
-        """The production in-place cache path: full [L, KV, ...] stacked
-        pools + a layer scalar-prefetch operand must COMPILE under
-        Mosaic (interpret=False) and read the right layer.  L=1
-        auto-wrap shares the DMA slicing pattern, but multi-layer
-        indexing on hardware is pinned only here."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp, L = 4, 16, 8, 128, 128, 33, 4, 3
-        lengths = [129, 7, 1, 255]
-        qs, kps, vps = [], [], []
-        tables = None
-        for layer in range(L):
-            q, kp, vp, tables, ln = _paged_setup(
-                B, H, KV, Hd, ps, n_pages, mp, lengths, jnp.bfloat16,
-                seed=20 + layer)
-            qs.append(q), kps.append(kp), vps.append(vp)
-        k_stack, v_stack = jnp.stack(kps), jnp.stack(vps)
-        for layer in range(L):
-            out = paged_decode_attention(
-                qs[layer], k_stack, v_stack, tables, ln,
-                interpret=False, layer=jnp.int32(layer))
-            out.block_until_ready()
-            ref = reference_paged_attention(qs[layer], kps[layer],
-                                            vps[layer], tables, ln)
-            np.testing.assert_allclose(
-                np.asarray(out, np.float32), np.asarray(ref, np.float32),
-                atol=5e-2, rtol=5e-2)
 
 
 class TestRaggedPagedAttentionHW:
@@ -494,6 +227,95 @@ class TestRaggedPagedAttentionHW:
         np.testing.assert_allclose(
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=5e-2, rtol=5e-2)
+
+    @pytest.mark.parametrize("grid", ["coalesced", "per-head", "split8"])
+    def test_sliding_window(self, grid):
+        """Mistral-style banded attention at serving shapes: decode rows
+        at ragged depths (a dead slot among them) and a chunk row from
+        the middle of a prompt skip their out-of-window pages on every
+        grid AND compile under Mosaic."""
+        from fusioninfer_tpu.ops.paged_attention import (
+            ragged_paged_attention,
+            ragged_paged_attention_kvsplit,
+            reference_ragged_paged_attention,
+        )
+
+        args = self._ragged(
+            q_lens=[1, 1, 1, 1, 0, 1, 1, 1, 189],
+            starts=[128, 999, 6, 0, 0, 127, 254, 512, 901], seed=7, mp=16)
+        if grid == "split8":
+            out = ragged_paged_attention_kvsplit(
+                *args, kv_splits=8, window=300, interpret=False)
+        else:
+            out = ragged_paged_attention(
+                *args, window=300, interpret=False,
+                coalesce=grid == "coalesced")
+        out.block_until_ready()
+        ref = reference_ragged_paged_attention(*args, window=300)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=5e-2, rtol=5e-2)
+
+    def test_five_token_windows_and_a_suffix_row(self):
+        """``--speculative-ngram`` k=4 packs 1 + 4-token window rows:
+        segments that are no multiple of the 8-token q tile, rows that
+        share a tile, a dead slot between them — and a cache-hit suffix
+        of 189 tokens from position 901 behind them."""
+        from fusioninfer_tpu.ops.paged_attention import (
+            ragged_paged_attention,
+            reference_ragged_paged_attention,
+        )
+
+        args = self._ragged(
+            q_lens=[5, 3, 1, 0, 5, 2, 4, 5, 189],
+            starts=[0, 17, 127, 129, 500, 900, 1, 1018, 901], seed=9, mp=16)
+        out = ragged_paged_attention(*args, interpret=False)
+        out.block_until_ready()
+        ref = reference_ragged_paged_attention(*args)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=5e-2, rtol=5e-2)
+
+    def test_stacked_pools_layer_indexing(self):
+        """The production in-place cache path: full [L, KV, ...] stacked
+        pools + a layer scalar-prefetch operand must COMPILE under
+        Mosaic (interpret=False) and read the right layer.  L=1
+        auto-wrap shares the DMA slicing pattern, but multi-layer
+        indexing on hardware is pinned only here."""
+        from fusioninfer_tpu.ops.paged_attention import (
+            ragged_paged_attention,
+            reference_ragged_paged_attention,
+        )
+
+        L = 3
+        ops = [self._ragged(q_lens=[1, 1, 0, 3, 40, 1],
+                            starts=[129, 7, 0, 255, 100, 500],
+                            seed=20 + layer, n_pages=33) for layer in range(L)]
+        k_stack = jnp.stack([o[1] for o in ops])
+        v_stack = jnp.stack([o[2] for o in ops])
+        for layer, (q, kp, vp, *rows) in enumerate(ops):
+            out = ragged_paged_attention(
+                q, k_stack, v_stack, *rows, interpret=False,
+                layer=jnp.int32(layer))
+            out.block_until_ready()
+            ref = reference_ragged_paged_attention(q, kp, vp, *rows)
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(ref, np.float32),
+                atol=5e-2, rtol=5e-2)
+
+    def test_inert_slots_zero(self):
+        """A decode step's dead slots (``q_len`` 0 at their own flat
+        offset, as ``decode_burst`` packs them) give exactly zero."""
+        from fusioninfer_tpu.ops.paged_attention import ragged_paged_attention
+
+        q, kp, vp, tables, starts, _, _ = self._ragged(
+            q_lens=[1, 1, 1, 1], starts=[0, 199, 0, 63], seed=11, n_pages=33)
+        out = np.asarray(ragged_paged_attention(
+            q, kp, vp, tables, starts, jnp.arange(4, dtype=jnp.int32),
+            jnp.asarray([0, 1, 0, 1], jnp.int32), interpret=False),
+            np.float32)
+        assert not out[0].any() and not out[2].any()
+        assert out[1].any() and out[3].any()
 
     def test_decode_only_offset_invariance_bits(self):
         """The scorer-switch retirement contract ON HARDWARE: the same

@@ -309,8 +309,13 @@ class TestStreamedPD:
     def test_streamed_kv_matches_slab_path(self):
         # chunked windows may reduce in a different order than the
         # monolithic padded window, so allow an odd bf16 ulp on the
-        # values; everything else (metadata, first token, layout) is
-        # exact and the decoded outputs are bit-identical (tests above)
+        # values, at the scale of their head vector (a projection's sum
+        # and the rotary pair mix a vector's components: one that
+        # cancels to near zero carries its partners' rounding step, 16
+        # ulps of its own value; here the first layer is bit-equal and
+        # 80 + 77 of the second's 10 240 values move, none by more);
+        # everything else (metadata, first token, layout) is exact and
+        # the decoded outputs are bit-identical (tests above)
         params = _greedy()
         slab_engine = NativeEngine(CFG, cache_cfg=CACHE, max_batch_size=2,
                                    seed=0)
@@ -330,12 +335,12 @@ class TestStreamedPD:
         assert out.first_token == slab.first_token
         assert out.prompt_tokens == slab.prompt_tokens
         assert out.quantized == slab.quantized
-        np.testing.assert_allclose(np.asarray(out.k, np.float32),
-                                   np.asarray(slab.k, np.float32),
-                                   rtol=2 ** -7)
-        np.testing.assert_allclose(np.asarray(out.v, np.float32),
-                                   np.asarray(slab.v, np.float32),
-                                   rtol=2 ** -7)
+        for got, want in ((out.k, slab.k), (out.v, slab.v)):
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            top = np.abs(want).max(axis=-1, keepdims=True)
+            ulp = 2.0 ** (np.floor(np.log2(np.maximum(top, 2.0 ** -126)))
+                          - 7)  # bfloat16: 8 significant bits
+            assert (np.abs(got - want) <= ulp).all()
         assert asm.overlap_fraction >= 0.5
 
     def test_incomplete_stream_falls_back_to_local_prefill(self):

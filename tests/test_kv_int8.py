@@ -23,7 +23,7 @@ from fusioninfer_tpu.engine.kv_cache import (
     init_kv_cache,
     page_bytes,
 )
-from fusioninfer_tpu.engine.model_runner import decode_step, prefill, verify_step
+from fusioninfer_tpu.engine.model_runner import decode_step, prefill
 from fusioninfer_tpu.engine.sampler import SamplingParams
 from fusioninfer_tpu.models.config import get_preset
 from fusioninfer_tpu.models.transformer import init_params
@@ -78,7 +78,9 @@ class TestQuantizeRoundtrip:
 @pytest.mark.parametrize("attn_impl", ["reference", "flash"])
 class TestStepEquivalence:
     """Quantized cache runs must stay close to bf16-cache runs — the
-    same prompts, same weights, tolerance = accumulated int8 error."""
+    same prompts, same weights, tolerance = accumulated int8 error (a
+    speculative window the same way:
+    ``tests/test_spec_decode.py::TestSpecWindowRows``)."""
 
     def _setup(self, attn_impl, kv_dtype):
         cfg = dataclasses.replace(CFG, attn_impl=attn_impl)
@@ -120,21 +122,6 @@ class TestStepEquivalence:
             # relative error of the logit vectors stays small
             denom = np.maximum(np.abs(b).max(), 1.0)
             assert np.max(np.abs(a - b)) / denom < 0.08
-
-    def test_verify_window_close(self, attn_impl):
-        cfg, cc, params, cache, rows, _ = self._setup(attn_impl, "int8")
-        cfgb, ccb, paramsb, cacheb, rowsb, _ = self._setup(attn_impl, "model")
-        rng = np.random.default_rng(3)
-        window = jnp.asarray(rng.integers(1, cfg.vocab_size, (2, 4),
-                                          dtype=np.int32))
-        starts = jnp.full((2,), 21, jnp.int32)
-        counts = jnp.asarray([4, 2], jnp.int32)
-        _, lq = verify_step(cfg, cc, params, cache, window, starts, counts, rows)
-        _, lb = verify_step(cfgb, ccb, paramsb, cacheb, window, starts, counts,
-                            rowsb)
-        a, b = np.asarray(lq, np.float32), np.asarray(lb, np.float32)
-        denom = np.maximum(np.abs(b).max(), 1.0)
-        assert np.max(np.abs(a[:, :2] - b[:, :2])) / denom < 0.08
 
 
 class TestEngineInt8KV:
@@ -302,38 +289,6 @@ class TestEngineInt8KV:
 
 
 class TestInt8WithSlidingWindow:
-    @pytest.mark.parametrize("coalesce", [False, True])
-    def test_windowed_quantized_decode_kernel(self, coalesce):
-        """Banding and scale folding compose: the page loop starts at the
-        window's first live page AND streams int8 scale rows from the
-        same offset."""
-        from fusioninfer_tpu.models.quantization import kv_quantize
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp = 4, 4, 2, 64, 16, 33, 8
-        ks = jax.random.split(jax.random.key(13), 3)
-        q = jax.random.normal(ks[0], (B, H, Hd), jnp.float32)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.float32)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.float32)
-        k8, ksc = kv_quantize(kp)
-        v8, vsc = kv_quantize(vp)
-        rng = np.random.default_rng(13)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        lengths = np.asarray([5, 40, 100, 0], np.int32)
-        out = paged_decode_attention(
-            q, k8, v8, jnp.asarray(tables), jnp.asarray(lengths),
-            ksc[:, :, None, :], vsc[:, :, None, :],
-            window=24, interpret=True, coalesce=coalesce)
-        kd = k8.astype(jnp.float32) * ksc[..., None]
-        vd = v8.astype(jnp.float32) * vsc[..., None]
-        ref = reference_paged_attention(
-            q, kd, vd, jnp.asarray(tables), jnp.asarray(lengths), window=24)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=3e-4, rtol=3e-4)
-
     def test_mistral_engine_with_int8_kv(self):
         """mistral-tiny serves end-to-end with quantized pages + window
         reclamation; greedy tokens match the bf16-page engine."""

@@ -1,10 +1,13 @@
-"""Pallas paged attention kernels vs gather oracles (interpret mode).
+"""The ragged paged-attention family vs gather oracles (interpret mode).
 
-Covers the standalone decode/suffix kernels AND the one true ragged
-kernel (``ragged_paged_attention``) every engine forward routes
-through — including the load-bearing bit-identity property: a row's
-output bits are independent of its flat offset and tile neighbors, so
-split and fused engine dispatches score identically.
+Every paged forward of the engine scores through
+``ragged_paged_attention`` / ``ragged_paged_attention_kvsplit``: the
+kernels against the flat oracle on every grid, the page stream, the walk
+lists, the VMEM guards — and the load-bearing bit-identity property: a
+row's output bits are independent of its flat offset and tile
+neighbors, so split and fused engine dispatches score identically.
+Row kind by row kind against second oracles:
+``tests/test_ragged_row_kinds.py``.
 """
 
 import jax
@@ -13,165 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from fusioninfer_tpu.ops.paged_attention import (
-    paged_decode_attention,
-    paged_prefill_attention,
     ragged_paged_attention,
     ragged_token_rows,
-    reference_paged_attention,
-    reference_paged_prefill_attention,
     reference_ragged_paged_attention,
 )
-
-
-def _setup(B=3, H=4, KV=2, Hd=64, n_pages=9, ps=16, mp=4, seed=0, dtype=jnp.float32):
-    ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (B, H, Hd), dtype)
-    k_pages = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), dtype)
-    v_pages = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), dtype)
-    # distinct page rows per sequence; trash page = n_pages - 1
-    rng = np.random.default_rng(seed)
-    tables = np.full((B, mp), n_pages - 1, np.int32)
-    perm = rng.permutation(n_pages - 1)
-    flat = iter(perm)
-    lengths = np.array([ps * 2 + 3, 1, ps * mp], np.int32)[:B]
-    for b in range(B):
-        need = -(-int(lengths[b]) // ps)
-        for i in range(need):
-            tables[b, i] = next(flat)
-    return q, k_pages, v_pages, jnp.asarray(tables), jnp.asarray(lengths)
-
-
-@pytest.mark.parametrize("coalesce", [False, True])
-def test_matches_gather_reference(coalesce):
-    q, kp, vp, tables, lengths = _setup()
-    out = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True, coalesce=coalesce)
-    ref = reference_paged_attention(q, kp, vp, tables, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("coalesce", [False, True])
-def test_inactive_slot_zero_output(coalesce):
-    q, kp, vp, tables, lengths = _setup(B=2)
-    lengths = jnp.asarray([0, 5], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True, coalesce=coalesce)
-    assert np.allclose(np.asarray(out)[0], 0.0)
-    ref = reference_paged_attention(q, kp, vp, tables, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("coalesce", [False, True])
-def test_gqa_grouping(coalesce):
-    q, kp, vp, tables, lengths = _setup(H=8, KV=2, seed=4)
-    out = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True, coalesce=coalesce)
-    ref = reference_paged_attention(q, kp, vp, tables, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("coalesce", [False, True])
-def test_bf16_pages(coalesce):
-    q, kp, vp, tables, lengths = _setup(dtype=jnp.bfloat16, seed=7)
-    out = paged_decode_attention(q, kp, vp, tables, lengths, interpret=True, coalesce=coalesce)
-    ref = reference_paged_attention(q, kp, vp, tables, lengths)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=4e-2, rtol=4e-2
-    )
-
-
-def _suffix_setup(C=32, H=4, KV=2, Hd=64, n_pages=9, ps=16, mp=8, seed=0,
-                  dtype=jnp.float32):
-    ks = jax.random.split(jax.random.key(seed), 3)
-    q = jax.random.normal(ks[0], (C, H, Hd), dtype)
-    k_pages = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), dtype)
-    v_pages = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), dtype)
-    rng = np.random.default_rng(seed)
-    row = np.full(mp, n_pages - 1, np.int32)
-    perm = rng.permutation(n_pages - 1)
-    row[: len(perm)] = perm[:mp]
-    return q, k_pages, v_pages, jnp.asarray(row)
-
-
-def _mask_pad(out, true_len):
-    """Kernel output past true_len is unspecified; zero it like the oracle."""
-    out = np.asarray(out, np.float32).copy()
-    out[true_len:] = 0.0
-    return out
-
-
-def test_suffix_matches_oracle_midstream():
-    """Queries starting mid-sequence (the prefix-cache hit shape)."""
-    q, kp, vp, row = _suffix_setup()
-    start, true_len = jnp.int32(19), jnp.int32(21)  # non-multiples of page size
-    out = paged_prefill_attention(q, kp, vp, row, start, true_len, interpret=True)
-    ref = reference_paged_prefill_attention(q, kp, vp, row, start, true_len)
-    np.testing.assert_allclose(
-        _mask_pad(out, 21), np.asarray(ref, np.float32), atol=2e-5, rtol=2e-5
-    )
-
-
-def test_suffix_from_zero_equals_full_prefill():
-    """start=0 degenerates to ordinary causal prefill over own pages."""
-    q, kp, vp, row = _suffix_setup(seed=3)
-    out = paged_prefill_attention(q, kp, vp, row, jnp.int32(0), jnp.int32(32),
-                                  interpret=True)
-    ref = reference_paged_prefill_attention(q, kp, vp, row, jnp.int32(0),
-                                            jnp.int32(32))
-    np.testing.assert_allclose(
-        _mask_pad(out, 32), np.asarray(ref, np.float32), atol=2e-5, rtol=2e-5
-    )
-
-
-def test_suffix_multi_qtile():
-    """C > block_q exercises the q-tile grid axis + causal page bounds."""
-    q, kp, vp, row = _suffix_setup(C=64, n_pages=17, ps=16, mp=12, seed=5)
-    start, true_len = jnp.int32(50), jnp.int32(40)
-    out = paged_prefill_attention(q, kp, vp, row, start, true_len,
-                                  block_q=32, interpret=True)
-    ref = reference_paged_prefill_attention(q, kp, vp, row, start, true_len)
-    np.testing.assert_allclose(
-        _mask_pad(out, 40), np.asarray(ref, np.float32), atol=2e-5, rtol=2e-5
-    )
-
-
-def test_suffix_gqa_bf16():
-    q, kp, vp, row = _suffix_setup(H=8, KV=2, dtype=jnp.bfloat16, seed=9)
-    start, true_len = jnp.int32(7), jnp.int32(30)
-    out = paged_prefill_attention(q, kp, vp, row, start, true_len, interpret=True)
-    ref = reference_paged_prefill_attention(q, kp, vp, row, start, true_len)
-    np.testing.assert_allclose(
-        _mask_pad(out, 30), np.asarray(ref, np.float32), atol=4e-2, rtol=4e-2
-    )
-
-
-@pytest.mark.parametrize("coalesce", [False, True])
-def test_stacked_layer_operand(coalesce):
-    """The production path passes the FULL [L, KV, ...] stacked pools
-    plus a layer scalar (the in-place cache design): attending layer l
-    of the stack must equal attending that layer's 4-d slice."""
-    L = 3
-    qs, kps, vps = [], [], []
-    for layer in range(L):
-        q, kp, vp, tables, lengths = _setup(seed=10 + layer)
-        qs.append(q), kps.append(kp), vps.append(vp)
-    k_stack = jnp.stack(kps)
-    v_stack = jnp.stack(vps)
-    for layer in range(L):
-        out = paged_decode_attention(
-            qs[layer], k_stack, v_stack, tables, lengths,
-            interpret=True, coalesce=coalesce, layer=jnp.int32(layer))
-        ref = paged_decode_attention(
-            qs[layer], kps[layer], vps[layer], tables, lengths,
-            interpret=True, coalesce=coalesce)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-
-
-def test_stacked_requires_layer():
-    q, kp, vp, tables, lengths = _setup()
-    with pytest.raises(ValueError, match="require layer"):
-        paged_decode_attention(q, jnp.stack([kp]), jnp.stack([vp]),
-                               tables, lengths, interpret=True)
-    with pytest.raises(ValueError, match="only applies"):
-        paged_decode_attention(q, kp, vp, tables, lengths,
-                               interpret=True, layer=0)
 
 
 def _ragged_setup(q_lens, starts, KV=2, G=2, Hd=64, ps=16, n_pages=17,
@@ -253,22 +101,22 @@ class TestRaggedKernel:
                                    atol=2e-5, rtol=2e-5)
 
     @pytest.mark.parametrize("coalesce", [False, True])
-    def test_int8_scaled_pages(self, coalesce):
-        from fusioninfer_tpu.models.quantization import kv_quantize
-
-        q, kp, vp, tables, starts, qb, ql = _ragged_setup(**_MIXED, seed=11)
-        k8, k_s = kv_quantize(kp)  # scales [KV, n_pages, ps]
-        v8, v_s = kv_quantize(vp)
-        out = ragged_paged_attention(q, k8, v8, tables, starts, qb, ql,
-                                     k_s[:, :, None, :], v_s[:, :, None, :],
-                                     interpret=True, coalesce=coalesce)
+    @pytest.mark.parametrize("window", [None, 24])
+    def test_int8_scaled_pages(self, window, coalesce):
+        """Scale folding is exact against the oracle over the dequantized
+        pages, and composes with the window: the walk starts at the
+        window's first live page AND streams the int8 scale rows from
+        the same offset."""
+        (q, k8, v8, *rows), (k_s, v_s), kw = _stream_variant(
+            "int8" if window is None else "int8+window")
+        out = ragged_paged_attention(q, k8, v8, *rows, k_s, v_s,
+                                     interpret=True, coalesce=coalesce, **kw)
         # oracle over the dequantized pages
-        kd = k8.astype(jnp.float32) * k_s[..., None]
-        vd = v8.astype(jnp.float32) * v_s[..., None]
-        ref = reference_ragged_paged_attention(q, kd, vd, tables, starts,
-                                               qb, ql)
+        kd = k8.astype(jnp.float32) * k_s[:, :, 0, :, None]
+        vd = v8.astype(jnp.float32) * v_s[:, :, 0, :, None]
+        ref = reference_ragged_paged_attention(q, kd, vd, *rows, **kw)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-2, rtol=2e-2)
+                                   atol=3e-4, rtol=3e-4)
 
     @pytest.mark.parametrize("coalesce", [False, True])
     def test_stacked_layer_operand(self, coalesce):
@@ -321,25 +169,20 @@ class TestRaggedKernel:
                 jnp.zeros((1,), jnp.int32), ql[r: r + 1], interpret=True))
             np.testing.assert_array_equal(solo, mixed[seg])
 
-    def test_matches_flattened_verify_rectangle(self):
-        """The ragged kernel over a flattened [B, C] rectangle computes
-        the verify kernel's math (tolerance — different tilings)."""
-        from fusioninfer_tpu.ops.paged_attention import paged_verify_attention
+    def test_stacked_requires_layer(self):
+        """Stacked pools without ``layer`` and ``layer`` with one
+        layer's pools both raise, on either wrapper."""
+        from fusioninfer_tpu.ops.paged_attention import (
+            ragged_paged_attention_kvsplit,
+        )
 
-        B, C = 2, 8
-        q, kp, vp, tables, starts, qb, ql = _ragged_setup(
-            q_lens=[C, C], starts=[3, 17], seed=9)
-        counts = jnp.asarray([5, 8], jnp.int32)
-        rect = paged_verify_attention(
-            q.reshape(B, C, *q.shape[1:]), kp, vp, tables, starts, counts,
-            interpret=True)
-        flat = ragged_paged_attention(q, kp, vp, tables, starts, qb, counts,
-                                      interpret=True)
-        rect_np = np.asarray(rect, np.float32).reshape(B, C, -1)
-        flat_np = np.asarray(flat, np.float32).reshape(B, C, -1)
-        for b, n in enumerate([5, 8]):  # padding rows are unspecified
-            np.testing.assert_allclose(flat_np[b, :n], rect_np[b, :n],
-                                       atol=2e-5, rtol=2e-5)
+        q, kp, vp, *rows = _ragged_setup(**_MIXED)
+        for run in (ragged_paged_attention, ragged_paged_attention_kvsplit):
+            with pytest.raises(ValueError, match="require layer"):
+                run(q, jnp.stack([kp]), jnp.stack([vp]), *rows,
+                    interpret=True)
+            with pytest.raises(ValueError, match="only applies"):
+                run(q, kp, vp, *rows, interpret=True, layer=0)
 
     def test_token_rows_zero_length_neighbors(self):
         """Token→row resolution must skip zero-length rows that share a
@@ -368,17 +211,23 @@ _STREAM_CASES = {
 
 def _stream_variant(variant):
     """``(operands, scale operands, kwargs)`` of a sliding-window, an
-    int8-page or a bfloat16 (native score dot) dispatch."""
+    int8-page, an int8-page sliding-window or a bfloat16 (native score
+    dot) dispatch."""
     from fusioninfer_tpu.models.quantization import kv_quantize
 
     if variant == "window":
         return (_ragged_setup(q_lens=[1, 6, 2], starts=[60, 24, 40], mp=6,
                               seed=5), (), {"window": 24})
-    if variant == "int8":
-        q, kp, vp, *rest = _ragged_setup(**_MIXED, seed=11)
+    if variant in ("int8", "int8+window"):
+        if variant == "int8":
+            (q, kp, vp, *rest), kw = _ragged_setup(**_MIXED, seed=11), {}
+        else:  # decode rows 5, 40 and 100 tokens deep and an inert slot
+            (q, kp, vp, *rest), kw = _ragged_setup(
+                q_lens=[1, 1, 1, 0], starts=[4, 39, 99, 0], mp=8,
+                n_pages=33, seed=13), {"window": 24}
         (k8, k_s), (v8, v_s) = kv_quantize(kp), kv_quantize(vp)
         return ((q, k8, v8, *rest),
-                (k_s[:, :, None, :], v_s[:, :, None, :]), {})
+                (k_s[:, :, None, :], v_s[:, :, None, :]), kw)
     return (_ragged_setup(q_lens=[1, 12, 1], starts=[30, 9, 47], KV=2, G=4,
                           dtype=jnp.bfloat16, seed=7), (), {})
 
@@ -422,7 +271,8 @@ class TestRaggedPageStream:
         np.testing.assert_array_equal(_stream_run(pa, grid, args), shipped)
 
     @pytest.mark.parametrize("grid", ["per-head", "coalesced", "split4"])
-    @pytest.mark.parametrize("variant", ["window", "int8", "bf16"])
+    @pytest.mark.parametrize("variant", ["window", "int8", "int8+window",
+                                         "bf16"])
     def test_ring_depth_decides_no_bit_variants(self, variant, grid,
                                                 monkeypatch):
         from fusioninfer_tpu.ops import paged_attention as pa
@@ -579,8 +429,25 @@ class TestStartupBudget:
 
 
 class TestRaggedVmemGuard:
+    """The coalesced grid's page ring [slots, KV, ps, Hd] and its tiles
+    must fit a conservative VMEM budget; oversized configurations fall
+    back to the per-head grid instead of failing Mosaic allocation."""
+
+    def test_scratch_bytes_math(self):
+        from fusioninfer_tpu.ops.paged_attention import coalesced_scratch_bytes
+
+        # 2 slots x KV=2 heads x 16 x 64 x (4 + 4) bytes f32 K+V
+        assert coalesced_scratch_bytes(16, 64, 2, jnp.float32, jnp.float32,
+                                       quantized=False,
+                                       slots=2) == 2 * 2 * 16 * 64 * 8
+        # int8 adds two f32 [1, ps] scale rows per head per slot
+        q8 = coalesced_scratch_bytes(16, 64, 2, jnp.int8, jnp.int8,
+                                     quantized=True, slots=3)
+        assert q8 == 3 * (2 * 16 * 64 * 2 + 2 * 2 * 16 * 4)
+
     def test_fits_vmem_adds_tile_term(self):
         from fusioninfer_tpu.ops.paged_attention import (
+            RAGGED_RING_SLOTS,
             coalesced_scratch_bytes,
             ragged_fits_vmem,
         )
@@ -588,10 +455,19 @@ class TestRaggedVmemGuard:
         assert ragged_fits_vmem(8, 128, 128, 8, 4, jnp.bfloat16,
                                 jnp.bfloat16, jnp.bfloat16,
                                 quantized=False)  # the serving shape
+        # a pathological KV x ps x Hd product must NOT coalesce
+        assert not ragged_fits_vmem(8, 2048, 256, 32, 1, jnp.float32,
+                                    jnp.float32, jnp.float32,
+                                    quantized=False)
+        # explicit budget override for unit determinism
+        assert not ragged_fits_vmem(8, 16, 64, 2, 2, jnp.float32,
+                                    jnp.float32, jnp.float32,
+                                    quantized=False, budget=1024)
         # the tile term matters: a budget that fits the page scratch
         # alone must reject once q/out tiles are counted
         pages = coalesced_scratch_bytes(16, 64, 2, jnp.float32,
-                                        jnp.float32, quantized=False)
+                                        jnp.float32, quantized=False,
+                                        slots=RAGGED_RING_SLOTS)
         assert not ragged_fits_vmem(8, 16, 64, 2, 2, jnp.float32,
                                     jnp.float32, jnp.float32,
                                     quantized=False, budget=pages + 1)
@@ -615,59 +491,11 @@ class TestRaggedVmemGuard:
                                    atol=2e-5, rtol=2e-5)
 
 
-class TestCoalesceVmemGuard:
-    """The coalesced grid's double-buffered [2, KV, ps, Hd] scratch must
-    fit a conservative VMEM budget; oversized configurations fall back
-    to the per-head grid instead of failing Mosaic allocation."""
-
-    def test_scratch_bytes_math(self):
-        from fusioninfer_tpu.ops.paged_attention import coalesced_scratch_bytes
-
-        # 2 slots x KV=2 heads x 16 x 64 x (4 + 4) bytes f32 K+V
-        assert coalesced_scratch_bytes(16, 64, 2, jnp.float32, jnp.float32,
-                                       quantized=False) == 2 * 2 * 16 * 64 * 8
-        # int8 adds two f32 [1, ps] scale rows per head per slot
-        q8 = coalesced_scratch_bytes(16, 64, 2, jnp.int8, jnp.int8,
-                                     quantized=True)
-        assert q8 == 2 * (2 * 16 * 64 * 2 + 2 * 2 * 16 * 4)
-
-    def test_fits_vmem_boundary(self):
-        from fusioninfer_tpu.ops.paged_attention import coalesce_fits_vmem
-
-        assert coalesce_fits_vmem(128, 128, 8, jnp.bfloat16, jnp.bfloat16,
-                                  quantized=False)  # the serving shape
-        # a pathological KV x ps x Hd product must NOT coalesce
-        assert not coalesce_fits_vmem(2048, 256, 32, jnp.float32,
-                                      jnp.float32, quantized=False)
-        # explicit budget override for unit determinism
-        assert not coalesce_fits_vmem(16, 64, 2, jnp.float32, jnp.float32,
-                                      quantized=False, budget=1024)
-
-    def test_oversized_request_falls_back_to_per_head_grid(self, monkeypatch):
-        """coalesce=True with an over-budget scratch must route to the
-        per-head kernel (observable: the coalesced body is never
-        entered) and still produce oracle-exact output."""
-        from fusioninfer_tpu.ops import paged_attention as pa
-
-        def bomb(*a, **k):
-            raise AssertionError("coalesced kernel entered despite "
-                                 "over-budget scratch")
-
-        monkeypatch.setattr(pa, "_paged_kernel_coalesced", bomb)
-        monkeypatch.setattr(pa, "_COALESCE_VMEM_SCRATCH_BUDGET", 1024)
-        q, kp, vp, tables, lengths = _setup()
-        out = pa.paged_decode_attention.__wrapped__(
-            q, kp, vp, tables, lengths, interpret=True, coalesce=True)
-        ref = reference_paged_attention(q, kp, vp, tables, lengths)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
-
-
 class TestEagerCoalesceResolution:
     """Flipping FUSIONINFER_DECODE_COALESCE mid-process must take effect:
     the engine resolves the env var OUTSIDE the jitted step and passes
     the concrete bool as a static argument, so the flip retraces instead
-    of silently reusing the latched variant (ADVICE r5)."""
+    of silently reusing the latched variant."""
 
     def test_decode_step_takes_coalesce_static(self, monkeypatch):
         from fusioninfer_tpu.engine.engine import NativeEngine, Request

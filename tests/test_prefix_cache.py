@@ -187,9 +187,9 @@ class TestReuseAwareAdmission:
 
 
 class TestBatchedSuffixPrefill:
-    """A burst of short-suffix cache hits runs as ONE verify_step forward
-    (engine._prefill_suffix_batch) — tokens must be identical to serial
-    per-request admission."""
+    """A burst of short-suffix cache hits runs as rows of ONE fused_step
+    forward (engine._prefill_suffix_batch) — tokens must be identical to
+    serial per-request admission."""
 
     def _mk(self, rid, prompt, seed=None, temperature=0.0):
         return Request(

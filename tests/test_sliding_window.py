@@ -1,10 +1,13 @@
 """Sliding-window attention (Mistral family).
 
-Every execution path — full forward, fresh prefill, suffix prefill,
-decode, verify — must band attention to the trailing ``sliding_window``
-positions, in both the Pallas kernels (which skip out-of-window pages)
-and the portable gather paths.  Correctness bars: windowed kernels match
-windowed oracles; window ≥ context reproduces full causal attention
+Every execution path — full forward, fresh prefill, and the decode,
+chunk, suffix and speculative-window rows of the ragged forwards — must
+band attention to the trailing ``sliding_window`` positions, in both the
+Pallas kernels (which skip out-of-window pages) and the portable gather
+paths.  Correctness bars: windowed kernels match windowed oracles (the
+flash kernel here; the ragged family row kind by row kind in
+``tests/test_ragged_row_kinds.py``); window ≥ context reproduces full
+causal attention
 exactly; the engine serves a Mistral-shaped model end-to-end with
 token identity between the portable and kernel paths.
 """
@@ -12,7 +15,6 @@ token identity between the portable and kernel paths.
 import dataclasses
 
 import jax
-import pytest
 import jax.numpy as jnp
 import numpy as np
 
@@ -61,82 +63,6 @@ class TestFlashWindow:
         full = reference_attention(q, k, v, causal=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                    atol=2e-4, rtol=2e-4)
-
-
-class TestPagedKernelsWindow:
-    def _pages(self, KV, n_pages, ps, Hd, seed=0):
-        ks = jax.random.split(jax.random.key(seed), 2)
-        return (jax.random.normal(ks[0], (KV, n_pages, ps, Hd), jnp.float32),
-                jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.float32))
-
-    @pytest.mark.parametrize("coalesce", [False, True])
-    def test_decode_kernel_windowed(self, coalesce):
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_decode_attention,
-            reference_paged_attention,
-        )
-
-        B, H, KV, Hd, ps, n_pages, mp = 4, 4, 2, 64, 16, 33, 8
-        kp, vp = self._pages(KV, n_pages, ps, Hd)
-        q = jax.random.normal(jax.random.key(2), (B, H, Hd), jnp.float32)
-        rng = np.random.default_rng(0)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        lengths = np.asarray([5, 40, 100, 0], np.int32)
-        for w in (8, 24, 64):
-            out = paged_decode_attention(
-                q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths),
-                window=w, interpret=True, coalesce=coalesce)
-            ref = reference_paged_attention(
-                q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths), window=w)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       atol=2e-4, rtol=2e-4)
-
-    def test_suffix_kernel_windowed(self):
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_prefill_attention,
-            reference_paged_prefill_attention,
-        )
-
-        C, H, KV, Hd, ps, n_pages, mp = 32, 4, 2, 64, 16, 17, 8
-        kp, vp = self._pages(KV, n_pages, ps, Hd, seed=1)
-        q = jax.random.normal(jax.random.key(3), (C, H, Hd), jnp.float32)
-        rng = np.random.default_rng(1)
-        row = jnp.asarray(rng.permutation(n_pages - 1)[:mp].astype(np.int32))
-        start, true_len = jnp.int32(67), jnp.int32(21)
-        for w in (8, 30):
-            out = paged_prefill_attention(
-                q, kp, vp, row, start, true_len, window=w,
-                block_q=16, interpret=True)
-            ref = reference_paged_prefill_attention(
-                q, kp, vp, row, start, true_len, window=w)
-            got = np.asarray(out).copy()
-            got[21:] = 0.0
-            np.testing.assert_allclose(got, np.asarray(ref),
-                                       atol=2e-4, rtol=2e-4)
-
-    def test_verify_kernel_windowed(self):
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 3, 4, 4, 2, 64, 16, 33, 8
-        kp, vp = self._pages(KV, n_pages, ps, Hd, seed=2)
-        q = jax.random.normal(jax.random.key(4), (B, C, H, Hd), jnp.float32)
-        rng = np.random.default_rng(2)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 37, 90], np.int32)
-        counts = np.asarray([4, 3, 0], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), window=16, interpret=True)
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), window=16)
-        got = np.asarray(out).copy()
-        for b in range(B):
-            got[b, counts[b]:] = 0.0
-        np.testing.assert_allclose(got, np.asarray(ref), atol=2e-4, rtol=2e-4)
 
 
 class TestModelLevel:

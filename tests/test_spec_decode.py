@@ -1,4 +1,4 @@
-"""Speculative decoding: n-gram proposer, verify_step, engine identity.
+"""Speculative decoding: n-gram proposer, window rows, engine identity.
 
 The invariants: greedy output with speculation on is BIT-identical to
 speculation off (argmax acceptance); sampled (temperature>0) rows
@@ -17,8 +17,9 @@ import numpy as np
 import pytest
 
 from fusioninfer_tpu.engine.engine import NativeEngine, Request
+from fusioninfer_tpu.engine.fused import pack_ragged_batch
 from fusioninfer_tpu.engine.kv_cache import CacheConfig, PageAllocator, init_kv_cache
-from fusioninfer_tpu.engine.model_runner import decode_step, prefill, verify_step
+from fusioninfer_tpu.engine.model_runner import decode_step, fused_step, prefill
 from fusioninfer_tpu.engine.sampler import SamplingParams
 from fusioninfer_tpu.engine.spec import NgramProposer
 from fusioninfer_tpu.models.config import get_preset
@@ -82,10 +83,34 @@ def _seeded_cache(cfg, cache_cfg, prompt_len, B):
     return params, cache, jnp.asarray(rows), prompt_len
 
 
+def _window_forward(cfg, cache_cfg, params, cache, window, starts, counts,
+                    rows):
+    """Speculative-window rows through the program the engine runs them
+    on: row ``b`` is ``counts[b]`` tokens of ``window[b]`` from position
+    ``starts[b]`` (``q_len = 1 + drafts``; 0 an inert slot), packed as
+    the engine packs a decode-only step, ``sel`` over every window
+    column → ``(cache, logits [B, W, V])``."""
+    B = window.shape[0]
+    p = pack_ragged_batch(
+        np.asarray(window, np.int32), np.asarray(counts, np.int32),
+        np.asarray(starts, np.int32), np.asarray(rows, np.int32),
+        np.zeros((B,), np.int32), [], cache_cfg.trash_page, chunk_rows=0)
+    cache, logits, _ = fused_step(
+        cfg, cache_cfg, params, cache, jnp.asarray(p.tokens),
+        jnp.asarray(p.row_starts), jnp.asarray(p.q_begins),
+        jnp.asarray(p.q_lens), jnp.asarray(p.page_tables),
+        jnp.asarray(p.sel), jnp.asarray(p.chunk_sel))
+    return cache, logits
+
+
 @pytest.mark.parametrize("attn_impl", ["reference", "flash"])
-class TestVerifyStep:
+class TestSpecWindowRows:
+    """A speculative window is rows of ``fused_step`` with ``q_len = 1 +
+    drafts``: what one forward says of a window is what sequential
+    ``decode_step``s say, kernel path and portable path alike."""
+
     def test_matches_sequential_decode(self, attn_impl):
-        """logits[b, j] of one verify_step == the j-th sequential
+        """logits[b, j] of one window forward == the j-th sequential
         decode_step's logits, and the final caches agree."""
         cfg = dataclasses.replace(CFG, attn_impl=attn_impl)
         cache_cfg = CacheConfig(n_pages=17, page_size=16, max_pages_per_seq=4)
@@ -94,11 +119,9 @@ class TestVerifyStep:
         rng = np.random.default_rng(3)
         window = rng.integers(1, cfg.vocab_size, (B, C), dtype=np.int32)
 
-        cache_v, logits_v = verify_step(
-            cfg, cache_cfg, params, jax.tree.map(jnp.copy, cache0),
-            jnp.asarray(window), jnp.full((B,), pos0, jnp.int32),
-            jnp.full((B,), C, jnp.int32), rows,
-        )
+        cache_v, logits_v = _window_forward(
+            cfg, cache_cfg, params, jax.tree.map(jnp.copy, cache0), window,
+            np.full((B,), pos0), np.full((B,), C), rows)
 
         cache_s = jax.tree.map(jnp.copy, cache0)
         for j in range(C):
@@ -112,30 +135,34 @@ class TestVerifyStep:
                 np.asarray(logits_v[:, j]), np.asarray(logits_j),
                 atol=2e-2, rtol=2e-2,
             )
+        # the flat axis' signature pad writes the trash page; every
+        # page a sequence owns agrees
+        real = np.arange(cache_cfg.n_pages) != cache_cfg.trash_page
         for k in ("k", "v"):
             np.testing.assert_allclose(
-                np.asarray(cache_v[k], np.float32),
-                np.asarray(cache_s[k], np.float32),
+                np.asarray(cache_v[k], np.float32)[:, :, real],
+                np.asarray(cache_s[k], np.float32)[:, :, real],
                 atol=1e-2, rtol=1e-2,
             )
 
     def test_partial_counts_mask_writes(self, attn_impl):
-        """Rows past counts[b] must not touch the sequence's pages, and
-        count-0 slots are fully inert."""
+        """Window columns past counts[b] must not touch the sequence's
+        pages, and count-0 slots are fully inert."""
         cfg = dataclasses.replace(CFG, attn_impl=attn_impl)
         cache_cfg = CacheConfig(n_pages=17, page_size=16, max_pages_per_seq=4)
         B, C, plen = 2, 4, 20
         params, cache0, rows, pos0 = _seeded_cache(cfg, cache_cfg, plen, B)
         window = np.full((B, C), 7, np.int32)
         counts = np.asarray([2, 0], np.int32)
-        cache_v, _ = verify_step(
-            cfg, cache_cfg, params, jax.tree.map(jnp.copy, cache0),
-            jnp.asarray(window), jnp.full((B,), pos0, jnp.int32),
-            jnp.asarray(counts), rows,
-        )
+        cache_v, _ = _window_forward(
+            cfg, cache_cfg, params, jax.tree.map(jnp.copy, cache0), window,
+            np.full((B,), pos0), counts, rows)
         ps = cache_cfg.page_size
         k0, kv = np.asarray(cache0["k"], np.float32), np.asarray(cache_v["k"], np.float32)
         # seq 0: positions pos0, pos0+1 written; pos0+2.. untouched
+        page = int(np.asarray(rows)[0, pos0 // ps])
+        assert not np.array_equal(kv[:, :, page, pos0 % ps + 1],
+                                  k0[:, :, page, pos0 % ps + 1])
         page = int(np.asarray(rows)[0, (pos0 + 2) // ps])
         slot = (pos0 + 2) % ps
         np.testing.assert_array_equal(kv[:, :, page, slot], k0[:, :, page, slot])
@@ -146,34 +173,24 @@ class TestVerifyStep:
                 continue
             np.testing.assert_array_equal(kv[:, :, p], k0[:, :, p])
 
-
-class TestVerifyKernelOracle:
-    def test_kernel_matches_oracle(self):
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 4, 8, 8, 4, 64, 16, 33, 8
-        ks = jax.random.split(jax.random.key(0), 3)
-        q = jax.random.normal(ks[0], (B, C, H, Hd), jnp.float32)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.float32)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.float32)
-        rng = np.random.default_rng(0)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 17, 30, 100], np.int32)
-        counts = np.asarray([8, 5, 1, 0], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), interpret=True,
-        )
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts))
-        got = np.asarray(out).copy().reshape(B, C, H * Hd)
-        for b in range(B):
-            got[b, counts[b]:] = 0.0  # padding rows unspecified
-        np.testing.assert_allclose(got, np.asarray(ref), atol=2e-4, rtol=2e-4)
+    def test_int8_pages_close_to_bf16(self, attn_impl):
+        """The same window over int8 pages stays within the accumulated
+        quantization error of the model-dtype pages."""
+        cfg = dataclasses.replace(CFG, attn_impl=attn_impl)
+        rng = np.random.default_rng(3)
+        window = rng.integers(1, cfg.vocab_size, (2, 4), dtype=np.int32)
+        counts = np.asarray([4, 2], np.int32)
+        logits = {}
+        for kv_dtype in ("int8", "model"):
+            cache_cfg = CacheConfig(n_pages=33, page_size=16,
+                                    max_pages_per_seq=8, kv_dtype=kv_dtype)
+            params, cache, rows, pos0 = _seeded_cache(cfg, cache_cfg, 21, 2)
+            _, lg = _window_forward(cfg, cache_cfg, params, cache, window,
+                                    np.full((2,), pos0), counts, rows)
+            logits[kv_dtype] = np.asarray(lg, np.float32)
+        a, b = logits["int8"], logits["model"]
+        denom = np.maximum(np.abs(b).max(), 1.0)
+        assert np.max(np.abs(a[:, :2] - b[:, :2])) / denom < 0.08
 
 
 def _drain(engine, requests, max_steps=500):
@@ -266,35 +283,6 @@ class TestEngineIdentity:
         text = EngineMetrics("m").render(spec)
         assert "vllm:spec_decode_num_draft_tokens_total" in text
         assert "vllm:spec_decode_num_accepted_tokens_total" in text
-
-
-    def test_kernel_q_tiling_matches_oracle(self):
-        """Windows longer than block_q tile over the q axis — the ragged
-        batched-suffix mode of the verify kernel."""
-        from fusioninfer_tpu.ops.paged_attention import (
-            paged_verify_attention,
-            reference_paged_verify_attention,
-        )
-
-        B, C, H, KV, Hd, ps, n_pages, mp = 3, 64, 4, 2, 64, 16, 33, 8
-        ks = jax.random.split(jax.random.key(9), 3)
-        q = jax.random.normal(ks[0], (B, C, H, Hd), jnp.float32)
-        kp = jax.random.normal(ks[1], (KV, n_pages, ps, Hd), jnp.float32)
-        vp = jax.random.normal(ks[2], (KV, n_pages, ps, Hd), jnp.float32)
-        rng = np.random.default_rng(9)
-        tables = rng.permutation(n_pages - 1)[: B * mp].reshape(B, mp).astype(np.int32)
-        starts = np.asarray([0, 21, 50], np.int32)
-        counts = np.asarray([64, 37, 0], np.int32)
-        out = paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts), interpret=True, block_q=16)
-        ref = reference_paged_verify_attention(
-            q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
-            jnp.asarray(counts))
-        got = np.asarray(out).copy()
-        for b in range(B):
-            got[b, counts[b]:] = 0.0
-        np.testing.assert_allclose(got, np.asarray(ref), atol=3e-4, rtol=3e-4)
 
 
 class TestSampledSpeculation:

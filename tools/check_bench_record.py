@@ -26,8 +26,8 @@ def check_record(record: dict) -> list[str]:
     if record.get("error"):
         problems.append(f"bench errored: {record['error']}")
         return problems
-    # ragged-kernel microbench leg (r06): dispersion + the two ratio
-    # fields + mfu_box must land in every record, so a regression that
+    # ragged-kernel microbench leg (r06): dispersion + the ratio
+    # field + mfu_box must land in every record, so a regression that
     # silently drops the kernel evidence fails CI
     micro = record.get("kernel_microbench")
     if not isinstance(micro, dict):
@@ -35,7 +35,7 @@ def check_record(record: dict) -> list[str]:
     elif micro.get("error"):
         problems.append(f"kernel_microbench errored: {micro['error']}")
     else:
-        for field in ("ragged_vs_gather", "ragged_vs_padded", "mfu_box"):
+        for field in ("ragged_vs_gather", "mfu_box"):
             if field not in micro:
                 problems.append(f"kernel_microbench.{field} missing")
         ragged = micro.get("ragged")
