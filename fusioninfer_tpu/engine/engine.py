@@ -802,9 +802,14 @@ class NativeEngine:
 
         cfg, cc = self.cfg, self.cache_cfg
         attention = ops_dispatch.resolve_attn(cfg.attn_impl)
-        grid, splits = None, 0
+        grid, splits, ring = None, 0, None
         if attention == "flash" and cfg.is_mla:
-            grid = "latent"  # ops/mla_attention.py: one grid, no split
+            from fusioninfer_tpu.ops import mla_attention
+
+            # ops/mla_attention.py: one grid, no split, one page stream
+            grid = "latent"
+            ring = (mla_attention.MLA_RING_SLOTS
+                    * mla_attention.MLA_PAGES_PER_UPDATE)
         elif attention == "flash":
             tp = (self._kernel_mesh.shape["tp"]
                   if self._kernel_mesh is not None else 1)
@@ -824,6 +829,8 @@ class NativeEngine:
             "interpret": ops_dispatch.kernel_interpret(),
             "grid": grid,
             "kv_splits": splits,
+            # pages in the latent kernel's ring: its page stream is on
+            "latent_ring_pages": ring,
             "sharded_attention": (
                 None if self.mesh is None else
                 "kernel-mesh" if self._kernel_mesh is not None else

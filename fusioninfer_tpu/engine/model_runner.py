@@ -157,12 +157,12 @@ def _add_moe_stats(cache: dict, stats) -> dict:
 
 def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
                     write_slot, page_tables, row_starts, q_begins, q_lens,
-                    *, use_kernel, interpret):
+                    *, use_kernel, interpret, walks=None):
     """Latent attention of flat tokens ``x`` [T, 1, D] over their rows'
     pages, the one body of decode and chunk rows alike: project, write
     each token's latent row, attend in the absorbed form straight over
     the latent pages → (cache, attention output [T, 1, D], residual NOT
-    added)."""
+    added).  ``walks``: :func:`_ragged_walks` of the same rows."""
     from fusioninfer_tpu.ops.mla_attention import (
         mla_ragged_paged_attention,
         reference_mla_ragged_paged_attention,
@@ -179,7 +179,8 @@ def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
                 q_begins, q_lens)
         if use_kernel:
             o_lat = mla_ragged_paged_attention(
-                *args, layer=l, rank=cfg.kv_lora_rank, interpret=interpret)
+                *args, layer=l, rank=cfg.kv_lora_rank, interpret=interpret,
+                walks=walks)
         else:
             o_lat = reference_mla_ragged_paged_attention(
                 *args, layer=l, rank=cfg.kv_lora_rank)
@@ -210,13 +211,19 @@ def _dequant_gather(ctx, scale_l, pages, flat_shape):
 
 def _ragged_walks(cfg, cache, mesh, use_kernel, n_tokens, page_tables,
                   row_starts, q_begins, q_lens, kv_splits):
-    """The ragged kernel's walk lists for one forward's rows, built
-    BEFORE the layer scan: every layer scores the same rows, and what a
-    scan body computes XLA leaves inside its loop.  None where the
-    ragged kernel does not run (portable branch, latent cache) and under
-    a serving mesh, where each shard's kernel builds its own."""
-    if not use_kernel or cfg.is_mla or mesh is not None:
+    """The paged kernel's walk lists for one forward's rows (the ragged
+    family's, or the latent kernel's over a latent cache), built BEFORE
+    the layer scan: every layer scores the same rows, and what a scan
+    body computes XLA leaves inside its loop.  None where no paged
+    kernel runs (portable branch) and under a serving mesh, where each
+    shard's kernel builds its own."""
+    if not use_kernel or mesh is not None:
         return None
+    if cfg.is_mla:
+        from fusioninfer_tpu.ops.mla_attention import mla_walk_lists
+
+        return mla_walk_lists(n_tokens, cache["kv"], row_starts, q_begins,
+                              q_lens)
     from fusioninfer_tpu.ops.paged_attention import ragged_walk_lists
 
     q = jax.ShapeDtypeStruct((n_tokens, cfg.n_heads, cfg.head_dim),
@@ -382,9 +389,8 @@ def _decode_step_impl(
             # same body (and bits) the fused step scores decode rows with
             cache, attn_out = _mla_attn_block(
                 cfg, layer, x, positions, cache, l, write_page, write_slot,
-                page_tables, positions, jnp.arange(B_, dtype=jnp.int32),
-                active.astype(jnp.int32), use_kernel=use_kernel,
-                interpret=dispatch.kernel_interpret())
+                *rows, use_kernel=use_kernel,
+                interpret=dispatch.kernel_interpret(), walks=walks)
             x = x + attn_out
             y, stats = mlp_block(cfg, layer, x, active[:, None])
             return (x + y, _add_moe_stats(cache, stats)), None
@@ -668,7 +674,8 @@ def fused_step(
             cache, attn_out = _mla_attn_block(
                 cfg, layer, x, positions, cache, l, write_page, write_slot,
                 page_tables, row_starts, q_begins, q_lens,
-                use_kernel=use_kernel, interpret=dispatch.kernel_interpret())
+                use_kernel=use_kernel, interpret=dispatch.kernel_interpret(),
+                walks=walks)
             x = x + attn_out
             y, stats = mlp_block(cfg, layer, x, live[:, None])
             return (x + y, _add_moe_stats(cache, stats)), None

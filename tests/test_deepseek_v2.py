@@ -326,6 +326,8 @@ def test_engine_streams_sit_on_the_references_best_logit(arch, reference):
         max_batch_size=4, seed=SEED, token_budget=24, decode_burst_steps=4)
     info = eng.runtime_info()
     assert info["kv_layout"] == "latent"
+    # the portable path runs no kernel: no grid, no ring to report
+    assert (info["grid"], info["latent_ring_pages"]) == (None, None)
     assert info["moe_experts"] == "ragged_dot dropless 4/16"
     prompts = {f"r{i}": prompt(n, seed=10 + i)
                for i, n in enumerate((5, 40, 70, 23))}
@@ -357,6 +359,22 @@ def test_engine_streams_sit_on_the_references_best_logit(arch, reference):
 
 
 # ---- absorbed = expanded --------------------------------------------------
+
+def test_a_kernel_engine_reports_the_latent_grid_and_its_page_ring():
+    """``/health`` says the stream is on: the ring's pages beside
+    ``"grid": "latent"`` (perfbench's ``expect`` names the grid)."""
+    from fusioninfer_tpu.ops import mla_attention as mla
+
+    eng = NativeEngine(
+        dataclasses.replace(tiny_cfg(), attn_impl="flash"),
+        cache_cfg=CacheConfig(n_pages=16, page_size=16, max_pages_per_seq=4),
+        max_batch_size=2, seed=SEED, token_budget=16)
+    info = eng.runtime_info()
+    assert (info["attention"], info["grid"], info["kv_splits"]) == (
+        "flash", "latent", 0)
+    assert info["latent_ring_pages"] == (
+        mla.MLA_RING_SLOTS * mla.MLA_PAGES_PER_UPDATE)
+
 
 def test_absorbed_attention_equals_expanded_attention():
     """Scores and values straight over latent rows (``q W_UK^T`` against

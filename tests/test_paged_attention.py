@@ -362,6 +362,27 @@ class TestRaggedPageStream:
                                    atol=2e-5, rtol=2e-5)
 
 
+def traced_equations(jaxpr) -> int:
+    """Equations of a jaxpr, those of every nested jaxpr included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += traced_equations(sub)
+    return n
+
+
+def kernel_of(jaxpr):
+    """The kernel jaxpr of the one top-level ``pallas_call``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["jaxpr"]
+    raise AssertionError("no pallas_call at the top level")
+
+
 class TestStartupBudget:
     """A program that carries the kernel pays for its traced size at
     every warm start: its first dispatch traces and lowers it again
@@ -391,23 +412,6 @@ class TestStartupBudget:
             return pa.ragged_paged_attention_kvsplit.__wrapped__(
                 q, kp, vp, tables, st, qb, ql, kv_splits=8, layer=layer)
 
-        def equations(jaxpr):
-            n = 0
-            for eqn in jaxpr.eqns:
-                n += 1
-                for v in eqn.params.values():
-                    for sub in v if isinstance(v, (list, tuple)) else [v]:
-                        sub = getattr(sub, "jaxpr", sub)
-                        if hasattr(sub, "eqns"):
-                            n += equations(sub)
-            return n
-
-        def kernel_of(jaxpr):
-            for eqn in jaxpr.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    return eqn.params["jaxpr"]
-            raise AssertionError("no pallas_call at the top level")
-
         sizes = {}
         for slots in (pa.RAGGED_RING_SLOTS, 6):
             for T in (16, 512):
@@ -419,7 +423,7 @@ class TestStartupBudget:
                         i32())
                     module = traced.lower(
                         lowering_platforms=("tpu",)).as_text()
-                sizes[slots, T] = (equations(kernel_of(traced.jaxpr.jaxpr)),
+                sizes[slots, T] = (traced_equations(kernel_of(traced.jaxpr.jaxpr)),
                                    len(module))
         for key, (kernel, module) in sizes.items():
             assert kernel <= 1.2 * 187, (key, kernel)

@@ -35,8 +35,26 @@ the persistent compile cache (a process before it put it there), the
 seconds of trace, lower, compile (= retrieve + load) and first run of
 the kernel alone at the decode (T 16) and the chunk (T 512) shape.
 
-``--variant name=path[@CONST=int]`` loads ANOTHER copy of
-``paged_attention.py`` under its own name, optionally with one module
+``--latent`` probes ``ops/mla_attention.py``'s kernel instead, at
+``deepseek-v2-ep4``'s cell shapes (ISSUE 33, Step 0): a pool of
+``[5, 1, 2048, 128, 640]`` bfloat16, 128 heads, rank 512 + rope 64, 64
+rows x 64-page tables.  Cases: ``decode_r64_3800`` and ``decode_r1_3800``
+(decode rows at 3.8 k), ``fill_r64x13_at1700`` (the fill's step: 64 chunk
+rows of 13 tokens at 1.7 k), ``chunk832_at0`` and ``chunk832_at3000``.
+Beside µs a call: the (tile, row, page) visits of the call and µs a
+visit, against what a visit's bytes take at the HBM peak and its dots
+(live query rows x page x 2 x (2 rank + rope)) at the MXU peak;
+``roofline_pct`` is the larger of the two over the measured time.
+``--ring 2 4`` adds the tree's kernel at those ring depths
+(``@MLA_RING_SLOTS=n``; a slot is ``MLA_PAGES_PER_UPDATE`` pages).
+
+    chiprun -- python tools/kernel_probe.py --latent --ring 2 4 \
+        --variant parent=.archive_parent/fusioninfer_tpu/ops/mla_attention.py \
+        --variant unit1=fusioninfer_tpu/ops/mla_attention.py@MLA_PAGES_PER_UPDATE=1
+
+``--variant name=path[@CONST=int[,CONST=int]]`` loads ANOTHER copy of
+``paged_attention.py`` (``mla_attention.py`` with ``--latent``) under
+its own name, optionally with one module
 constant set before anything is traced, so one call compares kernels on
 one chip.  Every process but the first is a child (``--child``): the
 parent never touches jax, because a chip belongs to one process at a
@@ -66,6 +84,21 @@ REAL = dict(KV=8, G=2, Hd=128, page=128, n_pages=744, layers=2, table=32,
 TINY = dict(KV=2, G=2, Hd=64, page=16, n_pages=40, layers=2, table=8,
             rows=8, contexts=(40, 100), decode_rows=(1, 4),
             chunks=((12, 30),), kv_splits=8)
+LATENT_TREE = "fusioninfer_tpu/ops/mla_attention.py"
+LATENT_REAL = dict(H=128, rank=512, rope=64, W=640, page=128, n_pages=2048,
+                   layers=5, table=64, rows=64, first_T=(64, 1024),
+                   cases={"decode_r64_3800": ([1] * 64, [3799] * 64),
+                          "decode_r1_3800": ([1], [3799]),
+                          "fill_r64x13_at1700": ([13] * 64, [1700] * 64),
+                          "chunk832_at0": ([832], [0]),
+                          "chunk832_at3000": ([832], [3000])})
+LATENT_TINY = dict(H=4, rank=64, rope=16, W=128, page=16, n_pages=80,
+                   layers=2, table=8, rows=8, first_T=(16, 32),
+                   cases={"decode_r8_100": ([1] * 8, [99] * 8),
+                          "decode_r1_100": ([1], [99]),
+                          "fill_r8x3_at50": ([3] * 8, [50] * 8),
+                          "chunk24_at0": ([24], [0]),
+                          "chunk24_at60": ([24], [60])})
 
 
 def _load(name: str, spec: str):
@@ -75,8 +108,8 @@ def _load(name: str, spec: str):
     mod = importlib.util.module_from_spec(s)
     sys.modules[s.name] = mod
     s.loader.exec_module(mod)
-    if setting:
-        attr, value = setting.split("=")
+    for one in filter(None, setting.split(",")):
+        attr, value = one.split("=")
         if not hasattr(mod, attr):
             raise SystemExit(f"{path} has no constant {attr}")
         setattr(mod, attr, int(value))
@@ -156,6 +189,38 @@ def _kernel(mod, shape: dict, interpret: bool):
     return call
 
 
+def _median_us(many, args) -> float:
+    """Median µs a call over ``REPEATS`` runs of the jitted loop ``many``
+    (``CALLS`` kernel calls), after one run that compiles it."""
+    many(*args).block_until_ready()
+    times = []
+    for _ in range(REPEATS):
+        t0 = time.perf_counter()
+        many(*args).block_until_ready()
+        times.append((time.perf_counter() - t0) / CALLS * 1e6)
+    return statistics.median(times)
+
+
+def _first_dispatch(call, args) -> dict:
+    """Seconds of trace, lower, compile (= retrieve + load from a warm
+    cache) and first run of ``call``, and its lowered module's size."""
+    import jax
+
+    jax.block_until_ready(args)
+    t = [time.perf_counter()]
+    traced = jax.jit(call).trace(*args)
+    t.append(time.perf_counter())
+    lowered = traced.lower()
+    t.append(time.perf_counter())
+    compiled = lowered.compile()
+    t.append(time.perf_counter())
+    compiled(*args).block_until_ready()
+    t.append(time.perf_counter())
+    return dict(zip(("trace_s", "lower_s", "compile_s", "run_s"),
+                    (b - a for a, b in zip(t, t[1:]))),
+                module_chars=len(lowered.as_text()))
+
+
 def child_time(mod, shape: dict, interpret: bool) -> dict:
     """µs a call of every case, the fit, and the error against the jnp
     oracle on the mixed case."""
@@ -191,15 +256,9 @@ def child_time(mod, shape: dict, interpret: bool) -> dict:
     for name, (T, tables, st, qb, ql) in _cases(shape).items():
         q, kp, vp = _operands(shape, T)
         args = (q, kp, vp, *map(jnp.asarray, (tables, st, qb, ql)))
-        many(*args).block_until_ready()
-        times = []
-        for _ in range(REPEATS):
-            t0 = time.perf_counter()
-            many(*args).block_until_ready()
-            times.append((time.perf_counter() - t0) / CALLS * 1e6)
+        us = _median_us(many, args)
         counts = _walk_counts(tree, shape, T, st, qb, ql)
         pages = counts.pop("pages")
-        us = statistics.median(times)
         out[name] = {"us_per_call": us, "pages_read": pages,
                      "roofline_pct": 100 * pages * page_us / us,
                      "cold_waits": counts}
@@ -229,7 +288,6 @@ def child_time(mod, shape: dict, interpret: bool) -> dict:
 
 def child_first(mod, shape: dict, interpret: bool) -> dict:
     """Seconds of each stage of a first dispatch of the kernel alone."""
-    import jax
     import jax.numpy as jnp
 
     from fusioninfer_tpu.engine import aot
@@ -242,26 +300,131 @@ def child_first(mod, shape: dict, interpret: bool) -> dict:
         q, kp, vp = _operands(shape, T)
         args = (q, kp, vp, *map(jnp.asarray, (tables, st, qb, ql)),
                 jnp.int32(0))
-        jax.block_until_ready(args)
-        t = [time.perf_counter()]
-        traced = jax.jit(call).trace(*args)
-        t.append(time.perf_counter())
-        lowered = traced.lower()
-        t.append(time.perf_counter())
-        compiled = lowered.compile()
-        t.append(time.perf_counter())
-        compiled(*args).block_until_ready()
-        t.append(time.perf_counter())
-        out[f"t{T}"] = dict(zip(("trace_s", "lower_s", "compile_s", "run_s"),
-                                (b - a for a, b in zip(t, t[1:]))),
-                            module_chars=len(lowered.as_text()))
+        out[f"t{T}"] = _first_dispatch(call, args)
+    return out
+
+
+# -- the latent (MLA) leg ------------------------------------------------
+
+def _latent_operands(shape: dict, T: int):
+    import jax
+    import jax.numpy as jnp
+
+    pool = (shape["layers"], 1, shape["n_pages"], shape["page"], shape["W"])
+    ks = jax.random.split(jax.random.key(0), 3)
+    return (jax.random.normal(ks[0], (T, shape["H"], shape["rank"]),
+                              jnp.bfloat16) * 0.05,
+            jax.random.normal(ks[1], (T, shape["H"], shape["rope"]),
+                              jnp.bfloat16) * 0.05,
+            jax.random.normal(ks[2], pool, jnp.bfloat16))
+
+
+def _latent_visits(mod, shape: dict, T: int, st, qb, ql) -> dict:
+    """(tile, row, page) visits of one call and the query rows they
+    score, from the tree's walk lists."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fusioninfer_tpu.ops import paged_attention as pa
+
+    bq = mod.MLA_BLOCK_Q
+    tile_walks, w_row, first, end = (np.asarray(a) for a in pa._ragged_walks(
+        jnp.asarray(qb), jnp.asarray(ql), jnp.asarray(st), nb=T // bq,
+        block_q=bq, page_size=shape["page"], window=None))
+    visits = rows = 0
+    for t in range(T // bq):
+        for w in range(tile_walks[t], tile_walks[t + 1]):
+            r = w_row[w]
+            live = min(qb[r] + ql[r], (t + 1) * bq) - max(qb[r], t * bq)
+            visits += int(end[w] - first[w])
+            rows += int(end[w] - first[w]) * int(live) * shape["H"]
+    return {"visits": visits, "walks": int(tile_walks[-1]), "q_rows": rows}
+
+
+def latent_child_time(mod, shape: dict, interpret: bool) -> dict:
+    """µs a call of every latent case, µs a page visit against its bytes
+    and its dots, and the error against the jnp oracle on a mixed call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import peaks  # perfbench/peaks.py: the one table of chip peaks
+
+    L, rank = shape["layers"], shape["rank"]
+    kw = dict(rank=rank, interpret=interpret)
+
+    @jax.jit
+    def many(ql_, qr, pages, tables, st, qb, ql):
+        walks = {}
+        if hasattr(mod, "mla_walk_lists"):  # once a forward, as the engine
+            walks["walks"] = mod.mla_walk_lists(ql_.shape[0], pages, st, qb, ql)
+
+        def body(i, q):
+            return mod.mla_ragged_paged_attention(
+                q, qr, pages, tables, st, qb, ql, layer=jnp.int32(i % L),
+                **kw, **walks)
+        return jax.lax.fori_loop(0, CALLS, body, ql_)
+
+    peak = ({"hbm_bytes_per_s": 819e9, "flops_bf16": 197e12}  # a stand-in
+            if interpret else peaks.peaks_for(jax.devices()[0].device_kind))
+    page_us = shape["page"] * shape["W"] * 2 / peak["hbm_bytes_per_s"] * 1e6
+    row_us = (shape["page"] * 2 * (2 * rank + shape["rope"])
+              / peak["flops_bf16"] * 1e6)
+    out = {}
+    for name, (q_lens, starts) in shape["cases"].items():
+        T, tables, st, qb, ql = _case(shape, q_lens, starts)
+        q_lat, q_rope, pages = _latent_operands(shape, T)
+        args = (q_lat, q_rope, pages, *map(jnp.asarray, (tables, st, qb, ql)))
+        us = _median_us(many, args)
+        c = _latent_visits(mod, shape, T, st, qb, ql)
+        least = max(c["visits"] * page_us, c["q_rows"] * row_us)
+        out[name] = {"us_per_call": us, **c, "us_per_visit": us / c["visits"],
+                     "visit_us_at_hbm_peak": page_us,
+                     "visit_us_at_mxu_peak": c["q_rows"] * row_us / c["visits"],
+                     "roofline_pct": 100 * least / us}
+        print(f"  {name}: {us:.1f} us, {c['visits']} page visits, "
+              f"{us / c['visits']:.3f} us a visit, "
+              f"{out[name]['roofline_pct']:.1f} % of its roofline", flush=True)
+    # the oracle, on a call that mixes decode rows and chunk rows
+    ps = shape["page"]
+    T, tables, st, qb, ql = _case(
+        shape, [1, 1, ps + 5, 1, 3], [3 * ps, 5, ps // 2, 2 * ps - 1, ps - 2])
+    q_lat, q_rope, pages = _latent_operands(shape, T)
+    d = tuple(map(jnp.asarray, (tables, st, qb, ql)))
+    got = mod.mla_ragged_paged_attention(q_lat, q_rope, pages, *d, layer=1, **kw)
+    want = mod.reference_mla_ragged_paged_attention(
+        q_lat, q_rope, pages, *d, layer=1, rank=rank)
+    out["max_abs_err_vs_oracle"] = float(np.abs(
+        np.asarray(got, np.float32) - np.asarray(want, np.float32)).max())
+    return out
+
+
+def latent_child_first(mod, shape: dict, interpret: bool) -> dict:
+    """Seconds of each stage of a first dispatch of the latent kernel
+    alone, the walk lists built inside it."""
+    import jax.numpy as jnp
+
+    from fusioninfer_tpu.engine import aot
+
+    aot.configure_cache(min_compile_seconds=0.0)
+
+    def call(*a):
+        return mod.mla_ragged_paged_attention(
+            *a, layer=jnp.int32(0), rank=shape["rank"], interpret=interpret)
+
+    out = {}
+    q_lens, starts = next(iter(shape["cases"].values()))
+    for T in shape["first_T"]:
+        _, tables, st, qb, ql = _case(shape, q_lens, starts)
+        q_lat, q_rope, pages = _latent_operands(shape, T)
+        args = (q_lat, q_rope, pages, *map(jnp.asarray, (tables, st, qb, ql)))
+        out[f"t{T}"] = _first_dispatch(call, args)
     return out
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", action="append", default=[],
-                    help="name=path/to/paged_attention.py[@CONST=int]")
+                    help="name=path/to/paged_attention.py[@CONST=int[,CONST=int]]")
     ap.add_argument("--no-tree", action="store_true",
                     help="the variants only")
     ap.add_argument("--time-only", action="append", default=[],
@@ -270,11 +433,21 @@ def main() -> int:
                     help="CPU rehearsal: tiny shapes, interpret kernels")
     ap.add_argument("--kv-splits", type=int, default=8,
                     help="0: the single-walk grid, for ROADMAP S2's A/B")
+    ap.add_argument("--latent", action="store_true",
+                    help="the latent (MLA) kernel at deepseek-v2-ep4's shapes")
+    ap.add_argument("--ring", type=int, nargs="*", default=[],
+                    help="--latent: the tree's kernel at these ring depths too")
     ap.add_argument("--out", default="chiprun_out/kernel_probe/probe.json")
     ap.add_argument("--child", nargs=3, metavar=("KIND", "NAME", "SPEC"),
                     help=argparse.SUPPRESS)
     args = ap.parse_args()
-    shape = dict(TINY if args.tiny else REAL, kv_splits=args.kv_splits)
+    if args.latent:
+        shape, tree = LATENT_TINY if args.tiny else LATENT_REAL, LATENT_TREE
+        children = {"time": latent_child_time, "first": latent_child_first}
+    else:
+        shape, tree = dict(TINY if args.tiny else REAL,
+                           kv_splits=args.kv_splits), TREE
+        children = {"time": child_time, "first": child_first}
 
     if args.child:
         import jax
@@ -282,14 +455,14 @@ def main() -> int:
         kind, name, spec = args.child
         if not args.tiny and jax.default_backend() != "tpu":
             raise SystemExit("kernel_probe: no TPU (--tiny rehearses on the CPU)")
-        fn = child_time if kind == "time" else child_first
-        res = fn(_load(name, spec), shape, interpret=args.tiny)
+        res = children[kind](_load(name, spec), shape, interpret=args.tiny)
         res["device"] = jax.devices()[0].device_kind
         print(json.dumps(res))
         return 0
 
-    variants = ([] if args.no_tree else [("tree", TREE)]) + [
-        tuple(v.split("=", 1)) for v in args.variant]
+    variants = ([] if args.no_tree else [("tree", tree)]) + [
+        tuple(v.split("=", 1)) for v in args.variant] + [
+        (f"ring{n}", f"{tree}@MLA_RING_SLOTS={n}") for n in args.ring]
     report = {"shape": {k: v for k, v in shape.items()},
               "calls": CALLS, "repeats": REPEATS, "variants": {}}
     os.makedirs(os.path.dirname(os.path.join(REPO, args.out)), exist_ok=True)
@@ -304,7 +477,8 @@ def main() -> int:
             print(f"== {name} {key}", flush=True)
             cmd = [sys.executable, os.path.abspath(__file__), "--child",
                    kind, name, spec, "--kv-splits", str(args.kv_splits)] + (
-                       ["--tiny"] if args.tiny else [])
+                       ["--tiny"] if args.tiny else []) + (
+                       ["--latent"] if args.latent else [])
             p = subprocess.run(cmd, cwd=REPO, stdout=subprocess.PIPE, text=True)
             lines = p.stdout.strip().splitlines()
             print("\n".join(lines[:-1]), flush=True)
