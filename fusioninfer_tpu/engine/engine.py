@@ -843,8 +843,10 @@ class NativeEngine:
             "kv_layout": "latent" if cfg.is_mla else "heads",
             # the one expert layer: sorted assignments through a grouped
             # product over the held experts, no capacity, nothing dropped
-            "moe_experts": ("%s dropless %d/%d" % (
-                grouped_matmul_impl(), cfg.experts_held, cfg.n_experts)
+            "moe_experts": ("%s dropless %d/%d%s" % (
+                grouped_matmul_impl(), cfg.experts_held, cfg.n_experts,
+                " + %d identity" % cfg.n_zero_experts
+                if cfg.n_zero_experts else "")
                 if cfg.is_moe else None),
             "token_budget": self.token_budget,
             "decode_burst": self.burst_steps,

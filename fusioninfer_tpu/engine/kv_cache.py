@@ -14,8 +14,10 @@ whole (see :mod:`fusioninfer_tpu.ops.paged_attention`).  The kv-head
 axis is also the ``tp`` shard axis.
 
 A model with latent attention (``cfg.is_mla``) caches ONE row per
-position and layer, shared by every head: the pool is one array
-``cache["kv"]`` of ``[n_layers, 1, n_pages, page_size, W]`` (``W`` =
+position and attention, shared by every head: the pool is one array
+``cache["kv"]`` of ``[n_cache_layers, 1, n_pages, page_size, W]`` (a
+layer of the stack may hold two attentions: ``cfg.n_cache_layers``;
+``W`` =
 ``cfg.latent_row_width``: the row's 576 values in whole 128-lane tiles)
 behind the same pages, page tables and allocator.  A model with expert
 layers also carries their counters in ``cache["moe_stats"]``
@@ -88,10 +90,10 @@ def init_kv_cache(cfg: ModelConfig, cache_cfg: CacheConfig) -> dict:
             raise ValueError("int8 pages are not available for a latent "
                              "(MLA) cache")
         return {"kv": jnp.zeros(
-            (cfg.n_layers, 1, cache_cfg.n_pages, cache_cfg.page_size,
+            (cfg.n_cache_layers, 1, cache_cfg.n_pages, cache_cfg.page_size,
              cfg.latent_row_width), cfg.jax_dtype), **stats}
     shape = (
-        cfg.n_layers,
+        cfg.n_cache_layers,
         cfg.n_kv_heads,
         cache_cfg.n_pages,
         cache_cfg.page_size,
@@ -99,7 +101,7 @@ def init_kv_cache(cfg: ModelConfig, cache_cfg: CacheConfig) -> dict:
     )
     if cache_cfg.quantized:
         scale_shape = (
-            cfg.n_layers,
+            cfg.n_cache_layers,
             cfg.n_kv_heads,
             cache_cfg.n_pages,
             1,
@@ -124,13 +126,13 @@ def page_bytes(cfg: ModelConfig, page_size: int,
     """Device bytes one KV page costs (k + v, or the latent rows; all
     layers).  A latent row is priced at the width it is stored at."""
     if cfg.is_mla:
-        return (cfg.n_layers * page_size * cfg.latent_row_width
+        return (cfg.n_cache_layers * page_size * cfg.latent_row_width
                 * jnp.dtype(cfg.jax_dtype).itemsize)
     if kv_dtype == "int8":
         per_token = cfg.head_dim * 1 + 4  # int8 values + one f32 scale
     else:
         per_token = cfg.head_dim * jnp.dtype(cfg.jax_dtype).itemsize
-    return 2 * cfg.n_layers * page_size * cfg.n_kv_heads * per_token
+    return 2 * cfg.n_cache_layers * page_size * cfg.n_kv_heads * per_token
 
 
 def model_param_bytes(cfg: ModelConfig) -> int:

@@ -172,6 +172,7 @@ class EngineMetrics:
                 ("assignments_local", "Assignments to an expert held by this process, each computed (no capacity, none dropped)"),
                 ("expert_touches", "Held experts with at least one row, summed over expert layers and forward passes"),
                 ("layer_passes", "Forward passes through an expert layer"),
+                ("assignments_zero", "Assignments to an identity (zero-compute) expert: the token's own input, weighted, computed where the token lives with no weights read and nothing exchanged"),
             ) for line in (
                 f"# HELP fusioninfer:moe_{name}_total {what}.",
                 f"# TYPE fusioninfer:moe_{name}_total counter",

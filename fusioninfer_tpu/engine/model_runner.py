@@ -40,12 +40,14 @@ from fusioninfer_tpu.models.quantization import (
 )
 from fusioninfer_tpu.models.transformer import (
     EXPERT_MATRICES,
+    SUBLAYER_MATRICES,
     attn_out_proj,
     layer_forward,
     layer_stacks,
     lm_head,
     mla_absorb_queries,
     mla_attn_out,
+    mla_block,
     mla_latent,
     mla_queries,
     mlp_block,
@@ -69,6 +71,13 @@ def _scan_layers(cfg, params, lora, body, carry):
         # product reads layer l of it in place (transformer.grouped_matmul)
         whole = {k: stack[k] for k in EXPERT_MATRICES
                  if "router" in stack and not is_quantized(stack[k])}
+        # nor are a double layer's twice-held matrices [L, 2, ...]: the
+        # scan's slice [2, ...] of one is a buffer of its own, written
+        # every layer (1.1 GB of dense-FFN weights a layer at
+        # LongCat-Flash's widths), where a dot reads ONE matrix of the
+        # stack in place (transformer.mla_block indexes it by 2 l + i)
+        if cfg.sublayers > 1:
+            whole.update({k: stack[k] for k in SUBLAYER_MATRICES})
         xs = [{k: v for k, v in stack.items() if k not in whole}]
         if lora is not None:
             xs.append(lora)
@@ -155,14 +164,38 @@ def _add_moe_stats(cache: dict, stats) -> dict:
     return {**cache, "moe_stats": cache["moe_stats"] + stats}
 
 
+def _cache_layer_of(cfg, l, i: int):
+    """The pool's layer of attention ``i`` of stack layer ``l``: ``l``
+    itself where a layer has one attention (nothing is traced for it)."""
+    return l if cfg.sublayers == 1 else cfg.sublayers * l + i
+
+
+def _mla_paged_block(cfg, layer, x, positions, cache, l, live, write_page,
+                     write_slot, rows, *, use_kernel, interpret, walks):
+    """One block (:func:`transformer.mla_block`) of flat tokens ``x``
+    [T, 1, D] whose attentions run over latent pages
+    (:func:`_mla_attn_block`, each into its own cache layer) → the scan
+    carry ``(x, cache)`` with the expert layer's counters added."""
+
+    def attend(i, sub, x, cache):
+        return _mla_attn_block(
+            cfg, sub, x, positions, cache, _cache_layer_of(cfg, l, i),
+            write_page, write_slot, *rows, use_kernel=use_kernel,
+            interpret=interpret, walks=walks)
+
+    x, cache, stats = mla_block(cfg, layer, x, attend, cache, live)
+    return x, _add_moe_stats(cache, stats)
+
+
 def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
                     write_slot, page_tables, row_starts, q_begins, q_lens,
                     *, use_kernel, interpret, walks=None):
     """Latent attention of flat tokens ``x`` [T, 1, D] over their rows'
     pages, the one body of decode and chunk rows alike: project, write
-    each token's latent row, attend in the absorbed form straight over
-    the latent pages → (cache, attention output [T, 1, D], residual NOT
-    added).  ``walks``: :func:`_ragged_walks` of the same rows."""
+    each token's latent row into cache layer ``l``, attend in the
+    absorbed form straight over the latent pages → (cache, attention
+    output [T, 1, D], residual NOT added).  ``walks``:
+    :func:`_ragged_walks` of the same rows."""
     from fusioninfer_tpu.ops.mla_attention import (
         mla_ragged_paged_attention,
         reference_mla_ragged_paged_attention,
@@ -314,9 +347,11 @@ def prefill(
             cfg, layer, x, positions, mesh=mesh, lora=layer_lora,
             adapter_ids=adapter_ids, live=live)
         if cfg.is_mla:  # a fresh prompt attends in the expanded form and
-            # caches what decode will read: the latent rows [B, S, .]
-            cache = _scatter_latent(cache, l, kv, page_of_token,
-                                    slot_of_token)
+            # caches what decode will read: each attention's latent rows
+            # [B, S, .] into its own cache layer
+            for i, latent in enumerate(kv):
+                cache = _scatter_latent(cache, _cache_layer_of(cfg, l, i),
+                                        latent, page_of_token, slot_of_token)
         else:
             # stacked head-major cache [L, KV, n_pages, ps, Hd]; k is
             # [B, S, KV, Hd] → in-place scatter at layer l, [B, S] maps
@@ -387,13 +422,10 @@ def _decode_step_impl(
         if cfg.is_mla:
             # B rows of one token each through the latent kernel: the
             # same body (and bits) the fused step scores decode rows with
-            cache, attn_out = _mla_attn_block(
-                cfg, layer, x, positions, cache, l, write_page, write_slot,
-                *rows, use_kernel=use_kernel,
-                interpret=dispatch.kernel_interpret(), walks=walks)
-            x = x + attn_out
-            y, stats = mlp_block(cfg, layer, x, active[:, None])
-            return (x + y, _add_moe_stats(cache, stats)), None
+            return _mla_paged_block(
+                cfg, layer, x, positions, cache, l, active[:, None],
+                write_page, write_slot, rows, use_kernel=use_kernel,
+                interpret=dispatch.kernel_interpret(), walks=walks), None
         q, k, v = qkv_proj(cfg, layer, x, pos, layer_lora, adapter_ids)
 
         # write this step's K/V into each sequence's page slot (stacked
@@ -671,14 +703,12 @@ def fused_step(
 
         layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
         if cfg.is_mla:
-            cache, attn_out = _mla_attn_block(
-                cfg, layer, x, positions, cache, l, write_page, write_slot,
-                page_tables, row_starts, q_begins, q_lens,
+            return _mla_paged_block(
+                cfg, layer, x, positions, cache, l, live[:, None],
+                write_page, write_slot,
+                (page_tables, row_starts, q_begins, q_lens),
                 use_kernel=use_kernel, interpret=dispatch.kernel_interpret(),
-                walks=walks)
-            x = x + attn_out
-            y, stats = mlp_block(cfg, layer, x, live[:, None])
-            return (x + y, _add_moe_stats(cache, stats)), None
+                walks=walks), None
         q, k, v = qkv_proj(cfg, layer, x, pos2, layer_lora, adapter_tok)
 
         # stacked head-major cache [L, KV, n_pages, ps, Hd]; k[:, 0] is

@@ -82,6 +82,27 @@ class ModelConfig:
     # YaRN rotary scaling as (factor, original_max_position, beta_fast,
     # beta_slow, mscale, mscale_all_dim); None = plain rotary embedding
     rope_yarn: tuple | None = None
+    # LongCat-Flash's latent attention: the query is scaled by
+    # sqrt(d_model / q_lora_rank) and the normed compressed KV by
+    # sqrt(d_model / kv_lora_rank) (``mla_scale_q_lora`` /
+    # ``mla_scale_kv_lora`` of its config.json)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
+    # identity ("zero-compute") experts: the router has ``n_experts +
+    # n_zero_experts`` outputs, and a token that chooses one of the last
+    # ``n_zero_experts`` gets its own input back, weighted, with no
+    # weights read and nothing exchanged
+    n_zero_experts: int = 0
+    # the router's score-correction bias (one float32 a router output,
+    # ``e_score_correction_bias``): added for the CHOICE of experts only,
+    # the weights stay the uncorrected scores
+    router_score_bias: bool = False
+    # "single": attention, then the FFN.  "shortcut_double"
+    # (LongCat-Flash's shortcut-connected layer): attention 0, then dense
+    # FFN 0 AND, from the same normed input, the expert layer whose
+    # result is held back; attention 1, dense FFN 1; only then the expert
+    # layer's result is added.  One layer = two cache layers.
+    block: str = "single"
 
     @property
     def jax_dtype(self):
@@ -117,6 +138,32 @@ class ModelConfig:
         return self.n_experts_held or self.n_experts
 
     @property
+    def router_width(self) -> int:
+        """The router's outputs: the routed experts, then the identity
+        experts."""
+        return self.n_experts + self.n_zero_experts
+
+    @property
+    def sublayers(self) -> int:
+        """Attentions (and dense FFNs) in one layer of the stack."""
+        return 2 if self.block == "shortcut_double" else 1
+
+    @property
+    def n_cache_layers(self) -> int:
+        """Layers of the KV pool: one for every attention."""
+        return self.n_layers * self.sublayers
+
+    @property
+    def mla_q_scale(self) -> float:
+        return ((self.d_model / self.q_lora_rank) ** 0.5
+                if self.mla_scale_q_lora else 1.0)
+
+    @property
+    def mla_kv_scale(self) -> float:
+        return ((self.d_model / self.kv_lora_rank) ** 0.5
+                if self.mla_scale_kv_lora else 1.0)
+
+    @property
     def n_dense_layers(self) -> int:
         """Layers with a dense FFN: all of them without experts."""
         return min(self.first_k_dense, self.n_layers) if self.is_moe else self.n_layers
@@ -142,7 +189,17 @@ class ModelConfig:
                 "held experts lie outside the router's width"
         else:
             assert not (self.first_k_dense or self.n_shared_experts
-                        or self.n_experts_held), "expert fields without experts"
+                        or self.n_experts_held or self.n_zero_experts
+                        or self.router_score_bias), \
+                "expert fields without experts"
+        assert self.block in ("single", "shortcut_double"), self.block
+        if self.block == "shortcut_double":
+            assert self.is_mla and self.is_moe and not self.first_k_dense, \
+                "a shortcut-connected double layer has latent attention, " \
+                "an expert layer and no leading dense layers"
+        assert not ((self.n_zero_experts or self.router_score_bias)
+                    and (self.norm_topk or self.n_group > 1)), \
+            "identity experts and the score bias go with plain softmax scores"
         if self.is_mla:
             assert min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
                        self.v_head_dim) > 0, "MLA needs all of its widths"
@@ -150,6 +207,7 @@ class ModelConfig:
             assert self.sliding_window is None and not self.qk_norm, \
                 "latent attention has neither a window nor per-head QK norm"
         else:
+            assert not (self.mla_scale_q_lora or self.mla_scale_kv_lora)
             assert not self.first_k_dense, \
                 "leading dense layers are drawn only for a latent-attention model"
         return self
@@ -360,5 +418,81 @@ register_preset(
         qk_rope_dim=16,
         v_head_dim=32,
         **{**_DEEPSEEK_V2, "n_experts_active": 3, "routed_scaling": 4.0},
+    )
+)
+
+# LongCat-Flash-Chat (huggingface.co/meituan-longcat/LongCat-Flash-Chat
+# config.json): shortcut-connected double layers (two latent attentions,
+# two dense FFNs, one expert layer on the shortcut), 512 routed + 256
+# identity experts, 12 a token, scaled latent attention, plain rotary.
+_LONGCAT_FLASH = dict(
+    qk_norm=False,
+    tie_embeddings=False,
+    rope_theta=10_000_000.0,
+    rms_eps=1e-5,
+    norm_topk=False,
+    block="shortcut_double",
+    router_score_bias=True,
+    mla_scale_q_lora=True,
+    mla_scale_kv_lora=True,
+)
+
+# One chip's share of a 32-chip expert-parallel deployment at published
+# widths (PERF.md section 4): four double layers (eight cache layers),
+# experts 0-15 of the 512, an eighth of the vocabulary.
+register_preset(
+    ModelConfig(
+        name="longcat-flash-ep32",
+        vocab_size=16_384,
+        d_model=6144,
+        n_layers=4,
+        n_heads=64,
+        n_kv_heads=64,
+        head_dim=192,  # qk_nope_dim + qk_rope_dim
+        d_ff=12_288,
+        max_seq_len=131_072,
+        n_experts=512,
+        n_zero_experts=256,
+        n_experts_active=12,
+        routed_scaling=6.0,
+        moe_d_ff=2048,
+        n_experts_held=16,
+        expert_offset=0,
+        kv_lora_rank=512,
+        q_lora_rank=1536,
+        qk_nope_dim=128,
+        qk_rope_dim=64,
+        v_head_dim=128,
+        **_LONGCAT_FLASH,
+    )
+)
+
+# The same architecture at a test size: 2 double layers, 16 routed + 8
+# identity experts, 4 a token, experts 4-7 held; deepseek-v2-tiny's
+# latent-attention sizes.
+register_preset(
+    ModelConfig(
+        name="longcat-flash-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=4,
+        head_dim=48,
+        d_ff=256,
+        max_seq_len=4096,
+        n_experts=16,
+        n_zero_experts=8,
+        n_experts_active=4,
+        routed_scaling=3.0,
+        moe_d_ff=64,
+        n_experts_held=4,
+        expert_offset=4,
+        kv_lora_rank=64,
+        q_lora_rank=96,
+        qk_nope_dim=32,
+        qk_rope_dim=16,
+        v_head_dim=32,
+        **_LONGCAT_FLASH,
     )
 )

@@ -132,13 +132,24 @@ def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array) -> jax.Array
         yarn_mscale(factor, mscale) / yarn_mscale(factor, all_dim))
 
 
+def _scaled_gain(gain: jax.Array, scale: float) -> jax.Array:
+    """A norm's gain times a Python ``scale``, in float32, so the scaled
+    result is rounded once; the gain itself where the scale is 1 (no
+    operation is traced for it)."""
+    return gain if scale == 1.0 else gain.astype(jnp.float32) * scale
+
+
 @jax.named_scope("mla_q")
 def mla_queries(cfg: ModelConfig, layer: Params, h: jax.Array,
                 positions: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Normed input ``h`` [B, S, D] → per-head queries: the no-position
-    part [B, S, H, nope] and the rotated rope part [B, S, H, rope]."""
+    part [B, S, H, nope] and the rotated rope part [B, S, H, rope].
+    ``cfg.mla_q_scale`` multiplies the compressed query (and so, the
+    up-projection being linear, every query)."""
     B, S, _ = h.shape
-    c_q = rms_norm(h @ layer["wq_a"], layer["q_a_norm"], cfg.rms_eps)
+    c_q = rms_norm(h @ layer["wq_a"],
+                   _scaled_gain(layer["q_a_norm"], cfg.mla_q_scale),
+                   cfg.rms_eps)
     q = (c_q @ layer["wq_b"]).reshape(
         B, S, cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim)
     return (q[..., :cfg.qk_nope_dim],
@@ -149,11 +160,15 @@ def mla_queries(cfg: ModelConfig, layer: Params, h: jax.Array,
 def mla_latent(cfg: ModelConfig, layer: Params, h: jax.Array,
                positions: jax.Array) -> jax.Array:
     """Normed input ``h`` [B, S, D] → the row a position caches
-    [B, S, kv_lora_rank + rope]: the compressed KV after its norm, then
-    the one rope key every head shares, after rotation."""
+    [B, S, kv_lora_rank + rope]: the compressed KV after its norm (times
+    ``cfg.mla_kv_scale``: the SCALED row is cached, so the up-projections
+    and the absorbed form stay as they are), then the one rope key every
+    head shares, after rotation."""
     r = cfg.kv_lora_rank
     ckv = h @ layer["wkv_a"]
-    c = rms_norm(ckv[..., :r], layer["kv_a_norm"], cfg.rms_eps)
+    c = rms_norm(ckv[..., :r],
+                 _scaled_gain(layer["kv_a_norm"], cfg.mla_kv_scale),
+                 cfg.rms_eps)
     k_rope = _mla_rope(cfg, ckv[..., None, r:], positions)[..., 0, :]
     return jnp.concatenate([c, k_rope], axis=-1)
 
@@ -255,11 +270,14 @@ def moe_ffn(x: jax.Array, router_w: jax.Array, w_gate: jax.Array, w_up: jax.Arra
 
 
 @jax.named_scope("moe_route")
-def moe_route(cfg: ModelConfig, h: jax.Array,
-              router_w: jax.Array) -> tuple[jax.Array, jax.Array]:
+def moe_route(cfg: ModelConfig, h: jax.Array, router_w: jax.Array,
+              bias: Optional[jax.Array] = None
+              ) -> tuple[jax.Array, jax.Array]:
     """Each token's experts and their weights, over the router's whole
-    width whatever share is held here → (ids [T, k] int32, weights
-    [T, k] float32).
+    width whatever share is held here (identity experts, the last
+    ``n_zero_experts`` outputs, among them) → (ids [T, k] int32, weights
+    [T, k] float32).  ``bias`` [E] (a score-correction bias) is added for
+    the choice only: the weights are the uncorrected scores.
 
     ``norm_topk``: top-k of the logits, weights a softmax over the
     chosen.  Otherwise the scores are a softmax over all experts, kept
@@ -279,17 +297,47 @@ def moe_route(cfg: ModelConfig, h: jax.Array,
             jnp.arange(T)[:, None], kept].set(True)
         choose = jnp.where(jnp.repeat(keep, per_group, axis=1), scores,
                            -jnp.inf)
+    if bias is not None:
+        choose = choose + bias
     top_vals, top_idx = lax.top_k(choose, cfg.n_experts_active)
+    if bias is not None:
+        top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
     weights = (jax.nn.softmax(top_vals, axis=-1) if cfg.norm_topk
                else top_vals * cfg.routed_scaling)
     return top_idx.astype(jnp.int32), weights
 
 
 # megablox tiles (rows, contraction, columns) of the grouped product on
-# the chip: of the four tried at the served shapes (decode pass: 384 rows,
-# 36 experts touched; chunk pass: 3072 rows) the fastest on both matrices
-# (PERF.md section 6, PR 29); rows are padded to whole tiles
-GMM_TILING = (128, 2560, 768)
+# the chip, by the product's (K, N); rows are padded to whole tiles.
+# DeepSeek-V2's two (5120 x 1536 and back): of the four tried at the
+# served shapes (decode pass: 384 rows, 36 experts touched; chunk pass:
+# 3072 rows) the fastest on both matrices (PERF.md section 6, PR 29).
+# LongCat-Flash's two (6144 x 2048 and back): whole tiles in K and N; of
+# seven tried a matrix at a decode pass (64 tokens, 11 experts touched)
+# and a chunk pass (832 tokens) the fastest on the first (476 / 708 us
+# against 532 / 793 for DeepSeek's tuple) and within 1.5 % of the best on
+# the second (PERF.md section 6, PR 34).
+GMM_TILING = {
+    (5120, 1536): (128, 2560, 768), (1536, 5120): (128, 2560, 768),
+    (6144, 2048): (128, 2048, 1024), (2048, 6144): (128, 2048, 1024),
+}
+GMM_TILE_ELEMENTS = 2560 * 768  # a weight tile of any other product
+
+
+def gmm_tiling(k: int, n: int) -> tuple[int, int, int]:
+    """The grouped product's tiles for a ``[K, N]`` matrix: the probed
+    tuple where the shape has one, else the largest whole 128-multiples
+    dividing K (at most 2560) and N within one weight tile's budget, so
+    that no tile is ragged."""
+    if (k, n) in GMM_TILING:
+        return GMM_TILING[(k, n)]
+
+    def whole(dim: int, most: int) -> int:
+        fits = [t for t in range(128, min(dim, most) + 1, 128) if dim % t == 0]
+        return fits[-1] if fits else min(dim, most)
+
+    tk = whole(k, 2560)
+    return 128, tk, whole(n, max(128, GMM_TILE_ELEMENTS // tk))
 
 
 def grouped_matmul_impl() -> str:
@@ -331,10 +379,11 @@ def grouped_matmul(xs: jax.Array, w, group_sizes: jax.Array,
             jnp.zeros((w.shape[0],), group_sizes.dtype), group_sizes,
             (stack_layer * group_sizes.shape[0],))
     A = xs.shape[0]
-    pad = -A % GMM_TILING[0]
+    tiling = gmm_tiling(*w.shape[1:])
+    pad = -A % tiling[0]
     out = gmm(jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs, w, group_sizes,
               preferred_element_type=jnp.dtype(out_dtype),
-              tiling=GMM_TILING)[:A]
+              tiling=tiling)[:A]
     # the kernel leaves rows past the last group unwritten
     rows = lax.broadcasted_iota(jnp.int32, (A, 1), 0)
     return jnp.where(rows < jnp.sum(group_sizes), out, 0)
@@ -345,21 +394,28 @@ EXPERT_MATRICES = ("w_gate", "w_up", "w_down")
 
 # counters a pass through an expert layer adds (engine: fusioninfer:moe_*)
 MOE_STATS = ("assignments", "assignments_local", "expert_touches",
-             "layer_passes")
+             "layer_passes", "assignments_zero")
 
 
 def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
               live: Optional[jax.Array] = None) -> tuple[jax.Array, jax.Array]:
-    """The ONE expert layer: route over all ``n_experts``, compute the
-    part of the result that the experts held here give, with no capacity
-    and no assignment dropped, plus the shared experts every token goes
-    through → (y [T, D], stats uint32 [4] in :data:`MOE_STATS` order).
+    """The ONE expert layer: route over the router's whole width,
+    compute the part of the result that the experts held here give, with
+    no capacity and no assignment dropped, plus what every process
+    computes alike for its OWN tokens: the shared experts and the
+    identity experts' term → (y [T, D], stats uint32 in
+    :data:`MOE_STATS` order).
 
-    Assignments are sorted by held expert (the others, and those of
-    tokens not ``live``, sort past the last group and weigh nothing),
-    their tokens' rows gathered, and each expert's rows go through its
-    own matrices in one grouped product; the weighted results return to
-    token order and sum in float32.  What the experts held elsewhere
+    An assignment is of three kinds: to an expert held here, to one held
+    elsewhere, or to an identity expert (id >= ``n_experts``), which
+    returns its input: those weights sum into one ``[T]`` factor on
+    ``h`` (scope ``moe_zero``), with no weights read and no exchange.
+
+    Assignments are sorted by held expert (the others, identity ones
+    included, and those of tokens not ``live``, sort past the last group
+    and weigh nothing), their tokens' rows gathered, and each expert's
+    rows go through its own matrices in one grouped product; the
+    weighted results return to token order and sum in float32.  What the experts held elsewhere
     would add is left out: on one process of an expert-parallel group
     that is its share before the exchange."""
     T, D = h.shape
@@ -367,7 +423,8 @@ def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
     k = cfg.n_experts_active
     held = (w_gate[0].shape[1] if isinstance(w_gate, tuple)
             else w_gate.shape[0])
-    top_idx, top_w = moe_route(cfg, h, layer["router"])
+    top_idx, top_w = moe_route(cfg, h, layer["router"],
+                               layer.get("router_bias"))
     with jax.named_scope("moe_experts"):
         local = top_idx - cfg.expert_offset
         mine = (local >= 0) & (local < held)
@@ -389,9 +446,18 @@ def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
         with jax.named_scope("moe_shared"):
             y = y + swiglu(h, layer["ws_gate"], layer["ws_up"],
                            layer["ws_down"]).astype(jnp.float32)
+    n_zero = jnp.zeros((), jnp.int32)
+    if cfg.n_zero_experts:
+        with jax.named_scope("moe_zero"):
+            zero = top_idx >= cfg.n_experts
+            if live is not None:
+                zero = zero & live[:, None]
+            factor = jnp.sum(jnp.where(zero, top_w, 0.0), axis=1)  # [T]
+            y = y + factor[:, None] * h.astype(jnp.float32)
+            n_zero = jnp.sum(zero)
     n_live = T if live is None else jnp.sum(live)
     stats = jnp.stack([n_live * k, jnp.sum(sizes), jnp.sum(sizes > 0),
-                       jnp.ones((), jnp.int32)]).astype(jnp.uint32)
+                       jnp.ones((), jnp.int32), n_zero]).astype(jnp.uint32)
     return y.astype(h.dtype), stats
 
 
@@ -475,14 +541,24 @@ STACK_SLOTS = {
     "wq_a": 14, "wq_b": 15, "wkv_a": 16, "wkv_b": 17,
     "w_gate": 20, "w_up": 21, "w_down": 22, "router": 23,
     "ws_gate": 24, "ws_up": 25, "ws_down": 26,
+    "wd_gate": 27, "wd_up": 28, "wd_down": 29,
 }
 DENSE_STACK_SLOT_OFFSET = 100  # the leading dense layers' matrices
+# what a shortcut-connected double layer holds twice, on a sub-layer
+# axis after the layer axis: norms, attention, and the dense FFNs
+# (``wd_*``: ``w_*`` are the expert stack's there)
+SUBLAYER_MATRICES = ("wq_a", "wq_b", "wkv_a", "wkv_b", "wo",
+                     "wd_gate", "wd_up", "wd_down")
+SUBLAYER_PARAMS = ("attn_norm", "mlp_norm", "q_a_norm", "kv_a_norm",
+                   *SUBLAYER_MATRICES)
 
 
 def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
     """name -> (one layer's shape, fan_in) of the seeded matrices of a
-    layer of the dense stack or of the expert stack."""
-    D, H = cfg.d_model, cfg.n_heads
+    layer of the dense stack or of the expert stack.  In a
+    shortcut-connected double layer the attention matrices and the two
+    dense FFNs (``wd_*``) lead with a sub-layer axis of 2."""
+    D, H, F = cfg.d_model, cfg.n_heads, cfg.d_ff
     qk = cfg.qk_nope_dim + cfg.qk_rope_dim
     out = {
         "wq_a": ((D, cfg.q_lora_rank), D),
@@ -492,12 +568,15 @@ def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
                   cfg.kv_lora_rank),
         "wo": ((cfg.attn_out_dim, D), cfg.attn_out_dim),
     }
+    if cfg.sublayers > 1:
+        out.update(wd_gate=((D, F), D), wd_up=((D, F), D), wd_down=((F, D), F))
+        out = {name: ((cfg.sublayers, *shape), fan_in)
+               for name, (shape, fan_in) in out.items()}
     if not experts:
-        F = cfg.d_ff
         out.update(w_gate=((D, F), D), w_up=((D, F), D), w_down=((F, D), F))
         return out
     E, EF = cfg.experts_held, cfg.expert_d_ff
-    out.update(router=((D, cfg.n_experts), D),
+    out.update(router=((D, cfg.router_width), D),
                w_gate=((E, D, EF), D), w_up=((E, D, EF), D),
                w_down=((E, EF, D), EF))
     if cfg.n_shared_experts:
@@ -518,12 +597,17 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array) -> Params:
     D = cfg.d_model
 
     def stack(n: int, experts: bool, slot_offset: int) -> Params:
+        lead = (n,) if cfg.sublayers == 1 else (n, cfg.sublayers)
         layers: Params = {
-            "attn_norm": jnp.ones((n, D), dtype),
-            "mlp_norm": jnp.ones((n, D), dtype),
-            "q_a_norm": jnp.ones((n, cfg.q_lora_rank), dtype),
-            "kv_a_norm": jnp.ones((n, cfg.kv_lora_rank), dtype),
+            "attn_norm": jnp.ones((*lead, D), dtype),
+            "mlp_norm": jnp.ones((*lead, D), dtype),
+            "q_a_norm": jnp.ones((*lead, cfg.q_lora_rank), dtype),
+            "kv_a_norm": jnp.ones((*lead, cfg.kv_lora_rank), dtype),
         }
+        if experts and cfg.router_score_bias:
+            # a buffer, zeros until a checkpoint brings a trained one
+            layers["router_bias"] = jnp.zeros((n, cfg.router_width),
+                                              jnp.float32)
         for name, (shape, fan_in) in stack_matrix_shapes(cfg, experts).items():
             k_m = jax.random.fold_in(key, STACK_SLOTS[name] + slot_offset)
             buf = jnp.zeros((n, *shape), dtype)
@@ -646,6 +730,55 @@ def attn_out_proj(layer: Params, attn: jax.Array, lora: Params = None,
     return out
 
 
+def mla_block(cfg: ModelConfig, layer: Params, x: jax.Array, attend,
+              carry, live: Optional[jax.Array] = None):
+    """ONE block of a latent-attention model → ``(x, carry, stats)``:
+    the body the no-cache forward and the three serving programs share.
+    They differ only in how a sub-layer attends, so that is a callback:
+    ``attend(i, sub, x, carry) -> (carry, attention output, residual NOT
+    added)`` for sub-layer ``i`` with its weights ``sub`` (a fresh causal
+    sequence in the expanded form in :func:`layer_forward`; the absorbed
+    form over latent pages in ``model_runner``), ``carry`` being the
+    caller's own (the rows to cache; the pool).
+
+    ``cfg.block`` "single": attention, then the FFN (:func:`mlp_block`).
+    "shortcut_double": ``a0 = x + MLA_0(N(x))``; ``m = N(a0)``; the
+    expert layer's ``s = MoE(m)`` is held back while ``b0 = a0 +
+    FFN_0(m)``, ``a1 = b0 + MLA_1(N(b0))``, ``b1 = a1 + FFN_1(N(a1))``
+    run; ``out = b1 + s``.  x: [B, S, D]; ``live`` [B, S] marks real
+    tokens for the experts; ``stats``: the expert layer's counters."""
+    if cfg.block == "single":
+        carry, attn = attend(0, layer, x, carry)
+        x = x + attn
+        y, stats = mlp_block(cfg, layer, x, live)
+        return x + y, carry, stats
+    B, S, D = x.shape
+
+    def of_sublayer(v, i):
+        """``v``: this layer's two ``[2, ...]``, or ``(stack [L, 2, ...],
+        l)``: matrix ``2 l + i`` of the whole stack, read in place."""
+        if not isinstance(v, tuple):
+            return v[i]
+        stack, l = v
+        return lax.dynamic_index_in_dim(
+            stack.reshape(-1, *stack.shape[2:]), cfg.sublayers * l + i, 0,
+            keepdims=False)
+
+    for i in range(cfg.sublayers):
+        sub = {k: (of_sublayer(v, i) if k in SUBLAYER_PARAMS else v)
+               for k, v in layer.items()}
+        carry, attn = attend(i, sub, x, carry)
+        x = x + attn
+        h = rms_norm(x, sub["mlp_norm"], cfg.rms_eps)
+        if i == 0:  # the shortcut: read here, added after the last FFN
+            shortcut, stats = moe_layer(
+                cfg, layer, h.reshape(B * S, D),
+                None if live is None else live.reshape(B * S))
+        with jax.named_scope("mlp"):
+            x = x + swiglu(h, sub["wd_gate"], sub["wd_up"], sub["wd_down"])
+    return x + shortcut.reshape(B, S, D), carry, stats
+
+
 def layer_forward(
     cfg: ModelConfig,
     layer: Params,
@@ -659,9 +792,10 @@ def layer_forward(
     live: Optional[jax.Array] = None,
 ):
     """One transformer block → ``(output, kv, stats)``: ``kv`` is what a
-    position caches, (k, v) or with latent attention the latent rows
-    [B, S, rank + rope]; ``stats`` the expert layer's counters (None for
-    a dense FFN).  ``live`` [B, S] marks real tokens for the experts.
+    position caches, (k, v) or with latent attention a tuple of the
+    latent rows [B, S, rank + rope] of each of the block's attentions;
+    ``stats`` the expert layer's counters (None for a dense FFN).
+    ``live`` [B, S] marks real tokens for the experts.
 
     x: [B, S, D]; positions: [B, S]; mask broadcastable to [B, 1, S, T].
     ``kv=None`` means fresh causal self-attention — the mask is derived
@@ -680,17 +814,19 @@ def layer_forward(
             raise NotImplementedError(
                 "latent attention runs fresh causal sequences on one device "
                 "without adapters; cached context goes through model_runner")
-        h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
-        q_nope, q_rope = mla_queries(cfg, layer, h, positions)
-        latent = mla_latent(cfg, layer, h, positions)
-        with jax.named_scope("attn"):
-            k, v = mla_expand_kv(cfg, layer, latent)
-            attn = _mla_fresh_attention(
-                cfg, jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
-        with jax.named_scope("mla_out"):
-            x = x + attn @ layer["wo"]
-        y, stats = mlp_block(cfg, layer, x, live)
-        return x + y, latent, stats
+
+        def attend(i, sub, x, latents):
+            h = rms_norm(x, sub["attn_norm"], cfg.rms_eps)
+            q_nope, q_rope = mla_queries(cfg, sub, h, positions)
+            latent = mla_latent(cfg, sub, h, positions)
+            with jax.named_scope("attn"):
+                k, v = mla_expand_kv(cfg, sub, latent)
+                attn = _mla_fresh_attention(
+                    cfg, jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
+            with jax.named_scope("mla_out"):
+                return (*latents, latent), attn @ sub["wo"]
+
+        return mla_block(cfg, layer, x, attend, (), live)
     q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids)
 
     with jax.named_scope("attn"):

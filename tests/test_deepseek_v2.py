@@ -493,7 +493,7 @@ def test_the_shares_routed_parts_plus_the_shared_experts_once_are_the_uncut_laye
     h = jax.random.normal(jax.random.key(7), (48, cfg.d_model))
     whole, stats = tf.moe_layer(
         dataclasses.replace(cfg, n_experts_held=0, expert_offset=0), full, h)
-    assert stats.tolist() == [48 * 3, 48 * 3, int(stats[2]), 1]
+    assert stats.tolist() == [48 * 3, 48 * 3, int(stats[2]), 1, 0]
     shared = tf.swiglu(h, full["ws_gate"], full["ws_up"], full["ws_down"])
     parts, local = [], 0
     for share in range(4):
@@ -521,7 +521,7 @@ def test_no_assignment_is_lost_when_every_token_picks_one_expert():
     w["router"] = jnp.asarray(router)
     h = jnp.abs(jax.random.normal(jax.random.key(8), (200, cfg.d_model))) + 0.5
     y, stats = tf.moe_layer(cfg, w, h)  # holds experts 4-7
-    assert stats.tolist() == [600, 600, 3, 1]
+    assert stats.tolist() == [600, 600, 3, 1, 0]
     sc = np.asarray(jax.nn.softmax(h @ w["router"], axis=-1))
     want = tf.swiglu(h, w["ws_gate"], w["ws_up"], w["ws_down"])
     for e in (4, 5, 6):
@@ -533,7 +533,7 @@ def test_no_assignment_is_lost_when_every_token_picks_one_expert():
     # tokens that are not live choose nothing
     live = jnp.arange(200) < 50
     _, stats = tf.moe_layer(cfg, w, h, live)
-    assert stats.tolist() == [150, 150, 3, 1]
+    assert stats.tolist() == [150, 150, 3, 1, 0]
 
 
 # ---- YaRN ------------------------------------------------------------------

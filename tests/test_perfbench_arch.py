@@ -31,7 +31,9 @@ def config_of(name):
 
 @pytest.mark.parametrize("config, model_type", [
     ("qwen3-1.7b", "qwen3"), ("qwen3-tiny-cpu", "qwen3"),
-    ("deepseek-v2-ep4", "deepseek_v2"), ("deepseek-v2-tiny-cpu", "deepseek_v2")])
+    ("deepseek-v2-ep4", "deepseek_v2"), ("deepseek-v2-tiny-cpu", "deepseek_v2"),
+    ("longcat-flash-ep32", "longcat_flash"),
+    ("longcat-flash-tiny-cpu", "longcat_flash")])
 def test_a_configuration_resolves_to_its_architectures_file(work, config,
                                                             model_type):
     cfg = config_of(config)
@@ -48,8 +50,8 @@ def test_a_configuration_resolves_to_its_architectures_file(work, config,
 def test_every_configuration_of_the_benchmark_resolves(work):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [c["name"] for c in bench["configs"]] == ["qwen3-1.7b",
-                                                     "deepseek-v2-ep4"]
+    assert [c["name"] for c in bench["configs"]] == [
+        "qwen3-1.7b", "deepseek-v2-ep4", "longcat-flash-ep32"]
     for entry in bench["configs"]:
         with open(os.path.join(ROOT, entry["file"])) as f:
             cfg = json.load(f)
@@ -112,13 +114,54 @@ def test_the_counts_of_the_tiny_deepseek_configuration(work):
     assert mod.decode_attn_flops(cfg, [10]) == 3 * 2 * 4 * (80 + 64) * 10
 
 
+def test_the_counts_of_longcat_flash_ep32(work):
+    """One chip's share (ISSUE 34's table): a double layer's two
+    attentions of 90 570 752 and two dense FFNs of 3 x 6144 x 12288, its
+    router over all 768 outputs and the EXPECTED 12 x 16 / 768 = 0.25 held
+    routed experts of 3 x 6144 x 2048 (the identity experts take a third
+    of the choices and multiply nothing), four layers, the head at 16 384;
+    attention counted in the published (expanded) form, 2 x 64 x (192 +
+    128) = 40 960 FLOP a cached position and CACHE layer, of which a layer
+    has two; a latent row of 576 values a cache layer."""
+    cfg = config_of("longcat-flash-ep32")
+    mod = work.load_arch(work.arch_path(cfg))
+    z = mod.sizes(cfg)
+    assert mod._attention_params(z) == 90_570_752
+    layer = 2 * (90_570_752 + 3 * 6144 * 12288) + 6144 * 768 + 9_437_184
+    assert work.matmul_params(cfg) == 4 * layer + 6144 * 16384 == 2_693_791_744
+    assert work.token_flops(cfg, 1000) == 5_715_263_488
+    assert work.token_flops(cfg, 1000, with_head=False) == 5_513_936_896
+    assert (work.token_flops(cfg, 1001) - work.token_flops(cfg, 1000)
+            == 8 * 40_960)
+    assert work.prompt_flops(cfg, 512) == 2_698_598_416_384
+    assert work.kv_bytes_per_position(cfg) == 8 * 576 * 2 == 9216
+    assert work.kv_bytes_per_position(cfg, kv_dtype_bytes=1) == 4608
+    assert work.decode_kv_bytes(cfg, [100, 200]) == 2_764_800
+    # the latent kernel's arithmetic: 2 x 64 x (576 + 512) = 139 264 FLOP a
+    # cached position and cache layer, 120.9 FLOP a byte of latent row:
+    # half DeepSeek-V2's (64 heads for 128) and half the v5e's ridge
+    assert mod.decode_attn_flops(cfg, [100, 200]) == 8 * 139_264 * 300
+    assert mod.decode_attn_flops(cfg, [1]) / work.decode_kv_bytes(
+        cfg, [1]) == pytest.approx(120.9, abs=0.1)
+    assert (z["q_scale"], round(z["kv_scale"], 4)) == (2.0, 3.4641)
+
+
+def test_the_counts_of_the_tiny_longcat_configuration(work):
+    cfg = config_of("longcat-flash-tiny-cpu")
+    mod = work.load_arch(work.arch_path(cfg))
+    assert work.matmul_params(cfg) == 792_576
+    assert work.token_flops(cfg, 10) == 1_610_752
+    assert work.kv_bytes_per_position(cfg) == 4 * 80 * 2
+    assert mod.decode_attn_flops(cfg, [10]) == 4 * 2 * 4 * (80 + 64) * 10
+
+
 def test_reading_the_counts_imports_neither_jax_nor_the_program():
     code = (
         "import json, sys\n"
         f"sys.path.insert(0, {BENCH!r})\n"
         "import work\n"
         "bad = []\n"
-        "for name in ('qwen3-1.7b', 'deepseek-v2-ep4'):\n"
+        "for name in ('qwen3-1.7b', 'deepseek-v2-ep4', 'longcat-flash-ep32'):\n"
         f"    cfg = json.load(open({os.path.join(BENCH, 'configs')!r} + '/' + name + '.json'))\n"
         "    assert work.prompt_flops(cfg, 8) > 0\n"
         "bad = [m for m in sys.modules if m.split('.')[0] in "

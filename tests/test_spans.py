@@ -340,3 +340,60 @@ def test_an_engine_without_the_hook_is_published_at_once():
     seen, got = _play([(7, True, False), (8, True, False)], hook=False)
     assert got == [7, 8]
     assert [n for _, n in seen] == [0, 0, 1, 1]
+
+
+def test_the_identity_experts_have_a_scope_and_a_counter():
+    """LongCat-Flash's identity ("zero-compute") experts: their term is
+    traced under ``moe_zero`` (device time by named scope reads it beside
+    ``moe_experts`` and ``mlp``), and what it did is counted on the device
+    and rendered as ``fusioninfer:moe_assignments_zero_total``."""
+    import dataclasses
+
+    from fusioninfer_tpu.engine import model_runner as mr
+    from fusioninfer_tpu.engine.kv_cache import init_kv_cache
+    from fusioninfer_tpu.engine.metrics import EngineMetrics
+    from fusioninfer_tpu.models import transformer as tf
+
+    cfg = dataclasses.replace(get_preset("longcat-flash-tiny"),
+                              attn_impl="reference")
+    cc = CacheConfig(n_pages=8, page_size=16, max_pages_per_seq=4)
+    params = jax.eval_shape(lambda: tf.init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, cc))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    text = mr.fused_step.lower(
+        cfg, cc, params, cache, i32(16), i32(8), i32(8), i32(8), i32(8, 4),
+        i32(2, 1), i32(2), coalesce=True, kv_splits=0).as_text(debug_info=True)
+    for scope in ("moe_zero", "moe_experts", "moe_route", "mlp", "mla_q",
+                  "mla_kv", "mla_out", "kv_write"):
+        assert re.search(r'loc\("([a-z_]+/)*%s[/"]' % scope, text), scope
+    # a model without identity experts traces no such scope
+    ds = dataclasses.replace(get_preset("deepseek-v2-tiny"),
+                             attn_impl="reference")
+    text = mr.fused_step.lower(
+        ds, cc, jax.eval_shape(lambda: tf.init_params(ds, jax.random.key(0))),
+        jax.eval_shape(lambda: init_kv_cache(ds, cc)), i32(16), i32(8),
+        i32(8), i32(8), i32(8, 4), i32(2, 1), i32(2), coalesce=True,
+        kv_splits=0).as_text(debug_info=True)
+    assert "moe_zero" not in text and "moe_experts/" in text
+
+    eng = NativeEngine(cfg, cache_cfg=CACHE, max_batch_size=2, seed=0,
+                       token_budget=16)
+    page = EngineMetrics("m").render(eng)
+    assert "# TYPE fusioninfer:moe_assignments_zero_total counter" in page
+    assert parse(page)["fusioninfer:moe_assignments_zero_total"] == 0
+    from fusioninfer_tpu.engine.engine import Request
+    from fusioninfer_tpu.engine.sampler import SamplingParams
+
+    eng.add_request(Request("a", [1] + list(range(3, 30)),
+                            SamplingParams(max_tokens=6, temperature=0.0)))
+    while eng.has_work():
+        eng.step()
+    eng._drain_moe_stats()
+    after = parse(EngineMetrics("m").render(eng))
+    zero = after["fusioninfer:moe_assignments_zero_total"]
+    assert 0 < zero < after["fusioninfer:moe_assignments_total"]
+    assert zero + after["fusioninfer:moe_assignments_local_total"] < after[
+        "fusioninfer:moe_assignments_total"]

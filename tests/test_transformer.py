@@ -127,7 +127,7 @@ class TestExpertLayer:
         x = jnp.abs(jax.random.normal(jax.random.key(3), (64, 16))) + 0.1
         layer = {"router": router, "w_gate": g, "w_up": u, "w_down": d}
         got, stats = moe_layer(self._cfg(8, 2), layer, x)
-        assert stats.tolist() == [128, 128, 2, 1]
+        assert stats.tolist() == [128, 128, 2, 1, 0]
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(moe_ffn(x, router, g, u, d, 2)),
             atol=1e-4, rtol=1e-4)

@@ -52,6 +52,19 @@ visit, against what a visit's bytes take at the HBM peak and its dots
         --variant parent=.archive_parent/fusioninfer_tpu/ops/mla_attention.py \
         --variant unit1=fusioninfer_tpu/ops/mla_attention.py@MLA_PAGES_PER_UPDATE=1
 
+``--latent longcat-flash-ep32`` probes the same kernel at that preset's
+shapes (ISSUE 34): 64 heads, a pool of ``[8, 1, 2048, 128, 640]``, 64 rows
+x 32-page tables; cases ``decode_r64_1100``, ``decode_r1_1100``,
+``fill_r64x12_at400`` and ``chunk768_at0`` / ``chunk768_at1500``.
+
+``--gmm`` times the routed experts' grouped product (megablox ``gmm``
+through a whole stack of ``4 x 16`` experts read in place, as
+``transformer.grouped_matmul`` hands it over) at ``longcat-flash-ep32``'s
+two matrices, 6144 x 2048 and 2048 x 6144, for a decode pass (64 tokens x
+12 assignments, 16 of them local over ~10 experts) and a chunk pass (832
+tokens, ~208 local rows), one line a tiling: ``transformer.gmm_tiling``'s
+own first, then the candidates of ``GMM_CANDIDATES``.
+
 ``--variant name=path[@CONST=int[,CONST=int]]`` loads ANOTHER copy of
 ``paged_attention.py`` (``mla_attention.py`` with ``--latent``) under
 its own name, optionally with one module
@@ -92,6 +105,26 @@ LATENT_REAL = dict(H=128, rank=512, rope=64, W=640, page=128, n_pages=2048,
                           "fill_r64x13_at1700": ([13] * 64, [1700] * 64),
                           "chunk832_at0": ([832], [0]),
                           "chunk832_at3000": ([832], [3000])})
+LATENT_LONGCAT = dict(H=64, rank=512, rope=64, W=640, page=128, n_pages=2048,
+                      layers=8, table=32, rows=64, first_T=(64, 1024),
+                      cases={"decode_r64_1100": ([1] * 64, [1099] * 64),
+                             "decode_r1_1100": ([1], [1099]),
+                             "fill_r64x12_at400": ([12] * 64, [400] * 64),
+                             "chunk768_at0": ([768], [0]),
+                             "chunk768_at1500": ([768], [1500])})
+LATENT_SHAPES = {"deepseek-v2-ep4": LATENT_REAL,
+                 "longcat-flash-ep32": LATENT_LONGCAT}
+# the grouped product at longcat-flash-ep32's shapes: (tokens, local rows)
+GMM_REAL = dict(layers=4, held=16, D=6144, F=2048, k=12,
+                passes={"decode_t64": (64, 16), "chunk_t832": (832, 208)})
+GMM_TINY = dict(layers=2, held=4, D=256, F=128, k=4,
+                passes={"decode_t8": (8, 6), "chunk_t48": (48, 30)})
+GMM_CANDIDATES = {
+    (6144, 2048): [(128, 2560, 768), (128, 2048, 512), (128, 3072, 512),
+                   (128, 1536, 1024), (128, 3072, 1024), (128, 6144, 512)],
+    (2048, 6144): [(128, 2560, 768), (128, 2048, 768), (128, 2048, 1536),
+                   (128, 1024, 1536), (128, 1024, 2048), (128, 2048, 512)],
+}
 LATENT_TINY = dict(H=4, rank=64, rope=16, W=128, page=16, n_pages=80,
                    layers=2, table=8, rows=8, first_T=(16, 32),
                    cases={"decode_r8_100": ([1] * 8, [99] * 8),
@@ -421,6 +454,60 @@ def latent_child_first(mod, shape: dict, interpret: bool) -> dict:
     return out
 
 
+def gmm_child_time(_mod, shape: dict, interpret: bool) -> dict:
+    """µs a call of the grouped product over one layer of a whole stack,
+    by matrix, pass and tiling; the bytes of the touched experts at the
+    HBM peak beside it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from fusioninfer_tpu.models import transformer as tf
+
+    L, G, k = shape["layers"], shape["held"], shape["k"]
+    rng = np.random.default_rng(0)
+    out = {}
+    for K, N in ((shape["D"], shape["F"]), (shape["F"], shape["D"])):
+        stack = jax.random.normal(jax.random.key(1), (L * G, K, N),
+                                  jnp.bfloat16) * 0.02
+        tilings = [tf.gmm_tiling(K, N)] + [
+            t for t in GMM_CANDIDATES.get((K, N), []) if t != tf.gmm_tiling(K, N)]
+        for name, (tokens, local) in shape["passes"].items():
+            A = tokens * k
+            sizes = np.bincount(rng.integers(0, G, local), minlength=G)
+            xs = jax.random.normal(jax.random.key(2), (A, K), jnp.bfloat16)
+            touched = int((sizes > 0).sum())
+            for tiling in tilings:
+                pad = -A % tiling[0]
+                xp = jnp.pad(xs, ((0, pad), (0, 0))) if pad else xs
+
+                @jax.jit
+                def many(x, w, sz, tiling=tiling):
+                    def body(i, acc):
+                        gs = jax.lax.dynamic_update_slice(
+                            jnp.zeros((L * G,), jnp.int32), sz, ((i % L) * G,))
+                        y = gmm(x, w, gs, preferred_element_type=jnp.bfloat16,
+                                tiling=tiling, interpret=interpret)
+                        return acc + y[0, 0].astype(jnp.float32)
+                    return jax.lax.fori_loop(0, CALLS, body, jnp.float32(0))
+
+                key = f"{K}x{N}.{name}.{'x'.join(map(str, tiling))}"
+                try:
+                    us = _median_us(many, (xp, stack, jnp.asarray(sizes, jnp.int32)))
+                except Exception as e:  # a tiling the chip's VMEM refuses
+                    out[key] = {"failed": str(e).splitlines()[0][:200]}
+                    print(f"  {key}: failed", flush=True)
+                    continue
+                least = touched * K * N * 2 / 819e9 * 1e6
+                out[key] = {"us_per_call": us, "touched": touched,
+                            "rows": int(local), "us_at_hbm_peak": least,
+                            "roofline_pct": 100 * least / us}
+                print(f"  {key}: {us:.1f} us, {touched} experts touched, "
+                      f"{100 * least / us:.1f} % of reading them", flush=True)
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", action="append", default=[],
@@ -433,16 +520,24 @@ def main() -> int:
                     help="CPU rehearsal: tiny shapes, interpret kernels")
     ap.add_argument("--kv-splits", type=int, default=8,
                     help="0: the single-walk grid, for ROADMAP S2's A/B")
-    ap.add_argument("--latent", action="store_true",
-                    help="the latent (MLA) kernel at deepseek-v2-ep4's shapes")
+    ap.add_argument("--latent", nargs="?", const="deepseek-v2-ep4",
+                    choices=sorted(LATENT_SHAPES),
+                    help="the latent (MLA) kernel at this preset's shapes "
+                         "(deepseek-v2-ep4 where none is named)")
+    ap.add_argument("--gmm", action="store_true",
+                    help="the grouped product at longcat-flash-ep32's shapes")
     ap.add_argument("--ring", type=int, nargs="*", default=[],
                     help="--latent: the tree's kernel at these ring depths too")
     ap.add_argument("--out", default="chiprun_out/kernel_probe/probe.json")
     ap.add_argument("--child", nargs=3, metavar=("KIND", "NAME", "SPEC"),
                     help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.latent:
-        shape, tree = LATENT_TINY if args.tiny else LATENT_REAL, LATENT_TREE
+    if args.gmm:  # jax's own kernel: the tree file named here is not read
+        shape, tree = GMM_TINY if args.tiny else GMM_REAL, LATENT_TREE
+        children = {"time": gmm_child_time}
+    elif args.latent:
+        shape = LATENT_TINY if args.tiny else LATENT_SHAPES[args.latent]
+        tree = LATENT_TREE
         children = {"time": latent_child_time, "first": latent_child_first}
     else:
         shape, tree = dict(TINY if args.tiny else REAL,
@@ -472,13 +567,15 @@ def main() -> int:
         # persistent cache, the second is the warm start that is reported
         for kind, key in (("time", "time"), ("first", "first_dispatch_cold"),
                           ("first", "first_dispatch")):
-            if kind == "first" and name in args.time_only:
+            if kind not in children or (
+                    kind == "first" and name in args.time_only):
                 continue
             print(f"== {name} {key}", flush=True)
             cmd = [sys.executable, os.path.abspath(__file__), "--child",
                    kind, name, spec, "--kv-splits", str(args.kv_splits)] + (
                        ["--tiny"] if args.tiny else []) + (
-                       ["--latent"] if args.latent else [])
+                       ["--latent", args.latent] if args.latent else []) + (
+                       ["--gmm"] if args.gmm else [])
             p = subprocess.run(cmd, cwd=REPO, stdout=subprocess.PIPE, text=True)
             lines = p.stdout.strip().splitlines()
             print("\n".join(lines[:-1]), flush=True)
