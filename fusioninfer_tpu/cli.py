@@ -220,8 +220,9 @@ def _add_engine_config_flags(p: argparse.ArgumentParser) -> None:
                         "weights stream from HBM once per step "
                         "(--no-fused-step restores the split "
                         "prefill-then-decode dispatch).  Burst engines "
-                        "(--decode-burst > 1) keep the split "
-                        "dispatch-ahead path either way")
+                        "(--decode-burst > 1) fuse such a step when no "
+                        "burst is in flight and the batch samples from "
+                        "candidates (see --fused-sampling)")
     p.add_argument("--fused-sampling", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="fuse sampling into the lm_head: eligible decode "

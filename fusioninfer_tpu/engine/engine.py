@@ -431,10 +431,11 @@ class NativeEngine:
         once per step instead of once per row-kind — decode is
         weight-bandwidth-bound, so the chunk rows ride nearly free.
         Greedy output streams are bit-identical with the flag on or off.
-        Burst-enabled engines (``decode_burst_steps > 1``) keep the
-        classic split dispatch either way: their span-1 fused
-        decode+sample path carries the dispatch-ahead control chain the
-        mixed-batch forward cannot.
+        Burst-enabled engines (``decode_burst_steps > 1``) fuse such a
+        step too when no burst is in flight and the live batch samples
+        from candidates (``fused_sampling``: greedy / top-k rows, none
+        guided, with logprobs, logit_bias or min_p); any other step
+        keeps the split dispatch (`_use_fused_step`).
 
         ``host_kv_tier``: an :class:`engine.kv_host_tier.HostKVTier` —
         evictable hashed pages reclaimed from the HBM prefix cache
@@ -716,7 +717,7 @@ class NativeEngine:
         self._ragged_rows = pow2_rows(2 * self.max_batch_size)
         self._ragged_chunk_rows = pow2_rows(self.max_batch_size)
         # fused mixed-batch stepping (decode + prefill chunks in one
-        # weight pass); burst engines keep the split dispatch-ahead path
+        # weight pass); a burst in flight keeps the split path
         self.fused_step_enabled = fused_step
         # fused lm_head→top-k sampling (ops/lm_head_topk.py): eligible
         # decode batches — every row greedy or 0 < top_k <= LM_HEAD_TOPK
@@ -728,6 +729,11 @@ class NativeEngine:
         # perf/debug switch, not a semantics switch.
         self.fused_sampling_enabled = fused_sampling
         self.fused_sampling_steps_total = 0
+        # what `sample_topk` takes for keys when every row is greedy
+        # (no key is read): [B] keys of the row keys' type, hashed from
+        # nothing
+        self._greedy_keys = jax.random.wrap_key_data(
+            jnp.zeros((self.max_batch_size, 2), jnp.uint32))
         # flash-decode KV-split grid (ops/paged_attention.py): resolved
         # ONCE from STATIC cache config so every dispatch of this engine
         # — and every process of a multi-host lockstep group — takes the
@@ -967,15 +973,26 @@ class NativeEngine:
         return budget
 
     def warm_chunk_forwards(self) -> int:
-        """Dispatch the one ragged forward once at every flat-token
-        bucket a budgeted chunk can have, on scratch pages released
-        before returning (as :meth:`calibrate_token_budget` does), and
-        return how many.  An AOT build leaves an executable in the
-        persistent cache; a program's FIRST live dispatch still traces,
-        lowers and loads it, seconds for a large model, and which bucket
-        a step's chunks add up to depends on what else is in flight, so
-        no client-side warm-up can be sure to reach them all: without
-        this the first step at a new bucket stalls every stream.
+        """Dispatch the engine's chunk-carrying forward once at every
+        flat-token bucket a budgeted chunk can have, on scratch pages
+        released before returning (as :meth:`calibrate_token_budget`
+        does), and return how many.  An AOT build leaves an executable
+        in the persistent cache; a program's FIRST live dispatch still
+        traces, lowers and loads it, seconds for a large model, and
+        which bucket a step's chunks add up to depends on what else is
+        in flight, so no client-side warm-up can be sure to reach them
+        all: without this the first step at a new bucket stalls every
+        stream.  On a burst engine that can fuse (`_mixed_on_burst`)
+        the forward is the mixed program, dispatched here with no live
+        decode row, and the mixed step's greedy sampling tail
+        (lm_head→top-k, the greedy draw, the count bump) is dispatched
+        once over a dead batch beside it: a greedy mixed step inside the
+        serving window meets no program for the first time, and a warm
+        start traces as many step programs as it did with the chunk-only
+        form.  What a top-k batch adds (its row keys, the top-k draw) is
+        left to a deployment's warm-up traffic, as every sampled
+        ``decode_burst`` variant is: it would cost every pod's start-up
+        about a second, whatever the pod serves.
         Single-process engines with a token budget only."""
         budget = self.token_budget
         if budget is None or self._mh is not None:
@@ -993,6 +1010,15 @@ class NativeEngine:
             logits.block_until_ready()
         finally:
             self.alloc.release(probe.request_id)
+        if self._mixed_on_burst:
+            B = self.max_batch_size
+            # [B, 1, D] -> [:, 0], as `_fused_step` slices its decode
+            # group; no row is live, so no count moves
+            hidden = jnp.zeros((B, 1, self.cfg.d_model),
+                               self.cfg.jax_dtype)[:, 0]
+            self._fused_sample_dispatch(
+                hidden, self._decode_controls({}), np.zeros(B, bool),
+                "greedy").block_until_ready()
         return len(set(sizes))
 
     def aot_signatures(self):
@@ -1013,7 +1039,12 @@ class NativeEngine:
         chunk-only (``window [0, 1]`` — batched suffix / chunk
         advances); burst engines add ``decode_burst`` at the two spans
         the scheduler uses ({1, k}) per sampling mode; the first-token
-        sampler chain completes the admission path.  Lowering uses the
+        sampler chain completes the admission path.  A burst engine
+        that can fuse (`_mixed_on_burst`) names ONE chunk-carrying
+        program a bucket, ``fused/mixed-hidden-t{T}``, in place of
+        ``fused/chunk-t{T}``: its mixed step samples from hidden states
+        only, and its chunk-only advances dispatch the same program
+        with every decode count zero.  Lowering uses the
         engine's REAL param/cache trees so in-sharding inference
         matches live dispatch exactly; nothing executes and nothing is
         donated (AOT lower/compile only)."""
@@ -1089,6 +1120,14 @@ class NativeEngine:
                 sigs.append((f"fused/decode-hidden-t{T}",
                              partial(lower_fused, T, B, W, 0, True)))
         for T in pow2_range(t_max):
+            if self._mixed_on_burst:
+                # ONE chunk-carrying program per bucket: a chunk advance
+                # with no live row is the mixed step with every decode
+                # count zero, so no chunk-only twin is built (or traced
+                # again at every warm start)
+                sigs.append((f"fused/mixed-hidden-t{T}",
+                             partial(lower_fused, T, B, W, NC, True)))
+                continue
             sigs.append((f"fused/chunk-t{T}",
                          partial(lower_fused, T, 0, 1, NC)))
             if self.fused_step_enabled and self.burst_steps == 1:
@@ -3262,24 +3301,29 @@ class NativeEngine:
         row i (inert pad entries: zero-length segments, trash-page tables).  The single
         assembly point for both the prefix-cache-burst and
         chunked-prefill batch paths; raises on forward failure (the
-        caller fails its own group)."""
+        caller fails its own group).  Where the engine's chunk-carrying
+        program is the mixed one (`_mixed_on_burst`) the windows ride
+        it behind ``B`` dead decode slots, so a chunk advance with and
+        without live rows beside it is one executable."""
         chunk_entries = [
             (toks, start, self.alloc.page_table_row(request.request_id),
              self._adapter_id(request))
             for request, toks, start in entries
         ]
+        B = self.max_batch_size if self._mixed_on_burst else 0
         packed = pack_ragged_batch(
-            np.zeros((0, 1), np.int32), np.zeros((0,), np.int32),
-            np.zeros((0,), np.int32),
-            np.zeros((0, self.cache_cfg.max_pages_per_seq), np.int32),
-            np.zeros((0,), np.int32), chunk_entries,
+            np.zeros((B, 1), np.int32), np.zeros((B,), np.int32),
+            np.zeros((B,), np.int32),
+            np.full((B, self.cache_cfg.max_pages_per_seq),
+                    self.cache_cfg.trash_page, np.int32),
+            np.zeros((B,), np.int32), chunk_entries,
             self.cache_cfg.trash_page, rows=self._ragged_rows,
             chunk_rows=self._ragged_chunk_rows)
         lora = self.lora_set.stacked if self.lora_set is not None else None
         # all NC rows, the real ones first: a [:B] here would give every
         # count of concurrent chunks a shape of its own, and each eager
         # op on it a compile of its own inside the serving window
-        return self._ragged_forward(packed, lora)[1]
+        return self._ragged_forward(packed, lora, decode_hidden=B > 0)[1]
 
     def _prefill_suffix_batch(
         self, items: list[tuple[Request, list[int], bool, int]]
@@ -3708,47 +3752,58 @@ class NativeEngine:
         mode = self._sample_mode(st.request.params for st in live.values())
         return mode if mode in ("greedy", "topk") else None
 
+    def _fused_sample_dispatch(self, hidden, ctl: dict, live_mask,
+                               mode: str):
+        """Dispatch the fused-sampling tail over the decode rows' hidden
+        states [B, D] → sampled tokens [B], still on the device: blocked
+        lm_head→top-k (penalties + min-tokens suppression per vocab
+        block inside the jit), the candidate draw, the count bump of
+        the ``live_mask`` rows."""
+        head, tied = lm_head_operands(self.cfg, self.params)
+        early = jnp.asarray(ctl["gen_counts"] < ctl["min_toks"])
+        if self._kernel_mesh is not None:
+            from fusioninfer_tpu.ops.sharded import lm_head_topk_tp
+
+            vals, idx = lm_head_topk_tp(
+                self._kernel_mesh, hidden, head, self._token_counts,
+                self._output_counts, jnp.asarray(ctl["presence"]),
+                jnp.asarray(ctl["frequency"]),
+                jnp.asarray(ctl["repetition"]), early, self._suppress,
+                tied=tied)
+        else:
+            vals, idx = lm_head_topk(
+                hidden, head, self._token_counts, self._output_counts,
+                jnp.asarray(ctl["presence"]),
+                jnp.asarray(ctl["frequency"]),
+                jnp.asarray(ctl["repetition"]), early, self._suppress,
+                tied=tied)
+        # the greedy draw reads candidate 0 and no key: an all-greedy
+        # batch spares the row-key program (and its first dispatch)
+        keys = (self._greedy_keys if mode == "greedy" else make_row_keys(
+            jnp.asarray(ctl["seeds"]), jnp.asarray(ctl["gen_counts"])))
+        sampled_dev = sample_topk(vals, idx, keys,
+                                  jnp.asarray(ctl["temps"]),
+                                  jnp.asarray(ctl["top_ks"]),
+                                  jnp.asarray(ctl["top_ps"]), mode=mode)
+        self._token_counts, self._output_counts = _bump_count_rows(
+            self._token_counts, self._output_counts, sampled_dev,
+            jnp.asarray(live_mask))
+        return sampled_dev
+
     def _decode_finish_fused(self, live: dict, hidden, ctl: dict,
                              failures: list, mode: str) -> list[StepOutput]:
         """The fused-sampling decode tail: blocked lm_head→top-k over
-        the decode rows' hidden states [B, D] (penalties + min-tokens
-        suppression applied per vocab block inside the jit), then the
-        candidate draw — no [B, V] logits tensor anywhere.  Emission
-        matches `_decode_finish`'s plain branch exactly; eligibility
-        (`_fused_sampling_mode`) already excluded every row kind that
-        branch special-cases."""
+        the decode rows' hidden states [B, D], then the candidate draw
+        (`_fused_sample_dispatch`) — no [B, V] logits tensor anywhere.
+        Emission matches `_decode_finish`'s plain branch exactly;
+        eligibility (`_fused_sampling_mode`) already excluded every row
+        kind that branch special-cases."""
         span = self.spans.span
         with span("step.dispatch", program="lm_head_topk"):
-            head, tied = lm_head_operands(self.cfg, self.params)
-            early = jnp.asarray(ctl["gen_counts"] < ctl["min_toks"])
-            if self._kernel_mesh is not None:
-                from fusioninfer_tpu.ops.sharded import lm_head_topk_tp
-
-                vals, idx = lm_head_topk_tp(
-                    self._kernel_mesh, hidden, head, self._token_counts,
-                    self._output_counts, jnp.asarray(ctl["presence"]),
-                    jnp.asarray(ctl["frequency"]),
-                    jnp.asarray(ctl["repetition"]), early, self._suppress,
-                    tied=tied)
-            else:
-                vals, idx = lm_head_topk(
-                    hidden, head, self._token_counts, self._output_counts,
-                    jnp.asarray(ctl["presence"]),
-                    jnp.asarray(ctl["frequency"]),
-                    jnp.asarray(ctl["repetition"]), early, self._suppress,
-                    tied=tied)
-            keys = make_row_keys(jnp.asarray(ctl["seeds"]),
-                                 jnp.asarray(ctl["gen_counts"]))
-            sampled_dev = sample_topk(vals, idx, keys,
-                                      jnp.asarray(ctl["temps"]),
-                                      jnp.asarray(ctl["top_ks"]),
-                                      jnp.asarray(ctl["top_ps"]), mode=mode)
-            B = self.max_batch_size
-            live_mask = np.zeros(B, bool)
+            live_mask = np.zeros(self.max_batch_size, bool)
             live_mask[list(live)] = True
-            self._token_counts, self._output_counts = _bump_count_rows(
-                self._token_counts, self._output_counts, sampled_dev,
-                jnp.asarray(live_mask))
+            sampled_dev = self._fused_sample_dispatch(hidden, ctl,
+                                                      live_mask, mode)
         with span("step.fetch", program="lm_head_topk"):
             sampled = np.asarray(sampled_dev)
         self.sched.charge_decode(len(live))
@@ -4020,19 +4075,46 @@ class NativeEngine:
             self._inflight = successor
         return outputs
 
+    @property
+    def _mixed_on_burst(self) -> bool:
+        """The per-engine half of a burst engine's mixed-step gate: can
+        a decode batch of this engine ever be fused-sampling eligible
+        (``_fused_sampling_mode``) with fused stepping on?  Such an
+        engine's ONE chunk-carrying program per flat-token bucket is the
+        mixed ``decode_hidden`` form of ``fused_step`` — a chunk advance
+        with no live row is that program with every decode count zero —
+        so `aot_signatures`, `warm_chunk_forwards`,
+        `_batched_window_forward` and `_fused_step` all build, warm and
+        dispatch the same executable."""
+        return (self.fused_step_enabled and self.burst_steps > 1
+                and self.fused_sampling_enabled and not self.spec_k)
+
     def _use_fused_step(self) -> bool:
-        """One dispatch for this step's decode AND chunk work?  True only
-        when both row kinds exist on a fused-enabled classic engine —
-        burst engines (``burst_steps > 1``) keep the split path: their
-        span-1 fused decode+sample dispatch carries the dispatch-ahead
-        control chain the mixed-batch forward cannot.  Reads only
-        replicated scheduler state, so every process of a multi-host
-        lockstep group answers identically."""
-        return (self.fused_step_enabled and self.burst_steps == 1
-                and self._inflight is None
-                and bool(self.prefilling)
-                and any(st.n_generated < st.request.params.max_tokens
-                        for st in self.running.values()))
+        """One dispatch for this step's decode AND chunk work?  True
+        when both row kinds exist on a fused-enabled engine and no
+        burst is in flight.  A burst engine (``burst_steps > 1``) asks
+        one thing more: that the live batch is fused-sampling eligible
+        (``_fused_sampling_mode``: greedy / top-k rows, no guided,
+        logprobs, logit_bias, min_p or speculative row), so its mixed
+        step never needs a ``[B, W, V]`` program and rows that want host
+        work per token keep the split path.  While something is
+        prefilling a burst engine's decode half is a span-1
+        ``decode_burst`` that `_pipeline_ready` would refuse a successor
+        anyway (`_admission_pending`): a second pass over all weights
+        for what the chunk forward can carry.  A burst IN FLIGHT is not
+        merged: its tokens are already being computed, so the split path
+        consumes it as before and the chunk forward queues behind it.
+        Reads only replicated scheduler state, so every process of a
+        multi-host lockstep group answers identically."""
+        if not (self.fused_step_enabled and self._inflight is None
+                and self.prefilling):
+            return False
+        live = {s: st for s, st in self.running.items()
+                if st.n_generated < st.request.params.max_tokens}
+        if not live:
+            return False
+        return (self.burst_steps == 1
+                or self._fused_sampling_mode(live) is not None)
 
     def _fused_step(self) -> list[StepOutput]:
         """Advance every mid-prefill sequence one budgeted chunk AND
@@ -4044,7 +4126,15 @@ class NativeEngine:
         activation.  Emission order matches the split path — chunk
         activations first, then decode tokens.  A forward failure fails
         the chunk rows (``_advance_prefilling_batch`` semantics) and
-        re-dispatches decode split for this step."""
+        re-dispatches decode split for this step.
+
+        On a burst engine this replaces "chunk forward, then a span-1
+        ``decode_burst``": the step never asks for a burst, so
+        ``burst_clamped_total`` is not bumped, the sampling tail is
+        always the fused one (`_use_fused_step`) and it keeps the
+        device-side penalty counts ``decode_burst`` carries
+        (``_bump_count_rows``); the burst dispatched on a later step
+        builds its controls from host state that holds this token."""
         failures, _ = self._ensure_decode_capacity(1)
         live = {s: st for s, st in self.running.items()
                 if st.n_generated < st.request.params.max_tokens}
@@ -4081,6 +4171,8 @@ class NativeEngine:
             window, counts_w, ctl["positions"], ctl["page_tables"],
             ctl["adapter_ids"], entries, self.cache_cfg.trash_page,
             rows=self._ragged_rows, chunk_rows=self._ragged_chunk_rows)
+        # a burst engine's batch is eligible here (`_use_fused_step`;
+        # preempting rows away cannot make it less so)
         fs_mode = self._fused_sampling_mode(live)
         try:
             logits_f, chunk_logits = self._ragged_forward(

@@ -12,13 +12,19 @@ forward and a decode forward back to back.  The invariants under test:
 * the ``weight_passes_per_step`` ledger shows ≈ 1 pass/step under mixed
   load on the fused path vs ≥ 2 on the split path, and decode-only
   stepping is untouched;
-* burst engines (``decode_burst_steps > 1``) never take the fused path
-  (their span-1 dispatch carries the dispatch-ahead control chain);
+* burst engines (``decode_burst_steps > 1``) take the fused path on a
+  step with both row kinds when no burst is in flight and the live batch
+  samples from candidates; a burst in flight, or a row that needs host
+  work per token, keeps the split path; such an engine builds, warms and
+  dispatches ONE chunk-carrying program per flat-token bucket;
 * the new ``/metrics`` families render with HELP/TYPE lines;
 * the packing helper (`engine/fused.py`) lays rows out slot-aligned.
 """
 
+import dataclasses
+
 import numpy as np
+import pytest
 
 from fusioninfer_tpu.engine.engine import NativeEngine, Request
 from fusioninfer_tpu.engine.fused import (
@@ -51,19 +57,32 @@ def _run_all(engine, requests, max_steps=400):
     return tokens
 
 
-def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100):
+def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100, top_k=0):
     """A decode stream + a long chunking prompt + a short prompt — the
-    mixed-load shape the fused step exists for."""
+    mixed-load shape the fused step exists for.  ``top_k`` makes the
+    seeded row a candidate sampler and puts a second one beside the
+    greedy stream, so sampled rows are live WHILE the long prompt
+    chunks (a burst engine fuses only candidate-sampling batches)."""
     rng = np.random.default_rng(seed)
-    return [
+    reqs = [
         Request("stream", [1, 2, 3],
                 SamplingParams(max_tokens=20, temperature=0.0)),
         Request("long", rng.integers(1, CFG.vocab_size, prompt_len).tolist(),
                 SamplingParams(max_tokens=max_tokens, temperature=0.8,
-                               seed=77)),
+                               seed=77, top_k=top_k)),
         Request("short", rng.integers(1, CFG.vocab_size, 9).tolist(),
                 SamplingParams(max_tokens=4, temperature=0.0)),
     ]
+    if top_k:
+        reqs.insert(1, Request(
+            "tk", [3, 2, 1], SamplingParams(max_tokens=20, temperature=0.7,
+                                            seed=5, top_k=top_k)))
+    return reqs
+
+
+# the two decode loops: classic per-token stepping and burst engines
+# (whose mixed step replaces "chunk forward, then a span-1 decode_burst")
+BURSTS = pytest.mark.parametrize("burst", [1, 8])
 
 
 class TestPacking:
@@ -151,34 +170,52 @@ class TestPacking:
 class TestEquivalence:
     """Bit-identity: the fused step must be invisible in the streams."""
 
-    def _ab(self, reqs_fn, cache_cfg=None, **engine_kw):
-        split = NativeEngine(CFG, cache_cfg=cache_cfg or _cache_cfg(),
-                             max_batch_size=4,
-                             token_budget=16, fused_step=False, **engine_kw)
-        fused = NativeEngine(CFG, cache_cfg=cache_cfg or _cache_cfg(),
-                             max_batch_size=4,
-                             token_budget=16, fused_step=True, **engine_kw)
+    def _ab(self, reqs_fn, cache_cfg=None, cfg=CFG, **engine_kw):
+        kw = dict(cache_cfg=cache_cfg or _cache_cfg(), max_batch_size=4,
+                  token_budget=16, **engine_kw)
+        split = NativeEngine(cfg, fused_step=False, **kw)
+        fused = NativeEngine(cfg, fused_step=True, **kw)
         a = _run_all(split, reqs_fn())
         b = _run_all(fused, reqs_fn())
         assert fused.sched.fused_steps_total > 0, \
             "fused path never engaged — the A/B proves nothing"
+        assert split.sched.fused_steps_total == 0
         assert a == b
         return split, fused
 
-    def test_mixed_load_greedy_and_seeded_sampled(self):
-        self._ab(_mixed_reqs)
+    @BURSTS
+    @pytest.mark.parametrize("top_k", [0, 8])
+    def test_mixed_load_greedy_and_seeded_sampled(self, burst, top_k):
+        """Greedy rows beside a seeded sampler: plain (``top_k`` 0: on a
+        burst engine the batch keeps the split path once that row is
+        live) and top-k (candidate draws ride the mixed step)."""
+        self._ab(lambda: _mixed_reqs(top_k=top_k), decode_burst_steps=burst)
 
-    def test_quantized_kv_int8(self):
+    @BURSTS
+    def test_quantized_kv_int8(self, burst):
         """int8 KV pages (per-token scales folded at read time) must be
         bit-identical fused vs split too — the scales ride the same
         ragged descriptors as the pages, and quantization amplifies any
         low-bit forward divergence into whole int8 buckets (this A/B
         caught both the scale-in-dot rewrite and the solo-suffix
         rectangle path)."""
-        self._ab(lambda: _mixed_reqs(prompt_len=72),
+        self._ab(lambda: _mixed_reqs(prompt_len=72, top_k=8),
                  cache_cfg=CacheConfig(n_pages=65, page_size=16,
                                        max_pages_per_seq=16,
-                                       kv_dtype="int8"))
+                                       kv_dtype="int8"),
+                 decode_burst_steps=burst)
+
+    @pytest.mark.parametrize("preset",
+                             ["deepseek-v2-tiny", "longcat-flash-tiny"])
+    def test_latent_cache_expert_layer_burst_engine(self, preset):
+        """The burst engine's mixed step over a latent pool and an
+        expert layer (LongCat: two cache layers a layer, identity
+        experts): streams identical to chunk forward + decode_burst."""
+        cfg = dataclasses.replace(get_preset(preset), dtype="float32",
+                                  attn_impl="reference")
+        _, fused = self._ab(lambda: _mixed_reqs(top_k=8), cfg=cfg,
+                            decode_burst_steps=8)
+        assert fused.runtime_info()["kv_layout"] == "latent"
 
     def test_logprobs_and_bias_rows_in_the_mix(self):
         """Tail-path rows (logprobs, logit_bias) share the fused decode
@@ -240,7 +277,8 @@ class TestEquivalence:
         assert fused.prefix_cache_hit_rate() > 0
         assert split.prefix_cache_hit_rate() > 0
 
-    def test_preemption_resume(self):
+    @BURSTS
+    def test_preemption_resume(self, burst):
         """Preempted-and-resumed sequences (the prefix-cache resume
         path: the full prompt+generated prefix re-prefills) stream
         identically fused vs split."""
@@ -249,16 +287,20 @@ class TestEquivalence:
         def run(fused_on):
             engine = NativeEngine(CFG, cache_cfg=cache, max_batch_size=2,
                                   enable_prefix_caching=False,
-                                  token_budget=16, fused_step=fused_on)
+                                  token_budget=16, fused_step=fused_on,
+                                  decode_burst_steps=burst)
+            # sized so that a row is preempted AND a mixed step runs on
+            # both decode loops (a burst engine pre-extends a span's
+            # pages, so it meets the pool's edge at another step)
             engine.add_request(Request(
                 "old", list(range(1, 16)),
-                SamplingParams(max_tokens=20, temperature=0.0)))
+                SamplingParams(max_tokens=40, temperature=0.0)))
             engine.step()
             engine.add_request(Request(
-                "long", list(range(1, 112)),
-                SamplingParams(max_tokens=2, temperature=0.0)))
+                "long", list(range(1, 91)),
+                SamplingParams(max_tokens=12, temperature=0.0)))
             results: dict[str, list] = {"old": [], "long": []}
-            for _ in range(120):
+            for _ in range(300):
                 if not engine.has_work():
                     break
                 for o in engine.step():
@@ -270,6 +312,7 @@ class TestEquivalence:
         a, ea = run(False)
         b, eb = run(True)
         assert ea.preemptions_total >= 1 and eb.preemptions_total >= 1
+        assert eb.sched.fused_steps_total > 0
         assert a == b
 
     def test_lora_adapter_rows(self):
@@ -310,13 +353,15 @@ class TestEquivalence:
         split, fused = self._ab(reqs, speculative_k=2)
         assert fused.spec_proposed_total > 0
 
-    def test_mid_chunk_cancellation(self):
+    @BURSTS
+    def test_mid_chunk_cancellation(self, burst):
         """Cancelling a mid-chunk prompt between fused steps releases
         its pages and leaves the surviving stream bit-identical."""
         def run(fused_on):
             engine = NativeEngine(CFG, cache_cfg=_cache_cfg(),
                                   max_batch_size=4, token_budget=16,
-                                  fused_step=fused_on)
+                                  fused_step=fused_on,
+                                  decode_burst_steps=burst)
             engine.add_request(Request(
                 "stream", [1, 2, 3],
                 SamplingParams(max_tokens=20, temperature=0.0)))
@@ -342,6 +387,7 @@ class TestEquivalence:
         a, ea = run(False)
         b, eb = run(True)
         assert a == b
+        assert eb.sched.fused_steps_total > 0
         assert eb.cancelled_total == 1
         # every page returned (one reserved trash page stays allocator-held)
         assert eb.alloc.free_pages == ea.alloc.free_pages
@@ -429,12 +475,142 @@ class TestWeightPassLedger:
         # admission step pays the prefill pass; every other step is 1
         assert engine.sched.weight_passes_total <= engine.sched.steps_total + 1
 
-    def test_burst_engines_never_fuse(self):
-        engine = NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4,
-                              token_budget=16, decode_burst_steps=4,
-                              fused_step=True)
-        _run_all(engine, _mixed_reqs())
+    def _burst_engine(self, **kw):
+        return NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4,
+                            token_budget=16, decode_burst_steps=8,
+                            fused_step=True, **kw)
+
+    def test_burst_engine_fuses_a_mixed_step_into_one_pass(self):
+        """A burst engine's step with both row kinds and nothing in
+        flight is ONE weight pass (no chunk forward + span-1 burst), and
+        a step that never asked for a burst clamps none."""
+        engine = self._burst_engine()
+        for r in _mixed_reqs(top_k=8):
+            engine.add_request(r)
+        sched, mixed = engine.sched, 0
+        while engine.has_work():
+            before = (sched.fused_steps_total, sched.weight_passes_total,
+                      sched.burst_clamped_total, sched.decode_tokens_total)
+            admitting = engine.num_waiting > 0  # whole-prompt prefills
+            engine.step()
+            if sched.fused_steps_total > before[0] and not admitting:
+                mixed += 1
+                assert not engine.forward_in_flight()
+                assert sched.fused_steps_total == before[0] + 1
+                assert sched.weight_passes_total == before[1] + 1
+                assert sched.burst_clamped_total == before[2]
+                assert sched.decode_tokens_total > before[3]
+        assert mixed > 0 and sched.fused_steps_total >= mixed
+        # decode-only stretches still burst at the full span
+        assert sched.burst_span_steps[8] > 0
+
+    def test_burst_in_flight_is_consumed_split(self):
+        """A chunk that arrives while a dispatched-ahead burst is in
+        flight rides the split path that step (the burst's tokens are
+        already being computed; the chunk forward queues behind it); the
+        next step, with nothing in flight, fuses."""
+        engine = self._burst_engine()
+        engine.add_request(Request(
+            "stream", [1, 2, 3], SamplingParams(max_tokens=60,
+                                                temperature=0.0)))
+        for _ in range(3):
+            engine.step()
+        assert engine.forward_in_flight()
+        engine.add_request(Request(
+            "long", list(range(1, 100)),
+            SamplingParams(max_tokens=2, temperature=0.0)))
+        passes = engine.sched.weight_passes_total
+        engine.step()
+        assert engine.num_prefilling == 1
         assert engine.sched.fused_steps_total == 0
+        # the chunk forward alone: the in-flight burst was charged when
+        # it was dispatched, and no successor may dispatch beside a chunk
+        assert engine.sched.weight_passes_total == passes + 1
+        assert not engine.forward_in_flight()
+        engine.step()
+        assert engine.sched.fused_steps_total == 1
+
+    @pytest.mark.parametrize("kind", ["logprobs", "logit_bias", "guided",
+                                      "min_p"])
+    def test_host_work_rows_keep_the_split_path(self, kind):
+        """One row that needs the full distribution (or host work) per
+        token keeps the whole batch on the split path, so a burst
+        engine never needs a [B, W, V] mixed program."""
+        from fusioninfer_tpu.engine.guided import build_token_byte_table
+        from fusioninfer_tpu.engine.tokenizer import ByteTokenizer
+
+        params = {
+            "logprobs": dict(temperature=0.0, logprobs=2),
+            "logit_bias": dict(temperature=0.0, logit_bias=((7, 3.0),)),
+            "guided": dict(temperature=0.0, guided_json=True),
+            "min_p": dict(temperature=0.8, seed=3, top_k=8, min_p=0.05),
+        }[kind]
+        engine = self._burst_engine(token_byte_table=build_token_byte_table(
+            ByteTokenizer(), CFG.vocab_size))
+        reqs = [
+            Request("special", [4, 5, 6],
+                    SamplingParams(max_tokens=40, **params)),
+            Request("plain", [6, 5, 4],
+                    SamplingParams(max_tokens=40, temperature=0.0)),
+            Request("long", list(range(1, 100)),
+                    SamplingParams(max_tokens=2, temperature=0.0)),
+        ]
+        _run_all(engine, reqs)
+        assert engine.sched.chunks_total > 1
+        assert engine.sched.fused_steps_total == 0
+
+    def test_burst_engine_one_chunk_carrying_program_per_bucket(self):
+        """`aot_signatures` of a burst engine that can fuse names ONE
+        chunk-carrying program per flat-token bucket (the mixed
+        ``decode_hidden`` form: no chunk-only twin, no [B, W, V] mixed
+        program), `warm_chunk_forwards` dispatches exactly those and the
+        mixed step's greedy sampling tail, and a greedy mixed load
+        afterwards meets no program for the first time."""
+        from fusioninfer_tpu.engine.engine import _bump_count_rows
+        from fusioninfer_tpu.engine.model_runner import fused_step
+        from fusioninfer_tpu.engine.sampler import make_row_keys, sample_topk
+        from fusioninfer_tpu.ops.lm_head_topk import lm_head_topk
+
+        def engine(**kw):
+            return NativeEngine(CFG, cache_cfg=CacheConfig(
+                n_pages=19, page_size=32, max_pages_per_seq=8),
+                max_batch_size=2, token_budget=96, decode_burst_steps=8,
+                **kw)
+
+        eng = engine()
+        names = [n for n, _ in eng.aot_signatures()]
+        chunk_carrying = [n for n in names if n.startswith(
+            ("fused/chunk-", "fused/mixed-"))]
+        assert chunk_carrying == [f"fused/mixed-hidden-t{t}"
+                                  for t in (16, 32, 64, 128)]
+        assert {"lm_head_topk/b2", "sample_topk/greedy",
+                "sample_topk/topk"} <= set(names)
+        before = fused_step._cache_size()
+        assert eng.warm_chunk_forwards() == 4
+        assert fused_step._cache_size() == before + 4
+        assert eng.alloc.used_pages == 0 and not eng.has_work()
+        programs = (fused_step, lm_head_topk, sample_topk, _bump_count_rows,
+                    make_row_keys)
+        warmed = [f._cache_size() for f in programs]
+        rng = np.random.default_rng(9)
+        _run_all(eng, [
+            Request("a", [1, 2, 3], SamplingParams(max_tokens=24,
+                                                   temperature=0.0)),
+            Request("b", rng.integers(1, CFG.vocab_size, 230).tolist(),
+                    SamplingParams(max_tokens=3, temperature=0.0)),
+        ])
+        assert eng.sched.fused_steps_total > 0
+        # decode-only classic steps never run here (every row bursts),
+        # so the warmed programs are all the ragged forward meets; an
+        # all-greedy tail reads no row key (first tokens take one of [1])
+        assert [f._cache_size() for f in programs] == warmed
+        # an engine that cannot fuse (fused sampling off, --no-fused-step,
+        # speculative) keeps the chunk-only program and no mixed one
+        for kw in (dict(fused_sampling=False), dict(fused_step=False),
+                   dict(speculative_k=2)):
+            names = [n for n, _ in engine(**kw).aot_signatures()]
+            assert "fused/chunk-t16" in names, kw
+            assert not any(n.startswith("fused/mixed-") for n in names), kw
 
     def test_flag_off_never_fuses(self):
         engine = NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4,
