@@ -56,6 +56,7 @@ from fusioninfer_tpu.engine.kv_cache import (
 from fusioninfer_tpu.engine.fused import pack_ragged_batch, pow2_rows
 from fusioninfer_tpu.engine.metrics import TTFT_BUCKETS, Histogram
 from fusioninfer_tpu.engine.model_runner import (
+    splits_of,
     CTL_F_COLS,
     CTL_I_COLS,
     decode_burst,
@@ -318,37 +319,57 @@ class _PrefillingState:
     pos: int  # next global position to write (starts at the reused length)
 
 
+# what a cache that is not ONE pool of K/V heads refuses at start-up, by
+# the flag's name (latent_cache_refusal, kind_cache_refusal)
+_NOT_YET = {
+    "mesh": "a device mesh (--tensor-parallel-size / ep / sp)",
+    "int8_weights": "int8 weights (--quantization int8)",
+    "int8_kv": "int8 KV pages (--kv-cache-dtype int8)",
+    "lora": "LoRA adapters (--lora)",
+    "speculative": "speculative decoding (--speculative-ngram)",
+    "host_tier": "the host KV tier (--kv-host-tier-mb)",
+    "kv_transfer": "KV transfer between prefill and decode roles "
+                   "(--prefill-upstream, prefill slabs and streams)",
+    "kv_fabric": "the cross-engine KV fabric (--kv-peer)",
+    "evacuate": "evacuation (--evacuate-grace-s / --evacuate-peer)",
+    "checkpoint": "checkpoint loading (--load-hf / --load-checkpoint)",
+}
+
+
+def _refusal(keeps: str, asked: dict) -> Optional[str]:
+    """``asked`` names what was asked for, a truthy value meaning "yes"."""
+    unknown = set(asked) - set(_NOT_YET)
+    if unknown:
+        raise KeyError(f"unknown feature names {sorted(unknown)}")
+    hit = [_NOT_YET[name] for name, on in asked.items() if on]
+    return f"{keeps}, which does not support yet: {'; '.join(hit)}" if hit else None
+
+
 def latent_cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
     """The message that refuses a deployment of a model with a latent
-    (MLA) cache, or None.  ``asked`` names what was asked for, a truthy
-    value meaning "yes".  What a latent page needs before these can
+    (MLA) cache, or None.  What a latent page needs before these can
     read it: a mesh rule for a cache with no head axis; int8 pages and
     weights; adapters through the latent projections; a verify window
     over latent pages; ONE frame format for transfer, fabric, host tier
     and evacuation (ROADMAP D4); a checkpoint name map."""
     if not cfg.is_mla:
         return None
-    not_yet = {
-        "mesh": "a device mesh (--tensor-parallel-size / ep / sp)",
-        "int8_weights": "int8 weights (--quantization int8)",
-        "int8_kv": "int8 KV pages (--kv-cache-dtype int8)",
-        "lora": "LoRA adapters (--lora)",
-        "speculative": "speculative decoding (--speculative-ngram)",
-        "host_tier": "the host KV tier (--kv-host-tier-mb)",
-        "kv_transfer": "KV transfer between prefill and decode roles "
-                       "(--prefill-upstream, prefill slabs and streams)",
-        "kv_fabric": "the cross-engine KV fabric (--kv-peer)",
-        "evacuate": "evacuation (--evacuate-grace-s / --evacuate-peer)",
-        "checkpoint": "checkpoint loading (--load-hf / --load-checkpoint)",
-    }
-    unknown = set(asked) - set(not_yet)
-    if unknown:
-        raise KeyError(f"unknown feature names {sorted(unknown)}")
-    hit = [not_yet[name] for name, on in asked.items() if on]
-    if not hit:
+    return _refusal(f"model {cfg.name} keeps a latent (MLA) KV cache", asked)
+
+
+def kind_cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
+    """The message that refuses a deployment of a model whose cache is
+    kept by layer kind (full and windowed attention mixed), or None;
+    ``asked`` as in :func:`latent_cache_refusal`.  What a second pool
+    and a page list a kind need before these can read them: a mesh rule
+    for two pools; int8 pages by kind; a scan by period over quantized
+    or adapter trees; a verify window that covers window-kind pages; a
+    frame of TWO page lists for transfer, fabric, host tier and
+    evacuation; a checkpoint name map (ROADMAP R4)."""
+    if not cfg.cache_by_kind:
         return None
-    return (f"model {cfg.name} keeps a latent (MLA) KV cache, which does "
-            f"not support yet: {'; '.join(hit)}")
+    return _refusal(f"model {cfg.name} keeps its KV cache by layer kind "
+                    f"(full and windowed attention mixed)", asked)
 
 
 class NativeEngine:
@@ -447,11 +468,12 @@ class NativeEngine:
         process-local and would diverge the SPMD lockstep)."""
         self.cfg = cfg.validate()
         self.cache_cfg = (cache_cfg or CacheConfig()).validate()
-        refusal = latent_cache_refusal(
-            cfg, mesh=mesh is not None,
-            int8_weights=cfg.quantization == "int8",
+        asked = dict(
+            mesh=mesh is not None, int8_weights=cfg.quantization == "int8",
             int8_kv=self.cache_cfg.quantized, lora=lora_adapters,
             speculative=speculative_k, host_tier=host_kv_tier is not None)
+        refusal = (latent_cache_refusal(cfg, **asked)
+                   or kind_cache_refusal(cfg, **asked))
         if refusal:
             raise ValueError(refusal)
         self.max_batch_size = max_batch_size
@@ -537,12 +559,19 @@ class NativeEngine:
                 params = quantize_params(cfg, params)
             self.cache = init_kv_cache(cfg, self.cache_cfg)
         self.params = params
-        self.prefix_caching = enable_prefix_caching
+        # over a cache kept by layer kind the prefix cache registers
+        # nothing: a block is a hit only where EVERY kind still holds
+        # what a resumed sequence would read, and window-kind pages go
+        # as the window passes (ROADMAP R4; /health says so)
+        self.prefix_caching = enable_prefix_caching and not cfg.cache_by_kind
         self.alloc = (
             PrefixCachingAllocator(self.cache_cfg)
-            if enable_prefix_caching
-            else PageAllocator(self.cache_cfg)
+            if self.prefix_caching
+            else PageAllocator(self.cache_cfg, window=cfg.sliding_window
+                               if cfg.cache_by_kind else None)
         )
+        # one inert page-table row ([mp]; [2, mp] by kind)
+        self._trash_row = self.alloc.blank_page_tables(1)[0]
         # hierarchical KV: reclaimed evictable pages offload to host
         # DRAM; prefix misses restore from it (engine/kv_host_tier.py)
         self._host_tier = None
@@ -658,6 +687,7 @@ class NativeEngine:
 
         if token_budget is None and prefill_chunk_size is not None:
             token_budget = prefill_chunk_size * self.prefill_chunks_per_step
+        self._check_window_span(token_budget)
         self.sched = TokenBudget(token_budget)
         # pre-seed the only two span keys a dispatch can ever record
         # ({1, burst_steps}): /metrics iterates this dict from an HTTP
@@ -746,6 +776,17 @@ class NativeEngine:
             if kv_splits is None else kv_splits)
         if cfg.is_mla:
             self._kv_splits = 0  # the latent kernel has one grid
+        if cfg.cache_by_kind:
+            # a choice a layer kind: (full, window).  A window kind's
+            # walk never passes the window plus a row's span, whatever
+            # the context bound
+            cc = self.cache_cfg
+            self._kv_splits = (self._kv_splits, (
+                ops_pick_kv_splits(
+                    cc.max_pages_per_seq, cc.page_size,
+                    window_reach=(cc.max_window_pages_per_seq - 1)
+                    * cc.page_size)
+                if kv_splits is None else kv_splits))
         # the expert layers' counters, summed on the device in the pool
         # tree (cache["moe_stats"], uint32) and read as differences
         self.moe_stats_total = {name: 0 for name in MOE_STATS}
@@ -790,7 +831,7 @@ class NativeEngine:
         self.evac_unparked_total = 0
         info = self.runtime_info()
         logger.info(
-            "engine on %s (%s x%d): attention=%s grid=%s kv_splits=%d "
+            "engine on %s (%s x%d): attention=%s grid=%s kv_splits=%s "
             "interpret=%s sharded=%s", info["platform"], info["device_kind"],
             info["device_count"], info["attention"], info["grid"],
             info["kv_splits"], info["interpret"], info["sharded_attention"])
@@ -819,12 +860,16 @@ class NativeEngine:
         elif attention == "flash":
             tp = (self._kernel_mesh.shape["tp"]
                   if self._kernel_mesh is not None else 1)
-            grid, splits = resolve_ragged_grid(
+            by_kind = [resolve_ragged_grid(
                 cc.page_size, cfg.head_dim, cfg.n_kv_heads // tp,
                 cfg.n_heads // cfg.n_kv_heads, cfg.jax_dtype,
                 self.cache["k"].dtype, self.cache["v"].dtype, cc.quantized,
                 coalesce=ops_dispatch.decode_coalesce(),
-                kv_splits=self._kv_splits)
+                kv_splits=splits_of(self._kv_splits, pool))
+                for pool in sorted({k.pool for k in cfg.layer_kinds})]
+            grid, splits = by_kind[0]
+            if cfg.cache_by_kind:
+                splits = {"full": splits, "window": by_kind[1][1]}
         devices = (list(self.mesh.local_devices) if self.mesh is not None
                    else jax.local_devices()[:1])
         return {
@@ -847,6 +892,25 @@ class NativeEngine:
             "max_pages_per_seq": cc.max_pages_per_seq,
             "kv_dtype": cc.kv_dtype,
             "kv_layout": "latent" if cfg.is_mla else "heads",
+            # the period's layers, "full" | "window:<width>" then
+            # "+rope" | "+nope" (one entry: every layer alike)
+            "layer_pattern": [
+                ("full" if k.window is None else "window:%d" % k.window)
+                + ("+rope" if k.rope else "+nope")
+                for k in cfg.layer_kinds],
+            # a cache kept by layer kind: each pool's layers and pages,
+            # and the most window-kind pages one sequence holds
+            "pages_by_kind": ({
+                "full": {"layers": cfg.n_pool_layers(""),
+                         "n_pages": cc.n_pages},
+                "window": {"layers": cfg.n_pool_layers("_win"),
+                           "n_pages": cc.n_window_pages,
+                           "max_pages_per_seq": cc.max_window_pages_per_seq},
+            } if cc.by_kind else None),
+            "prefix_cache": (
+                "on" if self.prefix_caching else
+                "registers nothing: the cache is kept by layer kind"
+                if cfg.cache_by_kind else "off"),
             # the one expert layer: sorted assignments through a grouped
             # product over the held experts, no capacity, nothing dropped
             "moe_experts": ("%s dropless %d/%d%s" % (
@@ -864,9 +928,31 @@ class NativeEngine:
         }
 
     def _refuse_if_latent(self, **asked) -> None:
-        refusal = latent_cache_refusal(self.cfg, **asked)
+        refusal = (latent_cache_refusal(self.cfg, **asked)
+                   or kind_cache_refusal(self.cfg, **asked))
         if refusal:
             raise ValueError(refusal)
+
+    def _check_window_span(self, token_budget: Optional[int]) -> None:
+        """Over a cache kept by layer kind a sequence holds the window
+        kind's pages its window reaches plus those its longest row
+        writes; the pool was sized for rows of a known span
+        (``kv_cache.auto_cache_config``'s ``step_span``).  A token
+        budget (= the longest chunk) past it is refused here, by name,
+        not found as a failed forward."""
+        if not self.cfg.cache_by_kind or token_budget is None:
+            return
+        from fusioninfer_tpu.engine.kv_cache import window_pages_per_seq
+
+        cc = self.cache_cfg
+        need = window_pages_per_seq(self.cfg, cc.page_size, token_budget,
+                                    cc.max_pages_per_seq)
+        if need > cc.max_window_pages_per_seq:
+            raise ValueError(
+                f"a token budget of {token_budget} (--tokens-per-step) "
+                f"writes rows that need {need} window-kind pages a "
+                f"sequence; the window pool was sized for "
+                f"{cc.max_window_pages_per_seq}")
 
     def _drain_moe_stats(self) -> None:
         """Fold the device's running expert counters into the host's
@@ -919,6 +1005,7 @@ class NativeEngine:
         budgeted chunked prefill when the engine was built without one."""
         if tokens_per_step < 1:
             raise ValueError("token_budget must be >= 1")
+        self._check_window_span(tokens_per_step)
         self.sched.tokens_per_step = tokens_per_step
         if self.prefill_chunk is None:
             self.prefill_chunk = tokens_per_step
@@ -1049,7 +1136,6 @@ class NativeEngine:
         matches live dispatch exactly; nothing executes and nothing is
         donated (AOT lower/compile only)."""
         cfg, cc = self.cfg, self.cache_cfg
-        mp = cc.max_pages_per_seq
         mesh = self._kernel_mesh
         coalesce = ops_dispatch.decode_coalesce()
         lora = self.lora_set.stacked if self.lora_set is not None else None
@@ -1078,7 +1164,7 @@ class NativeEngine:
                     return prefill.lower(
                         cfg, cc, self.params, self.cache,
                         jnp.zeros((R, bucket), i32), jnp.zeros((R,), i32),
-                        jnp.full((R, mp), cc.trash_page, i32),
+                        jnp.asarray(self.alloc.blank_page_tables(R)),
                         mesh=mesh, lora=lora, adapter_ids=ids(R))
                 sigs.append((f"prefill/b{bucket}r{R}", lower_prefill))
 
@@ -1103,7 +1189,7 @@ class NativeEngine:
                 cfg, cc, self.params, self.cache,
                 jnp.zeros((T,), i32), jnp.zeros((R,), i32),
                 jnp.zeros((R,), i32), jnp.zeros((R,), i32),
-                jnp.full((R, mp), cc.trash_page, i32),
+                jnp.asarray(self.alloc.blank_page_tables(R)),
                 jnp.zeros((sel_rows, sel_w), i32), jnp.zeros((nc,), i32),
                 mesh=mesh, lora=lora, adapter_ids=ids(R),
                 coalesce=coalesce, kv_splits=self._kv_splits,
@@ -1149,7 +1235,7 @@ class NativeEngine:
                             jnp.zeros((B, len(CTL_F_COLS)), jnp.float32),  # noqa:trace-dynamic-dim — fixed control-array layout
                             self._token_counts, self._output_counts,
                             self._suppress,
-                            jnp.full((B, mp), cc.trash_page, i32),
+                            jnp.asarray(self.alloc.blank_page_tables(B)),
                             n_steps=span, sample_mode=mode, mesh=mesh,
                             lora=lora, coalesce=coalesce,
                             kv_splits=self._kv_splits)
@@ -3294,6 +3380,16 @@ class NativeEngine:
         self.sched.charge_weight_pass()
         return logits, chunk_logits
 
+    def _chunk_table_row(self, request: Request, start: int,
+                         length: int) -> np.ndarray:
+        """The page-table row of a chunk row that writes positions
+        ``[start, start + length)`` of ``request``; over a cache kept by
+        layer kind the window kind's pages the row touches are made
+        ready first (``PageAllocator.cover_window``)."""
+        rid = request.request_id
+        self.alloc.cover_window(rid, start, start + length)
+        return self.alloc.page_table_row(rid)
+
     def _batched_window_forward(self, entries) -> "jax.Array":
         """ONE ragged multi-query forward for a batch of per-sequence
         token windows — ``entries`` is ``[(request, window_tokens,
@@ -3306,7 +3402,7 @@ class NativeEngine:
         it behind ``B`` dead decode slots, so a chunk advance with and
         without live rows beside it is one executable."""
         chunk_entries = [
-            (toks, start, self.alloc.page_table_row(request.request_id),
+            (toks, start, self._chunk_table_row(request, start, len(toks)),
              self._adapter_id(request))
             for request, toks, start in entries
         ]
@@ -3314,10 +3410,9 @@ class NativeEngine:
         packed = pack_ragged_batch(
             np.zeros((B, 1), np.int32), np.zeros((B,), np.int32),
             np.zeros((B,), np.int32),
-            np.full((B, self.cache_cfg.max_pages_per_seq),
-                    self.cache_cfg.trash_page, np.int32),
+            self.alloc.blank_page_tables(B),
             np.zeros((B,), np.int32), chunk_entries,
-            self.cache_cfg.trash_page, rows=self._ragged_rows,
+            self._trash_row, rows=self._ragged_rows,
             chunk_rows=self._ragged_chunk_rows)
         lora = self.lora_set.stacked if self.lora_set is not None else None
         # all NC rows, the real ones first: a [:B] here would give every
@@ -3500,14 +3595,16 @@ class NativeEngine:
         # Pad rows are inert: true_len 0 routes every write to the
         # trash page and their logits rows are never read.
         R = pow2_rows(max(B, 1))
-        mp = self.cache_cfg.max_pages_per_seq
         padded = np.zeros((R, bucket), np.int32)
-        rows = np.full((R, mp), self.cache_cfg.trash_page, np.int32)
+        rows = self.alloc.blank_page_tables(R)
         lens = np.zeros((R,), np.int32)
         ids = np.zeros((R,), np.int32)
         for i, (request, prefix, _) in enumerate(items):
             padded[i, : len(prefix)] = prefix
-            rows[i] = self.alloc.page_table_row(request.request_id)
+            # a whole prompt attends over its own fresh K/V: of the
+            # window kind's pages only those the NEXT query (position
+            # len) can see are kept, the rest write to the trash page
+            rows[i] = self._chunk_table_row(request, len(prefix), 0)
             lens[i] = len(prefix)
             ids[i] = self._adapter_id(request)
         lora = self.lora_set.stacked if self.lora_set is not None else None
@@ -4026,6 +4123,8 @@ class NativeEngine:
         try:
             for st, base, need in plan:
                 self.alloc.extend(st.request.request_id, base, need)
+                self.alloc.cover_window(st.request.request_id, base,
+                                        base + need)
         except MemoryError:  # max_pages_per_seq ceiling — skip pipelining
             return False
         return True
@@ -4041,9 +4140,7 @@ class NativeEngine:
         successor = None
         if (self._pipeline_ready(snapshot, span)
                 and self._extend_for_successor(snapshot, span)):
-            B = self.max_batch_size
-            mp = self.cache_cfg.max_pages_per_seq
-            tables = np.full((B, mp), self.cache_cfg.trash_page, np.int32)
+            tables = self.alloc.blank_page_tables(self.max_batch_size)
             for s, st in snapshot.items():
                 tables[s] = self.alloc.page_table_row(st.request.request_id)
             with self.spans.span("step.dispatch", program="decode_burst"):
@@ -4163,13 +4260,13 @@ class NativeEngine:
         window, counts_w = self._decode_window(live, ctl, spec_drafts)
         entries = [
             (st.prefix[st.pos: st.pos + chunks[i]], st.pos,
-             self.alloc.page_table_row(st.request.request_id),
+             self._chunk_table_row(st.request, st.pos, chunks[i]),
              self._adapter_id(st.request))
             for i, st in enumerate(take)
         ]
         packed = pack_ragged_batch(
             window, counts_w, ctl["positions"], ctl["page_tables"],
-            ctl["adapter_ids"], entries, self.cache_cfg.trash_page,
+            ctl["adapter_ids"], entries, self._trash_row,
             rows=self._ragged_rows, chunk_rows=self._ragged_chunk_rows)
         # a burst engine's batch is eligible here (`_use_fused_step`;
         # preempting rows away cannot make it less so)
@@ -4223,12 +4320,10 @@ class NativeEngine:
         """Per-slot numpy control arrays for a decode pass (split or
         fused): one entry per batch slot, trash/zero for dead slots."""
         B = self.max_batch_size
-        mp = self.cache_cfg.max_pages_per_seq
         ctl = {
             "tokens": np.zeros((B,), np.int32),
             "positions": np.zeros((B,), np.int32),
-            "page_tables": np.full((B, mp), self.cache_cfg.trash_page,
-                                   np.int32),
+            "page_tables": self.alloc.blank_page_tables(B),
             "active": np.zeros((B,), bool),
             "temps": np.zeros((B,), np.float32),
             "top_ks": np.zeros((B,), np.int32),
@@ -4343,7 +4438,7 @@ class NativeEngine:
         window, counts_w = self._decode_window(live, ctl, spec_drafts)
         packed = pack_ragged_batch(
             window, counts_w, ctl["positions"], ctl["page_tables"],
-            ctl["adapter_ids"], [], self.cache_cfg.trash_page,
+            ctl["adapter_ids"], [], self._trash_row,
             # chunk_rows=0: an empty chunk group, not the padded one — a
             # decode-only step must not pay NC dead lm_head rows
             rows=self._ragged_rows, chunk_rows=0)
@@ -4638,6 +4733,9 @@ class NativeEngine:
                     # input token occupies index len-1 -> need len tokens covered
                     self.alloc.extend(st.request.request_id,
                                       len(st.tokens) - 1, need)
+                    self.alloc.cover_window(
+                        st.request.request_id, len(st.tokens) - 1,
+                        len(st.tokens) - 1 + need)
                     break
                 except MemoryError:
                     if span > 1:

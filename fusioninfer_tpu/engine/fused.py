@@ -65,7 +65,9 @@ def pack_ragged_batch(
     decode_tables: np.ndarray,  # [B, mp] decode-row page tables
     decode_adapters: np.ndarray,  # [B] adapter ids
     chunk_entries: list,  # [(tokens list, start, table_row, adapter_id)]
-    trash_page: int,
+    trash_page,  # int; or, over a cache kept by layer kind, ONE inert
+    # table row [2, mp] (PageAllocator.blank_page_tables: each kind's
+    # row filled with its own pool's trash page)
     rows: int | None = None,  # fixed descriptor-row count (compile
     # discipline: the engine pins pow2(2·max_batch) so R never varies)
     chunk_rows: int | None = None,  # fixed chunk_sel width (engine pins
@@ -91,8 +93,8 @@ def pack_ragged_batch(
     into the flat range; their logits are never read.
     """
     B, W = window.shape
-    mp = decode_tables.shape[1] if B else (
-        np.asarray(chunk_entries[0][2]).shape[0] if chunk_entries else 0)
+    mp = decode_tables.shape[-1] if B else (
+        np.asarray(chunk_entries[0][2]).shape[-1] if chunk_entries else 0)
     n_chunks = len(chunk_entries)
     R = rows if rows is not None else pow2_rows(max(B + n_chunks, 1))
     if R < B + n_chunks:
@@ -115,7 +117,11 @@ def pack_ragged_batch(
 
     tokens = np.zeros((T,), np.int32)
     row_starts = np.zeros((R,), np.int32)
-    tables = np.full((R, mp), trash_page, np.int32)
+    if np.ndim(trash_page):
+        tables = np.tile(np.asarray(trash_page, np.int32),
+                         (R,) + (1,) * np.ndim(trash_page))
+    else:
+        tables = np.full((R, mp), trash_page, np.int32)
     sel = np.zeros((B, W), np.int32)
     chunk_sel = np.zeros((NC,), np.int32)
     ids = np.zeros((R,), np.int32)

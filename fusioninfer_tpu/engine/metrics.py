@@ -133,6 +133,36 @@ class EngineMetrics:
             self.tier_requests[name] = 0
             self.tier_shed[name] = 0
 
+    @staticmethod
+    def _kv_pages_by_kind(engine, labels: str) -> list[str]:
+        """KV pages by layer kind: ``full`` is the model's one pool, or
+        the full-attention layers' over a cache kept by layer kind;
+        ``window`` exists only there.  A scraper that sums a family's
+        samples cannot tell kinds apart, so the window kind's two
+        counters also stand under families of their own."""
+        alloc = getattr(engine, "alloc", None)
+        in_use = getattr(alloc, "pages_in_use", None)
+        if in_use is None:
+            return []
+        allocated = alloc.pages_allocated_total
+        lines = [
+            "# HELP fusioninfer:kv_pages_in_use KV pages handed out to sequences now, by layer kind (full: the full-attention layers' pool, or the model's one pool; window: the windowed layers' pool of a cache kept by layer kind).",
+            "# TYPE fusioninfer:kv_pages_in_use gauge",
+            *[f'fusioninfer:kv_pages_in_use{{{labels},kind="{kind}"}} {n}'
+              for kind, n in in_use().items()],
+            "# HELP fusioninfer:kv_pages_allocated_total KV pages handed out since start, by layer kind; a window-kind page is handed out when a step is about to write it.",
+            "# TYPE fusioninfer:kv_pages_allocated_total counter",
+            *[f'fusioninfer:kv_pages_allocated_total{{{labels},kind="{kind}"}} {allocated[kind]}'
+              for kind in in_use()],
+            "# HELP fusioninfer:kv_window_pages_allocated_total The kind=\"window\" sample of fusioninfer:kv_pages_allocated_total under a family of its own.",
+            "# TYPE fusioninfer:kv_window_pages_allocated_total counter",
+            f"fusioninfer:kv_window_pages_allocated_total{{{labels}}} {allocated['window']}",
+            "# HELP fusioninfer:kv_window_pages_trimmed_total Pages released because every position in them fell below the sliding window of the sequence that held them (the windowed layers' pages alone over a cache kept by layer kind).",
+            "# TYPE fusioninfer:kv_window_pages_trimmed_total counter",
+            f"fusioninfer:kv_window_pages_trimmed_total{{{labels}}} {alloc.window_pages_trimmed_total}",
+        ]
+        return lines
+
     def render(self, engine) -> str:
         """Text exposition from live engine state + accumulated histograms."""
         labels = f'model_name="{self.model_name}"'
@@ -152,6 +182,7 @@ class EngineMetrics:
             "# HELP vllm:kv_cache_usage_perc KV-cache usage (1 = full).",
             "# TYPE vllm:kv_cache_usage_perc gauge",
             f"vllm:kv_cache_usage_perc{{{labels}}} {engine.kv_cache_usage():.6f}",
+            *self._kv_pages_by_kind(engine, labels),
             "# HELP vllm:prompt_tokens_total Prefill tokens processed.",
             "# TYPE vllm:prompt_tokens_total counter",
             f"vllm:prompt_tokens_total{{{labels}}} {engine.prompt_tokens_total}",

@@ -35,61 +35,22 @@ from fusioninfer_tpu.ops import masks
 from fusioninfer_tpu.models.config import ModelConfig
 from fusioninfer_tpu.models.quantization import (
     embed_lookup,
-    is_quantized,
     kv_quantize,
+    maybe_dequantize_tree,
 )
 from fusioninfer_tpu.models.transformer import (
-    EXPERT_MATRICES,
-    SUBLAYER_MATRICES,
-    attn_out_proj,
+    attn_scope,
+    gqa_block,
     layer_forward,
-    layer_stacks,
     lm_head,
     mla_absorb_queries,
     mla_attn_out,
     mla_block,
     mla_latent,
     mla_queries,
-    mlp_block,
-    qkv_proj,
     rms_norm,
+    scan_layers as _scan_layers,
 )
-
-
-def _scan_layers(cfg, params, lora, body, carry):
-    """``lax.scan`` of the ONE layer ``body`` over each of the model's
-    layer stacks in turn (the leading dense layers, then the rest), the
-    layer index running on.  Per-layer scan operands: weights (+ lora) +
-    the layer index.  The KV cache is deliberately NOT xs: it rides the
-    scan CARRY as one donated stacked pool per array, updated in place
-    by :func:`_scatter_kv` — threading it through xs→ys made XLA write a
-    fresh cache-sized ys every step (a full pool copy per decode step;
-    measured step time scaled with pool size, round 5)."""
-    for stack, first in layer_stacks(cfg, params):
-        n = jax.tree.leaves(stack)[0].shape[0]
-        # a stack of experts is not sliced by the scan: the grouped
-        # product reads layer l of it in place (transformer.grouped_matmul)
-        whole = {k: stack[k] for k in EXPERT_MATRICES
-                 if "router" in stack and not is_quantized(stack[k])}
-        # nor are a double layer's twice-held matrices [L, 2, ...]: the
-        # scan's slice [2, ...] of one is a buffer of its own, written
-        # every layer (1.1 GB of dense-FFN weights a layer at
-        # LongCat-Flash's widths), where a dot reads ONE matrix of the
-        # stack in place (transformer.mla_block indexes it by 2 l + i)
-        if cfg.sublayers > 1:
-            whole.update({k: stack[k] for k in SUBLAYER_MATRICES})
-        xs = [{k: v for k, v in stack.items() if k not in whole}]
-        if lora is not None:
-            xs.append(lora)
-        xs.append(first + jnp.arange(n))
-
-        def stack_body(carry, inputs, whole=whole, first=first):
-            layer = {**inputs[0],
-                     **{k: (w, inputs[-1] - first) for k, w in whole.items()}}
-            return body(carry, (layer, *inputs[1:]))
-
-        carry, _ = lax.scan(stack_body, carry, tuple(xs))
-    return carry
 
 
 def _layer_unpack(inputs, has_lora: bool):
@@ -101,12 +62,13 @@ def _layer_unpack(inputs, has_lora: bool):
 
 @jax.named_scope("kv_write")
 def _scatter_kv(cache: dict, l, k, v, write_page, write_slot,
-                head_axis: int) -> dict:
+                head_axis: int, pool: str = "") -> dict:
     """Write fresh K/V (``[..., KV, Hd]`` with the head axis at
     ``head_axis``) into layer ``l`` of the stacked head-major pools
     ``[L, KV, n_pages, ps, Hd]`` IN PLACE, quantizing on the way when
     the cache is int8 (per-token scales land in the
-    ``[L, KV, n_pages, 1, ps]`` scale arrays).
+    ``[L, KV, n_pages, 1, ps]`` scale arrays).  ``pool``: the layer
+    kind's pool (``cache["k" + pool]``; "_win" = the window kind's).
 
     The index expression is load-bearing: a scalar basic ``l`` followed
     by an ADJACENT block of advanced indices (kv-head rows, page map,
@@ -120,14 +82,15 @@ def _scatter_kv(cache: dict, l, k, v, write_page, write_slot,
     if quantized:
         k, k_s = kv_quantize(k)
         v, v_s = kv_quantize(v)
-    KV = cache["k"].shape[1]
+    kn, vn = "k" + pool, "v" + pool
+    KV = cache[kn].shape[1]
     kvr = jnp.arange(KV).reshape((KV,) + (1,) * write_page.ndim)
     wp = write_page[None]
     ws = write_slot[None]
     out = dict(cache)
-    out["k"] = cache["k"].at[l, kvr, wp, ws].set(
+    out[kn] = cache[kn].at[l, kvr, wp, ws].set(
         jnp.moveaxis(k, head_axis, 0))
-    out["v"] = cache["v"].at[l, kvr, wp, ws].set(
+    out[vn] = cache[vn].at[l, kvr, wp, ws].set(
         jnp.moveaxis(v, head_axis, 0))
     if quantized:
         # scatter via the squeezed [L, KV, n_pages, ps] view (a bitcast
@@ -220,12 +183,12 @@ def _mla_attn_block(cfg, layer, x, positions, cache, l, write_page,
     return cache, mla_attn_out(cfg, layer, o_lat)[:, None, :]
 
 
-def _cache_layer(cache: dict, l):
+def _cache_layer(cache: dict, l, pool: str = ""):
     """Materialize ONE layer's pools (portable/gather attention branch
     only — the Pallas kernels read the stacked pools in place via their
     ``layer`` operand and never pay this slice)."""
-    k_l = lax.dynamic_index_in_dim(cache["k"], l, 0, keepdims=False)
-    v_l = lax.dynamic_index_in_dim(cache["v"], l, 0, keepdims=False)
+    k_l = lax.dynamic_index_in_dim(cache["k" + pool], l, 0, keepdims=False)
+    v_l = lax.dynamic_index_in_dim(cache["v" + pool], l, 0, keepdims=False)
     if "k_scale" in cache:
         ks_l = lax.dynamic_index_in_dim(cache["k_scale"], l, 0,
                                         keepdims=False)
@@ -242,14 +205,36 @@ def _dequant_gather(ctx, scale_l, pages, flat_shape):
     return ctx.astype(jnp.float32) * sc[..., None]
 
 
+def _pool_tables(cfg, cache_cfg, page_tables) -> dict:
+    """pool -> (its page tables [R, mp], its trash page, its layers'
+    window) for every pool of the model's cache: the one pool of a model
+    of one layer kind, or, over a cache kept by layer kind
+    (``page_tables`` [R, 2, mp]: a row's list in each pool), the full
+    kind's and the window kind's."""
+    kinds = {k.pool: k.window for k in cfg.layer_kinds}
+    if not cfg.cache_by_kind:
+        return {"": (page_tables, cache_cfg.trash_page, kinds[""])}
+    return {"": (page_tables[:, 0], cache_cfg.trash_page, kinds[""]),
+            "_win": (page_tables[:, 1], cache_cfg.window_trash_page,
+                     kinds["_win"])}
+
+
+def splits_of(kv_splits, pool: str) -> int:
+    """A pool's KV-split choice: ``kv_splits`` is one static int, or a
+    (full kind, window kind) pair over a cache kept by layer kind."""
+    return (kv_splits if isinstance(kv_splits, int)
+            else kv_splits[1 if pool else 0])
+
+
 def _ragged_walks(cfg, cache, mesh, use_kernel, n_tokens, page_tables,
-                  row_starts, q_begins, q_lens, kv_splits):
-    """The paged kernel's walk lists for one forward's rows (the ragged
-    family's, or the latent kernel's over a latent cache), built BEFORE
-    the layer scan: every layer scores the same rows, and what a scan
-    body computes XLA leaves inside its loop.  None where no paged
-    kernel runs (portable branch) and under a serving mesh, where each
-    shard's kernel builds its own."""
+                  row_starts, q_begins, q_lens, kv_splits, pool: str = "",
+                  window=None):
+    """The paged kernel's walk lists for one forward's rows over ONE
+    pool (the ragged family's, or the latent kernel's over a latent
+    cache), built BEFORE the layer scan: every layer of a kind scores
+    the same rows, and what a scan body computes XLA leaves inside its
+    loop.  None where no paged kernel runs (portable branch) and under
+    a serving mesh, where each shard's kernel builds its own."""
     if not use_kernel or mesh is not None:
         return None
     if cfg.is_mla:
@@ -262,14 +247,14 @@ def _ragged_walks(cfg, cache, mesh, use_kernel, n_tokens, page_tables,
     q = jax.ShapeDtypeStruct((n_tokens, cfg.n_heads, cfg.head_dim),
                              cfg.jax_dtype)
     return ragged_walk_lists(
-        q, cache["k"], cache["v"], page_tables, row_starts, q_begins,
-        q_lens, cache.get("k_scale"), window=cfg.sliding_window,
-        kv_splits=kv_splits)
+        q, cache["k" + pool], cache["v" + pool], page_tables, row_starts,
+        q_begins, q_lens, cache.get("k_scale"), window=window,
+        kv_splits=splits_of(kv_splits, pool))
 
 
 @jax.named_scope("attn")
 def _ragged_attn(mesh, q, cache, page_tables, row_starts, q_begins, q_lens,
-                 k_scales, v_scales, *, layer, window, coalesce,
+                 k_scales, v_scales, *, layer, kind, coalesce,
                  kv_splits, interpret, walks=None):
     """The ONE ragged-kernel dispatch every model-path forward routes
     through: tp shard_map when a serving mesh is given, the flash-decode
@@ -277,30 +262,60 @@ def _ragged_attn(mesh, q, cache, page_tables, row_starts, q_begins, q_lens,
     (``kv_splits > 0``, :func:`ops.paged_attention.pick_kv_splits`),
     else the single-walk grid — so no forward can reacquire a private
     kernel-selection policy.  ``walks``: :func:`_ragged_walks` of the
-    same rows."""
+    same rows.  ``kind``: the layer's static kind: its pool is read
+    under its window, traced under its scope, and the window kind's
+    calls carry a name of their own in the device trace."""
     from fusioninfer_tpu.ops import (
         ragged_paged_attention,
         ragged_paged_attention_kvsplit,
     )
 
-    if mesh is not None:
-        from fusioninfer_tpu.ops.sharded import ragged_paged_attention_tp
+    k_pages, v_pages = cache["k" + kind.pool], cache["v" + kind.pool]
+    named = {"name": "ragged_paged_attention_window"} if kind.pool else {}
+    with jax.named_scope(attn_scope(kind)):
+        if mesh is not None:
+            from fusioninfer_tpu.ops.sharded import ragged_paged_attention_tp
 
-        return ragged_paged_attention_tp(
-            mesh, q, cache["k"], cache["v"], page_tables, row_starts,
-            q_begins, q_lens, k_scales, v_scales, layer=layer,
-            interpret=interpret, window=window, coalesce=coalesce,
-            kv_splits=kv_splits)
-    if kv_splits > 0:
-        return ragged_paged_attention_kvsplit(
-            q, cache["k"], cache["v"], page_tables, row_starts,
-            q_begins, q_lens, k_scales, v_scales, layer=layer,
-            kv_splits=kv_splits, interpret=interpret, window=window,
-            walks=walks)
-    return ragged_paged_attention(
-        q, cache["k"], cache["v"], page_tables, row_starts, q_begins,
-        q_lens, k_scales, v_scales, layer=layer, interpret=interpret,
-        window=window, coalesce=coalesce, walks=walks)
+            return ragged_paged_attention_tp(
+                mesh, q, k_pages, v_pages, page_tables, row_starts,
+                q_begins, q_lens, k_scales, v_scales, layer=layer,
+                interpret=interpret, window=kind.window, coalesce=coalesce,
+                kv_splits=kv_splits)
+        if kv_splits > 0:
+            return ragged_paged_attention_kvsplit(
+                q, k_pages, v_pages, page_tables, row_starts,
+                q_begins, q_lens, k_scales, v_scales, layer=layer,
+                kv_splits=kv_splits, interpret=interpret,
+                window=kind.window, walks=walks, **named)
+        return ragged_paged_attention(
+            q, k_pages, v_pages, page_tables, row_starts, q_begins,
+            q_lens, k_scales, v_scales, layer=layer, interpret=interpret,
+            window=kind.window, coalesce=coalesce, walks=walks, **named)
+
+
+def _paged_attend(mesh, cache, q, k, v, kind, cache_l, write, rows,
+                  walks, portable, *, use_kernel, coalesce, kv_splits):
+    """What a GQA layer does with its fresh rows in every paged program
+    (:func:`transformer.gqa_block`'s ``attend``): write each token's K/V
+    (``k`` / ``v`` [T, KV, Hd]) into layer ``cache_l`` of the layer
+    kind's pool IN PLACE, then score ``q`` [T, 1, H, Hd] over the kind's
+    pages, with its window → (cache, [T, 1, H * Hd]).  ``write``:
+    (write_page, write_slot) of the kind's pool; ``rows``: the ragged
+    descriptors over that pool; ``portable(cache, q, kind, cache_l)``:
+    the caller's gather-based scorer where no kernel runs."""
+    from fusioninfer_tpu.ops import dispatch
+
+    cache = _scatter_kv(cache, cache_l, k, v, *write, head_axis=1,
+                        pool=kind.pool)
+    if not use_kernel:
+        with jax.named_scope("attn"), jax.named_scope(attn_scope(kind)):
+            return cache, portable(cache, q, kind, cache_l)
+    attn = _ragged_attn(
+        mesh, q[:, 0], cache, *rows, cache.get("k_scale"),
+        cache.get("v_scale"), layer=cache_l, kind=kind, coalesce=coalesce,
+        kv_splits=splits_of(kv_splits, kind.pool),
+        interpret=dispatch.kernel_interpret(), walks=walks)
+    return cache, attn[:, None, :]
 
 
 @partial(jax.jit, static_argnums=(0, 1), static_argnames=("mesh",), donate_argnums=(3,))
@@ -331,33 +346,40 @@ def prefill(
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
     token_idx = jnp.arange(S)[None, :]  # [1, S]
-    # Padded positions (>= true_len) write to the trash page.
-    page_of_token = jnp.where(
-        token_idx < true_lens[:, None],
-        jnp.take_along_axis(page_rows, token_idx // ps, axis=1),
-        cache_cfg.trash_page,
-    )  # [B, S]
+    # Padded positions (>= true_len) write to the trash page (each
+    # pool's own, over a cache kept by layer kind).
+    page_of_token = {
+        pool: jnp.where(
+            token_idx < true_lens[:, None],
+            jnp.take_along_axis(rows, token_idx // ps, axis=1),
+            trash,
+        )  # [B, S]
+        for pool, (rows, trash, _) in _pool_tables(
+            cfg, cache_cfg, page_rows).items()}
     slot_of_token = jnp.broadcast_to(token_idx % ps, (B, S))
     live = token_idx < true_lens[:, None]  # [B, S]
 
-    def body(carry, inputs):
+    def body(carry, inputs, kind, cache_l):
         x, cache = carry
         layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
         out, kv, stats = layer_forward(
             cfg, layer, x, positions, mesh=mesh, lora=layer_lora,
-            adapter_ids=adapter_ids, live=live)
+            adapter_ids=adapter_ids, live=live, kind=kind)
         if cfg.is_mla:  # a fresh prompt attends in the expanded form and
             # caches what decode will read: each attention's latent rows
             # [B, S, .] into its own cache layer
             for i, latent in enumerate(kv):
                 cache = _scatter_latent(cache, _cache_layer_of(cfg, l, i),
-                                        latent, page_of_token, slot_of_token)
+                                        latent, page_of_token[""],
+                                        slot_of_token)
         else:
             # stacked head-major cache [L, KV, n_pages, ps, Hd]; k is
-            # [B, S, KV, Hd] → in-place scatter at layer l, [B, S] maps
-            cache = _scatter_kv(cache, l, *kv, page_of_token, slot_of_token,
-                                head_axis=2)
-        return (out, _add_moe_stats(cache, stats)), None
+            # [B, S, KV, Hd] → in-place scatter at the layer's place in
+            # its kind's pool, [B, S] maps
+            cache = _scatter_kv(cache, cache_l, *kv,
+                                page_of_token[kind.pool], slot_of_token,
+                                head_axis=2, pool=kind.pool)
+        return out, _add_moe_stats(cache, stats)
 
     x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
@@ -387,7 +409,7 @@ def _decode_step_impl(
 
     B = tokens.shape[0]
     ps = cache_cfg.page_size
-    mp = page_tables.shape[1]
+    mp = page_tables.shape[-1]
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     quantized = cache_cfg.quantized
     use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
@@ -395,76 +417,74 @@ def _decode_step_impl(
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)[:, None, :]  # [B, 1, D]
     pos = positions[:, None]  # [B, 1]
 
-    write_page = jnp.where(
-        active, page_tables[jnp.arange(B), positions // ps], cache_cfg.trash_page
-    )
-    write_slot = positions % ps
+    # per pool of the cache (one, or the full and the window kind's):
+    # where this step's token lands, the ONE ragged kernel's degenerate
+    # descriptors (B rows of one token each, q_len = active), its walk
+    # lists, and the attention mask over the gathered [mp * ps] context
+    # (reference path)
+    write, rows, walks, attend = {}, {}, {}, {}
+    for pool, (tables, trash, window) in _pool_tables(
+            cfg, cache_cfg, page_tables).items():
+        write_page = jnp.where(
+            active, tables[jnp.arange(B), positions // ps], trash
+        )
+        write[pool] = (write_page, positions % ps)
+        rows[pool] = (tables, positions, jnp.arange(B, dtype=jnp.int32),
+                      active.astype(jnp.int32))
+        walks[pool] = _ragged_walks(cfg, cache, mesh, use_kernel, B,
+                                    *rows[pool], kv_splits, pool, window)
+        ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T]
+        attend[pool] = masks.attend(
+            positions[:, None], ctx_idx,
+            window)[:, None, None, :]  # [B, 1, 1, T] (new token included)
 
-    # the ONE ragged kernel's degenerate descriptors: B rows of one
-    # token each (q_len = active)
-    rows = (page_tables, positions, jnp.arange(B, dtype=jnp.int32),
-            active.astype(jnp.int32))
-    walks = _ragged_walks(cfg, cache, mesh, use_kernel, B, *rows, kv_splits)
+    def portable(cache, q, kind, cache_l):
+        # gather pages [KV, B, mp, ps, Hd] -> [KV, B, T, Hd]
+        tables = rows[kind.pool][0]
+        k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, cache_l,
+                                                        kind.pool)
+        k_ctx = k_cache_l[:, tables].reshape(KV, B, mp * ps, Hd)
+        v_ctx = v_cache_l[:, tables].reshape(KV, B, mp * ps, Hd)
+        if quantized:
+            k_ctx = _dequant_gather(k_ctx, ks_l, tables, (KV, B, mp * ps))
+            v_ctx = _dequant_gather(v_ctx, vs_l, tables, (KV, B, mp * ps))
 
-    # attention mask over the gathered [mp * ps] context (reference path)
-    ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T]
-    attend = masks.attend(positions[:, None], ctx_idx,
-                          cfg.sliding_window)  # [B, T] (new token included)
-    attend = attend[:, None, None, :]  # [B, 1, 1, T]
+        group = H // KV
+        qg = q.reshape(B, 1, KV, group, Hd)
+        scores = jnp.einsum("bskgd,kbtd->bkgst", qg, k_ctx).astype(jnp.float32) / jnp.sqrt(Hd)
+        scores = jnp.where(
+            attend[kind.pool][:, :, None, :, :] * jnp.ones_like(scores, bool),
+            scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
+        return jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
+            B, 1, H * Hd).astype(x.dtype)
 
-    def body(carry, inputs):
+    def body(carry, inputs, kind, cache_l):
         x, cache = carry
         layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
-        from fusioninfer_tpu.models.quantization import maybe_dequantize_tree
-
         layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
-        B_, S_, D_ = x.shape
         if cfg.is_mla:
             # B rows of one token each through the latent kernel: the
             # same body (and bits) the fused step scores decode rows with
             return _mla_paged_block(
                 cfg, layer, x, positions, cache, l, active[:, None],
-                write_page, write_slot, rows, use_kernel=use_kernel,
-                interpret=dispatch.kernel_interpret(), walks=walks), None
-        q, k, v = qkv_proj(cfg, layer, x, pos, layer_lora, adapter_ids)
+                *write[""], rows[""], use_kernel=use_kernel,
+                interpret=dispatch.kernel_interpret(), walks=walks[""])
 
-        # write this step's K/V into each sequence's page slot (stacked
-        # head-major cache [L, KV, n_pages, ps, Hd]; k[:, 0] is
-        # [B, KV, Hd]) — in place at layer l
-        cache = _scatter_kv(cache, l, k[:, 0], v[:, 0],
-                            write_page, write_slot, head_axis=1)
-        ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
+        def attend_paged(q, k, v, cache):
+            # this step's K/V into each sequence's page slot, then the
+            # same kernel (and bits) the fused mixed-batch path scores
+            # decode rows with
+            return _paged_attend(
+                mesh, cache, q, k[:, 0], v[:, 0], kind, cache_l,
+                write[kind.pool], rows[kind.pool], walks[kind.pool],
+                portable, use_kernel=use_kernel, coalesce=coalesce,
+                kv_splits=kv_splits)
 
-        if use_kernel:
-            # the same kernel (and bits) the fused mixed-batch path
-            # scores decode rows with
-            attn = _ragged_attn(
-                mesh, q[:, 0], cache, *rows, ks_s, vs_s, layer=l,
-                window=cfg.sliding_window, coalesce=coalesce,
-                kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(), walks=walks,
-            )[:, None, :]  # [B, 1, H*Hd]
-        else:
-            # portable path: gather pages [KV, B, mp, ps, Hd] -> [KV, B, T, Hd]
-            k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
-            k_ctx = k_cache_l[:, page_tables].reshape(KV, B_, mp * ps, Hd)
-            v_ctx = v_cache_l[:, page_tables].reshape(KV, B_, mp * ps, Hd)
-            if quantized:
-                k_ctx = _dequant_gather(k_ctx, ks_l, page_tables,
-                                        (KV, B_, mp * ps))
-                v_ctx = _dequant_gather(v_ctx, vs_l, page_tables,
-                                        (KV, B_, mp * ps))
-
-            group = H // KV
-            qg = q.reshape(B_, 1, KV, group, Hd)
-            scores = jnp.einsum("bskgd,kbtd->bkgst", qg, k_ctx).astype(jnp.float32) / jnp.sqrt(Hd)
-            scores = jnp.where(attend[:, :, None, :, :] * jnp.ones_like(scores, bool), scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
-            attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
-                B_, 1, H * Hd).astype(x.dtype)
-        x = x + attn_out_proj(layer, attn, layer_lora, adapter_ids)
-        y, stats = mlp_block(cfg, layer, x, active[:, None])
-        return (x + y, _add_moe_stats(cache, stats)), None
+        x, cache, stats = gqa_block(
+            cfg, layer, x, pos, kind, attend_paged, cache, active[:, None],
+            layer_lora, adapter_ids)
+        return x, _add_moe_stats(cache, stats)
 
     x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
@@ -561,7 +581,7 @@ def decode_burst(
     frequency = ctl_f[:, 4]
     repetition = ctl_f[:, 5]
 
-    max_tokens_covered = page_tables.shape[1] * cache_cfg.page_size
+    max_tokens_covered = page_tables.shape[-1] * cache_cfg.page_size
 
     def one(carry, _):
         cache, toks, pos, tcounts, ocounts, gcounts = carry
@@ -669,100 +689,103 @@ def fused_step(
 
     T = tokens.shape[0]
     ps = cache_cfg.page_size
-    mp = page_tables.shape[1]
+    mp = page_tables.shape[-1]
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     quantized = cache_cfg.quantized
     use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
 
     row_of, off, live = ragged_token_rows(q_begins, q_lens, T)
     positions = jnp.where(live, row_starts[row_of] + off, 0)
-    tables_tok = page_tables[row_of]  # [T, mp] — each token's row's pages
-    write_page = jnp.where(
-        live, tables_tok[jnp.arange(T), positions // ps],
-        cache_cfg.trash_page,
-    )
-    write_slot = positions % ps
+    pools = _pool_tables(cfg, cache_cfg, page_tables)
+    tables_tok, write = {}, {}
+    for pool, (tables, trash, _) in pools.items():
+        tables_tok[pool] = tables[row_of]  # [T, mp] — each token's row's pages
+        write_page = jnp.where(
+            live, tables_tok[pool][jnp.arange(T), positions // ps], trash,
+        )
+        write[pool] = (write_page, positions % ps)
     adapter_tok = adapter_ids[row_of] if adapter_ids is not None else None
 
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)[:, None, :]
     pos2 = positions[:, None]  # [T, 1]
 
-    walks = _ragged_walks(cfg, cache, mesh, use_kernel, T, page_tables,
-                          row_starts, q_begins, q_lens, kv_splits)
+    rows = {pool: (tables, row_starts, q_begins, q_lens)
+            for pool, (tables, _, _) in pools.items()}
+    walks = {pool: _ragged_walks(cfg, cache, mesh, use_kernel, T,
+                                 *rows[pool], kv_splits, pool, window)
+             for pool, (_, _, window) in pools.items()}
 
     # portable-path mask over each token's own gathered [mp * ps] context
-    ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T_ctx]
-    attend = masks.attend(positions[:, None], ctx_idx,
-                          cfg.sliding_window) & live[:, None]
-    attend = attend[:, None, None, :]  # [T, 1, 1, T_ctx]
+    attend = {}
+    for pool, (_, _, window) in pools.items():
+        ctx_idx = jnp.arange(mp * ps)[None, :]  # [1, T_ctx]
+        attend[pool] = (masks.attend(positions[:, None], ctx_idx, window)
+                        & live[:, None])[:, None, None, :]  # [T, 1, 1, T_ctx]
 
-    def body(carry, inputs):
+    def portable(cache, q, kind, cache_l):
+        # portable flat gather: decode_step's einsum with the flat
+        # tokens on the batch axis — per-token bits independent of
+        # the rest of the batch, so split/fused stay bit-identical.
+        # int8 pages fold their scales AFTER the dots (the kernel's
+        # scale-after-dot identity): multiplying the scale into the
+        # contraction operand lets XLA move it inside or outside
+        # the Σ_d per shape — a T-dependent algebraic rewrite that
+        # flipped sampled streams between split and fused packs
+        tok = tables_tok[kind.pool]
+        k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, cache_l,
+                                                        kind.pool)
+        k_ctx = k_cache_l[:, tok].reshape(KV, T, mp * ps, Hd)
+        v_ctx = v_cache_l[:, tok].reshape(KV, T, mp * ps, Hd)
+        if quantized:
+            k_ctx = k_ctx.astype(jnp.float32)
+            v_ctx = v_ctx.astype(jnp.float32)
+            # per-(head, token, position) scale planes [KV, T, S] →
+            # broadcast over the score axes (b=token, k, g, s=1, t)
+            k_sc = ks_l[:, tok, 0].reshape(
+                KV, T, mp * ps).transpose(1, 0, 2)[:, :, None, None, :]
+            v_sc = vs_l[:, tok, 0].reshape(
+                KV, T, mp * ps).transpose(1, 0, 2)[:, :, None, None, :]
+
+        group = H // KV
+        qg = q.reshape(T, 1, KV, group, Hd)
+        scores = jnp.einsum("bskgd,kbtd->bkgst", qg, k_ctx).astype(
+            jnp.float32) / jnp.sqrt(Hd)
+        if quantized:
+            scores = scores * k_sc
+        scores = jnp.where(
+            attend[kind.pool][:, :, None, :, :] * jnp.ones_like(scores, bool),
+            scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
+        if quantized:
+            probs = probs * v_sc
+        return jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
+            T, 1, H * Hd).astype(x.dtype)
+
+    def body(carry, inputs, kind, cache_l):
         x, cache = carry
         layer, layer_lora, l = _layer_unpack(inputs, lora is not None)
-        from fusioninfer_tpu.models.quantization import maybe_dequantize_tree
-
         layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
         if cfg.is_mla:
             return _mla_paged_block(
                 cfg, layer, x, positions, cache, l, live[:, None],
-                write_page, write_slot,
-                (page_tables, row_starts, q_begins, q_lens),
+                *write[""], rows[""],
                 use_kernel=use_kernel, interpret=dispatch.kernel_interpret(),
-                walks=walks), None
-        q, k, v = qkv_proj(cfg, layer, x, pos2, layer_lora, adapter_tok)
+                walks=walks[""])
 
-        # stacked head-major cache [L, KV, n_pages, ps, Hd]; k[:, 0] is
-        # [T, KV, Hd] → in-place scatter at layer l, per-token maps
-        cache = _scatter_kv(cache, l, k[:, 0], v[:, 0],
-                            write_page, write_slot, head_axis=1)
-        ks_s, vs_s = cache.get("k_scale"), cache.get("v_scale")
+        def attend_paged(q, k, v, cache):
+            # stacked head-major cache [L, KV, n_pages, ps, Hd]; k[:, 0]
+            # is [T, KV, Hd] → in-place scatter at the layer's place in
+            # its kind's pool, per-token maps
+            return _paged_attend(
+                mesh, cache, q, k[:, 0], v[:, 0], kind, cache_l,
+                write[kind.pool], rows[kind.pool], walks[kind.pool],
+                portable, use_kernel=use_kernel, coalesce=coalesce,
+                kv_splits=kv_splits)
 
-        if use_kernel:
-            attn = _ragged_attn(
-                mesh, q[:, 0], cache, page_tables, row_starts, q_begins,
-                q_lens, ks_s, vs_s, layer=l, window=cfg.sliding_window,
-                coalesce=coalesce, kv_splits=kv_splits,
-                interpret=dispatch.kernel_interpret(), walks=walks,
-            )[:, None, :]  # [T, 1, H*Hd]
-        else:
-            # portable flat gather: decode_step's einsum with the flat
-            # tokens on the batch axis — per-token bits independent of
-            # the rest of the batch, so split/fused stay bit-identical.
-            # int8 pages fold their scales AFTER the dots (the kernel's
-            # scale-after-dot identity): multiplying the scale into the
-            # contraction operand lets XLA move it inside or outside
-            # the Σ_d per shape — a T-dependent algebraic rewrite that
-            # flipped sampled streams between split and fused packs
-            k_cache_l, v_cache_l, ks_l, vs_l = _cache_layer(cache, l)
-            k_ctx = k_cache_l[:, tables_tok].reshape(KV, T, mp * ps, Hd)
-            v_ctx = v_cache_l[:, tables_tok].reshape(KV, T, mp * ps, Hd)
-            if quantized:
-                k_ctx = k_ctx.astype(jnp.float32)
-                v_ctx = v_ctx.astype(jnp.float32)
-                # per-(head, token, position) scale planes [KV, T, S] →
-                # broadcast over the score axes (b=token, k, g, s=1, t)
-                k_sc = ks_l[:, tables_tok, 0].reshape(
-                    KV, T, mp * ps).transpose(1, 0, 2)[:, :, None, None, :]
-                v_sc = vs_l[:, tables_tok, 0].reshape(
-                    KV, T, mp * ps).transpose(1, 0, 2)[:, :, None, None, :]
-
-            group = H // KV
-            qg = q.reshape(T, 1, KV, group, Hd)
-            scores = jnp.einsum("bskgd,kbtd->bkgst", qg, k_ctx).astype(
-                jnp.float32) / jnp.sqrt(Hd)
-            if quantized:
-                scores = scores * k_sc
-            scores = jnp.where(
-                attend[:, :, None, :, :] * jnp.ones_like(scores, bool),
-                scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(v_ctx.dtype)
-            if quantized:
-                probs = probs * v_sc
-            attn = jnp.einsum("bkgst,kbtd->bskgd", probs, v_ctx).reshape(
-                T, 1, H * Hd).astype(x.dtype)
-        x = x + attn_out_proj(layer, attn, layer_lora, adapter_tok)
-        y, stats = mlp_block(cfg, layer, x, live[:, None])
-        return (x + y, _add_moe_stats(cache, stats)), None
+        x, cache, stats = gqa_block(
+            cfg, layer, x, pos2, kind, attend_paged, cache, live[:, None],
+            layer_lora, adapter_tok)
+        return x, _add_moe_stats(cache, stats)
 
     x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)

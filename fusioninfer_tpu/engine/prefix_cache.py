@@ -78,6 +78,7 @@ class PrefixCachingAllocator(PageAllocator):
         return 0.0 if total == 0 else used / total
 
     def _take_free_page(self) -> int:
+        self.pages_allocated_total["full"] += 1
         if self._free:
             return self._free.pop()
         # reclaim the least-recently-used evictable page

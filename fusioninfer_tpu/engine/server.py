@@ -2655,14 +2655,19 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     params = None
     if load_hf and load_ckpt:
         raise SystemExit("--load-hf and --load-checkpoint are mutually exclusive")
-    from fusioninfer_tpu.engine.engine import latent_cache_refusal
+    from fusioninfer_tpu.engine.engine import (
+        kind_cache_refusal,
+        latent_cache_refusal,
+    )
 
     if load_hf or load_ckpt:
-        # models/loader.py has no name map for latent attention: a preset
-        # that keeps a latent cache is refused before anything is read
+        # models/loader.py has no name map for latent attention nor for a
+        # layer pattern: a preset that keeps a latent cache, or a cache by
+        # layer kind, is refused before anything is read
         try:
-            refusal = latent_cache_refusal(get_preset(args.model),
-                                           checkpoint=True)
+            preset = get_preset(args.model)
+            refusal = (latent_cache_refusal(preset, checkpoint=True)
+                       or kind_cache_refusal(preset, checkpoint=True))
         except KeyError:
             refusal = None
         if refusal:
@@ -2700,10 +2705,9 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     else:
         cfg = get_preset(args.model)
         model_name = args.model
-    # what a latent (MLA) cache does not support yet exits HERE, by the
-    # flag's name, before any weight is drawn
-    refusal = latent_cache_refusal(
-        cfg,
+    # what a latent (MLA) cache, or a cache kept by layer kind, does not
+    # support yet exits HERE, by the flag's name, before any weight is drawn
+    asked = dict(
         mesh=args.tensor_parallel_size != 1 or jax.process_count() > 1,
         int8_weights=quant == "int8",
         int8_kv=getattr(args, "kv_cache_dtype", "auto") == "int8",
@@ -2714,6 +2718,8 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
         kv_fabric=getattr(args, "kv_peer", None),
         evacuate=(getattr(args, "evacuate_grace_s", 0)
                   or getattr(args, "evacuate_peer", None)))
+    refusal = (latent_cache_refusal(cfg, **asked)
+               or kind_cache_refusal(cfg, **asked))
     if refusal:
         raise SystemExit(refusal)
     if quant != "none" and cfg.quantization == "none":
@@ -2781,8 +2787,15 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
         tp=tp,
         prefix_caching=not getattr(args, "no_prefix_caching", False),
         kv_dtype="int8" if kv_dtype == "int8" else "model",
+        # the longest row a step writes (a cache by layer kind sizes its
+        # window pool from it): the pinned budget, else the most the
+        # start-up calibration can answer
+        step_span=_nonneg_flag(args, "tokens_per_step") or 4096,
     )
-    logger.info("cache: %d pages of %d tokens", cache_cfg.n_pages, cache_cfg.page_size)
+    logger.info("cache: %d pages of %d tokens%s", cache_cfg.n_pages,
+                cache_cfg.page_size,
+                " + %d window-kind pages" % cache_cfg.n_window_pages
+                if cache_cfg.by_kind else "")
     no_budget = getattr(args, "no_token_budget", False)
     tokens_per_step = _nonneg_flag(args, "tokens_per_step")
     host_tier = None

@@ -15,6 +15,22 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer of the period is, as STATIC values a block is
+    traced with: the positions a query sees (``window``; None = full
+    causal), whether q and k are rotated (False = NoPE), and where the
+    layer caches: ``pool`` is the suffix of its kind's pool in the cache
+    tree ("" = ``cache["k"]``, "_win" = ``cache["k_win"]``), ``rank`` its
+    index among the period's ``per_period`` layers of that pool."""
+
+    window: int | None
+    rope: bool
+    pool: str = ""
+    rank: int = 0
+    per_period: int = 1
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str = "qwen3-tiny"
     vocab_size: int = 4096
@@ -48,6 +64,14 @@ class ModelConfig:
     # paged prefill/suffix, decode, verify — as a static mask bound, so
     # kernels skip out-of-window pages instead of reading them.
     sliding_window: int | None = None
+    # A per-layer pattern of ONE period, repeated down the stack: for
+    # each layer of the period ``(windowed, rotary)``.  A windowed layer
+    # sees ``sliding_window`` positions, a layer without rotary adds no
+    # position at all (NoPE).  None = a period of one layer of the kind
+    # ``sliding_window`` already says, rotated: the homogeneous decoder
+    # is the degenerate case.  SmallThinker: ((False, False), (True,
+    # True), (True, True), (True, True)).
+    layer_pattern: tuple | None = None
     # Router of a mixture-of-experts layer.  ``norm_topk``: the chosen
     # experts' weights are a softmax over the chosen logits (Qwen3-MoE);
     # False = the softmax over ALL experts is kept as it is and scaled by
@@ -103,6 +127,45 @@ class ModelConfig:
     # result is held back; attention 1, dense FFN 1; only then the expert
     # layer's result is added.  One layer = two cache layers.
     block: str = "single"
+    # the gate of an expert: ``W_down(act(W_gate m) * W_up m)``, "silu"
+    # (SwiGLU) or "relu" (SmallThinker's ReLU-gated experts)
+    expert_act: str = "silu"
+    # what the router reads: "mlp_norm", the normed input of the expert
+    # layer, or "layer_input", the layer's RAW input before attention
+    # (SmallThinker: the routing is known while attention still runs)
+    router_input: str = "mlp_norm"
+
+    @property
+    def layer_kinds(self) -> tuple[LayerKind, ...]:
+        """The period's layers in order.  Full and windowed layers of
+        one model cache in pools of their own (the windowed kind's is
+        "_win"); a model of one kind keeps the one pool."""
+        pattern = self.layer_pattern or (
+            (self.sliding_window is not None, True),)
+        mixed = len({bool(w) for w, _ in pattern}) > 1
+        kinds, seen = [], {}
+        for windowed, rope in pattern:
+            pool = "_win" if mixed and windowed else ""
+            kinds.append((windowed, rope, pool, seen.get(pool, 0)))
+            seen[pool] = seen.get(pool, 0) + 1
+        return tuple(
+            LayerKind(self.sliding_window if windowed else None, bool(rope),
+                      pool, rank, seen[pool])
+            for windowed, rope, pool, rank in kinds)
+
+    @property
+    def period(self) -> int:
+        return len(self.layer_pattern) if self.layer_pattern else 1
+
+    @property
+    def cache_by_kind(self) -> bool:
+        """Full and windowed layers both: two pools, a page list a kind."""
+        return any(k.pool for k in self.layer_kinds)
+
+    def n_pool_layers(self, pool: str = "") -> int:
+        """Cache layers of the pool ``pool`` ("" or "_win")."""
+        per_period = sum(k.pool == pool for k in self.layer_kinds)
+        return self.n_cache_layers // self.period * per_period
 
     @property
     def jax_dtype(self):
@@ -177,6 +240,20 @@ class ModelConfig:
         assert self.d_model % self.n_heads == 0 or self.head_dim, "need explicit head_dim"
         assert self.quantization in ("none", "int8"), f"unknown quantization {self.quantization!r}"
         assert self.sliding_window is None or self.sliding_window >= 1
+        assert self.expert_act in ("silu", "relu"), self.expert_act
+        assert self.router_input in ("mlp_norm", "layer_input"), \
+            self.router_input
+        if self.layer_pattern is not None:
+            assert self.layer_pattern and not self.is_mla, \
+                "a layer pattern is drawn for GQA attention"
+            assert self.n_layers % self.period == 0, \
+                "the stack is whole periods of the layer pattern"
+            assert (self.sliding_window is not None
+                    or not any(w for w, _ in self.layer_pattern)), \
+                "a windowed layer needs sliding_window"
+        assert self.router_input == "mlp_norm" or (
+            self.is_moe and not self.is_mla), \
+            "only a GQA expert layer routes from the layer's input"
         if self.is_moe:
             assert self.n_experts_active <= self.n_experts
             assert self.n_experts % self.n_group == 0, "n_group must divide n_experts"
@@ -494,5 +571,63 @@ register_preset(
         qk_rope_dim=16,
         v_head_dim=32,
         **_LONGCAT_FLASH,
+    )
+)
+
+# SmallThinker-21BA3B-Instruct (huggingface.co/PowerInfer/
+# SmallThinker-21BA3B-Instruct config.json; arXiv:2507.20984): every
+# fourth layer full causal attention with no positional encoding, the
+# three after it rotary over a 4096-position window; 64 ReLU-gated
+# experts of width 768, 6 a token, routed from the layer's raw input.
+_SMALLTHINKER = dict(
+    qk_norm=False,
+    tie_embeddings=False,
+    rope_theta=1_500_000.0,
+    layer_pattern=((False, False), (True, True), (True, True), (True, True)),
+    expert_act="relu",
+    router_input="layer_input",
+    norm_topk=True,
+)
+
+# Stage 0 of a 7-stage pipeline at published widths (PERF.md section 4):
+# two whole periods of the 52 layers, all 64 experts of each, the whole
+# vocabulary and the head.
+register_preset(
+    ModelConfig(
+        name="smallthinker-21b-a3b",
+        vocab_size=151_936,
+        d_model=2560,
+        n_layers=8,
+        n_heads=28,
+        n_kv_heads=4,
+        head_dim=128,
+        d_ff=768,
+        max_seq_len=16_384,
+        sliding_window=4096,
+        n_experts=64,
+        n_experts_active=6,
+        moe_d_ff=768,
+        **_SMALLTHINKER,
+    )
+)
+
+# The same architecture at a test size: 2 periods, a window of 24 (a
+# 3-page context of 16-token pages passes it), 8 experts, 2 a token.
+register_preset(
+    ModelConfig(
+        name="smallthinker-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=32,
+        d_ff=64,
+        max_seq_len=4096,
+        sliding_window=24,
+        n_experts=8,
+        n_experts_active=2,
+        moe_d_ff=64,
+        **_SMALLTHINKER,
     )
 )

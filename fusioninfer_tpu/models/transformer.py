@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fusioninfer_tpu.models.config import ModelConfig
+from fusioninfer_tpu.models.config import LayerKind, ModelConfig
 from fusioninfer_tpu.models.quantization import embed_lookup, maybe_dequantize_tree
 
 Params = dict[str, Any]
@@ -317,9 +317,16 @@ def moe_route(cfg: ModelConfig, h: jax.Array, router_w: jax.Array,
 # and a chunk pass (832 tokens) the fastest on the first (476 / 708 us
 # against 532 / 793 for DeepSeek's tuple) and within 1.5 % of the best on
 # the second (PERF.md section 6, PR 34).
+# SmallThinker's two (2560 x 768 and back): one whole weight tile an
+# expert and 256 rows; of six tried at a decode pass (32 tokens x 6, 62
+# experts touched) and a chunk pass (1024 tokens x 6) the fastest on the
+# chunk pass by 11-12 % (580 / 587 us against 657 / 659 for 128 rows)
+# and within 1.5 % of the best on the decode pass (381 / 371 against
+# 375 / 368) (PERF.md section 6, PR 36).
 GMM_TILING = {
     (5120, 1536): (128, 2560, 768), (1536, 5120): (128, 2560, 768),
     (6144, 2048): (128, 2048, 1024), (2048, 6144): (128, 2048, 1024),
+    (2560, 768): (256, 2560, 768), (768, 2560): (256, 768, 2560),
 }
 GMM_TILE_ELEMENTS = 2560 * 768  # a weight tile of any other product
 
@@ -397,9 +404,16 @@ MOE_STATS = ("assignments", "assignments_local", "expert_touches",
              "layer_passes", "assignments_zero")
 
 
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
-              live: Optional[jax.Array] = None) -> tuple[jax.Array, jax.Array]:
-    """The ONE expert layer: route over the router's whole width,
+              live: Optional[jax.Array] = None,
+              routing: Optional[tuple[jax.Array, jax.Array]] = None,
+              ) -> tuple[jax.Array, jax.Array]:
+    """The ONE expert layer: route over the router's whole width
+    (``routing``: :func:`moe_route`'s result where the caller routed
+    from another input than ``h``, ``cfg.router_input``),
     compute the part of the result that the experts held here give, with
     no capacity and no assignment dropped, plus what every process
     computes alike for its OWN tokens: the shared experts and the
@@ -423,8 +437,8 @@ def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
     k = cfg.n_experts_active
     held = (w_gate[0].shape[1] if isinstance(w_gate, tuple)
             else w_gate.shape[0])
-    top_idx, top_w = moe_route(cfg, h, layer["router"],
-                               layer.get("router_bias"))
+    top_idx, top_w = routing if routing is not None else moe_route(
+        cfg, h, layer["router"], layer.get("router_bias"))
     with jax.named_scope("moe_experts"):
         local = top_idx - cfg.expert_offset
         mine = (local >= 0) & (local < held)
@@ -434,7 +448,8 @@ def moe_layer(cfg: ModelConfig, layer: Params, h: jax.Array,
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
         xs = h[order // k]  # [A, D] rows in expert order
-        act = jax.nn.silu(grouped_matmul(xs, w_gate, sizes, h.dtype))
+        act = EXPERT_ACTS[cfg.expert_act](
+            grouped_matmul(xs, w_gate, sizes, h.dtype))
         act = act * grouped_matmul(xs, layer["w_up"], sizes, h.dtype)
         ys = grouped_matmul(act, layer["w_down"], sizes, jnp.float32)
         weight = jnp.where(mine, top_w, 0.0).reshape(T * k)[order]
@@ -488,10 +503,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
 
     One homogeneous stack (``params["layers"]``) is drawn a whole matrix
     at a time under ``split(key, 12)[slot]``.  A model with latent
-    attention (and with it, leading dense layers) is drawn by
-    :func:`_init_stacks`."""
+    attention (and with it, leading dense layers) or with a layer
+    pattern is drawn a layer at a time by :func:`_init_stacks`."""
     cfg.validate()
-    if cfg.is_mla:
+    if cfg.is_mla or cfg.layer_pattern is not None:
         return _init_stacks(cfg, key)
     dtype = cfg.jax_dtype
     L, D, H, KV, Hd, F = (
@@ -542,6 +557,7 @@ STACK_SLOTS = {
     "w_gate": 20, "w_up": 21, "w_down": 22, "router": 23,
     "ws_gate": 24, "ws_up": 25, "ws_down": 26,
     "wd_gate": 27, "wd_up": 28, "wd_down": 29,
+    "wq": 30, "wk": 31, "wv": 32,
 }
 DENSE_STACK_SLOT_OFFSET = 100  # the leading dense layers' matrices
 # what a shortcut-connected double layer holds twice, on a sub-layer
@@ -560,14 +576,20 @@ def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
     dense FFNs (``wd_*``) lead with a sub-layer axis of 2."""
     D, H, F = cfg.d_model, cfg.n_heads, cfg.d_ff
     qk = cfg.qk_nope_dim + cfg.qk_rope_dim
-    out = {
-        "wq_a": ((D, cfg.q_lora_rank), D),
-        "wq_b": ((cfg.q_lora_rank, H * qk), cfg.q_lora_rank),
-        "wkv_a": ((D, cfg.latent_dim), D),
-        "wkv_b": ((cfg.kv_lora_rank, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
-                  cfg.kv_lora_rank),
-        "wo": ((cfg.attn_out_dim, D), cfg.attn_out_dim),
-    }
+    if cfg.is_mla:
+        out = {
+            "wq_a": ((D, cfg.q_lora_rank), D),
+            "wq_b": ((cfg.q_lora_rank, H * qk), cfg.q_lora_rank),
+            "wkv_a": ((D, cfg.latent_dim), D),
+            "wkv_b": ((cfg.kv_lora_rank,
+                       H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                      cfg.kv_lora_rank),
+        }
+    else:
+        kv = cfg.n_kv_heads * cfg.head_dim
+        out = {"wq": ((D, H * cfg.head_dim), D), "wk": ((D, kv), D),
+               "wv": ((D, kv), D)}
+    out["wo"] = ((cfg.attn_out_dim, D), cfg.attn_out_dim)
     if cfg.sublayers > 1:
         out.update(wd_gate=((D, F), D), wd_up=((D, F), D), wd_down=((F, D), F))
         out = {name: ((cfg.sublayers, *shape), fan_in)
@@ -587,7 +609,8 @@ def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
 
 
 def _init_stacks(cfg: ModelConfig, key: jax.Array) -> Params:
-    """Seeded weights of a latent-attention model, in up to two stacks:
+    """Seeded weights of a latent-attention or layer-pattern model, in up
+    to two stacks:
     ``params["dense_layers"]`` (the leading ``first_k_dense`` layers)
     and ``params["layers"]`` (the expert layers after them).  Every
     matrix is drawn a LAYER at a time into its stack (:func:`_draw_into`):
@@ -601,9 +624,13 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array) -> Params:
         layers: Params = {
             "attn_norm": jnp.ones((*lead, D), dtype),
             "mlp_norm": jnp.ones((*lead, D), dtype),
-            "q_a_norm": jnp.ones((*lead, cfg.q_lora_rank), dtype),
-            "kv_a_norm": jnp.ones((*lead, cfg.kv_lora_rank), dtype),
         }
+        if cfg.is_mla:
+            layers["q_a_norm"] = jnp.ones((*lead, cfg.q_lora_rank), dtype)
+            layers["kv_a_norm"] = jnp.ones((*lead, cfg.kv_lora_rank), dtype)
+        elif cfg.qk_norm:
+            layers["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+            layers["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
         if experts and cfg.router_score_bias:
             # a buffer, zeros until a checkpoint brings a trained one
             layers["router_bias"] = jnp.zeros((n, cfg.router_width),
@@ -648,6 +675,77 @@ def layer_stacks(cfg: ModelConfig, params: Params) -> list[tuple[Params, int]]:
 # -- forward -----------------------------------------------------------------
 
 
+def scan_layers(cfg: ModelConfig, params: Params, lora, body, carry):
+    """``lax.scan`` of the ONE layer ``body`` over each of the model's
+    layer stacks in turn (the leading dense layers, then the rest), the
+    layer index running on, BY PERIOD: one scan step runs the period's
+    layers in turn, ``body(carry, (layer, [lora,] l), kind, cache_l)``
+    with the layer's static :class:`LayerKind` and its cache layer in
+    its kind's pool.  A model without a pattern is a period of one: the
+    scan it always was, ``cache_l`` the layer index itself.
+
+    Per-layer scan operands: weights (+ lora) + the layer index.  The KV
+    cache is deliberately NOT xs: it rides the scan CARRY as one donated
+    stacked pool per array, updated in place by ``_scatter_kv`` —
+    threading it through xs→ys made XLA write a fresh cache-sized ys
+    every step (a full pool copy per decode step; measured step time
+    scaled with pool size, round 5)."""
+    from fusioninfer_tpu.models.quantization import is_quantized
+
+    kinds, P = cfg.layer_kinds, cfg.period
+    for stack, first in layer_stacks(cfg, params):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        # a stack of experts is not sliced by the scan: the grouped
+        # product reads layer l of it in place (grouped_matmul)
+        whole = {k: stack[k] for k in EXPERT_MATRICES
+                 if "router" in stack and not is_quantized(stack[k])}
+        # nor are a double layer's twice-held matrices [L, 2, ...]: the
+        # scan's slice [2, ...] of one is a buffer of its own, written
+        # every layer (1.1 GB of dense-FFN weights a layer at
+        # LongCat-Flash's widths), where a dot reads ONE matrix of the
+        # stack in place (mla_block indexes it by 2 l + i)
+        if cfg.sublayers > 1:
+            whole.update({k: stack[k] for k in SUBLAYER_MATRICES})
+        # nor, for the same reason, the matrices a period holds several
+        # times a step: layer P s + j's are read in place
+        indexed = ({k: v for k, v in stack.items()
+                    if k not in whole and v.ndim > 2} if P > 1 else {})
+        rest = {k: v for k, v in stack.items()
+                if k not in whole and k not in indexed}
+        if P > 1:  # [n/P, P, ...]: a step's norms, a row a layer
+            rest = {k: v.reshape(n // P, P, *v.shape[1:])
+                    for k, v in rest.items()}
+        xs = [rest]
+        if lora is not None:
+            xs.append(lora)
+        xs.append(first + jnp.arange(n // P) * P if P > 1
+                  else first + jnp.arange(n))
+
+        def stack_body(carry, inputs, whole=whole, indexed=indexed,
+                       first=first):
+            if P == 1:
+                layer = {**inputs[0], **{k: (w, inputs[-1] - first)
+                                         for k, w in whole.items()}}
+                return body(carry, (layer, *inputs[1:]), kinds[0],
+                            inputs[-1]), None
+            for j, kind in enumerate(kinds):
+                l = inputs[-1] + j
+                layer = {
+                    **{k: v[j] for k, v in inputs[0].items()},
+                    **{k: lax.dynamic_index_in_dim(w, l - first, 0,
+                                                   keepdims=False)
+                       for k, w in indexed.items()},
+                    **{k: (w, l - first) for k, w in whole.items()}}
+                carry = body(carry, (layer, *inputs[1:-1], l), kind,
+                             (inputs[-1] - first) // P * kind.per_period
+                             + kind.rank)
+            return carry, None
+
+        carry, _ = lax.scan(stack_body, carry, tuple(xs))
+    return carry
+
+
+
 def _attention(q, k, v, mask):
     """Plain batched attention: q [B,S,H,Hd], k/v [B,T,KV,Hd], mask [B,1,S,T]."""
     B, S, H, Hd = q.shape
@@ -664,11 +762,12 @@ def _attention(q, k, v, mask):
 @jax.named_scope("attn_qkv")
 def qkv_proj(
     cfg: ModelConfig, layer: Params, x: jax.Array, positions: jax.Array,
-    lora: Params = None, adapter_ids: jax.Array = None,
+    lora: Params = None, adapter_ids: jax.Array = None, rope: bool = True,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pre-norm + QKV projection + (optional) QK-norm + RoPE — shared by
     every execution path (full forward, paged prefill/suffix, decode) so
-    model features can never drift between them.
+    model features can never drift between them.  ``rope`` False (a
+    NoPE layer's static kind): q and k are left as projected.
 
     x: [B, S, D] → q [B, S, H, Hd], k/v [B, S, KV, Hd].
     ``lora``: this layer's stacked adapter slice (``[N, d_in, r]`` per
@@ -693,25 +792,29 @@ def qkv_proj(
     if cfg.qk_norm:
         q = rms_norm(q, layer["q_norm"], cfg.rms_eps)
         k = rms_norm(k, layer["k_norm"], cfg.rms_eps)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
 def mlp_block(cfg: ModelConfig, layer: Params, x: jax.Array,
-              live: Optional[jax.Array] = None):
+              live: Optional[jax.Array] = None, routing=None):
     """Pre-norm + FFN, shared by every path → ``(y, stats)``: a dense
     SwiGLU (stats None), or the expert layer where the layer's tree
     holds a router (:func:`moe_layer`; stats its counters).
 
     x: [B, S, D] → [B, S, D] (residual NOT added).  ``live`` [B, S]
-    marks real tokens: padding chooses no expert.  Callers dequantize
-    the layer tree once at block entry (see qkv_proj invariant)."""
+    marks real tokens: padding chooses no expert; ``routing``: the
+    block's own :func:`moe_route` where the router reads the layer's
+    input (:func:`gqa_block`).  Callers dequantize the layer tree once
+    at block entry (see qkv_proj invariant)."""
     B, S, D = x.shape
     h = rms_norm(x, layer["mlp_norm"], cfg.rms_eps)
     if "router" in layer:
         y, stats = moe_layer(cfg, layer, h.reshape(B * S, D),
-                             None if live is None else live.reshape(B * S))
+                             None if live is None else live.reshape(B * S),
+                             routing)
         return y.reshape(B, S, D), stats
     with jax.named_scope("mlp"):
         return swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"]), None
@@ -728,6 +831,40 @@ def attn_out_proj(layer: Params, attn: jax.Array, lora: Params = None,
 
         out = out + lora_delta(lora, "wo", attn, adapter_ids)
     return out
+
+
+def attn_scope(kind: LayerKind) -> str:
+    """The named scope of a layer kind's attention, inside ``attn``."""
+    return "attn_full" if kind.window is None else "attn_window"
+
+
+def gqa_block(cfg: ModelConfig, layer: Params, x: jax.Array,
+              positions: jax.Array, kind: LayerKind, attend, carry,
+              live: Optional[jax.Array] = None, lora: Params = None,
+              adapter_ids: Optional[jax.Array] = None):
+    """ONE block of a GQA model → ``(x, carry, stats)``: the body the
+    no-cache forward and the three serving programs share, traced with
+    the layer's STATIC ``kind`` (rotary or not here; the window where
+    the caller attends).  They differ only in how the layer attends, so
+    that is a callback: ``attend(q, k, v, carry) -> (carry, attention
+    output [B, S, H * Hd])`` (fresh causal attention in
+    :func:`layer_forward`; write, then score over the kind's pages in
+    ``model_runner``), ``carry`` being the caller's own (the pool).
+
+    Where ``cfg.router_input`` is "layer_input" the experts are chosen
+    HERE, from the raw ``x`` before attention, and the expert layer is
+    handed the choice.  x: [B, S, D]; ``live`` [B, S] marks real
+    tokens for the experts; ``stats``: the expert layer's counters."""
+    routing = None
+    if cfg.router_input == "layer_input" and "router" in layer:
+        routing = moe_route(cfg, x.reshape(-1, x.shape[-1]), layer["router"],
+                            layer.get("router_bias"))
+    q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids,
+                       rope=kind.rope)
+    carry, attn = attend(q, k, v, carry)
+    x = x + attn_out_proj(layer, attn, lora, adapter_ids)
+    y, stats = mlp_block(cfg, layer, x, live, routing)
+    return x + y, carry, stats
 
 
 def mla_block(cfg: ModelConfig, layer: Params, x: jax.Array, attend,
@@ -790,12 +927,15 @@ def layer_forward(
     lora: Params = None,
     adapter_ids: Optional[jax.Array] = None,
     live: Optional[jax.Array] = None,
+    kind: Optional[LayerKind] = None,
 ):
     """One transformer block → ``(output, kv, stats)``: ``kv`` is what a
     position caches, (k, v) or with latent attention a tuple of the
     latent rows [B, S, rank + rope] of each of the block's attentions;
     ``stats`` the expert layer's counters (None for a dense FFN).
-    ``live`` [B, S] marks real tokens for the experts.
+    ``live`` [B, S] marks real tokens for the experts.  ``kind``: the
+    layer's static kind (:attr:`ModelConfig.layer_kinds`; default: the
+    period's first, which is every layer of a model without a pattern).
 
     x: [B, S, D]; positions: [B, S]; mask broadcastable to [B, 1, S, T].
     ``kv=None`` means fresh causal self-attention — the mask is derived
@@ -807,6 +947,8 @@ def layer_forward(
     shard via shard_map.
     """
     B, S, D = x.shape
+    if kind is None:
+        kind = cfg.layer_kinds[0]
 
     layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
     if cfg.is_mla:
@@ -827,15 +969,18 @@ def layer_forward(
                 return (*latents, latent), attn @ sub["wo"]
 
         return mla_block(cfg, layer, x, attend, (), live)
-    q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids)
+    if kv is None and mask is not None:
+        raise ValueError(
+            "layer_forward(kv=None) is causal self-attention; it derives "
+            "its own mask — pass kv=(k, v) history to use a custom mask"
+        )
+    if kv is not None and mask is None:
+        raise ValueError("layer_forward with kv history requires a mask")
 
-    with jax.named_scope("attn"):
-        if kv is None:
-            if mask is not None:
-                raise ValueError(
-                    "layer_forward(kv=None) is causal self-attention; it derives "
-                    "its own mask — pass kv=(k, v) history to use a custom mask"
-                )
+    def attend(q, k, v, fresh):
+        with jax.named_scope("attn"), jax.named_scope(attn_scope(kind)):
+            if kv is not None:
+                return (k, v), _attention(q, *kv, mask)
             from fusioninfer_tpu.ops import dispatch, flash_attention
 
             if dispatch.resolve_attn(cfg.attn_impl) == "flash" and dispatch.flash_seq_ok(S):
@@ -846,24 +991,19 @@ def layer_forward(
                     attn = flash_attention_tp(
                         mesh, q, k, v, causal=True,
                         interpret=dispatch.kernel_interpret(),
-                        window=cfg.sliding_window,
+                        window=kind.window,
                     )
                 else:
                     attn = flash_attention(
                         q, k, v, causal=True, interpret=dispatch.kernel_interpret(),
-                        window=cfg.sliding_window,
+                        window=kind.window,
                     )
             else:
-                attn = _attention(q, k, v,
-                                  causal_mask(S, window=cfg.sliding_window))
-        else:
-            if mask is None:
-                raise ValueError("layer_forward with kv history requires a mask")
-            attn_k, attn_v = kv
-            attn = _attention(q, attn_k, attn_v, mask)
-    x = x + attn_out_proj(layer, attn, lora, adapter_ids)
-    y, stats = mlp_block(cfg, layer, x, live)
-    return x + y, (k, v), stats
+                attn = _attention(q, k, v, causal_mask(S, window=kind.window))
+            return (k, v), attn
+
+    return gqa_block(cfg, layer, x, positions, kind, attend, None, live,
+                     lora, adapter_ids)
 
 
 def causal_mask(S: int, dtype=jnp.bool_, window: int | None = None) -> jax.Array:
@@ -912,11 +1052,10 @@ def hidden_states(cfg: ModelConfig, params: Params,
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
-    def body(x, layer):
-        return layer_forward(cfg, layer, x, positions)[0], None
+    def body(x, inputs, kind, _cache_l):
+        return layer_forward(cfg, inputs[0], x, positions, kind=kind)[0]
 
-    for stack, _first in layer_stacks(cfg, params):
-        x, _ = lax.scan(body, x, stack)
+    x = scan_layers(cfg, params, None, body, x)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 

@@ -689,7 +689,7 @@ def _ragged_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("sm_scale", "interpret", "window", "block_q",
-                              "coalesce")
+                              "coalesce", "name")
 )
 def ragged_paged_attention(
     q: jax.Array,  # [T, H, Hd] — flat ragged-concat query tokens
@@ -709,6 +709,7 @@ def ragged_paged_attention(
     coalesce: bool | None = None,
     layer: jax.Array | int | None = None,
     walks=None,
+    name: str | None = None,
 ) -> jax.Array:
     """The one true ragged paged-attention kernel → [T, H·Hd].
 
@@ -735,6 +736,8 @@ def ragged_paged_attention(
     ``_ragged_walk``), so split and fused engine dispatches scoring the
     same row are bit-identical.  ``walks``: the rows' walk lists where
     the caller built them once for many calls (:func:`ragged_walk_lists`).
+    ``name``: the call's name in a device trace where the caller tells
+    its calls apart (a layer kind's; None = the default name).
     """
     T, H, Hd = q.shape
     k_pages, v_pages, k_scales, v_scales, layer_arr = _as_stacked(
@@ -827,6 +830,7 @@ def ragged_paged_attention(
         out_shape=jax.ShapeDtypeStruct((Tp, KV, G, Hd), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
+        **({"name": name} if name else {}),
     )(*operands)
     return out.reshape(Tp, H * Hd)[:T]
 
@@ -874,13 +878,28 @@ KV_SPLIT_CHUNKS = 8
 KV_SPLIT_MIN_CTX_TOKENS = 4096
 
 
-def pick_kv_splits(max_pages_per_seq: int, page_size: int) -> int:
+def pick_kv_splits(max_pages_per_seq: int, page_size: int,
+                   window_reach: int | None = None) -> int:
     """The ragged_fits_vmem-style dispatch heuristic: 0 (single-walk
     grid, existing signature families) below the long-context floor,
     else the full ``KV_SPLIT_CHUNKS`` split fan-out.  A pure function
     of static cache config so every process of a multi-host lockstep
-    group — and every dispatch of one engine — resolves identically."""
-    if max_pages_per_seq * page_size < KV_SPLIT_MIN_CTX_TOKENS:
+    group — and every dispatch of one engine — resolves identically.
+
+    ``window_reach``: for a WINDOWED layer kind, the most positions a
+    row's walk ever covers (the window plus the longest row span a step
+    writes), judged by ``min(context bound, reach)``: where the window
+    binds, the kind keeps the single walk.  The split's chunks partition
+    the page TABLE, so a window's walk falls into the two or three of
+    the eight that hold its pages whatever the context, and the others'
+    programs only cost: at a 4096 window under a 16 k table the single
+    walk is faster at every probed shape (32 decode rows at 8 k / 12 k:
+    629 / 632 us against 727 / 730; a 1024-token chunk at 8 k: 2585
+    against 3886; PERF.md section 6, PR 36)."""
+    ctx = max_pages_per_seq * page_size
+    if window_reach is not None and window_reach < ctx:
+        return 0
+    if ctx < KV_SPLIT_MIN_CTX_TOKENS:
         return 0
     return KV_SPLIT_CHUNKS
 
@@ -1025,7 +1044,7 @@ def _ragged_kernel_kvsplit(
 
 @functools.partial(
     jax.jit, static_argnames=("sm_scale", "interpret", "window", "block_q",
-                              "kv_splits")
+                              "kv_splits", "name")
 )
 def ragged_paged_attention_kvsplit(
     q: jax.Array,  # [T, H, Hd] — flat ragged-concat query tokens
@@ -1045,6 +1064,7 @@ def ragged_paged_attention_kvsplit(
     block_q: int = RAGGED_BLOCK_Q,
     layer: jax.Array | int | None = None,
     walks=None,
+    name: str | None = None,
 ) -> jax.Array:
     """Flash-decode ragged paged attention → [T, H·Hd]: the one true
     ragged kernel's descriptor contract with the serial page walk
@@ -1071,7 +1091,7 @@ def ragged_paged_attention_kvsplit(
             q, k_pages, v_pages, page_tables, row_starts, q_begins,
             q_lens, k_scales, v_scales, sm_scale=sm_scale,
             interpret=interpret, window=window, block_q=block_q,
-            coalesce=True, layer=layer_arr, walks=walks)
+            coalesce=True, layer=layer_arr, walks=walks, name=name)
     chunks = KV_SPLIT_CHUNKS
     cpp = chunks // S
 
@@ -1143,6 +1163,7 @@ def ragged_paged_attention_kvsplit(
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        **({"name": name + "_kvsplit"} if name else {}),
     )(*operands)
     # the cross-chunk combine: a strict left-to-right fold at the fixed
     # chunk granularity (bit-identical whatever kv_splits computed the

@@ -205,7 +205,7 @@ ENTRY_POINTS: dict[str, dict] = {
         "family": "kernels",
         "static_argnums": (),
         "static_argnames": ("sm_scale", "interpret", "window", "block_q",
-                            "coalesce"),
+                            "coalesce", "name"),
         "runtime": "fusioninfer_tpu.ops.paged_attention:"
                    "ragged_paged_attention",
     },
@@ -214,7 +214,7 @@ ENTRY_POINTS: dict[str, dict] = {
         "family": "kvsplit",
         "static_argnums": (),
         "static_argnames": ("sm_scale", "interpret", "window", "block_q",
-                            "kv_splits"),
+                            "kv_splits", "name"),
         "runtime": "fusioninfer_tpu.ops.paged_attention:"
                    "ragged_paged_attention_kvsplit",
     },

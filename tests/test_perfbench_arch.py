@@ -33,7 +33,9 @@ def config_of(name):
     ("qwen3-1.7b", "qwen3"), ("qwen3-tiny-cpu", "qwen3"),
     ("deepseek-v2-ep4", "deepseek_v2"), ("deepseek-v2-tiny-cpu", "deepseek_v2"),
     ("longcat-flash-ep32", "longcat_flash"),
-    ("longcat-flash-tiny-cpu", "longcat_flash")])
+    ("longcat-flash-tiny-cpu", "longcat_flash"),
+    ("smallthinker-21b-a3b", "smallthinker"),
+    ("smallthinker-tiny-cpu", "smallthinker")])
 def test_a_configuration_resolves_to_its_architectures_file(work, config,
                                                             model_type):
     cfg = config_of(config)
@@ -51,7 +53,8 @@ def test_every_configuration_of_the_benchmark_resolves(work):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert [c["name"] for c in bench["configs"]] == [
-        "qwen3-1.7b", "deepseek-v2-ep4", "longcat-flash-ep32"]
+        "qwen3-1.7b", "deepseek-v2-ep4", "longcat-flash-ep32",
+        "smallthinker-21b-a3b"]
     for entry in bench["configs"]:
         with open(os.path.join(ROOT, entry["file"])) as f:
             cfg = json.load(f)
@@ -155,13 +158,121 @@ def test_the_counts_of_the_tiny_longcat_configuration(work):
     assert mod.decode_attn_flops(cfg, [10]) == 4 * 2 * 4 * (80 + 64) * 10
 
 
+def test_the_counts_of_smallthinker_21b_a3b(work):
+    """Stage 0 of 7 (ISSUE 36's table): a layer's attention of 20 971 520,
+    its router of 2560 x 64 and the 6 chosen experts of 3 x 2560 x 768,
+    eight layers, the untied head at 151 936.  Attention by layer KIND:
+    a full layer (2 of 8) sees the whole context, a windowed one (6) the
+    lesser of the context and 4096; a prefill's windowed layers attend
+    over the band's area, not the triangle's; 2048 B a position and
+    layer."""
+    cfg = config_of("smallthinker-21b-a3b")
+    mod = work.load_arch(work.arch_path(cfg))
+    z = mod.sizes(cfg)
+    assert z["windowed"] == [False, True, True, True] * 2 == z["rotary"]
+    layer = 20_971_520 + 2560 * 64 + 6 * 5_898_240
+    assert work.matmul_params(cfg) == 8 * layer + 2560 * 151_936 == 841_154_560
+    per_position = 4 * 28 * 128  # QK^T and PV, every head, one layer
+    assert work.token_flops(cfg, 1000) == 2 * 841_154_560 + per_position * 8 * 1000
+    assert work.token_flops(cfg, 12_288) == 2 * 841_154_560 + per_position * (
+        2 * 12_288 + 6 * 4096) == 2_386_952_192
+    assert work.token_flops(cfg, 1000, with_head=False) == 1_019_084_800
+    assert work.prompt_flops(cfg, 512) == 478_890_819_584
+    band = 4096 * 4097 / 2 + (8192 - 4096) * 4096
+    assert work.prompt_flops(cfg, 8192) == (
+        2 * 452_198_400 * 8192 + 2 * 2560 * 151_936
+        + per_position * (2 * 8192 * 8193 / 2 + 6 * band)) == 10_536_626_290_688
+    assert work.kv_bytes_per_position(cfg) == 8 * 2048
+    assert [work.decode_kv_bytes(cfg, [c]) for c in (1024, 4096, 12_288)] == [
+        8 * 1024 * 2048, 8 * 4096 * 2048, (2 * 12_288 + 6 * 4096) * 2048]
+    assert [mod.decode_window_kv_bytes(cfg, [c])
+            for c in (1024, 4096, 12_288)] == [
+        6 * 1024 * 2048, 6 * 4096 * 2048, 6 * 4096 * 2048]
+
+
+def test_the_counts_of_the_tiny_smallthinker_configuration(work):
+    cfg = config_of("smallthinker-tiny-cpu")
+    mod = work.load_arch(work.arch_path(cfg))
+    assert work.matmul_params(cfg) == 860_160
+    assert work.token_flops(cfg, 10) == 1_761_280
+    assert work.token_flops(cfg, 100) == 1_896_448  # windows of 24
+    assert work.kv_bytes_per_position(cfg) == 8 * 2 * 2 * 32 * 2
+    assert work.decode_kv_bytes(cfg, [10, 100]) == 108_544
+    assert mod.decode_window_kv_bytes(cfg, [10, 100]) == 52_224
+
+
+class _Record:
+    def __init__(self, prompt_len, stamps):
+        self.prompt_len, self.stamps = prompt_len, stamps
+
+
+class _Run:
+    """What a metric's reader is handed, as ``perfbench/run.py`` records
+    it: counters at the window's ends, the reduced trace, the client's
+    records."""
+
+    def __init__(self, config, ops, counters):
+        self.config, self.chips, self.seconds = config, 1, 2.0
+        self.t_open, self.t_close = 10.0, 12.0
+        self.peaks = {"hbm_bytes_per_s": 819e9, "flops_bf16": 197e12}
+        self.trace = {"ops": ops, "window_s": 1.0, "chips": 1}
+        self.counters_open = {k: 0.0 for k in counters}
+        self.counters_close = counters
+        # one request: prompt 8000, tokens 1..3 stamped inside the window
+        self.records = [_Record(8000, [9.0, 10.5, 11.0, 11.5, 13.0])]
+
+    def delta(self, family):
+        a, b = self.counters_open.get(family), self.counters_close.get(family)
+        return None if a is None or b is None else b - a
+
+
+def _reader(name):
+    sys.path.insert(0, BENCH)
+    import run
+
+    return run.metric_reader(ROOT, name)
+
+
+def test_the_two_new_readers_on_a_recorded_run(work):
+    cfg = config_of("smallthinker-21b-a3b")
+    ops = {"jit_decode_burst/ragged_paged_attention_window": 0.002,
+           "jit_fused_step/ragged_paged_attention_window_kvsplit": 0.001,
+           "jit_fused_step/ragged_paged_attention_kvsplit": 0.5,
+           "jit_prefill/ragged_paged_attention_window": 9.0}
+    counters = {"fusioninfer:kv_window_pages_trimmed_total": 36.0,
+                "fusioninfer:kv_window_pages_allocated_total": 80.0,
+                "fusioninfer:kv_pages_allocated_total": 300.0}
+    run = _Run(cfg, ops, counters)
+    assert _reader("kv_window_trimmed_pct")(run) == 45.0
+    # three output tokens at contexts 8001..8003: 6 windowed layers x 4096
+    # positions x 2048 B each, over 2 s of window, against 3 ms of the
+    # window kind's kernel in the two decode programs a traced second
+    least = 3 * 6 * 4096 * 2048 / 819e9 / 2.0
+    assert _reader("attn_window_decode_roofline")(run) == pytest.approx(
+        100.0 * least / 0.003)
+    # attn_decode_roofline still sums both kinds' calls
+    both = _reader("attn_decode_roofline")(run)
+    assert both == pytest.approx(100.0 * (
+        work.decode_kv_bytes(cfg, [8001, 8002, 8003]) / 819e9 / 2.0) / 0.503)
+    # a program without layer kinds: no such kernel, no such counters, and
+    # neither reader raises (the parent commit under this benchmark)
+    old = _Run(config_of("qwen3-1.7b"),
+               {"jit_decode_burst/ragged_paged_attention_kvsplit": 0.1}, {})
+    assert _reader("attn_window_decode_roofline")(old) is None
+    assert _reader("kv_window_trimmed_pct")(old) is None
+    # and an architecture whose file has no windowed count
+    odd = _Run(config_of("qwen3-1.7b"), ops, counters)
+    assert _reader("attn_window_decode_roofline")(odd) is None
+
+
 def test_reading_the_counts_imports_neither_jax_nor_the_program():
     code = (
         "import json, sys\n"
         f"sys.path.insert(0, {BENCH!r})\n"
         "import work\n"
         "bad = []\n"
-        "for name in ('qwen3-1.7b', 'deepseek-v2-ep4', 'longcat-flash-ep32'):\n"
+        "for name in ('qwen3-1.7b', 'deepseek-v2-ep4', 'longcat-flash-ep32', "
+        "'smallthinker-21b-a3b'):\n"
         f"    cfg = json.load(open({os.path.join(BENCH, 'configs')!r} + '/' + name + '.json'))\n"
         "    assert work.prompt_flops(cfg, 8) > 0\n"
         "bad = [m for m in sys.modules if m.split('.')[0] in "

@@ -397,3 +397,106 @@ def test_the_identity_experts_have_a_scope_and_a_counter():
     assert 0 < zero < after["fusioninfer:moe_assignments_total"]
     assert zero + after["fusioninfer:moe_assignments_local_total"] < after[
         "fusioninfer:moe_assignments_total"]
+
+
+@pytest.mark.parametrize("impl", ["reference", "flash"])
+def test_the_layer_kinds_have_scopes_and_the_router_runs_first(impl):
+    """SmallThinker's two layer kinds: each attention is traced under
+    ``attn_full`` or ``attn_window`` inside ``attn`` (device time by
+    named scope tells a full layer's walk from a window's), and
+    ``moe_route`` is traced BEFORE the layer's attention: it reads the
+    layer's input.  A model of one kind traces only its own."""
+    import dataclasses
+
+    from fusioninfer_tpu.engine import model_runner as mr
+    from fusioninfer_tpu.engine.kv_cache import (
+        auto_cache_config,
+        init_kv_cache,
+    )
+    from fusioninfer_tpu.models import transformer as tf
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def lowered(cfg, cc, tables):
+        params = jax.eval_shape(lambda: tf.init_params(cfg, jax.random.key(0)))
+        cache = jax.eval_shape(lambda: init_kv_cache(cfg, cc))
+        return mr.fused_step.lower(
+            cfg, cc, params, cache, i32(16), i32(8), i32(8), i32(8), tables,
+            i32(2, 1), i32(2), coalesce=True,
+            kv_splits=(0, 0) if cfg.cache_by_kind else 0).as_text(
+                debug_info=True)
+
+    cfg = dataclasses.replace(get_preset("smallthinker-tiny"), attn_impl=impl)
+    cc = auto_cache_config(cfg, page_size=8, max_model_len=64,
+                           max_batch_size=2, step_span=16)
+    text = lowered(cfg, cc, i32(8, 2, cc.max_pages_per_seq))
+    for scope in ("attn/attn_full", "attn/attn_window", "moe_route",
+                  "moe_experts", "attn_qkv", "kv_write"):
+        assert re.search(r'loc\("([a-z_]+/)*%s[/"]' % scope, text), scope
+    if impl == "flash":  # the window kind's calls carry their own name
+        assert "ragged_paged_attention_window" in text
+    first = {scope: text.index(f'loc("{scope}')
+             for scope in ("moe_route", "attn_qkv", "moe_experts")}
+    assert first["moe_route"] < first["attn_qkv"] < first["moe_experts"]
+    one = dataclasses.replace(CFG, attn_impl=impl)
+    text = lowered(one, CACHE, i32(8, CACHE.max_pages_per_seq))
+    assert "attn/attn_full" in text and "attn_window" not in text
+
+
+def test_pages_by_kind_are_rendered_and_counted():
+    """``fusioninfer:kv_pages_in_use{kind}``, ``fusioninfer:
+    kv_pages_allocated_total{kind}`` and ``fusioninfer:
+    kv_window_pages_trimmed_total``: a model of one kind renders
+    ``kind="full"`` alone and trims nothing unless it is windowed; a
+    cache kept by layer kind counts both and trims the window kind's."""
+    from fusioninfer_tpu.engine.engine import Request
+    from fusioninfer_tpu.engine.kv_cache import auto_cache_config
+    from fusioninfer_tpu.engine.metrics import EngineMetrics
+    from fusioninfer_tpu.engine.sampler import SamplingParams
+
+    def serve(eng, n_prompt, n_out):
+        eng.add_request(Request("a", [1] + list(range(3, 2 + n_prompt)),
+                                SamplingParams(max_tokens=n_out,
+                                               temperature=0.0)))
+        peak = {}
+        while eng.has_work():
+            eng.step()
+            page = EngineMetrics("m").render(eng)
+            for kind in ("full", "window"):
+                m = re.search(r'kv_pages_in_use\{[^}]*kind="%s"\} (\d+)'
+                              % kind, page)
+                if m:
+                    peak[kind] = max(peak.get(kind, 0), int(m.group(1)))
+        return EngineMetrics("m").render(eng), peak
+
+    page, peak = serve(NativeEngine(CFG, cache_cfg=CACHE, max_batch_size=2,
+                                    token_budget=16), 20, 30)
+    for family, kind in (("kv_pages_in_use", "gauge"),
+                         ("kv_pages_allocated_total", "counter"),
+                         ("kv_window_pages_allocated_total", "counter"),
+                         ("kv_window_pages_trimmed_total", "counter")):
+        assert f"# TYPE fusioninfer:{family} {kind}" in page
+        assert f"# HELP fusioninfer:{family} " in page
+    assert 'model_name="m",kind="window"' not in page
+    # 49 cached positions at the end (read a step before the last page)
+    assert list(peak) == ["full"] and peak["full"] in (6, 7)
+    got = parse(page)
+    assert got["fusioninfer:kv_window_pages_trimmed_total"] == 0
+    assert got["fusioninfer:kv_pages_allocated_total"] == 7
+
+    cfg = get_preset("smallthinker-tiny")
+    cc = auto_cache_config(cfg, page_size=8, max_model_len=96,
+                           max_batch_size=2, step_span=16)
+    page, peak = serve(NativeEngine(cfg, cache_cfg=cc, max_batch_size=2,
+                                    token_budget=16), 20, 60)
+    got = parse(page)
+    assert peak["full"] in (9, 10) and peak["window"] <= 24 // 8 + 16 // 8 + 1
+    trimmed = got["fusioninfer:kv_window_pages_trimmed_total"]
+    handed = got["fusioninfer:kv_window_pages_allocated_total"]
+    assert 0 < trimmed < handed == 10
+    # the labelled family holds both kinds' samples
+    for kind in ("full", "window"):
+        assert ('fusioninfer:kv_pages_allocated_total{model_name="m",'
+                f'kind="{kind}"}} 10') in page
+    assert got["vllm:kv_cache_usage_perc"] == 0.0

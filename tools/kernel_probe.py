@@ -65,6 +65,15 @@ two matrices, 6144 x 2048 and 2048 x 6144, for a decode pass (64 tokens x
 tokens, ~208 local rows), one line a tiling: ``transformer.gmm_tiling``'s
 own first, then the candidates of ``GMM_CANDIDATES``.
 
+``--gmm smallthinker-21b-a3b`` times it at that preset's two matrices
+(2560 x 768 and back, a stack of ``8 x 64`` experts, every assignment
+local: 32 tokens x 6 and 1024 tokens x 6).  ``--by-kind`` times the
+ragged kernel at ``smallthinker-21b-a3b``'s shapes (4 KV heads x 7, a
+table of 128 pages) as its two layer kinds call it, full attention and
+a window of 4096, each on the single-walk grid and on 8 splits: 32
+decode rows at 1 k / 8 k / 12 k and a 1 024-token chunk at 8 k
+(``pick_kv_splits``'s rule by kind is read off these lines).
+
 ``--variant name=path[@CONST=int[,CONST=int]]`` loads ANOTHER copy of
 ``paged_attention.py`` (``mla_attention.py`` with ``--latent``) under
 its own name, optionally with one module
@@ -119,7 +128,28 @@ GMM_REAL = dict(layers=4, held=16, D=6144, F=2048, k=12,
                 passes={"decode_t64": (64, 16), "chunk_t832": (832, 208)})
 GMM_TINY = dict(layers=2, held=4, D=256, F=128, k=4,
                 passes={"decode_t8": (8, 6), "chunk_t48": (48, 30)})
+# smallthinker-21b-a3b holds every expert: all assignments are local
+GMM_SMALLTHINKER = dict(layers=8, held=64, D=2560, F=768, k=6,
+                        passes={"decode_t32": (32, 192),
+                                "chunk_t1024": (1024, 6144)})
+GMM_SHAPES = {"longcat-flash-ep32": GMM_REAL,
+              "smallthinker-21b-a3b": GMM_SMALLTHINKER}
+# the ragged kernel as smallthinker-21b-a3b's two layer kinds call it
+KIND_REAL = dict(KV=4, G=7, Hd=128, page=128, n_pages=4200, layers=2,
+                 table=128, rows=64, windows=(None, 4096), splits=(0, 8),
+                 cases={"decode_r32_1k": ([1] * 32, [1023] * 32),
+                        "decode_r32_8k": ([1] * 32, [8191] * 32),
+                        "decode_r32_12k": ([1] * 32, [12287] * 32),
+                        "chunk1024_at8k": ([1024], [8192])})
+KIND_TINY = dict(KV=2, G=3, Hd=64, page=16, n_pages=80, layers=2, table=16,
+                 rows=8, windows=(None, 64), splits=(0, 8),
+                 cases={"decode_r4_200": ([1] * 4, [199] * 4),
+                        "chunk32_at100": ([32], [100])})
 GMM_CANDIDATES = {
+    (2560, 768): [(128, 1280, 768), (128, 2560, 384), (256, 2560, 768),
+                  (128, 2560, 256), (512, 2560, 768)],
+    (768, 2560): [(128, 768, 1280), (128, 768, 640), (256, 768, 2560),
+                  (128, 384, 2560), (512, 768, 2560)],
     (6144, 2048): [(128, 2560, 768), (128, 2048, 512), (128, 3072, 512),
                    (128, 1536, 1024), (128, 3072, 1024), (128, 6144, 512)],
     (2048, 6144): [(128, 2560, 768), (128, 2048, 768), (128, 2048, 1536),
@@ -454,6 +484,61 @@ def latent_child_first(mod, shape: dict, interpret: bool) -> dict:
     return out
 
 
+def kind_child_time(mod, shape: dict, interpret: bool) -> dict:
+    """µs a call of the ragged kernel by layer kind (window) and grid
+    (splits), the walk lists built once outside the loop as the engine
+    builds them, and the pages each call must read at the HBM peak."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    L = shape["layers"]
+    page_us = (2 * shape["KV"] * shape["page"] * shape["Hd"] * 2
+               / 819e9 * 1e6)
+    out = {}
+    for name, (q_lens, starts) in shape["cases"].items():
+        T, tables, st, qb, ql = _case(shape, q_lens, starts)
+        q, kp, vp = _operands(shape, T)
+        d = tuple(map(jnp.asarray, (tables, st, qb, ql)))
+        for window in shape["windows"]:
+            for splits in shape["splits"]:
+                @jax.jit
+                def many(q, kp, vp, tables, st, qb, ql, window=window,
+                         splits=splits):
+                    walks = mod.ragged_walk_lists(
+                        q, kp, vp, tables, st, qb, ql, window=window,
+                        kv_splits=splits)
+
+                    def body(i, q):
+                        kw = dict(interpret=interpret, window=window,
+                                  layer=jnp.int32(i % L), walks=walks)
+                        if splits:
+                            o = mod.ragged_paged_attention_kvsplit(
+                                q, kp, vp, tables, st, qb, ql,
+                                kv_splits=splits, **kw)
+                        else:
+                            o = mod.ragged_paged_attention(
+                                q, kp, vp, tables, st, qb, ql,
+                                coalesce=True, **kw)
+                        return o.reshape(q.shape)
+                    return jax.lax.fori_loop(0, CALLS, body, q)
+
+                us = _median_us(many, (q, kp, vp, *d))
+                nb = T // mod.RAGGED_BLOCK_Q
+                _, _, first, end = (np.asarray(a) for a in mod._ragged_walks(
+                    d[2], d[3], d[1], nb=nb, block_q=mod.RAGGED_BLOCK_Q,
+                    page_size=shape["page"], window=window))
+                pages = int((end - first).sum())
+                key = (f"{name}.{'full' if window is None else 'window'}"
+                       f".splits{splits}")
+                out[key] = {"us_per_call": us, "pages_read": pages,
+                            "roofline_pct": 100 * pages * page_us / us}
+                print(f"  {key}: {us:.1f} us, {pages} pages, "
+                      f"{out[key]['roofline_pct']:.1f} % of the memory "
+                      "roofline", flush=True)
+    return out
+
+
 def gmm_child_time(_mod, shape: dict, interpret: bool) -> dict:
     """µs a call of the grouped product over one layer of a whole stack,
     by matrix, pass and tiling; the bytes of the touched experts at the
@@ -524,8 +609,13 @@ def main() -> int:
                     choices=sorted(LATENT_SHAPES),
                     help="the latent (MLA) kernel at this preset's shapes "
                          "(deepseek-v2-ep4 where none is named)")
-    ap.add_argument("--gmm", action="store_true",
-                    help="the grouped product at longcat-flash-ep32's shapes")
+    ap.add_argument("--gmm", nargs="?", const="longcat-flash-ep32",
+                    choices=sorted(GMM_SHAPES),
+                    help="the grouped product at this preset's shapes "
+                         "(longcat-flash-ep32 where none is named)")
+    ap.add_argument("--by-kind", action="store_true",
+                    help="the ragged kernel as smallthinker-21b-a3b's full "
+                         "and window layers call it, single walk and 8 splits")
     ap.add_argument("--ring", type=int, nargs="*", default=[],
                     help="--latent: the tree's kernel at these ring depths too")
     ap.add_argument("--out", default="chiprun_out/kernel_probe/probe.json")
@@ -533,8 +623,12 @@ def main() -> int:
                     help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.gmm:  # jax's own kernel: the tree file named here is not read
-        shape, tree = GMM_TINY if args.tiny else GMM_REAL, LATENT_TREE
+        shape = GMM_TINY if args.tiny else GMM_SHAPES[args.gmm]
+        tree = LATENT_TREE
         children = {"time": gmm_child_time}
+    elif args.by_kind:
+        shape, tree = KIND_TINY if args.tiny else KIND_REAL, TREE
+        children = {"time": kind_child_time}
     elif args.latent:
         shape = LATENT_TINY if args.tiny else LATENT_SHAPES[args.latent]
         tree = LATENT_TREE
@@ -575,7 +669,8 @@ def main() -> int:
                    kind, name, spec, "--kv-splits", str(args.kv_splits)] + (
                        ["--tiny"] if args.tiny else []) + (
                        ["--latent", args.latent] if args.latent else []) + (
-                       ["--gmm"] if args.gmm else [])
+                       ["--gmm", args.gmm] if args.gmm else []) + (
+                       ["--by-kind"] if args.by_kind else [])
             p = subprocess.run(cmd, cwd=REPO, stdout=subprocess.PIPE, text=True)
             lines = p.stdout.strip().splitlines()
             print("\n".join(lines[:-1]), flush=True)
