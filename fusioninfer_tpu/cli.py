@@ -209,10 +209,10 @@ def _add_engine_config_flags(p: argparse.ArgumentParser) -> None:
                         "guided decoding) single-steps while the rest "
                         "of the batch keeps bursting")
     p.add_argument("--no-decode-pipeline", action="store_true",
-                   help="disable double-buffered burst pipelining "
-                        "(dispatching the next burst before the "
-                        "current one's fetch, hiding the host-device "
-                        "round trip in steady state)")
+                   help="disable dispatch-ahead pipelining "
+                        "(dispatching the next decode burst or mixed "
+                        "step before the current one's fetch, hiding "
+                        "the host-device round trip in steady state)")
     p.add_argument("--fused-step", action=argparse.BooleanOptionalAction,
                    default=True,
                    help="fuse each step's decode rows and budgeted "

@@ -319,6 +319,37 @@ class _PrefillingState:
     pos: int  # next global position to write (starts at the reused length)
 
 
+@dataclass
+class _InFlight:
+    """A dispatched forward whose tokens the host has not read yet: a
+    decode burst (``kind`` "burst") or a mixed step ("mixed").  The
+    engine holds at most one (``NativeEngine._inflight``)."""
+
+    kind: str
+    sampled: Optional[jax.Array]  # burst [span, B]; mixed [B] (None on
+    # a mixed step sampled from logits, which never goes in flight)
+    rows: dict  # slot -> _SeqState whose tokens these are, at dispatch
+    mode: Optional[str]  # sampling mode (None: the [B, V] logits tail)
+    lora: object = None
+    # burst: the device-side control carry its successor dispatches from
+    span: int = 1
+    next_ctl: Optional[jax.Array] = None
+    ctl_f: Optional[jax.Array] = None
+    # mixed: the chunk rows that complete their prompt ((chunk row,
+    # _PrefillingState) pairs), the forward's decode output and chunk
+    # logits, and the prefilling list as it stood at dispatch
+    done: list = field(default_factory=list)
+    out: Optional[jax.Array] = None
+    chunk_logits: Optional[jax.Array] = None
+    prefilling: list = field(default_factory=list)
+
+
+def _same(a, b) -> bool:
+    """The same objects in the same order (a dataclass's ``==`` compares
+    fields, and two requests' states may agree on every field)."""
+    return len(a) == len(b) and all(x is y for x, y in zip(a, b))
+
+
 # what a cache that is not ONE pool of K/V heads refuses at start-up, by
 # the flag's name (latent_cache_refusal, kind_cache_refusal)
 _NOT_YET = {
@@ -730,9 +761,10 @@ class NativeEngine:
         # dependency chain serializes all device work, and chaining
         # breaks whenever the running set changes (finish / cancel /
         # admission / preemption), so output streams are identical to
-        # unpipelined bursting.
+        # unpipelined bursting.  The same switch chains mixed steps
+        # (`_chain_mixed`): ONE in-flight slot holds either kind.
         self.pipeline_bursts = pipeline_bursts
-        self._inflight = None
+        self._inflight: Optional[_InFlight] = None
         # called on the engine thread each time a model forward has been
         # enqueued: from then on the device has work, so whatever the
         # caller held back for the device's sake may go (the server
@@ -764,6 +796,10 @@ class NativeEngine:
         # nothing
         self._greedy_keys = jax.random.wrap_key_data(
             jnp.zeros((self.max_batch_size, 2), jnp.uint32))
+        # what the mixed program's token carry takes when no decode row
+        # reads the step before's draws (`_ragged_forward`)
+        self._no_carry = (jnp.zeros((self.max_batch_size,), jnp.int32),
+                          jnp.zeros((self.max_batch_size,), bool))
         # flash-decode KV-split grid (ops/paged_attention.py): resolved
         # ONCE from STATIC cache config so every dispatch of this engine
         # — and every process of a multi-host lockstep group — takes the
@@ -1104,7 +1140,7 @@ class NativeEngine:
             hidden = jnp.zeros((B, 1, self.cfg.d_model),
                                self.cfg.jax_dtype)[:, 0]
             self._fused_sample_dispatch(
-                hidden, self._decode_controls({}), np.zeros(B, bool),
+                hidden, self._decode_controls({}), {},
                 "greedy").block_until_ready()
         return len(set(sizes))
 
@@ -1185,6 +1221,10 @@ class NativeEngine:
             return out
 
         def lower_fused(T, sel_rows, sel_w, nc, decode_hidden=False):
+            # the mixed hidden program takes the token carry
+            # (`_ragged_forward`)
+            carry = (dict(zip(("carry_tokens", "carry"), self._no_carry))
+                     if decode_hidden and nc else {})
             return fused_step.lower(
                 cfg, cc, self.params, self.cache,
                 jnp.zeros((T,), i32), jnp.zeros((R,), i32),
@@ -1193,7 +1233,7 @@ class NativeEngine:
                 jnp.zeros((sel_rows, sel_w), i32), jnp.zeros((nc,), i32),
                 mesh=mesh, lora=lora, adapter_ids=ids(R),
                 coalesce=coalesce, kv_splits=self._kv_splits,
-                decode_hidden=decode_hidden)
+                decode_hidden=decode_hidden, **carry)
 
         # fused-sampling engines run the decode/mixed selectors in the
         # decode_hidden variant (no spec windows by eligibility, W=1);
@@ -2603,7 +2643,11 @@ class NativeEngine:
                     with span("step.pack", rows=len(self.running)):
                         outputs += self._fused_step()
                 else:
-                    if self.prefilling:
+                    # a mixed step in flight carries this step's chunks:
+                    # _decode reads it back (and may enqueue the next)
+                    ahead = (self._inflight is not None
+                             and self._inflight.kind == "mixed")
+                    if self.prefilling and not ahead:
                         with span("step.prefill", rows=len(self.prefilling)):
                             outputs += self._advance_prefilling()
                     with span("step.pack", rows=len(self.running)):
@@ -3348,7 +3392,8 @@ class NativeEngine:
         self.sched.charge_prefill(len(prefix) - reused_tokens)
         return self._activate(request, prefix, resumed, logits)
 
-    def _ragged_forward(self, packed, lora, decode_hidden: bool = False):
+    def _ragged_forward(self, packed, lora, decode_hidden: bool = False,
+                        carry=None):
         """Dispatch ONE flat ragged forward (the one kernel, the one
         signature family) and charge its weight pass →
         ``(logits [B, W, V], chunk_logits [NC, V])`` — or, with
@@ -3358,7 +3403,15 @@ class NativeEngine:
         engine forward that reads paged context — decode rows, spec
         windows, chunk advances, batched cache-hit suffixes, mixed
         fused steps — assembles a :class:`RaggedBatch` and lands here,
-        so no path can reacquire a private scorer."""
+        so no path can reacquire a private scorer.  The mixed hidden
+        program (decode and chunk groups, ``decode_hidden``) always takes
+        the token carry, ``carry`` = ``(tokens_dev [B], mask [B])`` or
+        none of its slots, so a chained step and every other chunk
+        advance of a burst engine are one executable."""
+        carried = {}
+        if decode_hidden and packed.chunk_sel.size:
+            toks, mask = carry if carry is not None else self._no_carry
+            carried = {"carry_tokens": toks, "carry": jnp.asarray(mask)}
         with self.spans.span("step.dispatch", program="fused_step"):
             self.cache, logits, chunk_logits = fused_step(
                 self.cfg, self.cache_cfg, self.params, self.cache,
@@ -3375,6 +3428,7 @@ class NativeEngine:
                 coalesce=ops_dispatch.decode_coalesce(),
                 kv_splits=self._kv_splits,
                 decode_hidden=decode_hidden,
+                **carried,
             )
         self._forward_enqueued()
         self.sched.charge_weight_pass()
@@ -3541,15 +3595,7 @@ class NativeEngine:
         then caps per SLO tier: a tier's entries split what the tier
         ledger still allows it, floored at the 1-token trickle."""
         take = list(self.prefilling[: self.max_batch_size])
-        share = max(1, budget // len(take))
-        tier_n: dict[int, int] = {}
-        for st in take:
-            p = st.request.priority
-            tier_n[p] = tier_n.get(p, 0) + 1
-        tier_cap = {p: max(1, self._tier_prefill_left(p) // n)
-                    for p, n in tier_n.items()}
-        chunks = [min(share, len(st.prefix) - st.pos,
-                      tier_cap[st.request.priority]) for st in take]
+        chunks = self._chunk_sizes(take, budget)
         try:
             logits = self._batched_window_forward(
                 [(st.request, st.prefix[st.pos : st.pos + chunks[i]], st.pos)
@@ -3577,6 +3623,22 @@ class NativeEngine:
                 done.append((st.request, st.prefix, st.resumed,
                              logits[i][None]))
         return self._activate_group(done) if done else []
+
+    def _chunk_sizes(self, take: list, budget: int) -> list[int]:
+        """Each prefilling sequence's chunk this step: the budget splits
+        evenly across them (≥ 1 each), then caps per SLO tier — a tier's
+        entries split what the tier ledger still allows it, floored at
+        the 1-token trickle (the chunk advance and the mixed step, fresh
+        or dispatched ahead, size their chunks here)."""
+        share = max(1, budget // len(take))
+        tier_n: dict[int, int] = {}
+        for st in take:
+            p = st.request.priority
+            tier_n[p] = tier_n.get(p, 0) + 1
+        tier_cap = {p: max(1, self._tier_prefill_left(p) // n)
+                    for p, n in tier_n.items()}
+        return [min(share, len(st.prefix) - st.pos,
+                    tier_cap[st.request.priority]) for st in take]
 
     def _prefill_fresh_group(
         self, bucket: int, items: list[tuple[Request, list[int], bool]]
@@ -3849,13 +3911,15 @@ class NativeEngine:
         mode = self._sample_mode(st.request.params for st in live.values())
         return mode if mode in ("greedy", "topk") else None
 
-    def _fused_sample_dispatch(self, hidden, ctl: dict, live_mask,
+    def _fused_sample_dispatch(self, hidden, ctl: dict, live: dict,
                                mode: str):
         """Dispatch the fused-sampling tail over the decode rows' hidden
         states [B, D] → sampled tokens [B], still on the device: blocked
         lm_head→top-k (penalties + min-tokens suppression per vocab
         block inside the jit), the candidate draw, the count bump of
-        the ``live_mask`` rows."""
+        the ``live`` slots' rows."""
+        live_mask = np.zeros(self.max_batch_size, bool)
+        live_mask[list(live)] = True
         head, tied = lm_head_operands(self.cfg, self.params)
         early = jnp.asarray(ctl["gen_counts"] < ctl["min_toks"])
         if self._kernel_mesh is not None:
@@ -3887,27 +3951,25 @@ class NativeEngine:
             jnp.asarray(live_mask))
         return sampled_dev
 
-    def _decode_finish_fused(self, live: dict, hidden, ctl: dict,
-                             failures: list, mode: str) -> list[StepOutput]:
-        """The fused-sampling decode tail: blocked lm_head→top-k over
-        the decode rows' hidden states [B, D], then the candidate draw
-        (`_fused_sample_dispatch`) — no [B, V] logits tensor anywhere.
+    def _emit_fused(self, rows: dict, sampled_dev,
+                    failures: list) -> list[StepOutput]:
+        """Fetch and emit the fused-sampling tail's draws (dispatched by
+        `_fused_sample_dispatch`: no [B, V] logits tensor anywhere).
         Emission matches `_decode_finish`'s plain branch exactly;
         eligibility (`_fused_sampling_mode`) already excluded every row
-        kind that branch special-cases."""
+        kind that branch special-cases.  A row cancelled or preempted
+        since the dispatch (a mixed step read back a step later,
+        `_finish_mixed`) has its token discarded."""
         span = self.spans.span
-        with span("step.dispatch", program="lm_head_topk"):
-            live_mask = np.zeros(self.max_batch_size, bool)
-            live_mask[list(live)] = True
-            sampled_dev = self._fused_sample_dispatch(hidden, ctl,
-                                                      live_mask, mode)
         with span("step.fetch", program="lm_head_topk"):
             sampled = np.asarray(sampled_dev)
-        self.sched.charge_decode(len(live))
+        self.sched.charge_decode(len(rows))
         self.fused_sampling_steps_total += 1
         outputs = list(failures)
-        with span("step.emit", tokens=len(live)):
-            for slot, st in live.items():
+        with span("step.emit", tokens=len(rows)):
+            for slot, st in rows.items():
+                if self.running.get(slot) is not st:
+                    continue
                 token = int(sampled[slot])
                 st.tokens.append(token)
                 self.generation_tokens_total += 1
@@ -3980,14 +4042,16 @@ class NativeEngine:
             return 1
         return k
 
-    def _admission_pending(self) -> bool:
+    def _admission_pending(self, carried: list = ()) -> bool:
         """Is there scheduler work the NEXT host turn could act on,
         besides decoding the current batch?  The one predicate behind
-        both the span clamp and the dispatch-ahead gate.  Everything
-        but the wait queue counts by being there; the wait queue counts
-        only if its head could get in: a slot is available, or a
-        running row is strictly less urgent than the head (what
-        `_admit`'s `_preempt_youngest(than_key=...)` and
+        the span clamp and both dispatch-ahead gates.  Everything but
+        the wait queue counts by being there — the prefilling list too,
+        unless it is exactly ``carried``, the sequences whose chunks a
+        mixed successor would advance (`_chain_mixed`); the wait queue
+        counts only if its head could get in: a slot is available, or a
+        running or prefilling sequence is strictly less urgent than the
+        head (what `_admit`'s `_preempt_youngest(than_key=...)` and
         `_tier_budget_evict` would take — equal urgency waits for a
         finish).  Pages are not priced here (`can_admit` builds a hash
         chain): a free slot without pages stays pending, which only
@@ -3996,7 +4060,8 @@ class NativeEngine:
         identically.  The single-host ``_cancelled`` read is lock-free
         by design — a cancel racing this check is caught by the next
         step's drain."""
-        if (self.waiting_prefilled or self.prefilling or self._cancelled
+        if (self.waiting_prefilled or self._cancelled
+                or not _same(self.prefilling, carried)
                 or not self._slab_q.empty() or not self._embed_q.empty()
                 or self._pd_pending or self._embed_pending):
             return True
@@ -4004,10 +4069,9 @@ class NativeEngine:
             if not self.waiting:
                 return False
             head_key = _urgency(self.waiting.peek())
-        # nothing is mid-prefill here, so the running rows are the only
-        # victims a more urgent head could displace
         return self._avail_slots() > 0 or any(
-            _urgency(st.request) > head_key for st in self.running.values())
+            _urgency(st.request) > head_key
+            for st in [*self.running.values(), *self.prefilling])
 
     def _waiter_slot_frees_within(self, rows, span: int,
                                   inflight: int = 0) -> bool:
@@ -4023,8 +4087,8 @@ class NativeEngine:
     def forward_in_flight(self) -> bool:
         """Is a dispatched model forward still unread: has the device
         work of this engine's to run right now?  (The dispatched-ahead
-        successor burst; every other forward is read before its step
-        returns.)"""
+        successor, a burst or a mixed step; every other forward is read
+        before its step returns.)"""
         return self._inflight is not None
 
     def _forward_enqueued(self) -> None:
@@ -4057,22 +4121,24 @@ class NativeEngine:
         self._forward_enqueued()
         return sampled_dev, next_ctl
 
-    def _pipeline_ready(self, snapshot: dict, span: int) -> bool:
-        """May the successor burst dispatch from the device-side carry?
+    def _pipeline_ready(self, snapshot: dict, span: int,
+                        carried: list = ()) -> bool:
+        """May the successor dispatch from the device-side carry?
         Whenever nothing is admissible and the running set is EXACTLY
         the snapshot (same objects) — any admission, cancellation,
         finish or preemption since the snapshot was taken breaks the
         chain and the next pass rebuilds controls from host state.  A
         queue that cannot be admitted from (full slots, nobody less
         urgent) does not stop the chain: that is the case in which the
-        host's turn would otherwise be exposed on every step."""
+        host's turn would otherwise be exposed on every step.  A mixed
+        successor passes the prefilling list it ``carried``."""
         if (not self.pipeline_bursts or self._mh is not None
                 or self.spec_k):
             return False
-        # same predicate as _burst_span's clamp — the two gates enforce
-        # one invariant (a burst never delays an admission the host can
-        # foresee) and must not drift as admission sources are added
-        if self._admission_pending():
+        # same predicate as _burst_span's clamp — the gates enforce one
+        # invariant (a dispatch ahead never delays an admission the host
+        # can foresee) and must not drift as admission sources are added
+        if self._admission_pending(carried):
             return False
         if len(self.running) != len(snapshot):
             return False
@@ -4130,13 +4196,16 @@ class NativeEngine:
         return True
 
     def _consume_inflight(self) -> list[StepOutput]:
-        """Fetch and emit the in-flight burst, first dispatching its
-        successor from the device-side control carry when the pipeline
-        conditions hold (the dispatch must precede the blocking fetch —
-        that ordering IS the round-trip hiding)."""
-        sampled_dev, next_ctl, ctl_f_dev, snapshot, span, mode, lora = \
-            self._inflight
-        self._inflight = None
+        """Fetch and emit the in-flight forward, first dispatching its
+        successor when the pipeline conditions hold (the dispatch must
+        precede the blocking fetch — that ordering IS the round-trip
+        hiding).  A mixed step is read back by `_finish_mixed`; a burst
+        here, its successor dispatched from the device-side control
+        carry."""
+        fl, self._inflight = self._inflight, None
+        if fl.kind == "mixed":
+            return self._finish_mixed(fl)
+        snapshot, span = fl.rows, fl.span
         successor = None
         if (self._pipeline_ready(snapshot, span)
                 and self._extend_for_successor(snapshot, span)):
@@ -4145,14 +4214,15 @@ class NativeEngine:
                 tables[s] = self.alloc.page_table_row(st.request.request_id)
             with self.spans.span("step.dispatch", program="decode_burst"):
                 s_dev, s_next = self._dispatch_burst(
-                    next_ctl, ctl_f_dev, jnp.asarray(tables), span, mode,
-                    lora)
-            successor = (s_dev, s_next, ctl_f_dev, dict(snapshot), span,
-                         mode, lora)
+                    fl.next_ctl, fl.ctl_f, jnp.asarray(tables), span,
+                    fl.mode, fl.lora)
+            successor = _InFlight("burst", s_dev, dict(snapshot), fl.mode,
+                                  fl.lora, span=span, next_ctl=s_next,
+                                  ctl_f=fl.ctl_f)
             self.sched.dispatch_ahead_total += 1
         self.sched.charge_decode(span * len(snapshot))
         with self.spans.span("step.fetch", program="decode_burst"):
-            sampled_all = np.asarray(sampled_dev)  # [span, B] — blocks here
+            sampled_all = np.asarray(fl.sampled)  # [span, B] — blocks here
         outputs: list[StepOutput] = []
         with self.spans.span("step.emit") as sp:
             for slot, st in snapshot.items():
@@ -4201,8 +4271,10 @@ class NativeEngine:
         for what the chunk forward can carry.  A burst IN FLIGHT is not
         merged: its tokens are already being computed, so the split path
         consumes it as before and the chunk forward queues behind it.
-        Reads only replicated scheduler state, so every process of a
-        multi-host lockstep group answers identically."""
+        A mixed step in flight (`_chain_mixed`) already carries this
+        step's chunks: `step` reads it back instead.  Reads only
+        replicated scheduler state, so every process of a multi-host
+        lockstep group answers identically."""
         if not (self.fused_step_enabled and self._inflight is None
                 and self.prefilling):
             return False
@@ -4231,7 +4303,9 @@ class NativeEngine:
         always the fused one (`_use_fused_step`) and it keeps the
         device-side penalty counts ``decode_burst`` carries
         (``_bump_count_rows``); the burst dispatched on a later step
-        builds its controls from host state that holds this token."""
+        builds its controls from host state that holds this token.  Its
+        successor mixed step may go out before the first fetch
+        (`_finish_mixed`)."""
         failures, _ = self._ensure_decode_capacity(1)
         live = {s: st for s, st in self.running.items()
                 if st.n_generated < st.request.params.max_tokens}
@@ -4240,40 +4314,16 @@ class NativeEngine:
             # capacity pressure preempted one row kind away since the
             # step() gate: run the split halves (each no-ops if empty)
             return failures + self._advance_prefilling() + self._decode()
-        budget = self._chunk_budget()
-        share = max(1, budget // len(take))
-        # same tier discipline as _advance_prefilling_batch: a tier's
-        # entries split what the tier ledger still allows it, floored
-        # at the 1-token trickle (the fused path is the DEFAULT mixed
-        # interactive+batch path — tier enforcement must ride it too)
-        tier_n: dict[int, int] = {}
-        for st in take:
-            p = st.request.priority
-            tier_n[p] = tier_n.get(p, 0) + 1
-        tier_cap = {p: max(1, self._tier_prefill_left(p) // n)
-                    for p, n in tier_n.items()}
-        chunks = [min(share, len(st.prefix) - st.pos,
-                      tier_cap[st.request.priority]) for st in take]
+        # same tier discipline as _advance_prefilling_batch (the fused
+        # path is the DEFAULT mixed interactive+batch path — tier
+        # enforcement must ride it too)
+        chunks = self._chunk_sizes(take, self._chunk_budget())
         ctl = self._decode_controls(live)
-        lora = ctl["lora"]
         spec_drafts = self._propose_drafts(live, ctl) if self.spec_k else {}
         window, counts_w = self._decode_window(live, ctl, spec_drafts)
-        entries = [
-            (st.prefix[st.pos: st.pos + chunks[i]], st.pos,
-             self._chunk_table_row(st.request, st.pos, chunks[i]),
-             self._adapter_id(st.request))
-            for i, st in enumerate(take)
-        ]
-        packed = pack_ragged_batch(
-            window, counts_w, ctl["positions"], ctl["page_tables"],
-            ctl["adapter_ids"], entries, self._trash_row,
-            rows=self._ragged_rows, chunk_rows=self._ragged_chunk_rows)
-        # a burst engine's batch is eligible here (`_use_fused_step`;
-        # preempting rows away cannot make it less so)
-        fs_mode = self._fused_sampling_mode(live)
         try:
-            logits_f, chunk_logits = self._ragged_forward(
-                packed, lora, decode_hidden=fs_mode is not None)
+            fl = self._dispatch_mixed(live, take, chunks, ctl, window,
+                                      counts_w)
         except Exception as e:
             logger.exception("fused mixed-batch step of %d chunks failed",
                              len(take))
@@ -4286,35 +4336,139 @@ class NativeEngine:
             # decode rows were untouched by the failed dispatch: serve
             # them through the classic split decode this step
             return outputs + self._decode()
+        if fl.mode is not None:
+            return failures + self._finish_mixed(fl)
+        # decode sampling/spec-verify off the slot-aligned decode logits
+        outputs = failures + self._activate_chunks(fl)
+        spec = (self._spec_draws(fl.out, window, ctl, spec_drafts)
+                if self.spec_k else None)
+        return outputs + self._decode_finish(live, fl.out[:, 0], ctl,
+                                             spec_drafts, spec, [])
+
+    def _dispatch_mixed(self, live: dict, take: list, chunks: list[int],
+                        ctl: dict, window, counts_w,
+                        carry=None) -> _InFlight:
+        """Enqueue one mixed step: its forward and, where the live batch
+        samples from candidates (`_fused_sampling_mode`), its decode
+        tail — ``lm_head_topk`` → ``sample_topk`` → ``_bump_count_rows``
+        right behind the forward, before any first-token draw or fetch
+        (the slots an activation installs are never live here, so the
+        order moves no bits).  Then the chunk bookkeeping, as
+        ``_advance_prefilling_batch`` keeps it: charged after the
+        forward, positions advanced; a prompt its chunk completes is
+        noted and stays in ``prefilling`` until `_activate_chunks` reads
+        it back.  ``carry`` feeds the decode rows' input tokens from the
+        device (`_chain_mixed`).  Raises on a failed dispatch."""
+        entries = [
+            (st.prefix[st.pos: st.pos + chunks[i]], st.pos,
+             self._chunk_table_row(st.request, st.pos, chunks[i]),
+             self._adapter_id(st.request))
+            for i, st in enumerate(take)
+        ]
+        packed = pack_ragged_batch(
+            window, counts_w, ctl["positions"], ctl["page_tables"],
+            ctl["adapter_ids"], entries, self._trash_row,
+            rows=self._ragged_rows, chunk_rows=self._ragged_chunk_rows)
+        # a burst engine's batch is eligible here (`_use_fused_step`;
+        # preempting rows away cannot make it less so); on that path the
+        # forward hands back HIDDEN states and the candidate tail samples
+        # without [B, V] logits
+        mode = self._fused_sampling_mode(live)
+        out, chunk_logits = self._ragged_forward(
+            packed, ctl["lora"], decode_hidden=mode is not None,
+            carry=carry)
         self.sched.record_fused(packed.packed_tokens)
-        # chunk bookkeeping mirrors _advance_prefilling_batch: charged
-        # after the forward, completed prefills activate into their
-        # reserved slots off their chunk row's last-token logits
+        sampled = None
+        if mode is not None:
+            with self.spans.span("step.dispatch", program="lm_head_topk"):
+                sampled = self._fused_sample_dispatch(out[:, 0], ctl, live,
+                                                      mode)
         with self.spans.span("step.prefill", rows=len(take),
                              chunk_tokens=sum(chunks)):
             self._spend_prefill(sum(chunks), chunks=len(take))
             for i, st in enumerate(take):
                 self._note_tier_spend(st.request.priority, chunks[i])
-            done = []
-            for i, st in enumerate(take):
                 st.pos += chunks[i]
-                if st.pos == len(st.prefix):
-                    self.prefilling.remove(st)
-                    done.append((st.request, st.prefix, st.resumed,
-                                 chunk_logits[i][None]))
-            outputs = list(failures)
-            if done:
-                outputs += self._activate_group(done)
-        # decode sampling/spec-verify off the slot-aligned decode rows;
-        # on the fused-sampling path logits_f carries HIDDEN states and
-        # the candidate tail samples without [B, V] logits
-        if fs_mode is not None:
-            return outputs + self._decode_finish_fused(
-                live, logits_f[:, 0], ctl, [], fs_mode)
-        spec = (self._spec_draws(logits_f, window, ctl, spec_drafts)
-                if self.spec_k else None)
-        return outputs + self._decode_finish(live, logits_f[:, 0], ctl,
-                                             spec_drafts, spec, [])
+        done = [(i, st) for i, st in enumerate(take)
+                if st.pos == len(st.prefix)]
+        return _InFlight("mixed", sampled, dict(live), mode, ctl["lora"],
+                         done=done, out=out,
+                         chunk_logits=chunk_logits,
+                         prefilling=list(self.prefilling))
+
+    def _finish_mixed(self, fl: _InFlight) -> list[StepOutput]:
+        """Read a dispatched mixed step back: first enqueue its
+        successor when the next step's rows are known (`_chain_mixed`:
+        before any fetch — that order IS the hiding), then activate the
+        prompts its chunks completed (the group's one first-token fetch),
+        then fetch and emit its decode rows' tokens.  A fresh step
+        (`_fused_step`) and one read a step after its dispatch
+        (`_consume_inflight`) both end here."""
+        successor = self._chain_mixed(fl)
+        outputs = self._activate_chunks(fl)
+        outputs += self._emit_fused(fl.rows, fl.sampled, [])
+        self._inflight = successor
+        return outputs
+
+    def _activate_chunks(self, fl: _InFlight) -> list[StepOutput]:
+        """Activate the prompts whose last chunk ``fl`` carried, off
+        their chunk rows' last-token logits, into their reserved slots.
+        A prompt cancelled or preempted since the dispatch is no longer
+        in ``prefilling``: its chunk is discarded."""
+        done = [(i, st) for i, st in fl.done
+                if any(p is st for p in self.prefilling)]
+        if not done:
+            return []
+        with self.spans.span("step.prefill", rows=len(done)):
+            self.prefilling = [p for p in self.prefilling
+                               if all(p is not st for _, st in done)]
+            return self._activate_group(
+                [(st.request, st.prefix, st.resumed,
+                  fl.chunk_logits[i][None]) for i, st in done])
+
+    def _chain_mixed(self, fl: _InFlight) -> Optional[_InFlight]:
+        """Dispatch-ahead for mixed steps: enqueue the step after ``fl``
+        before ``fl``'s first blocking fetch, when the host already
+        knows that step's rows — pipelining on a burst engine whose mixed
+        step samples from candidates, `_pipeline_ready` (nothing
+        admissible, the running set and the prefilling list exactly
+        ``fl``'s), no chunk of ``fl`` completing a prompt (its first
+        token would join the batch) and no row spending its last token
+        in ``fl``.  Then the successor's chunks are the next chunks of
+        the same sequences, sized by the ledger a fresh step would open
+        (admission would spend none of it); its decode rows are ``fl``'s
+        one position on, their pages pre-extended all-or-nothing
+        (`_extend_for_successor`), their input tokens ``fl``'s draws
+        carried on the device, their sampling controls one token on.
+        Returns the successor in flight, or None."""
+        rows = fl.rows
+        if (fl.mode is None or not self._mixed_on_burst or fl.done
+                or any(st.request.params.max_tokens - st.n_generated <= 1
+                       for st in rows.values())
+                or not self._pipeline_ready(rows, 1, carried=fl.prefilling)
+                or not self._extend_for_successor(rows, 1)):
+            return None
+        take = list(self.prefilling[: self.max_batch_size])
+        self._step_prefill_left = self.sched.prefill_remainder(len(rows))
+        self._begin_tier_step()
+        chunks = self._chunk_sizes(take, self._chunk_budget())
+        ctl = self._decode_controls(rows)
+        for slot in rows:
+            ctl["positions"][slot] += 1
+            ctl["gen_counts"][slot] += 1
+        try:
+            nxt = self._dispatch_mixed(
+                rows, take, chunks, ctl, ctl["tokens"][:, None],
+                ctl["active"].astype(np.int32),
+                carry=(fl.sampled, ctl["active"]))
+        except MemoryError:
+            # a window-kind pool that cannot cover the chunk rows yet
+            # (raised before anything is enqueued): the next step runs
+            # fresh and meets the pool's pressure where a step does
+            return None
+        self.sched.dispatch_ahead_total += 1
+        self.sched.mixed_dispatch_ahead_total += 1
+        return nxt
 
     def _decode_controls(self, live: dict) -> dict:
         """Per-slot numpy control arrays for a decode pass (split or
@@ -4413,8 +4567,9 @@ class NativeEngine:
                     jnp.asarray(ctl["page_tables"]), span, mode, lora)
             # hand the fresh burst to the consume path, which may
             # dispatch its successor before the blocking fetch
-            self._inflight = (sampled_dev, next_ctl, ctl_f_dev,
-                              dict(burst_rows), span, mode, lora)
+            self._inflight = _InFlight(
+                "burst", sampled_dev, dict(burst_rows), mode, lora,
+                span=span, next_ctl=next_ctl, ctl_f=ctl_f_dev)
             carried = list(failures) + self._consume_inflight()
             # rows needing per-token host work (guided / logprobs /
             # logit_bias) take the classic single-step leg of this SAME
@@ -4446,8 +4601,10 @@ class NativeEngine:
         if fs_mode is not None:
             hidden_f, _ = self._ragged_forward(packed, lora,
                                                decode_hidden=True)
-            return self._decode_finish_fused(live, hidden_f[:, 0], ctl,
-                                             failures, fs_mode)
+            with self.spans.span("step.dispatch", program="lm_head_topk"):
+                sampled_dev = self._fused_sample_dispatch(
+                    hidden_f[:, 0], ctl, live, fs_mode)
+            return self._emit_fused(live, sampled_dev, failures)
         logits_f, _ = self._ragged_forward(packed, lora)
         spec = None
         if self.spec_k:

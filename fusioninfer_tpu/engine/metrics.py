@@ -542,9 +542,12 @@ class EngineMetrics:
             "# HELP fusioninfer:sched_burst_clamped_total Decode bursts clamped to span 1 because work was admissible or a waiter's slot would free inside the span; a clamped burst still pipelines (see sched_dispatch_ahead_total).",
             "# TYPE fusioninfer:sched_burst_clamped_total counter",
             f"fusioninfer:sched_burst_clamped_total{{{labels}}} {sched.burst_clamped_total}",
-            "# HELP fusioninfer:sched_dispatch_ahead_total Successor decode bursts dispatched before the in-flight fetch: runs whenever nothing is admissible.",
+            "# HELP fusioninfer:sched_dispatch_ahead_total Steps whose successor (a decode burst or a mixed step) was dispatched before the blocking fetch: runs whenever nothing is admissible.",
             "# TYPE fusioninfer:sched_dispatch_ahead_total counter",
             f"fusioninfer:sched_dispatch_ahead_total{{{labels}}} {sched.dispatch_ahead_total}",
+            "# HELP fusioninfer:sched_mixed_dispatch_ahead_total Mixed steps whose successor mixed step was dispatched before the blocking fetch, its decode rows' input tokens carried on the device (a part of sched_dispatch_ahead_total).",
+            "# TYPE fusioninfer:sched_mixed_dispatch_ahead_total counter",
+            f"fusioninfer:sched_mixed_dispatch_ahead_total{{{labels}}} {sched.mixed_dispatch_ahead_total}",
             "# HELP fusioninfer:sched_kv_restores_total KV pages restored from the host tier, charged against the step budget.",
             "# TYPE fusioninfer:sched_kv_restores_total counter",
             f"fusioninfer:sched_kv_restores_total{{{labels}}} {sched.kv_restores_total}",

@@ -649,6 +649,10 @@ def fused_step(
     decode_hidden: bool = False,  # fused-sampling path: return the decode
     # group's HIDDEN states [B, W, D] instead of its logits, so the
     # engine's lm_head→top-k never materializes [B·W, V]
+    carry_tokens: jax.Array = None,  # [B] int32 — the previous step's
+    # draws, still on the device (the mixed program's operands only)
+    carry: jax.Array = None,  # [B] bool — decode slots whose input token
+    # is ``carry_tokens[slot]`` instead of ``tokens[q_begins[slot]]``
 ):
     """ONE weight pass over a flat ragged-concat token axis →
     (cache, logits [B, W, V], chunk_logits [NC, V]).
@@ -657,7 +661,11 @@ def fused_step(
     windows (q_len=1+drafts) and budgeted prefill chunks (q_len=chunk)
     concatenate along ONE token dimension — ``T = Σ q_lens`` plus the
     power-of-two signature pad — and ride a single embed → layer-scan →
-    lm_head forward.  Decode is weight-bandwidth-bound by arithmetic
+    lm_head forward.  With ``carry`` the decode slots it marks take their
+    input token from ``carry_tokens`` (a dispatched-ahead mixed step's
+    decode rows read the step before's draws without a host round trip:
+    the engine's ``_chain_mixed``).  Decode is weight-bandwidth-bound by
+    arithmetic
     (ROADMAP S1 has the chip measurement to make), so chunked prefill
     riding the same pass should be nearly free; unlike the retired ``[rows, C]`` rectangle,
     dense (embed/QKV/MLP) work grows with the REAL token count — a
@@ -694,6 +702,13 @@ def fused_step(
     quantized = cache_cfg.quantized
     use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
 
+    if carry is not None:
+        # a decode slot's one token sits at its segment's start; slots
+        # without a carry scatter out of range and are dropped (a dead
+        # slot's zero-length segment shares its start with a live one's)
+        at = jnp.where(carry, q_begins[: carry.shape[0]], T)
+        tokens = tokens.at[at].set(carry_tokens.astype(tokens.dtype),
+                                   mode="drop")
     row_of, off, live = ragged_token_rows(q_begins, q_lens, T)
     positions = jnp.where(live, row_starts[row_of] + off, 0)
     pools = _pool_tables(cfg, cache_cfg, page_tables)

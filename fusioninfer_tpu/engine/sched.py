@@ -72,9 +72,13 @@ class TokenBudget:
     # waiter's slot would free inside the span (not "pipeline off":
     # a clamped burst still dispatches ahead)
     burst_clamped_total: int = 0
-    # successor bursts dispatched BEFORE the in-flight fetch (the
-    # dispatch-ahead pipelining counter)
+    # steps whose successor (a decode burst or a mixed step) was
+    # dispatched BEFORE the blocking fetch (the dispatch-ahead
+    # pipelining counter)
     dispatch_ahead_total: int = 0
+    # of those, mixed successors: a mixed step's next chunks and decode
+    # rows, its input tokens carried on the device
+    mixed_dispatch_ahead_total: int = 0
     # adaptive-burst histogram: dispatched span -> dispatch count
     burst_span_steps: dict = field(default_factory=dict)
     # hierarchical-KV restore ledger (engine/kv_host_tier.py): pages
@@ -124,6 +128,11 @@ class TokenBudget:
         tokens first and return the PREFILL remainder.  With no budget
         configured the remainder is unbounded (monolithic semantics)."""
         self.steps_total += 1
+        return self.prefill_remainder(decode_charge)
+
+    def prefill_remainder(self, decode_charge: int) -> int:
+        """What :meth:`begin_step` would leave for prefill, without
+        opening a step (a dispatched-ahead successor's ledger)."""
         if self.tokens_per_step is None:
             return 1 << 30
         return max(0, self.tokens_per_step - decode_charge)
@@ -181,6 +190,7 @@ class TokenBudget:
             "admission_deferred": self.admission_deferred_total,
             "burst_clamped": self.burst_clamped_total,
             "dispatch_ahead": self.dispatch_ahead_total,
+            "mixed_dispatch_ahead": self.mixed_dispatch_ahead_total,
             "burst_span_steps": {str(k): v for k, v in
                                  sorted(self.burst_span_steps.items())},
             "kv_restores": self.kv_restores_total,

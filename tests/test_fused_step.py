@@ -17,6 +17,12 @@ forward and a decode forward back to back.  The invariants under test:
   samples from candidates; a burst in flight, or a row that needs host
   work per token, keeps the split path; such an engine builds, warms and
   dispatches ONE chunk-carrying program per flat-token bucket;
+* there a mixed step enqueues its decode tail before any first-token
+  fetch and, when the next step's rows are known, its successor mixed
+  step (decode inputs carried on the device) before its own fetch: the
+  streams and the chunk sizes are those of an engine that never chains,
+  and the chain breaks on an admission, a completing prompt, a row's
+  last token and a cancel;
 * the new ``/metrics`` families render with HELP/TYPE lines;
 * the packing helper (`engine/fused.py`) lays rows out slot-aligned.
 """
@@ -32,7 +38,7 @@ from fusioninfer_tpu.engine.fused import (
     pack_ragged_batch,
     pow2_rows,
 )
-from fusioninfer_tpu.engine.kv_cache import CacheConfig
+from fusioninfer_tpu.engine.kv_cache import CacheConfig, auto_cache_config
 from fusioninfer_tpu.engine.sampler import SamplingParams
 from fusioninfer_tpu.models.config import get_preset
 
@@ -57,7 +63,8 @@ def _run_all(engine, requests, max_steps=400):
     return tokens
 
 
-def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100, top_k=0):
+def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100, top_k=0,
+                vocab=CFG.vocab_size):
     """A decode stream + a long chunking prompt + a short prompt — the
     mixed-load shape the fused step exists for.  ``top_k`` makes the
     seeded row a candidate sampler and puts a second one beside the
@@ -67,10 +74,10 @@ def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100, top_k=0):
     reqs = [
         Request("stream", [1, 2, 3],
                 SamplingParams(max_tokens=20, temperature=0.0)),
-        Request("long", rng.integers(1, CFG.vocab_size, prompt_len).tolist(),
+        Request("long", rng.integers(1, vocab, prompt_len).tolist(),
                 SamplingParams(max_tokens=max_tokens, temperature=0.8,
                                seed=77, top_k=top_k)),
-        Request("short", rng.integers(1, CFG.vocab_size, 9).tolist(),
+        Request("short", rng.integers(1, vocab, 9).tolist(),
                 SamplingParams(max_tokens=4, temperature=0.0)),
     ]
     if top_k:
@@ -83,6 +90,23 @@ def _mixed_reqs(seed=5, max_tokens=8, prompt_len=100, top_k=0):
 # the two decode loops: classic per-token stepping and burst engines
 # (whose mixed step replaces "chunk forward, then a span-1 decode_burst")
 BURSTS = pytest.mark.parametrize("burst", [1, 8])
+# the two A/B pairs: the fused step off / on, and on a burst engine the
+# mixed steps' dispatch-ahead chain off / on (``pipeline_bursts``)
+PAIRS = {"fused": ("fused_step", {}),
+         "chained": ("pipeline_bursts", {"fused_step": True})}
+
+
+def _chunk_log(engine) -> list:
+    """Every chunk sizing the engine makes, in order (one per batched
+    chunk dispatch, fresh or dispatched ahead)."""
+    log, sizes = [], engine._chunk_sizes
+
+    def logged(take, budget):
+        chunks = sizes(take, budget)
+        log.append(list(chunks))
+        return chunks
+    engine._chunk_sizes = logged
+    return log
 
 
 class TestPacking:
@@ -170,26 +194,41 @@ class TestPacking:
 class TestEquivalence:
     """Bit-identity: the fused step must be invisible in the streams."""
 
-    def _ab(self, reqs_fn, cache_cfg=None, cfg=CFG, **engine_kw):
+    def _ab(self, reqs_fn, cache_cfg=None, cfg=CFG, pair="fused",
+            **engine_kw):
+        """Streams of the pair's engine with the switch off (``split``)
+        and on (``fused``); on the "chained" pair also the chunk sizes
+        of every step."""
+        flag, kw = PAIRS[pair]
         kw = dict(cache_cfg=cache_cfg or _cache_cfg(), max_batch_size=4,
-                  token_budget=16, **engine_kw)
-        split = NativeEngine(cfg, fused_step=False, **kw)
-        fused = NativeEngine(cfg, fused_step=True, **kw)
+                  token_budget=16, **kw, **engine_kw)
+        split = NativeEngine(cfg, **{flag: False}, **kw)
+        fused = NativeEngine(cfg, **{flag: True}, **kw)
+        chunks = _chunk_log(split), _chunk_log(fused)
         a = _run_all(split, reqs_fn())
         b = _run_all(fused, reqs_fn())
         assert fused.sched.fused_steps_total > 0, \
             "fused path never engaged — the A/B proves nothing"
-        assert split.sched.fused_steps_total == 0
+        if pair == "fused":
+            assert split.sched.fused_steps_total == 0
+        else:
+            assert fused.sched.mixed_dispatch_ahead_total > 0, \
+                "the chain never engaged — the A/B proves nothing"
+            assert split.sched.mixed_dispatch_ahead_total == 0
+            assert chunks[0] == chunks[1]
         assert a == b
         return split, fused
 
-    @BURSTS
+    @pytest.mark.parametrize("burst,pair",
+                             [(1, "fused"), (8, "fused"), (8, "chained")])
     @pytest.mark.parametrize("top_k", [0, 8])
-    def test_mixed_load_greedy_and_seeded_sampled(self, burst, top_k):
+    def test_mixed_load_greedy_and_seeded_sampled(self, burst, pair, top_k):
         """Greedy rows beside a seeded sampler: plain (``top_k`` 0: on a
         burst engine the batch keeps the split path once that row is
-        live) and top-k (candidate draws ride the mixed step)."""
-        self._ab(lambda: _mixed_reqs(top_k=top_k), decode_burst_steps=burst)
+        live, and the chained steps carry greedy rows alone) and top-k
+        (candidate draws ride the mixed step)."""
+        self._ab(lambda: _mixed_reqs(top_k=top_k), pair=pair,
+                 decode_burst_steps=burst)
 
     @BURSTS
     def test_quantized_kv_int8(self, burst):
@@ -205,17 +244,27 @@ class TestEquivalence:
                                        kv_dtype="int8"),
                  decode_burst_steps=burst)
 
+    @pytest.mark.parametrize("pair", sorted(PAIRS))
     @pytest.mark.parametrize("preset",
-                             ["deepseek-v2-tiny", "longcat-flash-tiny"])
-    def test_latent_cache_expert_layer_burst_engine(self, preset):
-        """The burst engine's mixed step over a latent pool and an
-        expert layer (LongCat: two cache layers a layer, identity
-        experts): streams identical to chunk forward + decode_burst."""
+                             ["qwen3-tiny", "deepseek-v2-tiny",
+                              "longcat-flash-tiny", "smallthinker-tiny"])
+    def test_burst_engine_across_architectures(self, preset, pair):
+        """The burst engine's mixed step, and its chain, over one pool,
+        a latent pool and an expert layer (LongCat: two cache layers a
+        layer, identity experts) and a cache kept by layer kind
+        (SmallThinker: window-kind pages covered and trimmed as chunks
+        and chained decode rows advance): streams identical to chunk
+        forward + decode_burst, and to the engine that never chains."""
         cfg = dataclasses.replace(get_preset(preset), dtype="float32",
                                   attn_impl="reference")
-        _, fused = self._ab(lambda: _mixed_reqs(top_k=8), cfg=cfg,
-                            decode_burst_steps=8)
-        assert fused.runtime_info()["kv_layout"] == "latent"
+        cache_cfg = (auto_cache_config(cfg, page_size=16, max_model_len=128,
+                                       max_batch_size=4, step_span=16)
+                     if cfg.sliding_window else None)
+        _, fused = self._ab(
+            lambda: _mixed_reqs(top_k=8, vocab=cfg.vocab_size),
+            cache_cfg=cache_cfg, cfg=cfg, pair=pair, decode_burst_steps=8)
+        assert fused.runtime_info()["kv_layout"] == (
+            "latent" if cfg.is_mla else "heads")
 
     def test_logprobs_and_bias_rows_in_the_mix(self):
         """Tail-path rows (logprobs, logit_bias) share the fused decode
@@ -480,11 +529,14 @@ class TestWeightPassLedger:
                             token_budget=16, decode_burst_steps=8,
                             fused_step=True, **kw)
 
-    def test_burst_engine_fuses_a_mixed_step_into_one_pass(self):
+    @pytest.mark.parametrize("chained", [False, True])
+    def test_burst_engine_fuses_a_mixed_step_into_one_pass(self, chained):
         """A burst engine's step with both row kinds and nothing in
         flight is ONE weight pass (no chunk forward + span-1 burst), and
-        a step that never asked for a burst clamps none."""
-        engine = self._burst_engine()
+        a step that never asked for a burst clamps none.  Chained
+        (``pipeline_bursts``), a step may also enqueue its successor
+        mixed step: one more pass, and a fused one too."""
+        engine = self._burst_engine(pipeline_bursts=chained)
         for r in _mixed_reqs(top_k=8):
             engine.add_request(r)
         sched, mixed = engine.sched, 0
@@ -493,14 +545,18 @@ class TestWeightPassLedger:
                       sched.burst_clamped_total, sched.decode_tokens_total)
             admitting = engine.num_waiting > 0  # whole-prompt prefills
             engine.step()
-            if sched.fused_steps_total > before[0] and not admitting:
+            fused = sched.fused_steps_total - before[0]
+            if fused and not admitting:
                 mixed += 1
-                assert not engine.forward_in_flight()
-                assert sched.fused_steps_total == before[0] + 1
-                assert sched.weight_passes_total == before[1] + 1
+                assert sched.weight_passes_total == before[1] + fused
+                if chained:
+                    assert fused <= 2
+                else:
+                    assert fused == 1 and not engine.forward_in_flight()
                 assert sched.burst_clamped_total == before[2]
                 assert sched.decode_tokens_total > before[3]
         assert mixed > 0 and sched.fused_steps_total >= mixed
+        assert (sched.mixed_dispatch_ahead_total > 0) == chained
         # decode-only stretches still burst at the full span
         assert sched.burst_span_steps[8] > 0
 
@@ -528,7 +584,9 @@ class TestWeightPassLedger:
         assert engine.sched.weight_passes_total == passes + 1
         assert not engine.forward_in_flight()
         engine.step()
-        assert engine.sched.fused_steps_total == 1
+        # one fused step, and the successor it may enqueue
+        assert engine.sched.fused_steps_total == (
+            1 + engine.sched.mixed_dispatch_ahead_total)
 
     @pytest.mark.parametrize("kind", ["logprobs", "logit_bias", "guided",
                                       "min_p"])
@@ -600,6 +658,8 @@ class TestWeightPassLedger:
                     SamplingParams(max_tokens=3, temperature=0.0)),
         ])
         assert eng.sched.fused_steps_total > 0
+        # the chain's successors ride the same warmed program
+        assert eng.sched.mixed_dispatch_ahead_total > 0
         # decode-only classic steps never run here (every row bursts),
         # so the warmed programs are all the ragged forward meets; an
         # all-greedy tail reads no row key (first tokens take one of [1])
@@ -628,6 +688,169 @@ class TestWeightPassLedger:
             engine.sched.fused_steps_total
 
 
+def _chain_log(engine) -> list:
+    """Every chain decision: ``(row at its last token, prompt completing,
+    successor dispatched)`` per mixed step read back."""
+    log, chain = [], engine._chain_mixed
+
+    def logged(fl):
+        last = any(st.request.params.max_tokens - st.n_generated <= 1
+                   for st in fl.rows.values())
+        nxt = chain(fl)
+        log.append((last, bool(fl.done), nxt is not None))
+        return nxt
+    engine._chain_mixed = logged
+    return log
+
+
+class TestMixedChain:
+    """Dispatch-ahead for mixed steps on a burst engine: a mixed step
+    enqueues its decode tail before any first-token fetch and, when the
+    next step's rows are known, its successor (decode inputs carried on
+    the device) before its own fetch."""
+
+    def _engine(self, **kw):
+        return NativeEngine(CFG, cache_cfg=_cache_cfg(), max_batch_size=4,
+                            token_budget=16, decode_burst_steps=8, **kw)
+
+    def _stream_and_long(self, engine, stream_tokens=40):
+        """A greedy and a top-k row decoding, then a long prompt that
+        chunks beside them; the first step's outputs."""
+        engine.add_request(Request(
+            "stream", [1, 2, 3],
+            SamplingParams(max_tokens=stream_tokens, temperature=0.0)))
+        engine.add_request(Request(
+            "tk", [3, 2, 1], SamplingParams(max_tokens=40, temperature=0.7,
+                                            seed=5, top_k=8)))
+        outs = engine.step()
+        engine.add_request(Request(
+            "long", list(range(1, 120)),
+            SamplingParams(max_tokens=4, temperature=0.0)))
+        return outs
+
+    @staticmethod
+    def _drain(engine) -> dict:
+        toks: dict[str, list[int]] = {}
+        for _ in range(300):
+            if not engine.has_work():
+                break
+            for o in engine.step():
+                assert not (o.finish_reason or "").startswith("error"), o
+                toks.setdefault(o.request_id, []).append(o.token)
+        assert not engine.has_work()
+        return toks
+
+    def _chained_step(self, engine, toks=None):
+        """Step until a mixed successor is in flight (recording the
+        outputs into ``toks``)."""
+        for _ in range(20):
+            for o in engine.step():
+                if toks is not None:
+                    toks.setdefault(o.request_id, []).append(o.token)
+            fl = engine._inflight
+            if fl is not None and fl.kind == "mixed":
+                return fl
+        raise AssertionError("no mixed step was dispatched ahead")
+
+    def test_engages_in_a_steady_mixed_load(self):
+        engine = self._engine()
+        log = _chain_log(engine)
+        self._stream_and_long(engine)
+        self._drain(engine)
+        sched = engine.sched
+        assert sched.mixed_dispatch_ahead_total == sum(n for *_, n in log) > 0
+        # every chained successor is a dispatch ahead and a fused step
+        assert sched.dispatch_ahead_total >= sched.mixed_dispatch_ahead_total
+        assert sched.fused_steps_total > sched.mixed_dispatch_ahead_total
+        assert engine.sched.snapshot()["mixed_dispatch_ahead"] == \
+            sched.mixed_dispatch_ahead_total
+
+    @pytest.mark.parametrize("why", ["completing_prompt", "last_token"])
+    def test_breaks_where_the_next_rows_are_not_known(self, why):
+        """A chunk that completes its prompt (its first token joins the
+        next batch) and a row that spends its last token in the step
+        each end the chain: that step's read-back enqueues nothing."""
+        engine = self._engine()
+        log = _chain_log(engine)
+        self._stream_and_long(engine, stream_tokens=(
+            40 if why == "completing_prompt" else 20))
+        self._drain(engine)
+        col = 1 if why == "completing_prompt" else 0
+        assert any(n for *_, n in log)
+        broken = [entry for entry in log if entry[col]]
+        assert broken and not any(n for *_, n in broken)
+
+    def test_breaks_on_an_admission(self):
+        """A request admitted while a successor is in flight waits one
+        mixed step (as behind a burst), joins, and the read-back of the
+        in-flight step enqueues nothing."""
+        engine = self._engine()
+        self._stream_and_long(engine)
+        self._chained_step(engine)
+        engine.add_request(Request(
+            "late", [7, 8, 9], SamplingParams(max_tokens=3, temperature=0.0)))
+        outs = engine.step()
+        assert {o.request_id for o in outs} >= {"late", "stream", "tk"}
+        assert engine._inflight is None
+        assert "late" in {st.request.request_id
+                          for st in engine.running.values()}
+
+    def test_a_cancel_mid_chain_discards_the_in_flight_token(self):
+        """A decode row cancelled while its next token is in flight gets
+        no token after the cancel; the other streams are those of a run
+        without the cancel, and every page comes back."""
+        def run(cancel):
+            engine = self._engine()
+            toks: dict[str, list[int]] = {}
+            for o in self._stream_and_long(engine):
+                toks.setdefault(o.request_id, []).append(o.token)
+            fl = self._chained_step(engine, toks)
+            if cancel:
+                assert "tk" in {st.request.request_id
+                                for st in fl.rows.values()}
+                engine.cancel("tk")
+                before = len(toks["tk"])
+            for rid, t in self._drain(engine).items():
+                toks.setdefault(rid, []).extend(t)
+            return toks, engine, (before if cancel else None)
+
+        ref, _, _ = run(False)
+        got, eng, n = run(True)
+        assert got["tk"] == ref["tk"][:n] and n < len(ref["tk"])
+        assert {k: v for k, v in got.items() if k != "tk"} == \
+            {k: v for k, v in ref.items() if k != "tk"}
+        assert eng.cancelled_total == 1
+        assert eng.alloc.free_pages == self._engine().alloc.free_pages
+
+    def test_the_tail_dispatches_before_the_activation_fetch(self):
+        """Dispatch-order probe: in a mixed step that completes a prompt,
+        the decode rows' tail (lm_head→top-k, draw, count bump) is
+        enqueued before the first-token draw and its fetch."""
+        engine = self._engine(pipeline_bursts=False)
+        order = []
+        tail, activate = engine._fused_sample_dispatch, engine._activate_group
+
+        def probe_tail(*a, **k):
+            order.append("tail")
+            return tail(*a, **k)
+
+        def probe_activate(entries):
+            order.append("activate")
+            return activate(entries)
+        engine._fused_sample_dispatch = probe_tail
+        engine._activate_group = probe_activate
+        self._stream_and_long(engine)
+        mixed_with_activation = 0
+        while engine.has_work():
+            del order[:]
+            before = engine.sched.fused_steps_total
+            engine.step()
+            if engine.sched.fused_steps_total > before and "activate" in order:
+                mixed_with_activation += 1
+                assert order.index("tail") < order.index("activate"), order
+        assert mixed_with_activation > 0
+
+
 class TestCLIAndMetrics:
     def test_serve_flag_round_trip(self):
         from fusioninfer_tpu.cli import build_parser
@@ -647,6 +870,7 @@ class TestCLIAndMetrics:
         _run_all(engine, _mixed_reqs())
         text = EngineMetrics("m").render(engine)
         for family in ("fusioninfer:sched_fused_steps_total",
+                       "fusioninfer:sched_mixed_dispatch_ahead_total",
                        "fusioninfer:sched_weight_passes_total",
                        "fusioninfer:sched_fused_packed_tokens"):
             assert f"# TYPE {family} " in text, family

@@ -433,22 +433,24 @@ def test_seeded_weights_are_the_parents_bit_for_bit(preset):
 # served programs of an engine built as below, read on the parent commit
 # (e4dbd32) with this jax: "+flash" lowers the Pallas kernels (interpret
 # mode on the CPU), the others the portable branch.  A pool's names and
-# shapes and the programs' signatures are part of the text.
+# shapes and the programs' signatures are part of the text.  The third,
+# the mixed program, was read again when it took the decode rows' token
+# carry (two operands and one scatter: ``fused_step``'s ``carry``).
 PARENT_LOWERED = {
     "deepseek-v2-tiny": ("69e175e50750b7c8", "3d6b8bf5b7051210",
-                         "4ebc097096c04d80", "961c123c34b30d80"),
+                         "e06e909bfed84ebe", "961c123c34b30d80"),
     "deepseek-v2-tiny+flash": ("3f4276d5a079e912", "7b556aff6518dd68",
-                               "2e16540692e30f25", "ee019e3b5bd17244"),
+                               "529fb7ddb1ef5819", "ee019e3b5bd17244"),
     "longcat-flash-tiny": ("cbac70680b2bbc6b", "f9a0fc32b4bd0e4e",
-                           "961910a92cf88c1c", "c884cfba892f655e"),
+                           "a3140c5cdae1b157", "c884cfba892f655e"),
     "mistral-tiny": ("8e81c8d97f7e6dd0", "d7d8dbc3661ff65a",
-                     "1813790de8e52a9e", "6cdd37a3da3d8332"),
+                     "202c1fe3cd4b99be", "6cdd37a3da3d8332"),
     "mistral-tiny+flash": ("b21954f69169ccf3", "a10a6f6c9fd692de",
-                           "4dc33d61cafe63ff", "b3c84bff4124c5b3"),
+                           "03611b52488c334a", "b3c84bff4124c5b3"),
     "qwen3-tiny": ("b1667d3d9898b704", "e780ed95b7f9b83c",
-                   "6b13c7fe8e521a9d", "8e3211b8d3029993"),
+                   "c4c8d47dca3ec2f3", "8e3211b8d3029993"),
     "qwen3-tiny+flash": ("5fd27836213f138b", "8100d9bdc3f55148",
-                         "71988f27705babeb", "0718bd7217a5c46f"),
+                         "a8ccb423f57c6637", "0718bd7217a5c46f"),
 }
 PROGRAMS = ("prefill/b32r2", "fused/decode-t16", "fused/mixed-hidden-t64",
             "burst/s8-greedy")
