@@ -95,6 +95,11 @@ class Histogram:
         return out
 
 
+def _counter(name: str, help_: str, labels: str, value) -> list[str]:
+    return [f"# HELP {name} {help_}", f"# TYPE {name} counter",
+            f"{name}{{{labels}}} {value}"]
+
+
 TTFT_BUCKETS = (0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 TPOT_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
 
@@ -123,6 +128,11 @@ class EngineMetrics:
         self.tier_tpot: dict[str, Histogram] = {}
         self.tier_requests: dict[str, int] = {}
         self.tier_shed: dict[str, int] = {}
+        # the stream handlers' spans, chunks and delays (one clock for
+        # every handler thread of the server)
+        from fusioninfer_tpu.utils import spans
+
+        self.stream = spans.StreamClock()
 
     def register_tiers(self, names) -> None:
         """Install the per-tier metric families for the server's SLO
@@ -246,6 +256,7 @@ class EngineMetrics:
         lines += self._render_evacuation(engine, labels)
         lines += self._render_scheduler(engine, labels)
         lines += self._render_host(engine, labels)
+        lines += self._render_stream(labels)
         lines += self._render_aot(engine, labels)
         return "\n".join(lines) + "\n"
 
@@ -463,41 +474,51 @@ class EngineMetrics:
     @staticmethod
     def _render_host(engine, labels: str) -> list[str]:
         """Where the engine thread's time goes (utils/spans.py): self
-        seconds and counts per host span, the loop's wall and CPU clocks,
-        each request's queue and prefill wait, and jit seconds.  Separate
-        families, not a label: scrapers that sum a family's samples keep
-        each phase apart.  Engines without a span clock (test stubs) omit
-        the families."""
+        wall and self CPU seconds per host span, the loop's wall and CPU
+        clocks and its stalls, each request's queue and prefill wait, and
+        the process's jit and collector seconds.  Separate families, not
+        a label: scrapers that sum a family's samples keep each phase
+        apart.  Engines without a span clock (test stubs) omit the
+        families."""
         from fusioninfer_tpu.utils import spans
 
         clock = getattr(engine, "spans", None)
         if clock is None:
             return []
-        lines = []
+        lines: list[str] = []
 
         def counter(name: str, help_: str, value) -> None:
-            lines.extend([f"# HELP {name} {help_}", f"# TYPE {name} counter",
-                          f"{name}{{{labels}}} {value}"])
+            lines.extend(_counter(name, help_, labels, value))
 
         for span in spans.SPAN_NAMES:
-            family = "fusioninfer:host_" + span.replace(".", "_")
-            counter(family + "_seconds_total",
+            counter(f"fusioninfer:host_{span.replace('.', '_')}_seconds_total",
                     f"Engine-thread self time inside {span} spans.",
                     clock.ns[span] / 1e9)
-            counter(family + "_count_total", f"{span} spans closed.",
-                    clock.count[span])
+        for span in spans.SPAN_NAMES:
+            counter(f"fusioninfer:engine_cpu_{span.replace('.', '_')}"
+                    "_seconds_total",
+                    f"Engine-thread self CPU time inside {span} spans (the "
+                    "wall family less this one is time spent waiting).",
+                    clock.cpu_ns[span] / 1e9)
         counter("fusioninfer:engine_loop_seconds_total",
                 "Wall time since the engine loop started.",
                 clock.loop_ns / 1e9)
         counter("fusioninfer:engine_thread_cpu_seconds_total",
                 "CPU time of the engine thread since the loop started.",
-                clock.cpu_ns / 1e9)
+                clock.loop_cpu_ns / 1e9)
+        counter("fusioninfer:engine_stalls_total",
+                "Engine-loop iterations of 250 ms or more (each logs one "
+                "'engine stall' warning).", clock.stalls)
+        counter("fusioninfer:engine_stall_seconds_total",
+                "Wall time of the engine-loop iterations counted as stalls.",
+                clock.stall_ns / 1e9)
         counter("fusioninfer:jit_seconds_total",
                 "Seconds of jax trace + lower + compile on jit-cache misses.",
                 spans.jit_totals["seconds"])
-        counter("fusioninfer:jit_events_total",
-                "jax trace, lower and compile events (jit-cache misses).",
-                spans.jit_totals["events"])
+        counter("fusioninfer:gc_seconds_total",
+                "Wall seconds inside the cyclic garbage collector, any "
+                "thread, any generation (every thread waits for it).",
+                spans.gc_totals["seconds"])
         for name, help_, hist in (
                 ("vllm:request_queue_time_seconds",
                  "Arrival to the pop for admission.", engine.queue_time),
@@ -506,6 +527,39 @@ class EngineMetrics:
                  engine.prefill_time)):
             lines += [f"# HELP {name} {help_}", f"# TYPE {name} histogram",
                       *hist.render(name, labels)]
+        return lines
+
+    def _render_stream(self, labels: str) -> list[str]:
+        """The stream handlers' side (utils/spans.py ``StreamClock``): a
+        token's path from ``chan.put`` on the engine thread to its SSE
+        chunk's socket write, summed over every handler thread."""
+        stream = self.stream
+        chunks, delay_s = stream.chunks, stream.delay_ns / 1e9
+        lines: list[str] = []
+        for family, help_, value in (
+                ("stream_render_seconds_total",
+                 "Stream-handler wall time rendering chunks: a token's text, "
+                 "stop check, chunk and its JSON.",
+                 stream.render_ns / 1e9),
+                ("stream_write_seconds_total",
+                 "Stream-handler wall time writing chunks to the socket.",
+                 stream.write_ns / 1e9),
+                ("stream_cpu_seconds_total",
+                 "Stream-handler threads' CPU time while they stream, system "
+                 "time outside the interpreter lock included (each thread's "
+                 "clock read at most once a second).",
+                 stream.cpu_seconds()),
+                ("stream_chunks_total",
+                 "SSE chunks written that carry an engine output (one per "
+                 "streamed token; a tool-call stream's are not counted).",
+                 chunks)):
+            lines += _counter("fusioninfer:" + family, help_, labels, value)
+        name = "fusioninfer:stream_delay_seconds"
+        lines += [f"# HELP {name} From an output's hand-over to its stream "
+                  "(engine thread) to its chunk's write returning.",
+                  f"# TYPE {name} summary",
+                  f"{name}_sum{{{labels}}} {delay_s}",
+                  f"{name}_count{{{labels}}} {chunks}"]
         return lines
 
     @staticmethod

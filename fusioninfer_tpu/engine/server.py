@@ -77,18 +77,23 @@ _STREAM_IDLE_TIMEOUT_S = 300.0
 
 
 class _RequestChannel:
-    """Blocking bridge from engine thread to an HTTP handler thread."""
+    """Blocking bridge from engine thread to an HTTP handler thread.  An
+    item is queued with the ``time.perf_counter_ns`` of its ``put``;
+    ``stream()`` leaves the stamp of the item it last yielded in
+    ``published_ns``: its stream chunk's delay is counted from there."""
 
     def __init__(self):
-        self.q: queue.Queue = queue.Queue()
+        self.q: queue.Queue = queue.Queue()  # (item, put stamp)
+        self.published_ns = 0
 
     def put(self, item) -> None:
-        self.q.put(item)
+        self.q.put((item, time.perf_counter_ns()))
 
     def stream(self):
         while True:
             try:
-                item = self.q.get(timeout=_STREAM_IDLE_TIMEOUT_S)
+                item, self.published_ns = self.q.get(
+                    timeout=_STREAM_IDLE_TIMEOUT_S)
             except queue.Empty:
                 raise TimeoutError(
                     "engine produced no stream output for "
@@ -143,6 +148,15 @@ class _MultiChannel:
 
     def __init__(self, chans: list[_RequestChannel]):
         self.chans = chans
+
+
+class _Chunk(dict):
+    """A stream chunk built from one engine output, carrying the time
+    that output was published (``_RequestChannel.put``) and how long it
+    took to build.  Equal to, and serialised as, the plain dict; a copy
+    made from it is a plain dict and is not counted as a token's chunk."""
+
+    __slots__ = ("published_ns", "render_ns")
 
 
 _PUMP_DONE = object()  # sentinel: one merged sub-stream finished cleanly
@@ -322,6 +336,7 @@ class EngineServer:
             if tb is not None:
                 engine.set_guided_vocab(tb)
         self.metrics = EngineMetrics(model)
+        spans.watch_gc()
         self.slo_tiers = None
         if slo_tiers is not None:
             from fusioninfer_tpu.engine.slo import TierTable
@@ -359,6 +374,9 @@ class EngineServer:
         self._watchdog_thread: threading.Thread | None = None
         self._watchdog_started = False
         self._profiling = False
+        # what a stall line reports of a capture (one writer,
+        # handle_profile): off, starting, open, stopping
+        self._capture_phase = "off"
         # injectable so tests exercise the capture protocol without a
         # wall-time sleep (a 0.2s capture under a loaded test host was a
         # reliable tier-1 flake); production keeps the real sleep
@@ -392,8 +410,9 @@ class EngineServer:
                 and not getattr(self.engine, "is_multihost", False))
         if hold:
             self.engine.on_forward_enqueued = self._publish_held
+        marks = self._stall_marks()
         while not self._stop.is_set():
-            clock.tick()
+            marks = self._tick(clock, marks)
             if not self.engine.has_work():
                 self._publish_held()
                 consecutive_failures = 0  # an old incident must not
@@ -473,7 +492,33 @@ class EngineServer:
                 logger.info("multihost shutdown event; engine loop exits")
                 break
         self._publish_held()
-        clock.tick()
+        self._tick(clock, marks)
+
+    def _stall_marks(self) -> tuple:
+        """What a stall line reports as fallen inside it: the process's
+        jit and collector seconds and the stream handlers' CPU seconds,
+        and the capture's phase, as they stand now."""
+        return (spans.jit_totals["seconds"], spans.gc_totals["seconds"],
+                self.metrics.stream.cpu_seconds(), self._capture_phase)
+
+    def _tick(self, clock: spans.SpanClock, marks: tuple) -> tuple:
+        """One engine-loop tick.  An iteration of ``spans.STALL_NS`` or
+        more logs ONE warning with its ``time.monotonic`` edges (the load
+        generator's clock), the engine thread's CPU in it, the longest
+        span closed in it, and what of the marks fell inside it."""
+        stall = clock.tick()
+        now = self._stall_marks()
+        if stall is not None:
+            end = time.monotonic()
+            jit_s, gc_s, stream_cpu_s = (b - a for a, b in zip(marks[:3], now))
+            logger.warning(
+                "engine stall %.3f s: start=%.3f end=%.3f engine_cpu_s=%.3f "
+                "longest_span=%s longest_span_s=%.3f program=%s gc_s=%.3f "
+                "jit_s=%.3f stream_cpu_s=%.3f capture=%s/%s",
+                stall.wall_ns / 1e9, end - stall.wall_ns / 1e9, end,
+                stall.cpu_ns / 1e9, stall.span, stall.span_ns / 1e9,
+                stall.program, gc_s, jit_s, stream_cpu_s, marks[3], now[3])
+        return now
 
     def _publish_held(self) -> None:
         """Let go of the tokens the loop held back (engine thread)."""
@@ -824,13 +869,17 @@ class EngineServer:
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         try:
+            self._capture_phase = "starting"
             jax.profiler.start_trace(out_dir, profiler_options=options)
             spans.capturing = True
+            self._capture_phase = "open"
             self._profile_sleep(seconds)
             spans.capturing = False
+            self._capture_phase = "stopping"
             jax.profiler.stop_trace()
         finally:
             spans.capturing = False
+            self._capture_phase = "off"
             with self._lock:
                 self._profiling = False
         return {"status": "ok", "dir": out_dir, "seconds": seconds}
@@ -1316,90 +1365,95 @@ class EngineServer:
             for out in chan.stream():
                 if out is None:  # aborted mid-stream (client gone)
                     return
-                is_error = (out.finish_reason or "").startswith("error")
-                counted = not is_error and not (
-                    out.finished and out.finish_reason == "stop"
-                    and out.token == self.tokenizer.eos_token_id)
-                if counted:
-                    tokens.append(out.token)
-                full = self.tokenizer.decode(tokens)
-                finish = (out.finish_reason or "length") if out.finished else None
-                if stops:
-                    hit = _find_stop(full, stops)
-                    if hit is not None:
-                        # OpenAI semantics: the stop sequence is excluded
-                        full, finish = full[:hit], "stop"
-                        # drop the tokens past the cut so streamed usage
-                        # counts match the non-streaming path exactly
-                        while tokens and len(
-                                self.tokenizer.decode(tokens[:-1])) >= hit:
-                            tokens.pop()
-                            counted = False  # its text never ships
-                        self._cancel_chan(chan)
-                    elif not out.finished:
-                        full = full[: len(full) - _held_back(full, stops)]
-                if finish is None:
-                    # hold back trailing replacement chars: a multi-byte
-                    # utf-8 sequence split across deltas decodes as
-                    # U+FFFD now but as the REAL char once its
-                    # continuation bytes arrive — shipping it early
-                    # would freeze the mojibake into the client's text.
-                    # (gated on finish, not out.finished: a stop-string
-                    # cut is this stream's LAST chunk and must flush)
-                    full = full[:len(full.rstrip("�"))]
-                delta, emitted = full[emitted:], max(emitted, len(full))
-                if echo_prefix:  # OpenAI echo: prompt leads the stream
-                    delta, echo_prefix = echo_prefix + delta, ""
-                # a logprobs entry ships only for tokens whose text is
-                # actually delivered (not the trimmed EOS / stop-cut
-                # tokens) — matching the non-streaming trim exactly
-                if chat:
-                    choice = {"index": choice_index, "delta": {"content": delta},
-                              "finish_reason": finish}
-                    if out.logprob is not None and counted:
-                        choice["logprobs"] = {"content": [{
-                            "token": _piece(self.tokenizer, out.token),
-                            "logprob": out.logprob,
-                            "top_logprobs": [
-                                {"token": _piece(self.tokenizer, t),
-                                 "logprob": v}
-                                for t, v in (out.top_logprobs or {}).items()
-                            ],
-                        }]}
-                    obj = "chat.completion.chunk"
-                else:
-                    lp = None
-                    if out.logprob is not None and counted:
-                        lp = {"tokens": [_piece(self.tokenizer, out.token)],
-                              "token_logprobs": [out.logprob],
-                              "top_logprobs": [out.top_logprobs or {}]}
-                    choice = {"index": choice_index, "text": delta,
-                              "finish_reason": finish, "logprobs": lp}
+                t0 = self.metrics.stream.now()
+                with spans.annotation("stream.render"):
+                    is_error = (out.finish_reason or "").startswith("error")
+                    counted = not is_error and not (
+                        out.finished and out.finish_reason == "stop"
+                        and out.token == self.tokenizer.eos_token_id)
                     if counted:
-                        # raw id riding alongside the decoded delta (a
-                        # vLLM-style additive extension): decoded text is
-                        # LOSSY under fallback tokenizers (ByteTokenizer
-                        # drops non-byte ids), so stream-integrity
-                        # checkers (fleetsim.FleetClient) compare ids,
-                        # not text
-                        choice["token_id"] = out.token
-                    obj = "text_completion"
-                if is_error and out.retry_after_s is not None:
-                    # retriable engine-side abort mid-stream: a 503
-                    # can't be sent on a committed SSE response, so the
-                    # Retry-After hint rides the final error chunk —
-                    # clients retry another replica instead of erroring
-                    choice["retry_after_s"] = out.retry_after_s
-                yield {
-                    "id": completion_id,
-                    "object": obj,
-                    "created": created,
-                    # echo the REQUESTED model (adapter name for LoRA
-                    # routing) — clients validate/account against it
-                    "model": served_model or self.model_name,
-                    "system_fingerprint": _FINGERPRINT,
-                    "choices": [choice],
-                }
+                        tokens.append(out.token)
+                    full = self.tokenizer.decode(tokens)
+                    finish = (out.finish_reason or "length") if out.finished else None
+                    if stops:
+                        hit = _find_stop(full, stops)
+                        if hit is not None:
+                            # OpenAI semantics: the stop sequence is excluded
+                            full, finish = full[:hit], "stop"
+                            # drop the tokens past the cut so streamed usage
+                            # counts match the non-streaming path exactly
+                            while tokens and len(
+                                    self.tokenizer.decode(tokens[:-1])) >= hit:
+                                tokens.pop()
+                                counted = False  # its text never ships
+                            self._cancel_chan(chan)
+                        elif not out.finished:
+                            full = full[: len(full) - _held_back(full, stops)]
+                    if finish is None:
+                        # hold back trailing replacement chars: a multi-byte
+                        # utf-8 sequence split across deltas decodes as
+                        # U+FFFD now but as the REAL char once its
+                        # continuation bytes arrive — shipping it early
+                        # would freeze the mojibake into the client's text.
+                        # (gated on finish, not out.finished: a stop-string
+                        # cut is this stream's LAST chunk and must flush)
+                        full = full[:len(full.rstrip("�"))]
+                    delta, emitted = full[emitted:], max(emitted, len(full))
+                    if echo_prefix:  # OpenAI echo: prompt leads the stream
+                        delta, echo_prefix = echo_prefix + delta, ""
+                    # a logprobs entry ships only for tokens whose text is
+                    # actually delivered (not the trimmed EOS / stop-cut
+                    # tokens) — matching the non-streaming trim exactly
+                    if chat:
+                        choice = {"index": choice_index, "delta": {"content": delta},
+                                  "finish_reason": finish}
+                        if out.logprob is not None and counted:
+                            choice["logprobs"] = {"content": [{
+                                "token": _piece(self.tokenizer, out.token),
+                                "logprob": out.logprob,
+                                "top_logprobs": [
+                                    {"token": _piece(self.tokenizer, t),
+                                     "logprob": v}
+                                    for t, v in (out.top_logprobs or {}).items()
+                                ],
+                            }]}
+                        obj = "chat.completion.chunk"
+                    else:
+                        lp = None
+                        if out.logprob is not None and counted:
+                            lp = {"tokens": [_piece(self.tokenizer, out.token)],
+                                  "token_logprobs": [out.logprob],
+                                  "top_logprobs": [out.top_logprobs or {}]}
+                        choice = {"index": choice_index, "text": delta,
+                                  "finish_reason": finish, "logprobs": lp}
+                        if counted:
+                            # raw id riding alongside the decoded delta (a
+                            # vLLM-style additive extension): decoded text is
+                            # LOSSY under fallback tokenizers (ByteTokenizer
+                            # drops non-byte ids), so stream-integrity
+                            # checkers (fleetsim.FleetClient) compare ids,
+                            # not text
+                            choice["token_id"] = out.token
+                        obj = "text_completion"
+                    if is_error and out.retry_after_s is not None:
+                        # retriable engine-side abort mid-stream: a 503
+                        # can't be sent on a committed SSE response, so the
+                        # Retry-After hint rides the final error chunk —
+                        # clients retry another replica instead of erroring
+                        choice["retry_after_s"] = out.retry_after_s
+                    chunk = _Chunk({
+                        "id": completion_id,
+                        "object": obj,
+                        "created": created,
+                        # echo the REQUESTED model (adapter name for LoRA
+                        # routing) — clients validate/account against it
+                        "model": served_model or self.model_name,
+                        "system_fingerprint": _FINGERPRINT,
+                        "choices": [choice],
+                    })
+                chunk.published_ns = chan.published_ns
+                chunk.render_ns = self.metrics.stream.now() - t0
+                yield chunk
                 if finish is not None:
                     break
         finally:
@@ -2185,15 +2239,32 @@ class EngineServer:
                 self.send_header("Transfer-Encoding", "chunked")
                 self.end_headers()
 
+                stream = server.metrics.stream
+
                 def write_chunk(payload: bytes) -> None:
                     self.wfile.write(f"{len(payload):X}\r\n".encode() + payload + b"\r\n")
 
-                for chunk in chunks:
-                    if chunk is None:
-                        write_chunk(b"data: [DONE]\n\n")
-                    else:
-                        write_chunk(f"data: {json.dumps(chunk)}\n\n".encode())
-                write_chunk(b"")  # chunked EOF
+                # this thread's CPU while it streams (an n > 1 request's
+                # choices are rendered on pump threads: not counted)
+                with stream.streaming() as cpu:
+                    for chunk in chunks:
+                        cpu.tick()
+                        if chunk is None:
+                            write_chunk(b"data: [DONE]\n\n")
+                            continue
+                        t0 = stream.now()
+                        with spans.annotation("stream.render"):
+                            payload = f"data: {json.dumps(chunk)}\n\n".encode()
+                        t1 = stream.now()
+                        with spans.annotation("stream.write"):
+                            write_chunk(payload)
+                        t2 = stream.now()
+                        if isinstance(chunk, _Chunk):
+                            stream.written(chunk.render_ns + t1 - t0, t2 - t1,
+                                           t2 - chunk.published_ns)
+                        else:
+                            stream.written(t1 - t0, t2 - t1)
+                    write_chunk(b"")  # chunked EOF
 
             def log_message(self, *args):
                 pass

@@ -281,3 +281,32 @@ def test_reading_the_counts_imports_neither_jax_nor_the_program():
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=60, check=True)
     assert json.loads(out.stdout.strip().splitlines()[-1]) == []
+
+
+# the counters the program renders at the window's close (zero at its
+# open), and what each reader makes of them
+HOST_COUNTERS = {
+    "fusioninfer:sched_steps_total": 400.0,
+    "fusioninfer:stream_cpu_seconds_total": 2.0,
+    "fusioninfer:stream_delay_seconds_sum": 30.0,
+    "fusioninfer:stream_delay_seconds_count": 6000.0,
+    "fusioninfer:host_step_dispatch_seconds_total": 8.0,
+    "fusioninfer:engine_cpu_step_dispatch_seconds_total": 3.0,
+    "fusioninfer:gc_seconds_total": 0.2,
+    "fusioninfer:engine_stall_seconds_total": 0.75,
+}
+
+
+@pytest.mark.parametrize("name, want", [
+    ("stream_cpu_ms_per_step", 5.0), ("stream_delay_mean_ms", 5.0),
+    ("host_dispatch_offcpu_pct", 62.5), ("gc_ms_per_step", 0.5),
+    ("engine_stall_s_in_window", 0.75)])
+def test_the_host_readers_on_a_recorded_run(name, want):
+    cfg = config_of("qwen3-1.7b")
+    assert _reader(name)(_Run(cfg, {}, dict(HOST_COUNTERS))) == \
+        pytest.approx(want)
+    # a program without the family (the parent commit under this
+    # benchmark): nothing to read, and no exception
+    older = {k: v for k, v in HOST_COUNTERS.items()
+             if k.startswith(("fusioninfer:sched_", "fusioninfer:host_"))}
+    assert _reader(name)(_Run(cfg, {}, older)) is None

@@ -845,7 +845,7 @@ class TestDeadlineWatchdog:
         try:
             chan = server.submit([1, 2, 3], SamplingParams(max_tokens=4),
                                  deadline_s=0.15)
-            out = chan.q.get(timeout=5.0)
+            out, _ = chan.q.get(timeout=5.0)
             assert out.finished
             assert out.finish_reason == "error:deadline exceeded"
             assert engine.cancelled == [out.request_id], (
@@ -860,7 +860,7 @@ class TestDeadlineWatchdog:
         server, engine = self._server(default_deadline_s=0.15)
         try:
             chan = server.submit([1], SamplingParams(max_tokens=4))
-            out = chan.q.get(timeout=5.0)
+            out, _ = chan.q.get(timeout=5.0)
             assert out.finished
             assert out.finish_reason == "error:deadline exceeded"
         finally:
@@ -872,7 +872,7 @@ class TestDeadlineWatchdog:
         server, engine = self._server(watchdog_stall_s=0.15)
         try:
             chan = server.submit([1], SamplingParams(max_tokens=4))
-            out = chan.q.get(timeout=5.0)
+            out, _ = chan.q.get(timeout=5.0)
             assert out.finished
             assert out.finish_reason.startswith("error:watchdog")
             assert engine.cancelled == [out.request_id]

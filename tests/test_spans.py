@@ -1,11 +1,15 @@
 """Host spans and phase clocks of the engine thread (utils/spans.py):
-self-time arithmetic, the families on /metrics, no annotation outside a
-capture, a capture that holds the spans and no Python call trace, and
-the jit listener."""
+self-time arithmetic on the wall and CPU clocks, the families on
+/metrics, the stream handlers' spans and delays, stalled loop
+iterations, no annotation outside a capture, a capture that holds the
+spans and no Python call trace, and the jit and collector listeners."""
 
+import gc
 import glob
 import json
+import logging
 import re
+import time
 import urllib.request
 
 import jax
@@ -20,13 +24,21 @@ from fusioninfer_tpu.utils import spans
 
 CFG = get_preset("qwen3-tiny")
 CACHE = CacheConfig(n_pages=64, page_size=8, max_pages_per_seq=8)
-HOST_FAMILIES = [
-    f"fusioninfer:host_{name.replace('.', '_')}_{kind}_total"
-    for name in spans.SPAN_NAMES for kind in ("seconds", "count")]
-FAMILIES = HOST_FAMILIES + [
+HOST_FAMILIES = [f"fusioninfer:host_{name.replace('.', '_')}_seconds_total"
+                 for name in spans.SPAN_NAMES]
+CPU_FAMILIES = [f"fusioninfer:engine_cpu_{name.replace('.', '_')}_seconds_total"
+                for name in spans.SPAN_NAMES]
+STREAM_FAMILIES = [
+    "fusioninfer:stream_render_seconds_total",
+    "fusioninfer:stream_write_seconds_total",
+    "fusioninfer:stream_cpu_seconds_total", "fusioninfer:stream_chunks_total",
+    "fusioninfer:stream_delay_seconds_sum",
+    "fusioninfer:stream_delay_seconds_count"]
+FAMILIES = HOST_FAMILIES + CPU_FAMILIES + STREAM_FAMILIES + [
     "fusioninfer:engine_loop_seconds_total",
     "fusioninfer:engine_thread_cpu_seconds_total",
-    "fusioninfer:jit_seconds_total", "fusioninfer:jit_events_total",
+    "fusioninfer:engine_stalls_total", "fusioninfer:engine_stall_seconds_total",
+    "fusioninfer:jit_seconds_total", "fusioninfer:gc_seconds_total",
     "vllm:request_queue_time_seconds_sum",
     "vllm:request_queue_time_seconds_count",
     "vllm:request_prefill_time_seconds_sum",
@@ -64,8 +76,31 @@ def test_nested_spans_add_self_times():
     assert clock.ns["step.pack"] == 50 - 15 - 15
     assert clock.ns["step"] == 100 - 50 - 5
     assert sum(clock.ns.values()) == 100  # self times add up
-    assert clock.count["step.dispatch"] == 2 and clock.count["step"] == 1
-    assert clock.count["loop.idle"] == 0  # pre-seeded, never opened
+    assert clock.ns["loop.idle"] == 0  # pre-seeded, never opened
+
+
+def test_nested_spans_add_self_cpu_times():
+    """The CPU clock is read beside the wall at each edge, and a span's
+    self CPU time is its CPU time less its children's, as on the wall."""
+    now, cpu = FakeClock(), FakeClock()
+    clock = spans.SpanClock(now, cpu)
+    with clock.span("step"):                   # CPU [0, 15), wall [0, 100)
+        cpu.t = 5
+        with clock.span("step.dispatch"):      # CPU [5, 12), wall [0, 30)
+            now.t, cpu.t = 30, 12
+        with clock.span("step.fetch"):         # CPU [12, 13), wall [30, 90)
+            now.t, cpu.t = 90, 13
+        now.t, cpu.t = 100, 15
+    assert clock.stack == []
+    assert clock.cpu_ns["step.dispatch"] == 7
+    assert clock.cpu_ns["step.fetch"] == 1
+    assert clock.cpu_ns["step"] == 15 - 7 - 1
+    assert sum(clock.cpu_ns.values()) == 15  # self CPU times add up
+    assert clock.ns["step.fetch"] == 60 and clock.ns["step"] == 10
+    # wall less CPU: what each span waited
+    assert {k: clock.ns[k] - clock.cpu_ns[k]
+            for k in ("step", "step.dispatch", "step.fetch")} == {
+        "step": 3, "step.dispatch": 23, "step.fetch": 59}
 
 
 def test_a_span_that_raises_still_closes():
@@ -99,6 +134,21 @@ def complete(srv, prompt: str, max_tokens: int) -> dict:
         return json.load(r)
 
 
+def stream(srv, prompt: str, max_tokens: int) -> int:
+    """A streamed completion; the number of its chunks that carried a
+    token (every ``data:`` event but the closing ``[DONE]``)."""
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{srv.port}/v1/completions",
+        data=json.dumps({"prompt": prompt, "max_tokens": max_tokens,
+                         "temperature": 0.0, "stream": True}).encode(),
+        headers={"Content-Type": "application/json"})
+    with urllib.request.urlopen(req, timeout=120) as r:
+        events = [line[len("data: "):] for line in r.read().decode().splitlines()
+                  if line.startswith("data: ")]
+    assert events[-1] == "[DONE]"
+    return len(events) - 1
+
+
 def metrics(srv) -> dict:
     return parse(srv.metrics.render(srv.engine))
 
@@ -106,7 +156,9 @@ def metrics(srv) -> dict:
 @pytest.fixture(scope="module")
 def served():
     """A tiny engine served for a few dozen steps, then stopped: the
-    engine thread has made its last tick, so the totals stand still."""
+    engine thread has made its last tick, so the totals stand still.
+    Three requests stream (their token chunks are counted), the fourth
+    does not."""
     srv = EngineServer(model="qwen3-tiny", host="127.0.0.1", port=0,
                        max_batch_size=4, cache_cfg=CACHE)
     srv.start()
@@ -114,40 +166,122 @@ def served():
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{srv.port}/metrics", timeout=30) as r:
             first = parse(r.read().decode())
-        for i in range(3):
-            complete(srv, "span %d " % i * (2 + i), 12)
+        streamed = sum(stream(srv, "span %d " % i * (2 + i), 12)
+                       for i in range(3))
         mid = metrics(srv)
         complete(srv, "one more", 8)
     finally:
         srv.stop()
-    return srv, first, mid, metrics(srv)
+    return srv, first, mid, metrics(srv), streamed
 
 
 def test_every_family_is_rendered_and_monotone(served):
-    _, first, mid, last = served
+    _, first, mid, last, _ = served
     for family in FAMILIES:
         assert family in first, family  # pre-seeded: there before any work
         assert first[family] <= mid[family] <= last[family], family
     for name in ("step", "step.admit", "step.pack", "step.dispatch",
                  "step.fetch", "step.emit", "loop.publish", "loop.idle"):
-        family = f"fusioninfer:host_{name.replace('.', '_')}"
-        assert last[family + "_count_total"] > 0, name
-        assert last[family + "_seconds_total"] > 0, name
-    assert last["fusioninfer:host_step_count_total"] == \
-        last["fusioninfer:sched_steps_total"] >= 20
+        name = name.replace(".", "_")
+        assert last[f"fusioninfer:engine_cpu_{name}_seconds_total"] > 0, name
+        assert last[f"fusioninfer:host_{name}_seconds_total"] > 0, name
+    assert last["fusioninfer:sched_steps_total"] >= 20
     assert 0 < last["fusioninfer:engine_thread_cpu_seconds_total"] \
         <= last["fusioninfer:engine_loop_seconds_total"]
 
 
 def test_span_seconds_cover_the_loop(served):
-    _, _, _, last = served
+    last = served[3]
     loop = last["fusioninfer:engine_loop_seconds_total"]
-    covered = sum(last[f] for f in HOST_FAMILIES if f.endswith("_seconds_total"))
+    covered = sum(last[f] for f in HOST_FAMILIES)
     assert 0.95 * loop <= covered <= loop, (covered, loop)
 
 
+# the CPU clock and the wall are read one after the other at each edge
+# of a span, so a span that never left the CPU can read a little more CPU
+# than wall; this much, over a few dozen steps, is far more than that
+CLOCK_READS_S = 1e-3
+
+
+def test_engine_cpu_by_span_is_within_its_wall(served):
+    last = served[3]
+    for host, cpu in zip(HOST_FAMILIES, CPU_FAMILIES):
+        assert 0 <= last[cpu] <= last[host] + CLOCK_READS_S, (cpu, last[cpu],
+                                                              last[host])
+    # the thread's CPU in spans is within its CPU over the loop
+    assert sum(last[f] for f in CPU_FAMILIES) <= \
+        last["fusioninfer:engine_thread_cpu_seconds_total"] + CLOCK_READS_S
+
+
+def test_stream_chunks_and_delays_count_the_items_taken(served):
+    """One chunk, and one delay, per output taken from a stream's
+    channel; a request that does not stream writes none."""
+    _, first, mid, last, streamed = served
+    assert streamed >= 3
+    assert first["fusioninfer:stream_chunks_total"] == 0
+    assert mid["fusioninfer:stream_chunks_total"] == streamed
+    assert last["fusioninfer:stream_chunks_total"] == streamed
+    assert last["fusioninfer:stream_delay_seconds_count"] == streamed
+    assert last["fusioninfer:stream_delay_seconds_sum"] > 0
+
+
+def test_stream_render_write_and_cpu_grow(served):
+    _, first, mid, last, _ = served
+    for name in ("render", "write", "cpu"):
+        family = f"fusioninfer:stream_{name}_seconds_total"
+        assert first[family] == 0 < mid[family] <= last[family], family
+
+
+def test_a_streaming_thread_reads_its_cpu_clock_once_a_second():
+    """A chunk's accounting reads no CPU clock; a handler's CPU is read
+    as it starts streaming, at the first chunk a ``CPU_READ_NS`` or more
+    after its last read, and as it stops."""
+    now, reads = FakeClock(), []
+
+    def cpu():
+        reads.append(now.t)
+        return now.t // 2  # on the CPU half the time
+
+    stream = spans.StreamClock(now, cpu)
+    now.t = 7
+    stream.written(3, 4, 5_000_000)  # a token's chunk
+    stream.written(1, 1)             # a chunk that carries none
+    assert (stream.render_ns, stream.write_ns, stream.chunks) == (4, 5, 1)
+    assert stream.delay_ns == 5_000_000
+    assert reads == []
+    second = spans.CPU_READ_NS
+    with stream.streaming() as meter:
+        for _ in range(5):  # chunks 0.4 s apart
+            now.t += 2 * second // 5
+            meter.tick()
+        assert stream.cpu_seconds() == 0.6  # read once, at 1.2 s
+        now.t += second // 10
+    assert reads == [7, 7 + 6 * second // 5, 7 + 21 * second // 10]
+    assert stream.cpu_seconds() == 2.1 / 2
+
+
+def test_the_only_host_seconds_families_are_the_span_walls(served):
+    """``perfbench/spanread.all_spans_seconds`` sums every
+    ``fusioninfer:host_*_seconds_total`` as the engine thread's wall time
+    in spans: no other family may take that form."""
+    last = served[3]
+    got = {f for f in last
+           if re.fullmatch(r"fusioninfer:host_.*_seconds_total", f)}
+    assert got == set(HOST_FAMILIES) and len(got) == len(spans.SPAN_NAMES) == 9
+
+
+def test_a_collection_raises_gc_seconds(served):
+    srv = served[0]
+    before = metrics(srv)["fusioninfer:gc_seconds_total"]
+    gc.collect()
+    assert metrics(srv)["fusioninfer:gc_seconds_total"] > before
+    assert spans._on_gc in gc.callbacks
+    spans.watch_gc()  # registered once however often it is asked for
+    assert gc.callbacks.count(spans._on_gc) == 1
+
+
 def test_request_waits_count_first_tokens(served):
-    srv, _, _, last = served
+    srv, _, _, last, _ = served
     assert last["vllm:request_queue_time_seconds_count"] == 4
     assert last["vllm:request_prefill_time_seconds_count"] == 4
     assert last["vllm:request_queue_time_seconds_count"] == \
@@ -178,11 +312,16 @@ def test_no_annotation_outside_a_capture(monkeypatch):
     assert spans.capturing is False
     with clock.span("step", step=1) as sp:
         sp.note(tokens=3)
-    assert opened == [] and clock.count["step"] == 1
+    with spans.annotation("stream.write"):  # a stream handler's
+        pass
+    assert opened == [] and clock.stack == []
     monkeypatch.setattr(spans, "capturing", True)
     with clock.span("step", step=2) as sp:
         sp.note(tokens=3)
-    assert opened == [("step", {"step": 2}), ("note", {"tokens": 3})]
+    with spans.annotation("stream.write"):
+        pass
+    assert opened == [("step", {"step": 2}), ("note", {"tokens": 3}),
+                      ("stream.write", {})]
 
 
 def test_a_capture_holds_the_spans_and_no_python_calls(tmp_path):
@@ -194,8 +333,8 @@ def test_a_capture_holds_the_spans_and_no_python_calls(tmp_path):
     srv.enable_profiling = True
     srv.profile_dir = str(tmp_path)
     # the capture window: requests served while the trace runs
-    srv._profile_sleep = lambda _s: [complete(srv, "traced", 6)
-                                     for _ in range(2)]
+    srv._profile_sleep = lambda _s: [complete(srv, "traced", 6),
+                                     stream(srv, "traced", 6)]
     srv.start()
     try:
         complete(srv, "warm", 6)  # compile outside the capture
@@ -218,7 +357,8 @@ def test_a_capture_holds_the_spans_and_no_python_calls(tmp_path):
             for ev in line.events:
                 total += 1
                 names[ev.name] = names.get(ev.name, 0) + 1
-    for name in ("step", "step.fetch", "loop.publish"):
+    for name in ("step", "step.fetch", "loop.publish", "stream.render",
+                 "stream.write"):
         assert names.get(name, 0) > 0, sorted(names)[:40]
     # the default options trace every Python call of every thread:
     # hundreds of thousands of events for this much work
@@ -240,13 +380,86 @@ def test_jit_listener_counts_a_miss_and_nothing_on_a_hit():
         return x * 3 + 1
 
     x = jnp.arange(5.0)
-    before = dict(spans.jit_totals)
+    before = spans.jit_totals["seconds"]
     fresh(x).block_until_ready()
-    first = dict(spans.jit_totals)
-    assert first["events"] >= before["events"] + 2  # trace, lower(, compile)
-    assert first["seconds"] > before["seconds"]
+    first = spans.jit_totals["seconds"]
+    assert first > before  # trace, lower(, compile)
     fresh(x).block_until_ready()
-    assert dict(spans.jit_totals) == first
+    assert spans.jit_totals["seconds"] == first
+
+
+# -- an engine-loop iteration of 250 ms or more is a stall --------------------
+
+class _SlowStepEngine:
+    """An engine double whose second step takes 300 ms on the clock it
+    hands the loop, inside a ``step.fetch`` span."""
+
+    class _Cfg:
+        vocab_size = 512
+
+    cfg = _Cfg()
+    guided_enabled = True  # skips the guided-vocab bootstrap
+
+    def __init__(self):
+        self.now = FakeClock()
+        self.spans = spans.SpanClock(self.now)
+        self.steps = [1_000_000, 300_000_000]
+
+    def has_work(self):
+        return bool(self.steps)
+
+    def forward_in_flight(self):
+        return False
+
+    def cancel(self, request_id):
+        pass
+
+    def fail_all(self, reason, retry_after_s=None):
+        return []
+
+    def step(self):
+        with self.spans.span("step"):
+            with self.spans.span("step.fetch", program="decode_burst"):
+                self.now.t += self.steps[0]
+        self.steps.pop(0)
+        return []
+
+
+def test_a_long_iteration_is_one_stall_and_one_line(caplog):
+    from fusioninfer_tpu.engine.tokenizer import ByteTokenizer
+
+    engine = _SlowStepEngine()
+    srv = EngineServer(model="stub", host="127.0.0.1", port=0, engine=engine,
+                       tokenizer=ByteTokenizer())
+    with caplog.at_level(logging.WARNING, logger="fusioninfer.server"):
+        srv.start()
+        try:
+            deadline = time.monotonic() + 30
+            while engine.steps and time.monotonic() < deadline:
+                time.sleep(0.01)
+        finally:
+            srv.stop()
+    assert not engine.steps
+    clock = engine.spans
+    assert clock.stalls == 1 and clock.stall_ns == 300_000_000
+    from fusioninfer_tpu.engine.metrics import TTFT_BUCKETS, Histogram
+
+    engine.queue_time = engine.prefill_time = Histogram(TTFT_BUCKETS)
+    page = parse("\n".join(srv.metrics._render_host(engine, 'model_name="m"')))
+    assert page["fusioninfer:engine_stalls_total"] == 1
+    assert page["fusioninfer:engine_stall_seconds_total"] == 0.3
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("engine stall")]
+    assert len(lines) == 1, lines
+    line = lines[0]
+    assert line.startswith("engine stall 0.300 s: start=")
+    for field in ("longest_span=step.fetch", "longest_span_s=0.300",
+                  "program=decode_burst", "capture=off/off", "gc_s=",
+                  "jit_s=", "stream_cpu_s=", "engine_cpu_s="):
+        assert field in line, (field, line)
+    start, end = (float(re.search(rf"{k}=([0-9.]+)", line).group(1))
+                  for k in ("start", "end"))
+    assert end - start == pytest.approx(0.3, abs=2e-3)
 
 
 # -- the loop holds a step's tokens until the device has work ----------------
@@ -314,7 +527,7 @@ def _play(script, hook=True):
     engine.script = list(script)  # has_work() turns true: the loop steps
     srv.start()
     try:
-        got = [engine.chan.q.get(timeout=10.0).token for _ in script]
+        got = [engine.chan.q.get(timeout=10.0)[0].token for _ in script]
     finally:
         srv.stop()
     return engine.seen, got
