@@ -552,7 +552,11 @@ class EngineMetrics:
                 ("stream_chunks_total",
                  "SSE chunks written that carry an engine output (one per "
                  "streamed token; a tool-call stream's are not counted).",
-                 chunks)):
+                 chunks),
+                ("stream_writes_total",
+                 "Socket writes that carry such chunks (a stream's chunks "
+                 "of one engine step go out in one write).",
+                 stream.writes)):
             lines += _counter("fusioninfer:" + family, help_, labels, value)
         name = "fusioninfer:stream_delay_seconds"
         lines += [f"# HELP {name} From an output's hand-over to its stream "

@@ -28,7 +28,7 @@ from fusioninfer_tpu.engine.kv_cache import CacheConfig
 from fusioninfer_tpu.engine.kv_transfer import HTTPPullConnector, KVTransferError
 from fusioninfer_tpu.engine.metrics import EngineMetrics
 from fusioninfer_tpu.engine.sampler import SamplingParams
-from fusioninfer_tpu.engine.tokenizer import load_tokenizer
+from fusioninfer_tpu.engine.tokenizer import detokenizer, load_tokenizer
 from fusioninfer_tpu.models.config import get_preset
 from fusioninfer_tpu.resilience import RetryBudgetExhausted, RetryPolicy
 from fusioninfer_tpu.utils import spans
@@ -77,31 +77,51 @@ _STREAM_IDLE_TIMEOUT_S = 300.0
 
 
 class _RequestChannel:
-    """Blocking bridge from engine thread to an HTTP handler thread.  An
-    item is queued with the ``time.perf_counter_ns`` of its ``put``;
-    ``stream()`` leaves the stamp of the item it last yielded in
-    ``published_ns``: its stream chunk's delay is counted from there."""
+    """Blocking bridge from engine thread to an HTTP handler thread.  A
+    ``put`` hands over one output, or a list of one step's outputs for
+    this request (one queue item, one wake-up), queued with the
+    ``time.perf_counter_ns`` of the ``put``.  ``stream()`` yields one
+    output at a time whatever was put, and leaves the stamp of the item
+    it last yielded in ``published_ns``: its stream chunk's delay is
+    counted from there.  ``ready()`` tells the consumer whether what
+    follows the output last yielded comes without waiting for the engine:
+    more of its hand-off, or the stream's end."""
 
     def __init__(self):
-        self.q: queue.Queue = queue.Queue()  # (item, put stamp)
+        self.q: queue.Queue = queue.Queue()  # (item or list, put stamp)
         self.published_ns = 0
+        self.left = 0  # outputs of the current hand-off not yet yielded
+        self.ended = False  # the terminal item was yielded
 
     def put(self, item) -> None:
         self.q.put((item, time.perf_counter_ns()))
 
+    def ready(self) -> bool:
+        return self.left > 0 or self.ended
+
     def stream(self):
-        while True:
-            try:
-                item, self.published_ns = self.q.get(
-                    timeout=_STREAM_IDLE_TIMEOUT_S)
-            except queue.Empty:
-                raise TimeoutError(
-                    "engine produced no stream output for "
-                    f"{_STREAM_IDLE_TIMEOUT_S:.0f}s — aborting the "
-                    "handler instead of holding it forever")
-            yield item
-            if item is None or item.finished:
-                return
+        try:
+            while True:
+                try:
+                    items, self.published_ns = self.q.get(
+                        timeout=_STREAM_IDLE_TIMEOUT_S)
+                except queue.Empty:
+                    raise TimeoutError(
+                        "engine produced no stream output for "
+                        f"{_STREAM_IDLE_TIMEOUT_S:.0f}s — aborting the "
+                        "handler instead of holding it forever")
+                if type(items) is not list:
+                    items = (items,)
+                self.left = len(items)
+                for item in items:
+                    self.left -= 1
+                    if item is None or item.finished:
+                        self.ended = True
+                        yield item
+                        return
+                    yield item
+        finally:  # the consumer reads no further (a stop string, say)
+            self.left, self.ended = 0, True
 
 
 class Draining(Exception):
@@ -148,6 +168,12 @@ class _MultiChannel:
 
     def __init__(self, chans: list[_RequestChannel]):
         self.chans = chans
+
+    def ready(self) -> bool:
+        """Some choice has more of its hand-off to come, or every
+        choice has ended: nothing waits for the engine."""
+        return (any(c.left for c in self.chans)
+                or all(c.ended for c in self.chans))
 
 
 class _Chunk(dict):
@@ -527,41 +553,48 @@ class EngineServer:
             self._publish(held)
 
     def _publish(self, outputs: list) -> None:
-        """Hand a step's outputs to their requests' stream handlers and
-        stamp the serving histograms (engine thread)."""
+        """Hand a step's outputs to their requests' stream handlers, one
+        hand-off a request (its outputs in order), and stamp the serving
+        histograms (engine thread)."""
         with self._loop_clock.span("loop.publish", outputs=len(outputs)):
             now = time.monotonic()
+            by_request: dict[str, list] = {}
             for out in outputs:
-                with self._lock:
-                    chan = self._channels.get(out.request_id)
-                    meta = self._req_meta.get(out.request_id)
+                by_request.setdefault(out.request_id, []).append(out)
+            with self._lock:
+                found = [(self._channels.get(rid), self._req_meta.get(rid),
+                          outs) for rid, outs in by_request.items()]
+            for chan, meta, outs in found:
                 if meta is not None:
-                    tname = meta.get("tier")
-                    if out.is_first_token:
-                        self.metrics.ttft.observe(now - meta["arrival"])
-                        if (self.boot_t0 is not None
-                                and self.metrics.cold_start_ttft_s is None):
-                            # the server's FIRST first-token: boot →
-                            # serving, the AOT warm-start gauge
-                            self.metrics.cold_start_ttft_s = (
-                                now - self.boot_t0)
-                        if tname is not None:
-                            self.metrics.tier_ttft[tname].observe(
-                                now - meta["arrival"])
-                    else:
-                        self.metrics.tpot.observe(now - meta["last_token_time"])
-                        if tname is not None:
-                            self.metrics.tier_tpot[tname].observe(
-                                now - meta["last_token_time"])
-                    meta["last_token_time"] = now
-                    if out.finished:
-                        self.metrics.e2e_latency.observe(now - meta["arrival"])
-                        # a finished request whose client drains slowly
-                        # keeps its channel registered — the watchdog
-                        # must not count it as stalled or expired
-                        meta["finished"] = True
+                    for out in outs:
+                        self._observe(meta, out, now)
                 if chan is not None:
-                    chan.put(out)
+                    chan.put(outs if len(outs) > 1 else outs[0])
+
+    def _observe(self, meta: dict, out: StepOutput, now: float) -> None:
+        """Stamp one output into the serving histograms (engine thread)."""
+        tname = meta.get("tier")
+        if out.is_first_token:
+            self.metrics.ttft.observe(now - meta["arrival"])
+            if (self.boot_t0 is not None
+                    and self.metrics.cold_start_ttft_s is None):
+                # the server's FIRST first-token: boot → serving, the AOT
+                # warm-start gauge
+                self.metrics.cold_start_ttft_s = now - self.boot_t0
+            if tname is not None:
+                self.metrics.tier_ttft[tname].observe(now - meta["arrival"])
+        else:
+            self.metrics.tpot.observe(now - meta["last_token_time"])
+            if tname is not None:
+                self.metrics.tier_tpot[tname].observe(
+                    now - meta["last_token_time"])
+        meta["last_token_time"] = now
+        if out.finished:
+            self.metrics.e2e_latency.observe(now - meta["arrival"])
+            # a finished request whose client drains slowly keeps its
+            # channel registered — the watchdog must not count it as
+            # stalled or expired
+            meta["finished"] = True
 
     # -- watchdog ------------------------------------------------------------
 
@@ -1360,6 +1393,15 @@ class EngineServer:
         )
         created = created or int(time.time())
         tokens: list[int] = []
+        detok = detokenizer(self.tokenizer)
+        # the decoded text of ``tokens`` is ``text + tail``: ``text`` no
+        # later token changes, ``tail`` an unfinished character as a
+        # whole-list decode renders it now; a step reads the window of it
+        # a new token can touch, so it costs O(new text), not O(position)
+        text = tail = ""
+        # a stop match not found before ends in the new text, so it starts
+        # at most this far before it
+        lookback = max(map(len, stops), default=1) - 1
         emitted = 0  # chars already sent
         try:
             for out in chan.stream():
@@ -1371,24 +1413,29 @@ class EngineServer:
                     counted = not is_error and not (
                         out.finished and out.finish_reason == "stop"
                         and out.token == self.tokenizer.eos_token_id)
+                    # ``win`` is the decoded text from ``start`` on
+                    start = min(emitted, max(0, len(text) - lookback))
                     if counted:
                         tokens.append(out.token)
-                    full = self.tokenizer.decode(tokens)
+                        stable, tail = detok.add(out.token)
+                        text += stable
+                    win = text[start:] + tail
                     finish = (out.finish_reason or "length") if out.finished else None
                     if stops:
-                        hit = _find_stop(full, stops)
+                        hit = _find_stop(win, stops)
                         if hit is not None:
                             # OpenAI semantics: the stop sequence is excluded
-                            full, finish = full[:hit], "stop"
+                            win, finish = win[:hit], "stop"
                             # drop the tokens past the cut so streamed usage
                             # counts match the non-streaming path exactly
-                            while tokens and len(
-                                    self.tokenizer.decode(tokens[:-1])) >= hit:
+                            # (the stream's last chunk: whole-list decodes)
+                            while tokens and len(self.tokenizer.decode(
+                                    tokens[:-1])) >= start + hit:
                                 tokens.pop()
                                 counted = False  # its text never ships
                             self._cancel_chan(chan)
                         elif not out.finished:
-                            full = full[: len(full) - _held_back(full, stops)]
+                            win = win[: len(win) - _held_back(win, stops)]
                     if finish is None:
                         # hold back trailing replacement chars: a multi-byte
                         # utf-8 sequence split across deltas decodes as
@@ -1397,8 +1444,9 @@ class EngineServer:
                         # would freeze the mojibake into the client's text.
                         # (gated on finish, not out.finished: a stop-string
                         # cut is this stream's LAST chunk and must flush)
-                        full = full[:len(full.rstrip("�"))]
-                    delta, emitted = full[emitted:], max(emitted, len(full))
+                        win = win[:len(win.rstrip("�"))]
+                    delta = win[emitted - start:]
+                    emitted = max(emitted, start + len(win))
                     if echo_prefix:  # OpenAI echo: prompt leads the stream
                         delta, echo_prefix = echo_prefix + delta, ""
                     # a logprobs entry ships only for tokens whose text is
@@ -2228,11 +2276,16 @@ class EngineServer:
             def _stream(self, body: dict, chat: bool) -> None:
                 chan, chunks = server.stream_completion(body, chat=chat)
                 try:
-                    self._send_sse(chunks)
+                    self._send_sse(chunks, chan)
                 finally:
                     server.abort(chan)
 
-            def _send_sse(self, chunks) -> None:
+            def _send_sse(self, chunks, chan) -> None:
+                """Each chunk is its own ``data:`` event; the events
+                rendered while ``chan`` has more ready go out together, in
+                ONE chunked-transfer write once it has none (a step's
+                hand-off: one write), and ``[DONE]`` with the chunked EOF
+                in the last."""
                 self.send_response(200)
                 self.send_header("Content-Type", "text/event-stream")
                 self.send_header("Cache-Control", "no-cache")
@@ -2240,9 +2293,26 @@ class EngineServer:
                 self.end_headers()
 
                 stream = server.metrics.stream
+                events: list[bytes] = []  # rendered, not yet written
+                # of the events: render time, token chunks, their stamps
+                render_ns = tokens = published_ns = 0
 
-                def write_chunk(payload: bytes) -> None:
-                    self.wfile.write(f"{len(payload):X}\r\n".encode() + payload + b"\r\n")
+                def flush(end: bytes = b"") -> None:
+                    nonlocal render_ns, tokens, published_ns
+                    if not events:
+                        self.wfile.write(end)
+                        return
+                    body = b"".join(events)
+                    events.clear()
+                    t1 = stream.now()
+                    with spans.annotation("stream.write"):
+                        self.wfile.write(
+                            b"%X\r\n%s\r\n%s" % (len(body), body, end))
+                    t2 = stream.now()
+                    stream.written(render_ns, t2 - t1,
+                                   tokens * t2 - published_ns if tokens
+                                   else None, tokens)
+                    render_ns = tokens = published_ns = 0
 
                 # this thread's CPU while it streams (an n > 1 request's
                 # choices are rendered on pump threads: not counted)
@@ -2250,21 +2320,20 @@ class EngineServer:
                     for chunk in chunks:
                         cpu.tick()
                         if chunk is None:
-                            write_chunk(b"data: [DONE]\n\n")
+                            events.append(b"data: [DONE]\n\n")
                             continue
                         t0 = stream.now()
                         with spans.annotation("stream.render"):
-                            payload = f"data: {json.dumps(chunk)}\n\n".encode()
-                        t1 = stream.now()
-                        with spans.annotation("stream.write"):
-                            write_chunk(payload)
-                        t2 = stream.now()
+                            events.append(
+                                f"data: {json.dumps(chunk)}\n\n".encode())
+                        render_ns += stream.now() - t0
                         if isinstance(chunk, _Chunk):
-                            stream.written(chunk.render_ns + t1 - t0, t2 - t1,
-                                           t2 - chunk.published_ns)
-                        else:
-                            stream.written(t1 - t0, t2 - t1)
-                    write_chunk(b"")  # chunked EOF
+                            render_ns += chunk.render_ns
+                            tokens += 1
+                            published_ns += chunk.published_ns
+                        if not chan.ready():
+                            flush()
+                    flush(b"0\r\n\r\n")  # chunked EOF
 
             def log_message(self, *args):
                 pass

@@ -9,9 +9,63 @@ instead.
 
 from __future__ import annotations
 
+import codecs
 import logging
 
 logger = logging.getLogger("fusioninfer.tokenizer")
+
+
+class Utf8Detokenizer:
+    """Incremental detokenisation for a byte-level vocabulary (an id is
+    fixed bytes): the ids' bytes go through an incremental UTF-8 decoder,
+    which keeps the bytes of an unfinished character and nothing else.
+    ``add`` returns ``(stable, tail)``: the text no later id can change,
+    and the unfinished tail as a whole-list ``decode`` renders it now
+    (U+FFFD).  The stables joined, plus the last tail, are ``decode`` of
+    every id added: O(the id's bytes) a call, not O(position)."""
+
+    __slots__ = ("_bytes_of", "_utf8")
+
+    def __init__(self, bytes_of):
+        self._bytes_of = bytes_of
+        self._utf8 = codecs.getincrementaldecoder("utf-8")("replace")
+
+    def add(self, token: int) -> tuple[str, str]:
+        stable = self._utf8.decode(self._bytes_of(token))
+        tail = self._utf8.getstate()[0]
+        return stable, tail.decode("utf-8", "replace") if tail else ""
+
+
+class OffsetDetokenizer:
+    """Incremental detokenisation over any ``decode`` (vLLM's prefix and
+    read offsets): each call decodes the ids since the last stable point
+    twice, with and without what arrived after it, and keeps the
+    difference unless it ends in U+FFFD (an unfinished character), which
+    stays the tail until a later id completes it."""
+
+    __slots__ = ("_decode", "_ids", "_prefix", "_read")
+
+    def __init__(self, decode):
+        self._decode = decode
+        self._ids: list[int] = []
+        self._prefix = self._read = 0
+
+    def add(self, token: int) -> tuple[str, str]:
+        ids = self._ids
+        ids.append(token)
+        prefix = self._decode(ids[self._prefix:self._read])
+        text = self._decode(ids[self._prefix:])
+        if len(text) > len(prefix) and not text.endswith("�"):
+            self._prefix, self._read = self._read, len(ids)
+            return text[len(prefix):], ""
+        return "", text[len(prefix):]
+
+
+def detokenizer(tokenizer):
+    """The incremental detokeniser a tokenizer offers, else offsets over
+    its ``decode``."""
+    make = getattr(tokenizer, "detokenizer", None)
+    return make() if make is not None else OffsetDetokenizer(tokenizer.decode)
 
 
 class ByteTokenizer:
@@ -21,6 +75,7 @@ class ByteTokenizer:
     BOS_ID = 1
     EOS_ID = 2
     OFFSET = 3
+    _PIECES = (b"",) * OFFSET + tuple(bytes((b,)) for b in range(256))
 
     @property
     def vocab_size(self) -> int:
@@ -40,6 +95,11 @@ class ByteTokenizer:
         # under random or mismatched weights
         data = bytes(i - self.OFFSET for i in ids if self.OFFSET <= i < self.OFFSET + 256)
         return data.decode("utf-8", errors="replace")
+
+    def detokenizer(self) -> Utf8Detokenizer:
+        pieces = self._PIECES
+        return Utf8Detokenizer(
+            lambda i: pieces[i] if 0 <= i < len(pieces) else b"")
 
 
 class TrieTokenizer:
@@ -96,6 +156,11 @@ class TrieTokenizer:
         out = b"".join(self._tokens[i] or b"" for i in ids
                        if 0 <= i < len(self._tokens))
         return out.decode("utf-8", errors="replace")
+
+    def detokenizer(self) -> Utf8Detokenizer:
+        tokens = self._tokens
+        return Utf8Detokenizer(
+            lambda i: (tokens[i] or b"") if 0 <= i < len(tokens) else b"")
 
 
 class HFTokenizer:

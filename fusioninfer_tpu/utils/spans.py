@@ -14,8 +14,8 @@ plane on the profiler's clock, the one the device planes use.
 
 A ``SpanClock`` belongs to ONE thread (the engine's): no lock is taken,
 the ``/metrics`` thread only reads the pre-seeded dicts.  The stream
-handler threads' side is a ``StreamClock``, lighter: a chunk is a few
-wall-clock reads, and ``annotation``, which decides for every span,
+handler threads' side is a ``StreamClock``, lighter: a socket write is a
+few wall-clock reads, and ``annotation``, which decides for every span,
 opens the same ``TraceAnnotation`` while capturing.  A thread's CPU clock is a system call (5.8 µs a read on
 a TPU v5e host against 0.1 µs for the wall), so handlers read theirs at
 most once a second.
@@ -185,30 +185,33 @@ _NO_ANNOTATION = contextlib.nullcontext()
 class StreamClock:
     """The stream handlers' side, one set of totals for every handler
     thread of a server (one per connection), added under one small lock
-    once a chunk: the wall time rendering chunks (a token's text, stop
-    check, chunk and its JSON) and writing them, the chunks that carry a
-    token, and the sum of their delays since their tokens were published.
-    No ``_Span`` here: a handler does a chunk's accounting with a few
-    wall-clock reads, and reads its thread's CPU clock (a system call) as
-    it starts streaming, at most once a ``CPU_READ_NS`` and as it stops
-    (``streaming``)."""
+    once a socket write: the wall time rendering chunks (a token's text,
+    stop check, chunk and its JSON) and writing them, the chunks that
+    carry a token, the sum of their delays since their tokens were
+    published, and the writes that carried them.  No ``_Span`` here: a
+    handler does a write's accounting with a few wall-clock reads, and
+    reads its thread's CPU clock (a system call) as it starts streaming,
+    at most once a ``CPU_READ_NS`` and as it stops (``streaming``)."""
 
     def __init__(self, now=time.perf_counter_ns, cpu=time.thread_time_ns):
         self.now, self.thread_cpu = now, cpu
         self.render_ns = self.write_ns = self.cpu_total_ns = 0
-        self.chunks = self.delay_ns = 0
+        self.chunks = self.delay_ns = self.writes = 0
         self._lock = threading.Lock()
 
     def written(self, render_ns: int, write_ns: int,
-                delay_ns: int | None = None) -> None:
-        """A chunk was rendered and written; ``delay_ns``: since its
-        token was published, where it carries one."""
+                delay_ns: int | None = None, chunks: int = 1) -> None:
+        """Chunks were rendered and written in one write; ``delay_ns``:
+        the sum, over the ``chunks`` of them that carry a token, of each
+        one's time since its token was published (None where none
+        does)."""
         with self._lock:
             self.render_ns += render_ns
             self.write_ns += write_ns
             if delay_ns is not None:
-                self.chunks += 1
+                self.chunks += chunks
                 self.delay_ns += delay_ns
+                self.writes += 1
 
     def streaming(self) -> "_ThreadCpu":
         """Around one thread's streaming loop: its CPU time from entry to

@@ -290,6 +290,8 @@ HOST_COUNTERS = {
     "fusioninfer:stream_cpu_seconds_total": 2.0,
     "fusioninfer:stream_delay_seconds_sum": 30.0,
     "fusioninfer:stream_delay_seconds_count": 6000.0,
+    "fusioninfer:stream_chunks_total": 6000.0,
+    "fusioninfer:stream_writes_total": 2500.0,
     "fusioninfer:host_step_dispatch_seconds_total": 8.0,
     "fusioninfer:engine_cpu_step_dispatch_seconds_total": 3.0,
     "fusioninfer:gc_seconds_total": 0.2,
@@ -300,7 +302,7 @@ HOST_COUNTERS = {
 @pytest.mark.parametrize("name, want", [
     ("stream_cpu_ms_per_step", 5.0), ("stream_delay_mean_ms", 5.0),
     ("host_dispatch_offcpu_pct", 62.5), ("gc_ms_per_step", 0.5),
-    ("engine_stall_s_in_window", 0.75)])
+    ("engine_stall_s_in_window", 0.75), ("stream_chunks_per_write", 2.4)])
 def test_the_host_readers_on_a_recorded_run(name, want):
     cfg = config_of("qwen3-1.7b")
     assert _reader(name)(_Run(cfg, {}, dict(HOST_COUNTERS))) == \

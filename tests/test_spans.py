@@ -32,7 +32,7 @@ STREAM_FAMILIES = [
     "fusioninfer:stream_render_seconds_total",
     "fusioninfer:stream_write_seconds_total",
     "fusioninfer:stream_cpu_seconds_total", "fusioninfer:stream_chunks_total",
-    "fusioninfer:stream_delay_seconds_sum",
+    "fusioninfer:stream_writes_total", "fusioninfer:stream_delay_seconds_sum",
     "fusioninfer:stream_delay_seconds_count"]
 FAMILIES = HOST_FAMILIES + CPU_FAMILIES + STREAM_FAMILIES + [
     "fusioninfer:engine_loop_seconds_total",
@@ -223,6 +223,37 @@ def test_stream_chunks_and_delays_count_the_items_taken(served):
     assert last["fusioninfer:stream_chunks_total"] == streamed
     assert last["fusioninfer:stream_delay_seconds_count"] == streamed
     assert last["fusioninfer:stream_delay_seconds_sum"] > 0
+
+
+def test_a_write_carries_a_step_of_a_stream(served):
+    """One socket write a hand-off: never more writes than token chunks
+    (a one-token engine writes each chunk alone)."""
+    _, first, _, last, streamed = served
+    assert first["fusioninfer:stream_writes_total"] == 0
+    assert 0 < last["fusioninfer:stream_writes_total"] <= streamed
+
+
+def test_a_burst_streams_in_fewer_writes_than_chunks():
+    """On a burst engine a step hands a stream its span of tokens, and
+    the stream writes them in one write: fewer writes than chunks, and
+    still one chunk and one delay per token."""
+    engine = NativeEngine(CFG, cache_cfg=CACHE, max_batch_size=2, seed=0,
+                          decode_burst_steps=4)
+    srv = EngineServer(model="qwen3-tiny", host="127.0.0.1", port=0,
+                       engine=engine)
+    srv.start()
+    try:
+        before = metrics(srv)
+        streamed = stream(srv, "a burst ", 12)
+        after = metrics(srv)
+    finally:
+        srv.stop()
+    chunks, writes, delays = (
+        after[f] - before[f] for f in ("fusioninfer:stream_chunks_total",
+                                       "fusioninfer:stream_writes_total",
+                                       "fusioninfer:stream_delay_seconds_count"))
+    assert chunks == delays == streamed == 12
+    assert 1 <= writes < chunks
 
 
 def test_stream_render_write_and_cpu_grow(served):
