@@ -351,7 +351,8 @@ def _same(a, b) -> bool:
 
 
 # what a cache that is not ONE pool of K/V heads refuses at start-up, by
-# the flag's name (latent_cache_refusal, kind_cache_refusal)
+# the flag's name (latent_cache_refusal, kind_cache_refusal,
+# sparse_cache_refusal)
 _NOT_YET = {
     "mesh": "a device mesh (--tensor-parallel-size / ep / sp)",
     "int8_weights": "int8 weights (--quantization int8)",
@@ -401,6 +402,28 @@ def kind_cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
         return None
     return _refusal(f"model {cfg.name} keeps its KV cache by layer kind "
                     f"(full and windowed attention mixed)", asked)
+
+
+def sparse_cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
+    """The message that refuses a deployment of a model with learned
+    sparse attention, whose pages carry an indexer key a position
+    (``cache["k_idx"]``), or None; ``asked`` as in
+    :func:`latent_cache_refusal`.  What ships, shards or rebuilds pages
+    reads K/V alone today: a mesh rule and a frame for the third array
+    (host tier, transfer, fabric, evacuation), int8 pages beside it, a
+    verify window through the selection, the indexer's matrices in the
+    int8, adapter and checkpoint paths (ROADMAP, "Reach")."""
+    if not cfg.is_sparse:
+        return None
+    return _refusal(f"model {cfg.name} keeps an indexer-key cache for "
+                    f"learned sparse attention", asked)
+
+
+def cache_refusal(cfg: ModelConfig, **asked) -> Optional[str]:
+    """The first of the three refusals that applies to ``cfg``."""
+    return (latent_cache_refusal(cfg, **asked)
+            or kind_cache_refusal(cfg, **asked)
+            or sparse_cache_refusal(cfg, **asked))
 
 
 class NativeEngine:
@@ -503,8 +526,7 @@ class NativeEngine:
             mesh=mesh is not None, int8_weights=cfg.quantization == "int8",
             int8_kv=self.cache_cfg.quantized, lora=lora_adapters,
             speculative=speculative_k, host_tier=host_kv_tier is not None)
-        refusal = (latent_cache_refusal(cfg, **asked)
-                   or kind_cache_refusal(cfg, **asked))
+        refusal = cache_refusal(cfg, **asked)
         if refusal:
             raise ValueError(refusal)
         self.max_batch_size = max_batch_size
@@ -826,6 +848,9 @@ class NativeEngine:
         # the expert layers' counters, summed on the device in the pool
         # tree (cache["moe_stats"], uint32) and read as differences
         self.moe_stats_total = {name: 0 for name in MOE_STATS}
+        # a sparse-attention model's positions scored by the indexer and
+        # chosen for attention, every layer (cache["dsa_stats"], read whole)
+        self.dsa_stats_total = {"scored": 0, "selected": 0}
         self._moe_stats_seen = np.zeros((len(MOE_STATS),), np.uint32)  # noqa:trace-dynamic-dim — fixed counter layout
         # AOT warm-start report (engine/aot.py::warmup stamps it; the
         # server renders it as fusioninfer:aot_cache_* metrics)
@@ -928,6 +953,17 @@ class NativeEngine:
             "max_pages_per_seq": cc.max_pages_per_seq,
             "kv_dtype": cc.kv_dtype,
             "kv_layout": "latent" if cfg.is_mla else "heads",
+            # learned sparse attention: the indexer, the exact selection
+            # and the kernels that serve it (None: dense attention)
+            "sparse_attention": ({
+                "topk": cfg.index_topk, "index_heads": cfg.index_n_heads,
+                "index_head_dim": cfg.index_head_dim,
+                "selection": ("exact:bisect" if attention == "flash"
+                              else "exact:top_k"),
+                "kernels": ("indexer_paged_scores+sparse_select"
+                            "+sparse_paged_attention"
+                            if attention == "flash" else "reference"),
+            } if cfg.is_sparse else None),
             # the period's layers, "full" | "window:<width>" then
             # "+rope" | "+nope" (one entry: every layer alike)
             "layer_pattern": [
@@ -964,8 +1000,7 @@ class NativeEngine:
         }
 
     def _refuse_if_latent(self, **asked) -> None:
-        refusal = (latent_cache_refusal(self.cfg, **asked)
-                   or kind_cache_refusal(self.cfg, **asked))
+        refusal = cache_refusal(self.cfg, **asked)
         if refusal:
             raise ValueError(refusal)
 
@@ -1004,6 +1039,16 @@ class NativeEngine:
         self._moe_stats_seen = now
         for name, d in zip(MOE_STATS, delta.tolist()):
             self.moe_stats_total[name] += d
+
+    def _drain_dsa_stats(self) -> None:
+        """Read the device's sparse-attention sums (low and high words),
+        when the newest pool tree is already computed: never a wait."""
+        stats = self.cache.get("dsa_stats")
+        if stats is None or not stats.is_ready():
+            return
+        for name, (lo, hi) in zip(("scored", "selected"),
+                                  np.asarray(stats).tolist()):
+            self.dsa_stats_total[name] = (hi << 32) | lo
 
     def set_token_byte_table(self, table) -> None:
         """Legacy single-byte form: [V] int32, token id → byte value or
@@ -2656,6 +2701,7 @@ class NativeEngine:
                 self._in_step_body = False
                 self._last_step_end = self._clock()
             self._drain_moe_stats()
+            self._drain_dsa_stats()
             return [o for o in outputs if o is not None]
 
     def _admit_half(self) -> list[StepOutput]:
@@ -3157,7 +3203,8 @@ class NativeEngine:
         the page↔block alignment the chain registration needs.
         Returns the number of pages parked (0 = nothing parkable)."""
         if (not self.prefix_caching or self.cfg.sliding_window is not None
-                or self.cfg.is_mla):  # latent pages: not parked yet (D4)
+                or self.cfg.is_mla  # latent pages: not parked yet (D4)
+                or self.cfg.is_sparse):  # nor indexer-key pages
             return 0
         ps = self.cache_cfg.page_size
         pages = self.alloc.pages_of(request.request_id)

@@ -36,6 +36,16 @@ materialises the pages a step writes and releases those that fell below
 the window; placeholders keep the positions).  A model of ONE kind,
 windowed or full, keeps the one pool under today's names.
 
+A model with learned sparse attention (``cfg.is_sparse``) adds a third
+paged array, ``cache["k_idx"]`` ``[L, n_pages, ps, W]`` (``W`` =
+``cfg.index_row_width``: the key's 64 values in a whole 128-lane tile, as
+the device stores a 64-wide minor dimension anyway): one indexer key a
+position and layer, under the SAME page ids and page
+tables as ``k`` / ``v``, so the allocator, admission and whole-page reuse
+need no second list; and ``cache["dsa_stats"]``, the positions the
+indexer scored and the attention chose, summed on the device as (low,
+high) uint32 words.
+
 Host-side: a free-list allocator (:class:`PageAllocator`) — allocation is
 a Python-time concern, never traced.
 """
@@ -127,6 +137,16 @@ def init_kv_cache(cfg: ModelConfig, cache_cfg: CacheConfig) -> dict:
         return {"kv": jnp.zeros(
             (cfg.n_cache_layers, 1, cache_cfg.n_pages, cache_cfg.page_size,
              cfg.latent_row_width), cfg.jax_dtype), **stats}
+    if cfg.is_sparse:
+        if cache_cfg.quantized:
+            raise ValueError("int8 pages are not available beside an "
+                             "indexer-key cache")
+        stats = {**stats,
+                 "k_idx": jnp.zeros(
+                     (cfg.n_cache_layers, cache_cfg.n_pages,
+                      cache_cfg.page_size, cfg.index_row_width),
+                     cfg.jax_dtype),
+                 "dsa_stats": jnp.zeros((2, 2), jnp.uint32)}
     if cfg.cache_by_kind != cache_cfg.by_kind:
         raise ValueError(
             f"model {cfg.name} keeps "
@@ -178,7 +198,8 @@ def page_bytes(cfg: ModelConfig, page_size: int,
                kv_dtype: str = "model", pool: str = "") -> int:
     """Device bytes one KV page costs (k + v, or the latent rows; all
     layers of the pool ``pool``: a model of one layer kind has the one
-    pool "").  A latent row is priced at the width it is stored at."""
+    pool ""; the indexer keys of a sparse-attention model).  A latent
+    row is priced at the width it is stored at."""
     if cfg.is_mla:
         return (cfg.n_cache_layers * page_size * cfg.latent_row_width
                 * jnp.dtype(cfg.jax_dtype).itemsize)
@@ -186,7 +207,10 @@ def page_bytes(cfg: ModelConfig, page_size: int,
         per_token = cfg.head_dim * 1 + 4  # int8 values + one f32 scale
     else:
         per_token = cfg.head_dim * jnp.dtype(cfg.jax_dtype).itemsize
-    return 2 * cfg.n_pool_layers(pool) * page_size * cfg.n_kv_heads * per_token
+    index_keys = (cfg.n_cache_layers * page_size * cfg.index_row_width
+                  * jnp.dtype(cfg.jax_dtype).itemsize if cfg.is_sparse else 0)
+    return (2 * cfg.n_pool_layers(pool) * page_size * cfg.n_kv_heads
+            * per_token + index_keys)
 
 
 def window_pages_per_seq(cfg: ModelConfig, page_size: int,

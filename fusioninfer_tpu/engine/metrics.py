@@ -219,6 +219,14 @@ class EngineMetrics:
                 f"# TYPE fusioninfer:moe_{name}_total counter",
                 f"fusioninfer:moe_{name}_total{{{labels}}} {getattr(engine, 'moe_stats_total', {}).get(name, 0)}",
             )],
+            *[line for name, what in (
+                ("scored", "Cached positions the sparse-attention indexer scored, summed over layers (decode and chunk rows alike)"),
+                ("selected", "Positions the sparse attention attended over, summed over layers (at most the indexer's top-k a query)"),
+            ) for line in (
+                f"# HELP fusioninfer:dsa_positions_{name}_total {what}.",
+                f"# TYPE fusioninfer:dsa_positions_{name}_total counter",
+                f"fusioninfer:dsa_positions_{name}_total{{{labels}}} {getattr(engine, 'dsa_stats_total', {}).get(name, 0)}",
+            )],
             "# HELP vllm:num_preemptions_total Requests preempted to reclaim KV-cache pages.",
             "# TYPE vllm:num_preemptions_total counter",
             f"vllm:num_preemptions_total{{{labels}}} {engine.preemptions_total}",

@@ -127,6 +127,102 @@ def _add_moe_stats(cache: dict, stats) -> dict:
     return {**cache, "moe_stats": cache["moe_stats"] + stats}
 
 
+def _add_dsa_stats(cfg, cache: dict, positions, live) -> dict:
+    """Add one forward's sparse-attention counts to the pool tree's
+    running sums (``cache["dsa_stats"]``: the positions the indexer
+    scored and those the attention chose, every layer, each as (low,
+    high) uint32 words with the carry taken): a live token at position
+    ``p`` scores ``p + 1`` and chooses ``min(p + 1, index_topk)``."""
+    ctx = jnp.where(live, positions + 1, 0).astype(jnp.uint32)
+    add = jnp.stack([jnp.sum(ctx),
+                     jnp.sum(jnp.minimum(ctx, jnp.uint32(cfg.index_topk)))]
+                    ) * jnp.uint32(cfg.n_cache_layers)
+    st = cache["dsa_stats"]
+    lo = st[:, 0] + add
+    hi = st[:, 1] + (lo < add).astype(jnp.uint32)
+    return {**cache, "dsa_stats": jnp.stack([lo, hi], axis=1)}
+
+
+@jax.named_scope("kv_write")
+def _scatter_index_keys(cache: dict, l, k_idx, write_page, write_slot) -> dict:
+    """Write fresh indexer keys ``[T, Di]`` into layer ``l`` of
+    ``cache["k_idx"]`` ``[L, n_pages, ps, W]`` IN PLACE, zero-padded to the
+    stored width, at the pages and slots its K/V go to
+    (:func:`_scatter_latent`'s index form)."""
+    pool = cache["k_idx"]
+    rows = jnp.pad(k_idx.astype(pool.dtype),
+                   ((0, 0), (0, pool.shape[-1] - k_idx.shape[-1])))
+    return {**cache, "k_idx": pool.at[l, write_page, write_slot].set(rows)}
+
+
+def _sparse_paged_attend(cfg, cache, q, k, v, idx, cache_l, write, tables,
+                         items, *, use_kernel):
+    """What a sparse-attention layer does with its fresh rows in every
+    paged program (:func:`transformer.gqa_block`'s ``attend`` with the
+    indexer's projections ``idx``): write each token's K/V and indexer
+    key into layer ``cache_l`` IN PLACE, score the indexer over each
+    token's row (``attn/indexer``), choose the exact top ``index_topk``
+    (``attn/select``) and attend over those alone (``attn/sparse``) →
+    (cache, [T, 1, H * Hd]).  ``q`` [T, 1, H, Hd], ``k`` / ``v`` [T, 1,
+    KV, Hd]; ``tables`` [R, mp] the rows' pages; ``items``:
+    :func:`ops.sparse_attention.sparse_items` of the same rows."""
+    from fusioninfer_tpu.ops import dispatch
+    from fusioninfer_tpu.ops import sparse_attention as sa
+
+    q_i, w, k_i = (a[:, 0] for a in idx)
+    cache = _scatter_kv(cache, cache_l, k[:, 0], v[:, 0], *write,
+                        head_axis=1)
+    cache = _scatter_index_keys(cache, cache_l, k_i, *write)
+    H, Hd = cfg.n_heads, cfg.head_dim
+    KV, G = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    N, bq = items.tok.shape
+    C = tables.shape[1] * cache["k"].shape[3]
+    interpret = {"interpret": dispatch.kernel_interpret()} if use_kernel else {}
+    with jax.named_scope("attn"):
+        with jax.named_scope("indexer"):
+            score = (sa.indexer_paged_scores if use_kernel
+                     else sa.reference_indexer_paged_scores)
+            scores = score(jnp.moveaxis(sa.to_items(q_i, items), 2, 1),
+                           sa.to_items(w, items), cache["k_idx"], tables,
+                           items, layer=cache_l, **interpret)
+        with jax.named_scope("select"):
+            if use_kernel:
+                thr_s, thr_c = sa.sparse_select(scores, items, cfg.index_topk,
+                                                **interpret)
+            else:
+                thr_s, thr_c = sa.sparse_threshold(scores.reshape(N * bq, C),
+                                                   cfg.index_topk)
+        with jax.named_scope("sparse"):
+            qa = sa.to_items(q[:, 0], items).reshape(N, bq, KV, G, Hd)
+            qa = qa.transpose(0, 2, 3, 1, 4).reshape(N, KV, G * bq, Hd)
+            attend = (sa.sparse_paged_attention if use_kernel
+                      else sa.reference_sparse_paged_attention)
+            out = attend(qa, cache["k"], cache["v"], scores,
+                         thr_s.reshape(N, bq), thr_c.reshape(N, bq), tables,
+                         items, layer=cache_l, **interpret)
+            out = out.reshape(N, KV, G, bq, Hd).transpose(0, 3, 1, 2, 4)
+            attn = sa.from_items(out.reshape(N, bq, H * Hd), items)
+    return cache, attn[:, None, :].astype(q.dtype)
+
+
+def _sparse_body(cfg, body_inputs, x, positions, cache, live, write, tables,
+                 items, lora, adapter_ids, *, use_kernel):
+    """One sparse-attention block of flat tokens ``x`` [T, 1, D] (the
+    three paged programs share it) → the scan carry ``(x, cache)``."""
+    inputs, kind, cache_l = body_inputs
+    layer, layer_lora, _ = _layer_unpack(inputs, lora is not None)
+    layer = maybe_dequantize_tree(layer, cfg.jax_dtype)
+
+    def attend(q, k, v, cache, idx):
+        return _sparse_paged_attend(cfg, cache, q, k, v, idx, cache_l, write,
+                                    tables, items, use_kernel=use_kernel)
+
+    x, cache, stats = gqa_block(cfg, layer, x, positions[:, None], kind,
+                                attend, cache, live[:, None], layer_lora,
+                                adapter_ids)
+    return x, _add_moe_stats(cache, stats)
+
+
 def _cache_layer_of(cfg, l, i: int):
     """The pool's layer of attention ``i`` of stack layer ``l``: ``l``
     itself where a layer has one attention (nothing is traced for it)."""
@@ -342,6 +438,9 @@ def prefill(
     """
     B, S = tokens.shape
     ps = cache_cfg.page_size
+    if cfg.is_sparse:
+        return _sparse_prefill(cfg, cache_cfg, params, cache, tokens,
+                               true_lens, page_rows, lora, adapter_ids)
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
@@ -387,6 +486,47 @@ def prefill(
     return cache, lm_head(cfg, params, last)
 
 
+def _sparse_prefill(cfg, cache_cfg, params, cache, tokens, true_lens,
+                    page_rows, lora, adapter_ids):
+    """:func:`prefill` of a sparse-attention model: the B prompts as rows
+    of ONE flat token axis (row ``b`` = tokens ``[b S, b S + len_b)`` at
+    positions from 0), written into their pages and attended over them
+    layer by layer, as the ragged step does."""
+    from fusioninfer_tpu.ops import dispatch
+    from fusioninfer_tpu.ops.sparse_attention import (
+        SPARSE_BLOCK_Q,
+        sparse_items,
+    )
+
+    B, S = tokens.shape
+    ps = cache_cfg.page_size
+    T = B * S
+    use_kernel = dispatch.resolve_attn(cfg.attn_impl) == "flash"
+    off = jnp.broadcast_to(jnp.arange(S), (B, S))
+    live = (off < true_lens[:, None]).reshape(T)
+    positions = off.reshape(T)
+    write_page = jnp.where(
+        live, jnp.take_along_axis(page_rows, off // ps, axis=1).reshape(T),
+        cache_cfg.trash_page)
+    write = (write_page, positions % ps)
+    items = sparse_items(jnp.arange(B, dtype=jnp.int32) * S, true_lens,
+                         jnp.zeros((B,), jnp.int32), T, SPARSE_BLOCK_Q)
+    ids = (None if adapter_ids is None
+           else jnp.repeat(adapter_ids, S, total_repeat_length=T))
+    x = embed_lookup(params["embed"], tokens.reshape(T), cfg.jax_dtype)
+    cache = _add_dsa_stats(cfg, cache, positions, live)
+
+    def body(carry, inputs, kind, cache_l):
+        return _sparse_body(cfg, (inputs, kind, cache_l), carry[0],
+                            positions, carry[1], live, write, page_rows,
+                            items, lora, ids, use_kernel=use_kernel)
+
+    x, cache = _scan_layers(cfg, params, lora, body, (x[:, None, :], cache))
+    x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_eps).reshape(B, S, -1)
+    last = x[jnp.arange(B), jnp.maximum(true_lens - 1, 0)]  # [B, D]
+    return cache, lm_head(cfg, params, last)
+
+
 def _decode_step_impl(
     cfg: ModelConfig,
     cache_cfg: CacheConfig,
@@ -416,6 +556,29 @@ def _decode_step_impl(
 
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)[:, None, :]  # [B, 1, D]
     pos = positions[:, None]  # [B, 1]
+    if cfg.is_sparse:
+        # B rows of one token each, as the ragged step lays them out
+        from fusioninfer_tpu.ops.sparse_attention import (
+            SPARSE_BLOCK_Q_DECODE,
+            sparse_items,
+        )
+
+        items = sparse_items(jnp.arange(B, dtype=jnp.int32),
+                             active.astype(jnp.int32), positions, B,
+                             SPARSE_BLOCK_Q_DECODE)
+        write = (jnp.where(active, page_tables[jnp.arange(B), positions // ps],
+                           cache_cfg.trash_page), positions % ps)
+        cache = _add_dsa_stats(cfg, cache, positions, active)
+
+        def sparse(carry, inputs, kind, cache_l):
+            return _sparse_body(cfg, (inputs, kind, cache_l), carry[0],
+                                positions, carry[1], active, write,
+                                page_tables, items, lora, adapter_ids,
+                                use_kernel=use_kernel)
+
+        x, cache = _scan_layers(cfg, params, lora, sparse, (x, cache))
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return cache, lm_head(cfg, params, x[:, 0])
 
     # per pool of the cache (one, or the full and the window kind's):
     # where this step's token lands, the ONE ragged kernel's degenerate
@@ -723,6 +886,24 @@ def fused_step(
 
     x = embed_lookup(params["embed"], tokens, cfg.jax_dtype)[:, None, :]
     pos2 = positions[:, None]  # [T, 1]
+    if cfg.is_sparse:
+        from fusioninfer_tpu.ops.sparse_attention import (
+            SPARSE_BLOCK_Q,
+            sparse_items,
+        )
+
+        items = sparse_items(q_begins, q_lens, row_starts, T, SPARSE_BLOCK_Q)
+        cache = _add_dsa_stats(cfg, cache, positions, live)
+
+        def sparse(carry, inputs, kind, cache_l):
+            return _sparse_body(cfg, (inputs, kind, cache_l), carry[0],
+                                positions, carry[1], live, write[""],
+                                page_tables, items, lora, adapter_tok,
+                                use_kernel=use_kernel)
+
+        x, cache = _scan_layers(cfg, params, lora, sparse, (x, cache))
+        return _fused_heads(cfg, params, cache, x, sel, chunk_sel,
+                            decode_hidden)
 
     rows = {pool: (tables, row_starts, q_begins, q_lens)
             for pool, (tables, _, _) in pools.items()}
@@ -803,6 +984,13 @@ def fused_step(
         return x, _add_moe_stats(cache, stats)
 
     x, cache = _scan_layers(cfg, params, lora, body, (x, cache))
+    return _fused_heads(cfg, params, cache, x, sel, chunk_sel, decode_hidden)
+
+
+def _fused_heads(cfg, params, cache, x, sel, chunk_sel, decode_hidden):
+    """:func:`fused_step`'s tail: the final norm, then the decode group's
+    and the chunk rows' heads through separate ``lm_head`` calls."""
+    T = x.shape[0]
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     h = x[:, 0]  # [T, D]
     idx = jnp.clip(sel.astype(jnp.int32), 0, T - 1)  # [B, W]

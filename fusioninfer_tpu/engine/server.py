@@ -2795,19 +2795,16 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     params = None
     if load_hf and load_ckpt:
         raise SystemExit("--load-hf and --load-checkpoint are mutually exclusive")
-    from fusioninfer_tpu.engine.engine import (
-        kind_cache_refusal,
-        latent_cache_refusal,
-    )
+    from fusioninfer_tpu.engine.engine import cache_refusal
 
     if load_hf or load_ckpt:
-        # models/loader.py has no name map for latent attention nor for a
-        # layer pattern: a preset that keeps a latent cache, or a cache by
-        # layer kind, is refused before anything is read
+        # models/loader.py has no name map for latent attention, a layer
+        # pattern or an indexer: a preset that keeps a latent cache, a
+        # cache by layer kind or indexer keys is refused before anything
+        # is read
         try:
             preset = get_preset(args.model)
-            refusal = (latent_cache_refusal(preset, checkpoint=True)
-                       or kind_cache_refusal(preset, checkpoint=True))
+            refusal = cache_refusal(preset, checkpoint=True)
         except KeyError:
             refusal = None
         if refusal:
@@ -2845,8 +2842,9 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
     else:
         cfg = get_preset(args.model)
         model_name = args.model
-    # what a latent (MLA) cache, or a cache kept by layer kind, does not
-    # support yet exits HERE, by the flag's name, before any weight is drawn
+    # what a latent (MLA) cache, a cache kept by layer kind or an
+    # indexer-key cache does not support yet exits HERE, by the flag's
+    # name, before any weight is drawn
     asked = dict(
         mesh=args.tensor_parallel_size != 1 or jax.process_count() > 1,
         int8_weights=quant == "int8",
@@ -2858,8 +2856,7 @@ def _engine_from_args(args) -> tuple[NativeEngine, str]:
         kv_fabric=getattr(args, "kv_peer", None),
         evacuate=(getattr(args, "evacuate_grace_s", 0)
                   or getattr(args, "evacuate_peer", None)))
-    refusal = (latent_cache_refusal(cfg, **asked)
-               or kind_cache_refusal(cfg, **asked))
+    refusal = cache_refusal(cfg, **asked)
     if refusal:
         raise SystemExit(refusal)
     if quant != "none" and cfg.quantization == "none":

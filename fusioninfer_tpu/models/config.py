@@ -134,6 +134,23 @@ class ModelConfig:
     # layer, or "layer_input", the layer's RAW input before attention
     # (SmallThinker: the routing is known while attention still runs)
     router_input: str = "mlp_norm"
+    # learned sparse attention (DeepSeek Sparse Attention's indexer): a
+    # query attends over the ``index_topk`` positions of highest indexer
+    # score alone.  ``index_n_heads`` indexer heads of ``index_head_dim``
+    # (rotary on the leading half) score against ONE indexer key a
+    # position, cached beside K/V in ``cache["k_idx"]``.  0 = dense.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+
+    @property
+    def is_sparse(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def index_row_width(self) -> int:
+        """Width an indexer key is STORED at: whole 128-lane tiles."""
+        return -(-self.index_head_dim // 128) * 128
 
     @property
     def layer_kinds(self) -> tuple[LayerKind, ...]:
@@ -277,6 +294,16 @@ class ModelConfig:
         assert not ((self.n_zero_experts or self.router_score_bias)
                     and (self.norm_topk or self.n_group > 1)), \
             "identity experts and the score bias go with plain softmax scores"
+        if self.is_sparse:
+            assert (self.index_n_heads > 0 and self.index_head_dim > 0
+                    and self.index_head_dim % 4 == 0), \
+                "sparse attention needs indexer heads of an even rotary half"
+            assert not (self.is_mla or self.layer_pattern is not None
+                        or self.sliding_window is not None), \
+                "the indexer is drawn for full GQA attention of one kind"
+        else:
+            assert not (self.index_n_heads or self.index_head_dim), \
+                "indexer fields without index_topk"
         if self.is_mla:
             assert min(self.q_lora_rank, self.qk_nope_dim, self.qk_rope_dim,
                        self.v_head_dim) > 0, "MLA needs all of its widths"
@@ -629,5 +656,64 @@ register_preset(
         n_experts_active=2,
         moe_d_ff=64,
         **_SMALLTHINKER,
+    )
+)
+
+# Keye-VL-2.0-30B-A3B's language model (huggingface.co/Kwai-Keye/
+# Keye-VL-2.0-30B-A3B config.json): Qwen3-30B-A3B's widths (GQA 32 / 4
+# heads with QK-norm, 128 experts of width 768, 8 a token, softmax over
+# the chosen) with a DeepSeek-Sparse-Attention indexer in every layer
+# (``sa_config``: 16 indexer heads of 64, one indexer key head, top
+# 2048).  Text positions: the three M-RoPE sections carry one position,
+# which is 1-D rotary at theta 1e7.
+_KEYE_VL2 = dict(
+    qk_norm=True,
+    tie_embeddings=False,
+    rope_theta=10_000_000.0,
+    norm_topk=True,
+    index_n_heads=16,
+    index_head_dim=64,
+)
+
+# Stage 0 of a 12-stage pipeline at published widths (PERF.md section
+# 4): 4 of the 48 layers, all 128 experts of each, the whole vocabulary
+# and the head.
+register_preset(
+    ModelConfig(
+        name="keye-vl2-30b-a3b",
+        vocab_size=151_936,
+        d_model=2048,
+        n_layers=4,
+        n_heads=32,
+        n_kv_heads=4,
+        head_dim=128,
+        d_ff=6144,
+        max_seq_len=65_536,
+        n_experts=128,
+        n_experts_active=8,
+        moe_d_ff=768,
+        index_topk=2048,
+        **_KEYE_VL2,
+    )
+)
+
+# The same architecture at a test size: top 24 of contexts to 512, so a
+# 16-token page is crossed by the selection; 8 experts, 2 a token.
+register_preset(
+    ModelConfig(
+        name="keye-vl2-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=32,
+        d_ff=256,
+        max_seq_len=4096,
+        n_experts=8,
+        n_experts_active=2,
+        moe_d_ff=64,
+        index_topk=24,
+        **{**_KEYE_VL2, "index_n_heads": 4, "index_head_dim": 16},
     )
 )

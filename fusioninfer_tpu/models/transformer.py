@@ -504,9 +504,10 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     One homogeneous stack (``params["layers"]``) is drawn a whole matrix
     at a time under ``split(key, 12)[slot]``.  A model with latent
     attention (and with it, leading dense layers) or with a layer
-    pattern is drawn a layer at a time by :func:`_init_stacks`."""
+    pattern or an indexer is drawn a layer at a time by
+    :func:`_init_stacks`."""
     cfg.validate()
-    if cfg.is_mla or cfg.layer_pattern is not None:
+    if cfg.is_mla or cfg.layer_pattern is not None or cfg.is_sparse:
         return _init_stacks(cfg, key)
     dtype = cfg.jax_dtype
     L, D, H, KV, Hd, F = (
@@ -558,6 +559,7 @@ STACK_SLOTS = {
     "ws_gate": 24, "ws_up": 25, "ws_down": 26,
     "wd_gate": 27, "wd_up": 28, "wd_down": 29,
     "wq": 30, "wk": 31, "wv": 32,
+    "wiq": 40, "wik": 41, "ww": 42,
 }
 DENSE_STACK_SLOT_OFFSET = 100  # the leading dense layers' matrices
 # what a shortcut-connected double layer holds twice, on a sub-layer
@@ -590,6 +592,9 @@ def stack_matrix_shapes(cfg: ModelConfig, experts: bool) -> dict:
         out = {"wq": ((D, H * cfg.head_dim), D), "wk": ((D, kv), D),
                "wv": ((D, kv), D)}
     out["wo"] = ((cfg.attn_out_dim, D), cfg.attn_out_dim)
+    if cfg.is_sparse:  # the indexer's queries, its one key, head weights
+        HI, Di = cfg.index_n_heads, cfg.index_head_dim
+        out.update(wiq=((D, HI * Di), D), wik=((D, Di), D), ww=((D, HI), D))
     if cfg.sublayers > 1:
         out.update(wd_gate=((D, F), D), wd_up=((D, F), D), wd_down=((F, D), F))
         out = {name: ((cfg.sublayers, *shape), fan_in)
@@ -631,6 +636,9 @@ def _init_stacks(cfg: ModelConfig, key: jax.Array) -> Params:
         elif cfg.qk_norm:
             layers["q_norm"] = jnp.ones((n, cfg.head_dim), dtype)
             layers["k_norm"] = jnp.ones((n, cfg.head_dim), dtype)
+        if cfg.is_sparse:  # the LayerNorm on the indexer key
+            layers["ik_norm"] = jnp.ones((n, cfg.index_head_dim), dtype)
+            layers["ik_bias"] = jnp.zeros((n, cfg.index_head_dim), dtype)
         if experts and cfg.router_score_bias:
             # a buffer, zeros until a checkpoint brings a trained one
             layers["router_bias"] = jnp.zeros((n, cfg.router_width),
@@ -759,6 +767,70 @@ def _attention(q, k, v, mask):
     return out.reshape(B, S, H * Hd)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float) -> jax.Array:
+    orig_dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(orig_dtype)
+
+
+def _index_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    """Rotary (half-rotation layout) on the leading half of the indexer
+    dims, the rest as projected."""
+    r = cfg.index_head_dim // 2
+    return jnp.concatenate(
+        [apply_rope(x[..., :r], positions, cfg.rope_theta), x[..., r:]],
+        axis=-1)
+
+
+def indexer_proj(cfg: ModelConfig, layer: Params, x: jax.Array,
+                 positions: jax.Array):
+    """The sparse-attention indexer's projections of the pre-normed
+    input, traced under ``attn/indexer``: x [B, S, D] → (queries [B, S,
+    HI, Di], head weights [B, S, HI] float32 with ``HI^-1/2`` folded in,
+    the position's one key [B, S, Di] after its LayerNorm)."""
+    B, S, _ = x.shape
+    HI, Di = cfg.index_n_heads, cfg.index_head_dim
+    with jax.named_scope("attn"), jax.named_scope("indexer"):
+        h = rms_norm(x, layer["attn_norm"], cfg.rms_eps)
+        q = _index_rope(cfg, (h @ layer["wiq"]).reshape(B, S, HI, Di),
+                        positions)
+        k = layer_norm(h @ layer["wik"], layer["ik_norm"], layer["ik_bias"],
+                       cfg.rms_eps)
+        k = _index_rope(cfg, k[..., None, :], positions)[..., 0, :]
+        w = jnp.einsum("bsd,dh->bsh", h, layer["ww"],
+                       preferred_element_type=jnp.float32) * HI ** -0.5
+    return q, w, k
+
+
+def sparse_fresh_attention(cfg: ModelConfig, q, k, v, idx) -> jax.Array:
+    """Causal sparse attention of whole fresh sequences, written out: the
+    indexer over every earlier position, the exact top ``index_topk``,
+    the softmax over those alone → [B, S, H * Hd]."""
+    from fusioninfer_tpu.ops.sparse_attention import (
+        selection_mask,
+        sparse_threshold,
+    )
+
+    q_i, w, k_i = idx
+    B, S = q.shape[:2]
+    causal = causal_mask(S)[0, 0]  # [S, S]
+    with jax.named_scope("indexer"):
+        s = jnp.einsum("bthd,bsd->bths", q_i, k_i,
+                       preferred_element_type=jnp.float32)
+        s = jnp.maximum(s * cfg.index_head_dim ** -0.5, 0.0)
+        s = jnp.einsum("bths,bth->bts", s, w)
+        s = jnp.where(causal, jnp.where(s == 0, 0.0, s), -jnp.inf)
+    with jax.named_scope("select"):
+        flat = s.reshape(B * S, S)
+        keep = selection_mask(flat, *sparse_threshold(flat, cfg.index_topk))
+    with jax.named_scope("sparse"):
+        return _attention(q, k, v, (keep.reshape(B, S, S) & causal)[:, None])
+
+
 @jax.named_scope("attn_qkv")
 def qkv_proj(
     cfg: ModelConfig, layer: Params, x: jax.Array, positions: jax.Array,
@@ -847,7 +919,9 @@ def gqa_block(cfg: ModelConfig, layer: Params, x: jax.Array,
     the layer's STATIC ``kind`` (rotary or not here; the window where
     the caller attends).  They differ only in how the layer attends, so
     that is a callback: ``attend(q, k, v, carry) -> (carry, attention
-    output [B, S, H * Hd])`` (fresh causal attention in
+    output [B, S, H * Hd])``, with the indexer's projections
+    (:func:`indexer_proj`) as a fifth argument where ``cfg.is_sparse``
+    (fresh causal attention in
     :func:`layer_forward`; write, then score over the kind's pages in
     ``model_runner``), ``carry`` being the caller's own (the pool).
 
@@ -861,7 +935,11 @@ def gqa_block(cfg: ModelConfig, layer: Params, x: jax.Array,
                             layer.get("router_bias"))
     q, k, v = qkv_proj(cfg, layer, x, positions, lora, adapter_ids,
                        rope=kind.rope)
-    carry, attn = attend(q, k, v, carry)
+    if cfg.is_sparse:  # the indexer's projections go to ``attend`` too
+        carry, attn = attend(q, k, v, carry,
+                             indexer_proj(cfg, layer, x, positions))
+    else:
+        carry, attn = attend(q, k, v, carry)
     x = x + attn_out_proj(layer, attn, lora, adapter_ids)
     y, stats = mlp_block(cfg, layer, x, live, routing)
     return x + y, carry, stats
@@ -977,8 +1055,16 @@ def layer_forward(
     if kv is not None and mask is None:
         raise ValueError("layer_forward with kv history requires a mask")
 
-    def attend(q, k, v, fresh):
+    if cfg.is_sparse and kv is not None:
+        raise NotImplementedError(
+            "sparse attention reads its history from the paged cache "
+            "(model_runner); the no-cache forward runs fresh sequences")
+
+    def attend(q, k, v, fresh, idx=None):
         with jax.named_scope("attn"), jax.named_scope(attn_scope(kind)):
+            if idx is not None:  # kv: the position's K, V and indexer key
+                return (k, v, idx[2]), sparse_fresh_attention(
+                    cfg, q, k, v, idx)
             if kv is not None:
                 return (k, v), _attention(q, *kv, mask)
             from fusioninfer_tpu.ops import dispatch, flash_attention

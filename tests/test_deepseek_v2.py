@@ -667,7 +667,7 @@ def test_a_running_latent_engine_refuses_transfer_fabric_and_evacuation():
         latent_cache_refusal(tiny_cfg(), no_such_feature=True)
 
 
-# ---- the latent kernel compiles for the chip at published widths -----------
+# ---- the latent and sparse-attention kernels compile for the chip ----------
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -703,3 +703,48 @@ def test_latent_kernel_compiles_for_a_v5e_at_published_widths(one_chip):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sparse_attention_kernels_compile_for_a_v5e_at_published_widths(
+        one_chip):
+    """``keye-vl2-30b-a3b``'s three kernels (the indexer's scores over
+    paged indexer keys, the exact top-2048 selection, attention over the
+    chosen positions) in one mixed step of 2 048 flat tokens over a pool
+    of 8 x 65 536 positions: Mosaic refuses here what interpret mode
+    lets through (a slice off the tiling, too much VMEM)."""
+    from fusioninfer_tpu.ops import sparse_attention as sa
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    T, R, mp, ps, n_pages = 2048, 16, 512, 128, 8 * 512 + 1
+    HI, Di, KV, G, Hd, K = 16, 64, 4, 8, 128, 2048
+
+    def step(q_i, w, q, k_idx, kp, vp, tables, begins, lens, starts):
+        items = sa.sparse_items(begins, lens, starts, T, sa.SPARSE_BLOCK_Q)
+        N, bq = items.tok.shape
+        scores = sa.indexer_paged_scores(
+            jnp.moveaxis(sa.to_items(q_i, items), 2, 1),
+            sa.to_items(w, items), k_idx, tables, items, layer=1)
+        thr_s, thr_c = sa.sparse_select(scores, items, K)
+        qa = sa.to_items(q, items).reshape(N, bq, KV, G, Hd).transpose(
+            0, 2, 3, 1, 4).reshape(N, KV, G * bq, Hd)
+        return sa.sparse_paged_attention(
+            qa, kp, vp, scores, thr_s.reshape(N, bq), thr_c.reshape(N, bq),
+            tables, items, layer=1)
+
+    pool = (4, KV, n_pages, ps, Hd)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(step).lower(
+            sds((T, HI, Di), jnp.bfloat16), sds((T, HI), jnp.float32),
+            sds((T, KV * G, Hd), jnp.bfloat16),
+            sds((4, n_pages, ps, 128), jnp.bfloat16),
+            sds(pool, jnp.bfloat16), sds(pool, jnp.bfloat16), sds((R, mp)),
+            sds((R,)), sds((R,)), sds((R,))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    text = compiled.as_text()
+    for kernel in ("indexer_paged_scores", "sparse_select",
+                   "sparse_paged_attention"):
+        assert kernel in text, kernel

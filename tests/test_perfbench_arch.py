@@ -35,7 +35,8 @@ def config_of(name):
     ("longcat-flash-ep32", "longcat_flash"),
     ("longcat-flash-tiny-cpu", "longcat_flash"),
     ("smallthinker-21b-a3b", "smallthinker"),
-    ("smallthinker-tiny-cpu", "smallthinker")])
+    ("smallthinker-tiny-cpu", "smallthinker"),
+    ("keye-vl2-30b-a3b", "KeyeVL2"), ("keye-vl2-tiny-cpu", "KeyeVL2")])
 def test_a_configuration_resolves_to_its_architectures_file(work, config,
                                                             model_type):
     cfg = config_of(config)
@@ -54,7 +55,7 @@ def test_every_configuration_of_the_benchmark_resolves(work):
         bench = json.load(f)
     assert [c["name"] for c in bench["configs"]] == [
         "qwen3-1.7b", "deepseek-v2-ep4", "longcat-flash-ep32",
-        "smallthinker-21b-a3b"]
+        "smallthinker-21b-a3b", "keye-vl2-30b-a3b"]
     for entry in bench["configs"]:
         with open(os.path.join(ROOT, entry["file"])) as f:
             cfg = json.load(f)
@@ -201,6 +202,52 @@ def test_the_counts_of_the_tiny_smallthinker_configuration(work):
     assert mod.decode_window_kv_bytes(cfg, [10, 100]) == 52_224
 
 
+def test_the_counts_of_keye_vl2_30b_a3b(work):
+    """Stage 0 of 12: a layer's attention of 18 874 368
+    (wq, wk, wv, wo at 32 / 4 heads of 128), its indexer of 2048 x (16 x
+    64 + 64 + 16), its router of 2048 x 128 and the 8 chosen experts of 3 x
+    2048 x 768, four layers, the untied head at 151 936.  The indexer
+    reads the whole context (16 heads x (2 x 64 + 2) FLOPs a position), the
+    attention min(context, 2048) positions; a position caches 2 x 4 x 128
+    values and one 64-wide indexer key a layer."""
+    cfg = config_of("keye-vl2-30b-a3b")
+    mod = work.load_arch(work.arch_path(cfg))
+    layer = 18_874_368 + 2048 * 1104 + 2048 * 128 + 8 * 3 * 2048 * 768
+    assert layer == 59_146_240
+    assert work.matmul_params(cfg) == 4 * layer + 2048 * 151_936 == 547_749_888
+    index, attend = 16 * 130, 4 * 32 * 128  # a position, one layer
+    assert work.token_flops(cfg, 1000) == (
+        2 * 547_749_888 + 4 * (index + attend) * 1000) == 1_169_355_776
+    assert work.token_flops(cfg, 40_000) == (
+        2 * 547_749_888 + 4 * (index * 40_000 + attend * 2048))
+    assert work.token_flops(cfg, 1000, with_head=False) == (
+        1_169_355_776 - 2 * 2048 * 151_936)
+    n = 4096
+    chosen = 2048 * 2049 / 2 + (n - 2048) * 2048
+    assert work.prompt_flops(cfg, n) == (
+        2 * 4 * layer * n + 2 * 2048 * 151_936
+        + 4 * index * n * (n + 1) / 2 + 4 * attend * chosen)
+    assert work.kv_bytes_per_position(cfg) == 4 * (2 * 4 * 128 + 64) * 2
+    assert work.decode_kv_bytes(cfg, [1000, 40_000]) == (
+        4 * 64 * 2 * 41_000 + 4 * 2048 * (1000 + 2048))
+    assert mod.indexer_bytes(cfg, [1000]) == 4 * 64 * 2 * 1000
+    assert mod.sparse_attn_bytes(cfg, [40_000]) == 4 * 2048 * 2048
+    assert mod.indexer_flops(cfg, [10, 20]) == 4 * index * 30
+    assert mod.prompt_indexer_flops(cfg, 100) == 4 * index * 5050
+
+
+def test_the_counts_of_the_tiny_keye_vl2_configuration(work):
+    cfg = config_of("keye-vl2-tiny-cpu")
+    layer = (128 * 8 * 32 + 4 * 32 * 128 + 128 * (4 * 16 + 16 + 4)
+             + 128 * 8 + 2 * 3 * 128 * 64)
+    assert work.matmul_params(cfg) == 2 * layer + 128 * 512 == 285_696
+    assert work.token_flops(cfg, 10) == (
+        2 * 285_696 + 2 * (4 * 34 + 4 * 4 * 32) * 10)
+    assert work.token_flops(cfg, 100) == (
+        2 * 285_696 + 2 * (4 * 34 * 100 + 4 * 4 * 32 * 24))
+    assert work.kv_bytes_per_position(cfg) == 2 * (2 * 2 * 32 + 16) * 2
+
+
 class _Record:
     def __init__(self, prompt_len, stamps):
         self.prompt_len, self.stamps = prompt_len, stamps
@@ -265,6 +312,53 @@ def test_the_two_new_readers_on_a_recorded_run(work):
     assert _reader("attn_window_decode_roofline")(odd) is None
 
 
+def test_the_sparse_attention_readers_on_a_recorded_run(work):
+    cfg = config_of("keye-vl2-30b-a3b")
+    mod = work.load_arch(work.arch_path(cfg))
+    ops = {"jit_decode_burst/indexer_paged_scores": 0.004,
+           "jit_fused_step/indexer_paged_scores": 0.2,
+           "jit_decode_burst/sparse_paged_attention": 0.01,
+           "jit_fused_step/sparse_paged_attention": 0.3,
+           "jit_prefill/sparse_paged_attention": 5.0,
+           "jit_decode_burst/sparse_select": 0.02,
+           "jit_fused_step/sparse_select": 0.08}
+    counters = {"fusioninfer:dsa_positions_scored_total": 4.0e9,
+                "fusioninfer:dsa_positions_selected_total": 2.4e8}
+    run = _Run(cfg, ops, counters)
+    assert _reader("dsa_selected_share_pct")(run) == pytest.approx(6.0)
+    # three output tokens at 8001..8003 and no first token in the window:
+    # the indexer over their contexts, FLOP-bound at the bf16 peak,
+    # against 204 ms of the kernel a traced second (every program)
+    contexts = [8001, 8002, 8003]
+    least = max(mod.indexer_bytes(cfg, contexts) / 819e9,
+                mod.indexer_flops(cfg, contexts) / 197e12) / 2.0
+    assert _reader("dsa_indexer_roofline")(run) == pytest.approx(
+        100.0 * least / 0.204)
+    # a first token inside the window adds its prompt's causal triangle
+    # of FLOPs and its keys, read once
+    run.records.append(_Record(1000, [10.5, 11.0]))
+    least = max(mod.indexer_bytes(cfg, contexts + [1001, 1000])
+                / 819e9, (mod.indexer_flops(cfg, contexts + [1001])
+                          + mod.prompt_indexer_flops(cfg, 1000)) / 197e12) / 2.0
+    assert _reader("dsa_indexer_roofline")(run) == pytest.approx(
+        100.0 * least / 0.204)
+    # the chosen K/V rows of the output tokens, against the kernel in the
+    # two decode programs
+    contexts += [1001]
+    assert _reader("dsa_sparse_attn_roofline")(run) == pytest.approx(
+        100.0 * mod.sparse_attn_bytes(cfg, contexts) / 819e9 / 2.0 / 0.31)
+    # every scored position's float32 score read once, against the
+    # selection kernel in every program
+    assert _reader("dsa_select_roofline")(run) == pytest.approx(
+        100.0 * 4.0e9 * 4 / 819e9 / 2.0 / 0.1)
+    # a program without sparse attention (the parent commit under this
+    # benchmark): nothing to read, and no exception
+    old = _Run(cfg, {"jit_fused_step/ragged_paged_attention": 0.1}, {})
+    for name in ("dsa_selected_share_pct", "dsa_indexer_roofline",
+                 "dsa_sparse_attn_roofline", "dsa_select_roofline"):
+        assert _reader(name)(old) is None, name
+
+
 def test_reading_the_counts_imports_neither_jax_nor_the_program():
     code = (
         "import json, sys\n"
@@ -272,7 +366,7 @@ def test_reading_the_counts_imports_neither_jax_nor_the_program():
         "import work\n"
         "bad = []\n"
         "for name in ('qwen3-1.7b', 'deepseek-v2-ep4', 'longcat-flash-ep32', "
-        "'smallthinker-21b-a3b'):\n"
+        "'smallthinker-21b-a3b', 'keye-vl2-30b-a3b'):\n"
         f"    cfg = json.load(open({os.path.join(BENCH, 'configs')!r} + '/' + name + '.json'))\n"
         "    assert work.prompt_flops(cfg, 8) > 0\n"
         "bad = [m for m in sys.modules if m.split('.')[0] in "

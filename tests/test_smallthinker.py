@@ -413,6 +413,9 @@ PARENT_WEIGHTS = {
     "moe-tiny": "382c77e017973ff2",
     "deepseek-v2-tiny": "12b3844898a3bc9a",
     "longcat-flash-tiny": "da6eb1f77eea9a6d",
+    # read on the parent commit 820a192 (the layer-pattern recipe, whose
+    # draw the indexer's slots leave as it was)
+    "smallthinker-tiny": "27b864e2d5498b17",
 }
 
 
@@ -451,6 +454,12 @@ PARENT_LOWERED = {
                    "c4c8d47dca3ec2f3", "8e3211b8d3029993"),
     "qwen3-tiny+flash": ("5fd27836213f138b", "8100d9bdc3f55148",
                          "a8ccb423f57c6637", "0718bd7217a5c46f"),
+    # a cache kept by layer kind (its pools sized by auto_cache_config),
+    # read on the parent commit 820a192 before the sparse-attention path
+    "smallthinker-tiny": ("d1fc5a37b9dfdf1a", "019d13e265268762",
+                          "2744284bd0079d31", "62234242bd577cdb"),
+    "smallthinker-tiny+flash": ("71cc221e4feab165", "c262f50a4a4edb21",
+                                "e353c1744a231185", "84f90a542b30f8e4"),
 }
 PROGRAMS = ("prefill/b32r2", "fused/decode-t16", "fused/mixed-hidden-t64",
             "burst/s8-greedy")
@@ -464,9 +473,12 @@ def test_the_served_programs_lower_to_the_parents_text(variant):
     cfg = get_preset(variant.split("+")[0])
     if variant.endswith("+flash"):
         cfg = dataclasses.replace(cfg, attn_impl="flash")
-    eng = NativeEngine(
-        cfg, CacheConfig(n_pages=33, page_size=16, max_pages_per_seq=8),
-        max_batch_size=4, token_budget=64, decode_burst_steps=8)
+    cc = (auto_cache_config(cfg, page_size=16, max_model_len=128,
+                            max_batch_size=4, step_span=64)
+          if cfg.cache_by_kind else
+          CacheConfig(n_pages=33, page_size=16, max_pages_per_seq=8))
+    eng = NativeEngine(cfg, cc, max_batch_size=4, token_budget=64,
+                       decode_burst_steps=8)
     lower = dict(eng.aot_signatures())
     got = tuple(hashlib.sha256(lower[p]().as_text().encode()).hexdigest()[:16]
                 for p in PROGRAMS)

@@ -153,6 +153,21 @@ def metrics(srv) -> dict:
     return parse(srv.metrics.render(srv.engine))
 
 
+def settled(srv, before: dict, streamed: int) -> dict:
+    """The metrics once the stream handlers have counted the ``streamed``
+    token chunks a client has read since ``before``: a handler records a
+    write after the write returns, so its client can finish reading
+    first (a bounded wait)."""
+    family = "fusioninfer:stream_chunks_total"
+    deadline = time.monotonic() + 10.0
+    while True:
+        now = metrics(srv)
+        if (now[family] - before[family] >= streamed
+                or time.monotonic() > deadline):
+            return now
+        time.sleep(0.005)
+
+
 @pytest.fixture(scope="module")
 def served():
     """A tiny engine served for a few dozen steps, then stopped: the
@@ -168,7 +183,7 @@ def served():
             first = parse(r.read().decode())
         streamed = sum(stream(srv, "span %d " % i * (2 + i), 12)
                        for i in range(3))
-        mid = metrics(srv)
+        mid = settled(srv, first, streamed)
         complete(srv, "one more", 8)
     finally:
         srv.stop()
@@ -245,7 +260,7 @@ def test_a_burst_streams_in_fewer_writes_than_chunks():
     try:
         before = metrics(srv)
         streamed = stream(srv, "a burst ", 12)
-        after = metrics(srv)
+        after = settled(srv, before, streamed)
     finally:
         srv.stop()
     chunks, writes, delays = (
@@ -744,3 +759,66 @@ def test_pages_by_kind_are_rendered_and_counted():
         assert ('fusioninfer:kv_pages_allocated_total{model_name="m",'
                 f'kind="{kind}"}} 10') in page
     assert got["vllm:kv_cache_usage_perc"] == 0.0
+
+
+@pytest.mark.parametrize("impl", ["reference", "flash"])
+def test_sparse_attention_has_its_scopes_and_counters(impl):
+    """A sparse-attention layer's three steps are traced under
+    ``attn/indexer``, ``attn/select`` and ``attn/sparse`` (device time by
+    named scope reads each), the kernels carry their own names
+    (``indexer_paged_scores``, ``sparse_paged_attention``), and what the
+    indexer scored and the attention chose is counted on the device and
+    rendered as ``fusioninfer:dsa_positions_{scored,selected}_total``; a
+    dense model traces none of it and renders zeros."""
+    import dataclasses
+
+    from fusioninfer_tpu.engine import model_runner as mr
+    from fusioninfer_tpu.engine.engine import Request
+    from fusioninfer_tpu.engine.kv_cache import init_kv_cache
+    from fusioninfer_tpu.engine.metrics import EngineMetrics
+    from fusioninfer_tpu.engine.sampler import SamplingParams
+    from fusioninfer_tpu.models import transformer as tf
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    def lowered(cfg, debug_info=True):
+        params = jax.eval_shape(lambda: tf.init_params(cfg, jax.random.key(0)))
+        cache = jax.eval_shape(lambda: init_kv_cache(cfg, CACHE))
+        return mr.fused_step.lower(
+            cfg, CACHE, params, cache, i32(16), i32(8), i32(8), i32(8),
+            i32(8, CACHE.max_pages_per_seq), i32(2, 1), i32(2),
+            coalesce=True, kv_splits=0).as_text(debug_info=debug_info)
+
+    cfg = dataclasses.replace(get_preset("keye-vl2-tiny"), attn_impl=impl)
+    text = lowered(cfg)
+    for scope in ("attn/indexer", "attn/select", "attn/sparse", "kv_write",
+                  "moe_experts"):
+        assert re.search(r'loc\("([a-z_]+/)*%s[/"]' % scope, text), scope
+    if impl == "flash":
+        assert "indexer_paged_scores" in text
+        assert "sparse_select" in text
+        assert "sparse_paged_attention" in text
+    dense = lowered(dataclasses.replace(CFG, attn_impl=impl), False)
+    assert "indexer_paged_scores" not in dense
+    assert "sparse_paged_attention" not in dense and "k_idx" not in dense
+
+    eng = NativeEngine(cfg, cache_cfg=CACHE, max_batch_size=2, seed=0,
+                       token_budget=16)
+    page = EngineMetrics("m").render(eng)
+    for name in ("scored", "selected"):
+        assert f"# TYPE fusioninfer:dsa_positions_{name}_total counter" in page
+        assert parse(page)[f"fusioninfer:dsa_positions_{name}_total"] == 0
+    eng.add_request(Request("a", [1] + list(range(3, 60)),
+                            SamplingParams(max_tokens=4, temperature=0.0)))
+    while eng.has_work():
+        eng.step()
+    eng._drain_dsa_stats()
+    after = parse(EngineMetrics("m").render(eng))
+    scored = after["fusioninfer:dsa_positions_scored_total"]
+    # 58 prompt positions and 3 decoded ones, two layers, top 24
+    assert scored >= 2 * sum(range(1, 62))
+    assert 0 < after["fusioninfer:dsa_positions_selected_total"] < scored
+    plain = NativeEngine(CFG, cache_cfg=CACHE, max_batch_size=2, seed=0)
+    assert parse(EngineMetrics("m").render(plain))[
+        "fusioninfer:dsa_positions_scored_total"] == 0
